@@ -78,8 +78,14 @@ def main(argv) -> None:
     p /= p.sum(axis=1, keepdims=True)
     loss = float(-(y * np.log(p + 1e-12)).sum(axis=1).mean())
 
+    # Outside the timed window: name the platform this rank's JAX sees,
+    # so the spawning bench can hold every child to the CPU (one process
+    # per chip — the parent has it).
+    import jax
+
     print(f"NATIVE_LR_OK rank={rank} dt={dt:.6f} steps={steps} "
-          f"batch={batch} loss={loss:.6f} codec={codec}", flush=True)
+          f"batch={batch} loss={loss:.6f} codec={codec} "
+          f"platform={jax.devices()[0].platform}", flush=True)
     rt.shutdown()
 
 

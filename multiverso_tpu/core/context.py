@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
-from .. import config, dashboard, metrics, tracing
+from .. import compile_cache, config, dashboard, metrics, tracing
 from ..log import Log
 
 __all__ = [
@@ -236,15 +236,16 @@ def init(args: Optional[List[str]] = None,
 
         log_configure(config.get("log_level"), config.get("log_file"))
 
-        if distributed:
+        compile_cache.configure()
+
+        if distributed and not jax.distributed.is_initialized():
             # Multi-host bring-up (DCN): the reference's NetInterface::Init +
             # Control_Register handshake collapses into this one call. Must
             # run before anything touches the backend (so no process_count()
-            # guard here); tolerate an environment that already initialized.
-            try:
-                jax.distributed.initialize(**distributed_kwargs)
-            except RuntimeError as e:
-                Log.info("jax.distributed.initialize skipped: %s", e)
+            # guard here).  An environment that already initialized is the
+            # only thing tolerated: a failure raises, because N ranks that
+            # carried on would each train alone.
+            jax.distributed.initialize(**distributed_kwargs)
 
         if mesh is None:
             mesh = _default_mesh()

@@ -55,15 +55,21 @@ def lib_path() -> str:
     return _LIB
 
 
-def ensure_built(quiet: bool = True) -> str:
-    """Build libmvtpu.so if missing; returns its path."""
-    if not os.path.exists(_LIB):
-        subprocess.run(
-            ["make", "-C", _DIR, "-j", str(os.cpu_count() or 2),
-             f"{os.path.join('build', 'libmvtpu.so')}"],
-            check=True,
-            stdout=subprocess.DEVNULL if quiet else None,
-            stderr=subprocess.STDOUT if quiet else None)
+def ensure_built() -> str:
+    """Bring libmvtpu.so up to date with the sources; returns its path.
+
+    Always runs ``make`` (a no-op when fresh) rather than building only
+    when the file is missing: ``build/`` is ignored by git, so a copied
+    working tree can carry a library older than the sources beside it.
+    A failed build raises with the compiler's output."""
+    proc = subprocess.run(
+        ["make", "-C", _DIR, "-j", str(os.cpu_count() or 2),
+         os.path.join("build", "libmvtpu.so")],
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"native build failed (make rc={proc.returncode}):\n"
+            f"{proc.stdout[-4000:]}")
     return _LIB
 
 

@@ -170,20 +170,23 @@ bool KernelSupportsOp(uint8_t op, std::string* reason) {
       *reason = std::string("io_uring_setup: ") + ::strerror(errno);
     return false;
   }
-  struct {
-    io_uring_probe probe;
-    io_uring_probe_op ops[64];
-  } pb;
-  std::memset(&pb, 0, sizeof(pb));
-  int rc = UringRegister(fd, IORING_REGISTER_PROBE, &pb, 64);
+  // io_uring_probe ends in a flexible array member, so it cannot be a
+  // non-final struct field (g++ 12 rejects that): carve the header and
+  // its kProbeOps trailing entries out of one aligned byte buffer.
+  constexpr unsigned kProbeOps = 64;
+  alignas(io_uring_probe) unsigned char
+      buf[sizeof(io_uring_probe) + kProbeOps * sizeof(io_uring_probe_op)];
+  std::memset(buf, 0, sizeof(buf));
+  auto* probe = reinterpret_cast<io_uring_probe*>(buf);
+  int rc = UringRegister(fd, IORING_REGISTER_PROBE, probe, kProbeOps);
   ::close(fd);
   if (rc < 0) {
     if (reason)
       *reason = std::string("IORING_REGISTER_PROBE: ") + ::strerror(errno);
     return false;
   }
-  if (op >= pb.probe.ops_len ||
-      !(pb.ops[op].flags & kProbeOpSupported)) {
+  if (op >= kProbeOps || op >= probe->ops_len ||
+      !(probe->ops[op].flags & kProbeOpSupported)) {
     if (reason)
       *reason = "kernel lacks io_uring opcode " + std::to_string(op);
     return false;

@@ -283,8 +283,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
     # Remat seam: under jax.checkpoint the partial-eval inlines this fwd
     # rule, so naming the kernel outputs lets a policy SAVE them — the
     # backward then feeds the dq/dkv kernels directly instead of
-    # replaying the forward kernel to regenerate its residuals (the
-    # ~12% remat tax measured in BENCH_r04).  models/transformer.py's
+    # replaying the forward kernel to regenerate its residuals (a ~12%
+    # remat tax: pre-round figure, record removed in PR 21 — a claim to
+    # re-measure).  models/transformer.py's
     # "dots" policy saves both names; costs one o-sized buffer per
     # layer (lse is ~D× smaller).
     o = checkpoint_name(o, "flash_out")

@@ -25,7 +25,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from multiverso_tpu.parallel._compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["gpipe", "stage_pspec"]
@@ -126,5 +125,5 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
             axis_name)
         return outs
 
-    return shard_map(local, mesh=mesh, in_specs=(p_spec, x_spec),
-                     out_specs=x_spec, check_vma=False)(stage_params, x)
+    return jax.shard_map(local, mesh=mesh, in_specs=(p_spec, x_spec),
+                         out_specs=x_spec, check_vma=False)(stage_params, x)

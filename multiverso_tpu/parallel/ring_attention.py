@@ -19,13 +19,16 @@ collective-permute with the block compute on TPU.
 
 from __future__ import annotations
 
-from typing import Optional
+import os
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from multiverso_tpu.parallel._compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+from .. import metrics
+from ..log import Log
 
 __all__ = ["ring_attention", "blockwise_attention_local"]
 
@@ -74,34 +77,60 @@ def _flash_block(t: int, cap: int, head_dim: int) -> int:
     return b if b >= 64 else 0
 
 
+def _flash_dispatch(tq: int, tk: int,
+                    head_dim: int) -> Optional[Tuple[int, int, bool]]:
+    """``(block_q, block_k, interpret)`` for the Pallas flash kernel, or
+    ``None`` for the jnp streaming path.
+
+    The ONE place the kernel-or-reference decision is taken.  It is taken
+    at trace time, so a compiled step holds whichever body this returned
+    and never switches; every decision is therefore counted in
+    ``attention.traced{path=mosaic|interpret|jnp}`` (``metrics``), and a
+    TPU trace that lands on the O(T²) jnp body is logged with its shapes.
+
+    - TPU backend, blocks fit: the compiled Mosaic kernel.
+    - ``MVTPU_FORCE_FLASH`` (any non-empty value) off-TPU: the same
+      kernel in interpret mode, so CI covers this exact dispatch.
+    - ``MVTPU_NO_FLASH``, or no block ≥64 divides the sequence: jnp.
+    """
+    bq = _flash_block(tq, cap=512, head_dim=head_dim)
+    bk = _flash_block(tk, cap=1024, head_dim=head_dim)
+    on_tpu = jax.default_backend() == "tpu"
+    wanted = ((on_tpu or os.environ.get("MVTPU_FORCE_FLASH"))
+              and not os.environ.get("MVTPU_NO_FLASH"))
+    if bq and bk and wanted:
+        path = "mosaic" if on_tpu else "interpret"
+    else:
+        path = "jnp"
+        if on_tpu:
+            Log.info("attention Tq=%d Tk=%d D=%d traced on the O(T^2) jnp "
+                     "path (flash blocks %d/%d, MVTPU_NO_FLASH=%r)",
+                     tq, tk, head_dim, bq, bk,
+                     os.environ.get("MVTPU_NO_FLASH", ""))
+    metrics.counter("attention.traced", {"path": path}).inc()
+    return None if path == "jnp" else (bq, bk, not on_tpu)
+
+
 def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
                               q_offset: int = 0, k_offset: int = 0):
     """Single-device attention (the ring's degenerate case).
 
-    On TPU backends with aligned shapes this dispatches to the Pallas
-    flash kernel (``ops/flash_attention.py``) — O(T) memory, causal-block
-    skipping, differentiable via its custom_vjp; elsewhere (CPU tests,
-    odd shapes, offset blocks) the jnp streaming-softmax path runs and
-    XLA fuses it.  Setting ``MVTPU_FORCE_FLASH`` (any non-empty value)
-    forces the kernel on any backend — in interpret mode off-TPU, so CI
-    covers this exact dispatch; ``MVTPU_NO_FLASH`` disables it.
+    Aligned shapes dispatch to the Pallas flash kernel
+    (``ops/flash_attention.py``) — O(T) memory, causal-block skipping,
+    differentiable via its custom_vjp — as ``_flash_dispatch`` decides;
+    offset blocks and whatever it declines run the jnp streaming-softmax
+    path, which XLA fuses.
     """
-    import os
-
     B, H, T, D = q.shape
-    bq = _flash_block(T, cap=512, head_dim=D)
-    bk = _flash_block(T, cap=1024, head_dim=D)
-    on_tpu = jax.default_backend() == "tpu"
-    force = os.environ.get("MVTPU_FORCE_FLASH", "")
-    use_flash = (q_offset == 0 and k_offset == 0 and T == k.shape[2]
-                 and bq and bk and not os.environ.get("MVTPU_NO_FLASH")
-                 and (on_tpu or force))
-    if use_flash:
+    flash = None
+    if q_offset == 0 and k_offset == 0 and T == k.shape[2]:
+        flash = _flash_dispatch(T, T, D)
+    if flash is not None:
         from ..ops import flash_attention
 
+        bq, bk, interpret = flash
         return flash_attention(q, k, v, scale=scale, causal=causal,
-                               block_q=bq, block_k=bk,
-                               interpret=not on_tpu)
+                               block_q=bq, block_k=bk, interpret=interpret)
     o = jnp.zeros(q.shape, jnp.float32)
     m = jnp.full((B, H, T, 1), _NEG, jnp.float32)
     l = jnp.zeros((B, H, T, 1), jnp.float32)
@@ -118,26 +147,20 @@ def _attn_piece(q, k, v, scale, causal: bool):
     compose across ring steps: ``lse' = logaddexp(lse1, lse2); o' =
     o1·e^{lse1-lse'} + o2·e^{lse2-lse'}`` — so each ring step can run the
     Pallas flash kernel at full kernel speed and the combination stays
-    pure jnp (fused by XLA).  On non-TPU backends (unless
-    ``MVTPU_FORCE_FLASH``) the jnp streaming path computes the same pair.
-    ``causal=True`` requires Tq == Tk (aligned diagonal), matching the
-    kernel's contract.
+    pure jnp (fused by XLA).  Where ``_flash_dispatch`` declines, the jnp
+    streaming path computes the same pair.  ``causal=True`` requires
+    Tq == Tk (aligned diagonal), matching the kernel's contract.
     """
-    import os
-
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    bq = _flash_block(Tq, cap=512, head_dim=D)
-    bk = _flash_block(Tk, cap=1024, head_dim=D)
-    on_tpu = jax.default_backend() == "tpu"
-    force = os.environ.get("MVTPU_FORCE_FLASH", "")
-    if (bq and bk and not os.environ.get("MVTPU_NO_FLASH")
-            and (on_tpu or force)):
+    flash = _flash_dispatch(Tq, Tk, D)
+    if flash is not None:
         from ..ops import flash_attention
 
+        bq, bk, interpret = flash
         return flash_attention(q, k, v, scale=scale, causal=causal,
-                               block_q=bq, block_k=bk,
-                               interpret=not on_tpu, return_lse=True)
+                               block_q=bq, block_k=bk, interpret=interpret,
+                               return_lse=True)
     o = jnp.zeros(q.shape, jnp.float32)
     m = jnp.full((B, H, Tq, 1), _NEG, jnp.float32)
     l = jnp.zeros((B, H, Tq, 1), jnp.float32)
@@ -187,14 +210,21 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if mesh.size == 1:
+        return blockwise_attention_local(q, k, v, scale, causal)
+    # Every multi-device mesh goes through shard_map (all axes manual),
+    # ring or not: a Mosaic kernel under plain jit on >1 device fails to
+    # lower ("cannot be automatically partitioned").
     axes = dict(mesh.shape)
     sp = int(axes.get(axis_name, 1))
     b_ax = batch_axis if (batch_axis and batch_axis in axes) else None
+    if b_ax and q.shape[0] % axes[b_ax]:
+        Log.info("ring_attention: batch %d does not divide mesh axis '%s' "
+                 "(%d): attention REPLICATED over it, not sharded",
+                 q.shape[0], b_ax, axes[b_ax])
+        b_ax = None
     h_ax = head_axis if (head_axis and head_axis in axes) else None
     spec = P(b_ax, h_ax, axis_name if sp > 1 else None, None)
-
-    if sp == 1 and b_ax is None and h_ax is None:
-        return blockwise_attention_local(q, k, v, scale, causal)
 
     if layout not in ("auto", "zigzag", "contiguous"):
         raise ValueError(
@@ -317,8 +347,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
         return o_acc.astype(q_l.dtype)
 
     local = local_zigzag if use_zigzag else local_contiguous
-    out = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_vma=False)(q, k, v)
+    out = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec, check_vma=False)(q, k, v)
     if use_zigzag:
         out = jnp.take(out, inv_perm, axis=2)
     return out

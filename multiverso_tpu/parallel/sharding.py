@@ -69,19 +69,32 @@ def batch_placer(mesh: Mesh, batch_axis: str = "worker", dtype=None):
     """Resolve the data-parallel axis and build a batch-placing closure.
 
     Shared by the apps' fused steps: dim 0 of each input shards over the
-    mesh's ``batch_axis`` (falling back to the mesh's first axis); a batch
-    whose leading dim isn't divisible by the axis size is replicated instead
-    (correct, just unsharded).  Returns ``(axis_name, place)``.
+    mesh's ``batch_axis`` (falling back to the mesh's first axis).  A host
+    batch goes to its shards in ONE ``device_put`` — never through device
+    0 first.  A batch whose leading dim isn't divisible by the axis size
+    is replicated instead (correct, just unsharded: every device then
+    computes the whole batch), which is logged once per placer with the
+    shapes.  Returns ``(axis_name, place)``.
     """
-    import jax.numpy as jnp
+    from ..log import Log
 
     axis = batch_axis if batch_axis in mesh.shape else list(mesh.shape)[0]
     n = int(mesh.shape[axis])
     rep = replicated(mesh)
+    warned = False
 
     def place(a):
-        a = jnp.asarray(a) if dtype is None else jnp.asarray(a, dtype)
+        nonlocal warned
+        if isinstance(a, jax.Array):
+            a = a if dtype is None else a.astype(dtype)
+        else:
+            a = np.asarray(a, dtype)
         if a.shape[0] % n:
+            if not warned:
+                warned = True
+                Log.info("batch_placer: batch %s does not divide mesh axis "
+                         "'%s' (%d): REPLICATED on every device, not "
+                         "sharded", tuple(a.shape), axis, n)
             return jax.device_put(a, rep)
         return jax.device_put(a, shard_along(mesh, a.ndim, 0, axis))
 

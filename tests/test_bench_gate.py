@@ -2,10 +2,10 @@
 ``make bench-gate`` / tools/bench_compare.py).
 
 Three jobs: the committed BENCH_BASELINE.json must parse and run green
-against the newest committed bench line; a seeded regression must fail
+against a line carrying its own values; a seeded regression must fail
 loudly (the gate demonstrably fires); and the line-extraction must
-survive the messy real formats (driver wrappers, partial lines, the
-r05-style unparseable file)."""
+survive the messy real formats (driver wrappers, partial lines, a
+killed run's unparseable file)."""
 
 import json
 import os
@@ -36,13 +36,27 @@ def test_baseline_parses_and_names_real_keys():
         assert "band_rel" in spec or "band_abs" in spec, key
 
 
-def test_gate_green_against_committed_bench_line():
-    """`make bench-gate` with no arguments: the newest parseable
-    BENCH_r*.json must sit inside every band it measures (missing keys
-    skip — sections are individually best-effort)."""
-    rc, out = _gate()
+def test_gate_green_against_baseline_own_values(tmp_path):
+    """A line carrying every baseline key at its committed value sits
+    inside every band: the baseline is self-consistent and the gate's
+    green path runs over all of its keys."""
+    with open(os.path.join(REPO, "BENCH_BASELINE.json")) as fh:
+        keys = json.load(fh)["keys"]
+    p = tmp_path / "baseline_values.json"
+    p.write_text(json.dumps(
+        {"extras": {k: spec["value"] for k, spec in keys.items()}}) + "\n")
+    rc, out = _gate("--line", str(p))
     assert rc == 0, out
+    assert f"{len(keys)} key(s) in band" in out, out
     assert "0 regression(s)" in out, out
+
+
+def test_gate_without_a_line_says_so():
+    """No bench record is committed: with no --line there is nothing to
+    gate, and the gate says how to name one instead of passing."""
+    rc, out = _gate()
+    assert rc == 2, out
+    assert "--line" in out and "LINE=" in out, out
 
 
 def test_gate_fails_on_seeded_regression(tmp_path):

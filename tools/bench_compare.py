@@ -8,14 +8,13 @@ exits nonzero on an out-of-band regression — so a perf PR that silently
 regresses an earlier tentpole (serve p50 after a codec change, wire RTT
 after a socket-option slip, MFU after a remat tweak) fails loudly.
 
-Sources, in precedence order:
-
-- ``--line PATH``: a file whose LAST parseable JSON object carries the
-  bench ``extras`` (a raw ``bench.py`` stdout capture works), or a
-  ``BENCH_r*.json`` driver wrapper (the ``parsed``/``tail`` form);
-  ``-`` reads stdin.
-- default: the newest ``BENCH_r*.json`` in the repo root that yields a
-  parseable line (r05's rc=124 null-parse is skipped, not fatal).
+The line to gate is always named: ``--line PATH`` is a file whose LAST
+parseable JSON object carries the bench ``extras`` (a raw ``bench.py``
+stdout capture works, so does a driver wrapper in the ``parsed``/``tail``
+form); ``-`` reads stdin.  The repo commits no bench record to fall back
+on — with no ``--line`` the gate says so and exits 2
+(``python bench.py wire_micro > line.json; make bench-gate
+LINE=line.json``).
 
 Baseline format (``BENCH_BASELINE.json``)::
 
@@ -40,7 +39,6 @@ line/baseline.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -87,17 +85,6 @@ def load_line(path):
         return _extras_from_text(text)
 
 
-def newest_bench_line():
-    """Newest BENCH_r*.json that actually parses to a bench line."""
-    paths = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")),
-                   reverse=True)
-    for p in paths:
-        extras = load_line(p)
-        if extras:
-            return p, extras
-    return None, None
-
-
 def check(extras, baseline, strict=False):
     """Returns (failures, skipped, checked) finding lists."""
     failures, skipped, checked = [], [], []
@@ -131,8 +118,7 @@ def check(extras, baseline, strict=False):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--line", default=None,
-                    help="bench output file ('-' = stdin); default: the "
-                         "newest parseable BENCH_r*.json")
+                    help="bench output file to gate ('-' = stdin)")
     ap.add_argument("--baseline",
                     default=os.path.join(REPO, "BENCH_BASELINE.json"))
     ap.add_argument("--strict", action="store_true",
@@ -147,12 +133,15 @@ def main(argv=None):
               file=sys.stderr)
         return 2
 
-    if args.line:
-        src, extras = args.line, load_line(args.line)
-    else:
-        src, extras = newest_bench_line()
+    if not args.line:
+        print("bench-gate: no --line given, nothing gated.  Capture one "
+              "(`python bench.py wire_micro > line.json`) and pass it: "
+              "`make bench-gate LINE=line.json`", file=sys.stderr)
+        return 2
+    src, extras = args.line, load_line(args.line)
     if not extras:
-        print("bench-gate: no parseable bench line found", file=sys.stderr)
+        print(f"bench-gate: no parseable bench line in {src}",
+              file=sys.stderr)
         return 2
 
     failures, skipped, checked = check(extras, baseline,

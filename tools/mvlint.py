@@ -206,7 +206,11 @@ import os
 import re
 import sys
 
-SKIP_DIRS = {".git", "build", "__pycache__", ".claude", "node_modules"}
+# chiprun_stage/ is the unpacked `git archive` the chip smoke is proven
+# from (a second copy of the tree, ignored by git), chiprun_out/ what the
+# chip tool brings back.
+SKIP_DIRS = {".git", "build", "__pycache__", ".claude", "node_modules",
+             "chiprun_stage", "chiprun_out", ".jax_cache"}
 
 # Helpers that wrap numpy buffers into ctypes pointers (native binding).
 PTR_HELPERS = {"_fp", "_ip"}
