@@ -1,0 +1,18 @@
+"""``kernel.flash_dq_roofline``: the flash backward dq kernel's share of its
+compute roofline: a third of the causal attention a step requires at the
+bf16 peak, over the time in the Mosaic call named ``flash_bwd_dq``
+(``ops/flash_attention.py``)."""
+
+from benchmarks.trace import program
+
+NAME = "kernel.flash_dq_roofline"
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "device_trace"
+LAYER = "kernels"
+MOVES = "tokens_per_chip_s"
+APPLIES = {"runner": "lm_train"}
+
+
+def read(reading):
+    return program.kernel_roofline(reading, "flash_bwd_dq")

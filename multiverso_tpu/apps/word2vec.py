@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core import context as core_context
 from ..tables import MatrixTable
 from ..updaters import AddOption
@@ -172,11 +173,13 @@ class SkipGram:
         @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def step(din, sin, dout, sout, c, o, neg):
             B, K = neg.shape
-            vc = din[c]
-            uo = dout[o]
-            un = dout[neg.reshape(-1)].reshape(B, K, D)
-            loss, grads = jax.value_and_grad(
-                _sgns_loss, argnums=(0, 1, 2))(vc, uo, un)
+            with jax.named_scope("tables.gather"):
+                vc = din[c]
+                uo = dout[o]
+                un = dout[neg.reshape(-1)].reshape(B, K, D)
+            with jax.named_scope("sgns.grad"):
+                loss, grads = jax.value_and_grad(
+                    _sgns_loss, argnums=(0, 1, 2))(vc, uo, un)
             dvc, duo, dun = grads
             din, sin = scatter_apply(upd_in, din, sin, c, dvc, opt)
             out_rows = jnp.concatenate([o, neg.reshape(-1)])
@@ -192,28 +195,32 @@ class SkipGram:
                           seed: int = 0) -> Tuple[int, float]:
         from ..util import prefetch_to_device
 
-        step, place = self.make_fused_step()
-        din, sin = self.table_in.raw_value()
-        dout, sout = self.table_out.raw_value()
-        loss = jnp.zeros(())
-        steps = 0
-        # Index batches go device-side one step ahead of the compiled
-        # step (H2D rides behind the previous step's compute), placed by
-        # the same batch_placer closure the step's shardings expect.
-        for c, o, neg in prefetch_to_device(
-                self.batches(corpus, batch_size, seed=seed), size=2,
-                sharding=place):
-            din, sin, dout, sout, loss = step(
-                din, sin, dout, sout, c, o, neg)
-            steps += 1
-        if steps == 0:
-            raise ValueError(
-                f"corpus of {corpus.shape[0]} tokens produced no full batch "
-                f"of {batch_size} pairs (partial batches are dropped for "
-                "static shapes)")
-        self.table_in.raw_assign(din, sin)
-        self.table_out.raw_assign(dout, sout)
-        return steps, float(loss)
+        with tracing.span("mv.sgns.epoch"):
+            step, place = self.make_fused_step()
+            din, sin = self.table_in.raw_value()
+            dout, sout = self.table_out.raw_value()
+            loss = jnp.zeros(())
+            steps = 0
+            # Index batches go device-side one step ahead of the compiled
+            # step (H2D rides behind the previous step's compute), placed
+            # by the same batch_placer closure the step's shardings expect.
+            for c, o, neg in prefetch_to_device(
+                    self.batches(corpus, batch_size, seed=seed), size=2,
+                    sharding=place):
+                with tracing.span("mv.sgns.dispatch", step=steps):
+                    din, sin, dout, sout, loss = step(
+                        din, sin, dout, sout, c, o, neg)
+                steps += 1
+            if steps == 0:
+                raise ValueError(
+                    f"corpus of {corpus.shape[0]} tokens produced no full "
+                    f"batch of {batch_size} pairs (partial batches are "
+                    "dropped for static shapes)")
+            with tracing.span("mv.sgns.sync"):
+                self.table_in.raw_assign(din, sin)
+                self.table_out.raw_assign(dout, sout)
+                loss = float(loss)
+        return steps, loss
 
     # ------------------------------------------------------------- analysis
     def most_similar(self, token: int, topk: int = 5) -> np.ndarray:

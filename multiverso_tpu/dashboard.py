@@ -10,14 +10,13 @@ shutdown through the logger.  The ``monitor()`` / ``get_monitor()`` /
 so ``report()`` prints p50/p95/p99 and ``metrics.snapshot()`` exposes
 every monitor alongside the counters/gauges of the rest of the system.
 
-When tracing is armed (``-trace_dir`` / ``tracing.enable()``), each
-monitored section also records a span into ``multiverso_tpu.tracing``
-— table ops, barriers, and jitted steps show up on the merged timeline
-without new call sites.
+Each monitored section runs under ``tracing.span``: table ops,
+barriers and jitted steps show up on the merged timeline when tracing
+is armed (``-trace_dir`` / ``tracing.enable()``), and on the host line
+of any ``jax.profiler`` capture, without new call sites.
 
-TPU-native additions: monitors can also wrap jitted calls (timing includes
-``block_until_ready``), and ``jax.profiler`` trace capture can be toggled
-for a deeper look (SURVEY.md §5 "Tracing/profiling").
+TPU-native addition: monitors can also wrap jitted calls (timing includes
+``block_until_ready``).
 """
 
 from __future__ import annotations
@@ -30,8 +29,7 @@ from typing import Dict, Iterator
 from . import metrics, tracing
 from .log import Log
 
-__all__ = ["Monitor", "monitor", "get_monitor", "report", "reset",
-           "start_trace", "stop_trace"]
+__all__ = ["Monitor", "monitor", "get_monitor", "report", "reset"]
 
 
 class Monitor:
@@ -112,18 +110,11 @@ def get_monitor(name: str) -> Monitor:
 def monitor(name: str) -> Iterator[Monitor]:
     """``with dashboard.monitor("Worker::Get"):`` — the MONITOR macro.
 
-    With tracing armed the section runs under a span context too, so
+    The section runs under a span context, so with tracing armed
     nested monitors (and native calls the caller stamps via
     ``NativeRuntime.set_trace_id``) share its trace id.
     """
     m = get_monitor(name)
-    if not tracing.enabled():
-        t0 = m.begin()
-        try:
-            yield m
-        finally:
-            m.end(t0)
-        return
     with tracing.span(name):
         t0 = time.perf_counter()
         try:
@@ -150,25 +141,3 @@ def reset() -> None:
         for name in _MONITORS:
             metrics.REGISTRY.remove(name)
         _MONITORS.clear()
-
-
-_trace_active = False
-
-
-def start_trace(log_dir: str) -> None:
-    """Start a jax.profiler trace (TPU-native deep profiling path)."""
-    global _trace_active
-    import jax
-
-    if not _trace_active:
-        jax.profiler.start_trace(log_dir)
-        _trace_active = True
-
-
-def stop_trace() -> None:
-    global _trace_active
-    import jax
-
-    if _trace_active:
-        jax.profiler.stop_trace()
-        _trace_active = False

@@ -25,7 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..updaters import AddOption, get_updater
-from .. import dashboard
+from .. import dashboard, tracing
 
 __all__ = ["TransformerConfig", "init_params", "stack_layer_params",
            "transformer_forward", "TransformerTrainer"]
@@ -228,7 +228,8 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
             f"sequence length {tokens.shape[1]} exceeds max_seq "
             f"{cfg.max_seq}")
     dt = cfg.compute_dtype
-    x = params["embed"][tokens].astype(dt)                # [B,T,dim]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(dt)            # [B,T,dim]
     B, T, _ = x.shape
     scale = cfg.head_dim ** -0.5
     use_pp = (mesh is not None and cfg.pipeline_microbatches > 0
@@ -269,37 +270,40 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
                 # bf16 copy of the layer weights of residency.
                 return checkpoint_name(w.astype(dt), "wcast")
 
-            h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
-            q = (h @ wc(lyr["wq"])).reshape(Bb, Tb, local_heads,
-                                            cfg.head_dim)
-            k = (h @ wc(lyr["wk"])).reshape(Bb, Tb, local_heads,
-                                            cfg.head_dim)
-            v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_heads,
-                                            cfg.head_dim)
-            q = _rope(q.transpose(0, 2, 1, 3), cfg.rope_theta)
-            k = _rope(k.transpose(0, 2, 1, 3), cfg.rope_theta)
-            v = v.transpose(0, 2, 1, 3)
-            if attn_in_shard_map:
-                o = ring_attention(q, k, v, mesh, axis_name="sp",
-                                   causal=True, scale=scale)
-            else:
-                o = blockwise_attention_local(q, k, v, scale, causal=True)
-            o = o.transpose(0, 2, 1, 3).reshape(Bb, Tb,
-                                                local_heads * cfg.head_dim)
-            x = x + red(o @ wc(lyr["wo"]))
+            with jax.named_scope("attn"):
+                h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
+                q = (h @ wc(lyr["wq"])).reshape(Bb, Tb, local_heads,
+                                                cfg.head_dim)
+                k = (h @ wc(lyr["wk"])).reshape(Bb, Tb, local_heads,
+                                                cfg.head_dim)
+                v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_heads,
+                                                cfg.head_dim)
+                q = _rope(q.transpose(0, 2, 1, 3), cfg.rope_theta)
+                k = _rope(k.transpose(0, 2, 1, 3), cfg.rope_theta)
+                v = v.transpose(0, 2, 1, 3)
+                if attn_in_shard_map:
+                    o = ring_attention(q, k, v, mesh, axis_name="sp",
+                                       causal=True, scale=scale)
+                else:
+                    o = blockwise_attention_local(q, k, v, scale,
+                                                  causal=True)
+                o = o.transpose(0, 2, 1, 3).reshape(
+                    Bb, Tb, local_heads * cfg.head_dim)
+                x = x + red(o @ wc(lyr["wo"]))
 
-            h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
-            if cfg.num_experts:
-                from .moe import moe_ffn
+            with jax.named_scope("mlp"):
+                h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
+                if cfg.num_experts:
+                    from .moe import moe_ffn
 
-                out, aux = moe_ffn(lyr["moe"], h, top_k=cfg.top_k,
-                                   compute_dtype=dt,
-                                   dispatch=cfg.moe_dispatch,
-                                   capacity_factor=cfg.capacity_factor)
-                return x + out, aux
-            gated = (jax.nn.silu(h @ wc(lyr["w1"]))
-                     * (h @ wc(lyr["w3"])))
-            return x + red(gated @ wc(lyr["w2"])), jnp.float32(0)
+                    out, aux = moe_ffn(lyr["moe"], h, top_k=cfg.top_k,
+                                       compute_dtype=dt,
+                                       dispatch=cfg.moe_dispatch,
+                                       capacity_factor=cfg.capacity_factor)
+                    return x + out, aux
+                gated = (jax.nn.silu(h @ wc(lyr["w1"]))
+                         * (h @ wc(lyr["w3"])))
+                return x + red(gated @ wc(lyr["w2"])), jnp.float32(0)
 
         if cfg.remat:
             # Under scan the body already blocks CSE, so the anti-CSE
@@ -389,10 +393,11 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
         # dp batch shards, so no cross-device reshard per step — a
         # contiguous split would all-to-all the whole activation tensor.
         xm = x.reshape(B // M, M, T, cfg.dim).swapaxes(0, 1)
-        xm = gpipe(stage_fn, stages, xm, mesh, axis_name="pp",
-                   batch_axis="dp",
-                   param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
-                                else None))
+        with jax.named_scope("layers"):
+            xm = gpipe(stage_fn, stages, xm, mesh, axis_name="pp",
+                       batch_axis="dp",
+                       param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
+                                    else None))
         x = xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
         aux_total = jnp.float32(0)
     elif cfg.scan_layers:
@@ -401,16 +406,19 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
             x, a = block(x, lyr)
             return (x, aux + a), None
 
-        (x, aux_total), _ = jax.lax.scan(
-            scan_body, (x, jnp.float32(0)), params["layers"])
+        with jax.named_scope("layers"):
+            (x, aux_total), _ = jax.lax.scan(
+                scan_body, (x, jnp.float32(0)), params["layers"])
     else:
         aux_total = jnp.float32(0)
-        for lyr in params["layers"]:
-            x, a = block(x, lyr)
-            aux_total = aux_total + a
+        with jax.named_scope("layers"):
+            for lyr in params["layers"]:
+                x, a = block(x, lyr)
+                aux_total = aux_total + a
 
-    x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
-    logits = x @ params["head"].astype(dt)
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
+        logits = x @ params["head"].astype(dt)
     if return_aux:
         return logits, aux_total
     return logits
@@ -469,7 +477,8 @@ def lm_loss(params, tokens, cfg: TransformerConfig,
                                       return_aux=True)
     # Crossover measured between 8192 (big loss) and 16384 (small win).
     ce_fn = _ce if cfg.vocab_size >= 16384 else _ce_value
-    ce = ce_fn(logits[:, :-1], tokens[:, 1:])
+    with jax.named_scope("loss"):
+        ce = ce_fn(logits[:, :-1], tokens[:, 1:])
     if cfg.num_experts:
         return ce + cfg.aux_loss_coef * aux
     return ce
@@ -501,6 +510,7 @@ class TransformerTrainer:
                             for _ in range(self.updater.num_slots)),
             self.params)
         self._step = None
+        self._steps_dispatched = 0     # the ``step`` of mv.trainer.dispatch
         self._eval = None
         self._offload = None  # (bridge, leaf shapes/shardings) — see below
 
@@ -510,8 +520,9 @@ class TransformerTrainer:
         flat_p, tree = jax.tree_util.tree_flatten(params)
         flat_s = tree.flatten_up_to(state)
         flat_g = tree.flatten_up_to(grads)
-        out = [updater.apply_dense(p, s, g, opt)
-               for p, s, g in zip(flat_p, flat_s, flat_g)]
+        with jax.named_scope("update"):
+            out = [updater.apply_dense(p, s, g, opt)
+                   for p, s, g in zip(flat_p, flat_s, flat_g)]
         params = jax.tree_util.tree_unflatten(tree, [p for p, _ in out])
         state = jax.tree_util.tree_unflatten(tree, [s for _, s in out])
         return params, state
@@ -719,9 +730,15 @@ class TransformerTrainer:
         cached PER accum value, so interleaving regimes does not
         recompile."""
         step, place = self._jitted_step(accum)
+        with tracing.span("mv.trainer.place"):
+            placed = place(tokens)
+        dispatch = tracing.span("mv.trainer.dispatch",
+                                step=self._steps_dispatched)
+        self._steps_dispatched += 1
         if self._offload is None:
-            self.params, self.state, loss = step(self.params, self.state,
-                                                 place(tokens))
+            with dispatch:
+                self.params, self.state, loss = step(self.params,
+                                                     self.state, placed)
             return loss
         # Offloaded state (docs/host_bridge.md): the vector prefetched
         # during the previous step's tail is ready (or fetched now on
@@ -730,8 +747,8 @@ class TransformerTrainer:
         # behind it (FIFO) while the caller moves on.
         with dashboard.monitor("Transformer::offload_wait"):
             state = self._flat_to_state(self._offload.wait())
-        self.params, new_state, loss = step(self.params, state,
-                                            place(tokens))
+        with dispatch:
+            self.params, new_state, loss = step(self.params, state, placed)
         with dashboard.monitor("Transformer::offload_push"):
             self._offload.push(self._state_to_flat(new_state))
             self._offload.prefetch()

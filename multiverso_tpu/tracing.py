@@ -7,12 +7,17 @@ time went* across the Python/native/wire boundaries.
 
 Three pieces:
 
-- **Python spans** — :func:`span` is a context manager recording a
-  wall-clock span into a bounded in-process buffer; ``dashboard``
-  monitors emit spans automatically when tracing is on, so every table
-  op / barrier / jitted step shows up without new call sites.  Trace
-  ids are thread-local: nested spans share the outermost id (mirroring
-  the native ``Monitor`` contract in ``mvtpu/dashboard.h``).
+- **Python spans** — :func:`span` is one call site with two sinks.  It
+  always runs its body under a ``jax.profiler.TraceAnnotation`` of the
+  same name, so under any ``jax.profiler`` capture the span lands on
+  the host's ``python`` line on the device's clock, beside the device
+  events; and when tracing is on it also records a wall-clock span
+  into a bounded in-process buffer.  With neither it costs one
+  inactive ``TraceMe`` check.  ``dashboard`` monitors run under
+  :func:`span`, so every table op / barrier / jitted step shows up in
+  both without new call sites.  Trace ids are thread-local: nested
+  spans share the outermost id (mirroring the native ``Monitor``
+  contract in ``mvtpu/dashboard.h``).
 - **Native spans** — the C runtime records the same span shape
   (``MV_DumpSpans``; ids propagate through message headers across
   ranks).  :func:`add_native_spans` folds a dump into this buffer so
@@ -20,9 +25,7 @@ Three pieces:
 - **Export** — :func:`save` writes Chrome trace-event JSON (load it in
   Perfetto / ``chrome://tracing``); :func:`merge_dir` merges per-rank
   files into one timeline (timestamps are wall-clock µs, so same-host
-  ranks line up).  ``jax.profiler`` capture stays available through
-  ``dashboard.start_trace`` for XLA-level depth — this layer is the
-  cheap always-on complement.
+  ranks line up).
 
 Enable with the ``-trace_dir=<dir>`` flag (``init()`` arms it and
 ``shutdown()`` writes ``trace_rank<r>.json``), or programmatically with
@@ -39,6 +42,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .log import Log
 
@@ -141,22 +146,27 @@ def span(name: str, trace_id: Optional[int] = None,
     is off) so callers can stamp it into native calls
     (``NativeRuntime.set_trace_id``) or log lines.  Nested spans share
     the outermost id; an explicit ``trace_id`` pins it.
+
+    The body always runs under a ``TraceAnnotation(name, **args)``: a
+    ``jax.profiler`` session in progress records it whether or not
+    tracing is on here.
     """
-    if not _ENABLED:
-        yield 0
-        return
-    prev = current_trace_id()
-    tid = int(trace_id) if trace_id else (prev or new_trace_id())
-    set_trace_id(tid)
-    ts = time.time()
-    t0 = time.perf_counter()
-    try:
-        yield tid
-    finally:
-        dur = time.perf_counter() - t0
-        set_trace_id(prev)
-        record_span(name, int(ts * 1e6), int(dur * 1e6), trace_id=tid,
-                    args=args)
+    with TraceAnnotation(name, **args):
+        if not _ENABLED:
+            yield 0
+            return
+        prev = current_trace_id()
+        tid = int(trace_id) if trace_id else (prev or new_trace_id())
+        set_trace_id(tid)
+        ts = time.time()
+        t0 = time.perf_counter()
+        try:
+            yield tid
+        finally:
+            dur = time.perf_counter() - t0
+            set_trace_id(prev)
+            record_span(name, int(ts * 1e6), int(dur * 1e6), trace_id=tid,
+                        args=args)
 
 
 def events() -> List[SpanEvent]:

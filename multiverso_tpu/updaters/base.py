@@ -136,12 +136,14 @@ def scatter_apply(upd: "Updater", data, state, rows, delta, opt: AddOption):
     fused step": linear updaters scatter duplicates directly (adds
     commute); non-linear ones get duplicates segment-summed first via
     ``aggregate_rows`` — matching the eager path's host-side np.unique
-    aggregation.  Used by every app's fused step.
+    aggregation.  Used by every app's fused step, so the scope it opens
+    names the scatter in each of their compiled programs.
     """
-    if upd.linear:
-        return upd.apply_rows(data, state, rows, delta, opt)
-    uniq, agg, mask = aggregate_rows(rows, delta)
-    return upd.apply_rows(data, state, uniq, agg, opt, mask=mask)
+    with jax.named_scope("tables.scatter_apply"):
+        if upd.linear:
+            return upd.apply_rows(data, state, rows, delta, opt)
+        uniq, agg, mask = aggregate_rows(rows, delta)
+        return upd.apply_rows(data, state, uniq, agg, opt, mask=mask)
 
 
 def masked(delta: jax.Array, mask: Optional[jax.Array]) -> jax.Array:

@@ -14,8 +14,9 @@ its host-thread sibling :class:`~multiverso_tpu.util.AsyncBuffer`).
 from __future__ import annotations
 
 import collections
-import itertools
 from typing import Any, Iterable, Iterator, Optional
+
+from .. import tracing
 
 __all__ = ["prefetch_to_device"]
 
@@ -78,10 +79,24 @@ def _prefetch_gen(it: Iterator[Any], size: int,
         return jax.tree_util.tree_map(put_leaf, batch)
 
     queue: collections.deque = collections.deque()
+    dry = object()
 
     def enqueue(n: int) -> None:
-        for batch in itertools.islice(it, n):
-            queue.append(put(batch))
+        # The host iterator is spanned from outside its next(), one span
+        # a pull (the one pull that finds it dry included), never around
+        # a yield: for word2vec one span is one batch out of
+        # SkipGram.batches.
+        nonlocal it
+        for _ in range(n):
+            if it is None:
+                return
+            with tracing.span("mv.input.next"):
+                batch = next(it, dry)
+            if batch is dry:
+                it = None
+                return
+            with tracing.span("mv.input.place"):
+                queue.append(put(batch))
 
     enqueue(size)
     while queue:

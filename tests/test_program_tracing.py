@@ -1,0 +1,283 @@
+"""The program's own names on a trace (docs/observability.md, "chip plane"):
+``tracing.span`` reaches both the in-memory buffer and a ``jax.profiler``
+session, the ``mv.*`` spans sit where the host work happens, and the
+compiled steps carry the scopes the benchmark's per-layer metrics read."""
+
+import contextlib
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from multiverso_tpu import tracing
+from multiverso_tpu.models import TransformerConfig, TransformerTrainer
+from multiverso_tpu.models.transformer import init_params, lm_loss
+
+CFG = TransformerConfig(vocab_size=64, dim=32, n_layers=2, n_heads=2,
+                        hidden=64, max_seq=32, scan_layers=True, remat=True,
+                        remat_policy="dots")
+TRAINER_STEPS = 3
+SGNS_SPANS = ("mv.input.next", "mv.input.place", "mv.sgns.dispatch",
+              "mv.sgns.sync", "mv.sgns.epoch")
+TRAINER_SPANS = ("mv.trainer.place", "mv.trainer.dispatch")
+
+
+def _tokens(seed=0, shape=(2, 16)):
+    return np.random.RandomState(seed).randint(
+        CFG.vocab_size, size=shape).astype(np.int32)
+
+
+def _trainer():
+    return TransformerTrainer(
+        CFG, Mesh(np.asarray(jax.devices()[:1]), ("dp",)),
+        updater_type="sgd")
+
+
+def _run_trainer():
+    tr = _trainer()
+    for _ in range(TRAINER_STEPS):
+        loss = tr.train_step_async(_tokens())
+    return float(loss)
+
+
+def _run_sgns():
+    """One toy fused epoch; returns the steps it took and the buffer as it
+    stood before ``shutdown`` (which clears it)."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.apps import SkipGram, synthetic_corpus
+
+    mv.config.reset()
+    if mv.initialized():
+        mv.shutdown()
+    mv.init(updater_type="sgd")
+    try:
+        sg = SkipGram(vocab_size=64, dim=8, window=3, negatives=2,
+                      learning_rate=0.1, name="traced_w2v")
+        steps, _ = sg.train_epoch_fused(synthetic_corpus(300, 64, seed=1),
+                                        batch_size=128, seed=1)
+        return steps, tracing.events()
+    finally:
+        mv.shutdown()
+        mv.config.reset()
+
+
+@pytest.fixture
+def clean_tracing():
+    tracing.disable()
+    tracing.clear()
+    yield
+    tracing.disable()
+    tracing.clear()
+
+
+# ------------------------------------------------ (a) the in-memory buffer
+@pytest.fixture(scope="module")
+def buffered():
+    """Both toy loops under ``tracing.enable()``: the events and the step
+    count of the fused epoch."""
+    tracing.disable()
+    tracing.clear()
+    tracing.enable(rank=0)
+    try:
+        steps, events = _run_sgns()
+        _run_trainer()
+        return events + tracing.events(), steps
+    finally:
+        tracing.disable()
+        tracing.clear()
+
+
+def _named(events, name):
+    return [e for e in events if e.name == name]
+
+
+@pytest.mark.parametrize("name", SGNS_SPANS)
+def test_fused_epoch_leaves_its_spans(buffered, name):
+    events, steps = buffered
+    assert steps >= 2
+    # One pull a step and the pull that finds the batcher dry; one
+    # placement and one dispatch a step; one sync and one epoch a call.
+    want = {"mv.input.next": steps + 1, "mv.input.place": steps,
+            "mv.sgns.dispatch": steps, "mv.sgns.sync": 1,
+            "mv.sgns.epoch": 1}[name]
+    found = _named(events, name)
+    assert len(found) == want
+    (epoch,) = _named(events, "mv.sgns.epoch")
+    for e in found:         # nested: inside the epoch, under its trace id
+        assert e.trace_id == epoch.trace_id != 0
+        assert epoch.ts_us <= e.ts_us
+        assert e.ts_us + e.dur_us <= epoch.ts_us + epoch.dur_us + 1
+    if name == "mv.sgns.dispatch":
+        assert [e.args["step"] for e in found] == list(range(steps))
+
+
+@pytest.mark.parametrize("name", TRAINER_SPANS)
+def test_train_step_async_leaves_its_spans(buffered, name):
+    found = _named(buffered[0], name)
+    assert len(found) == TRAINER_STEPS
+    if name == "mv.trainer.dispatch":
+        assert [e.args["step"] for e in found] == list(range(TRAINER_STEPS))
+        places = _named(buffered[0], "mv.trainer.place")
+        assert all(p.ts_us <= d.ts_us for p, d in zip(places, found))
+
+
+@pytest.mark.parametrize("run", [_run_sgns, _run_trainer],
+                         ids=["sgns", "trainer"])
+def test_disabled_the_buffer_stays_empty(clean_tracing, run):
+    out = run()
+    assert tracing.events() == []
+    if run is _run_sgns:
+        assert out[0] >= 2 and out[1] == []
+
+
+def test_monitor_runs_under_a_span_either_way(clean_tracing):
+    from multiverso_tpu import dashboard
+
+    with dashboard.monitor("Test::bridged") as m:
+        pass
+    assert m.count >= 1 and tracing.events() == []
+    tracing.enable(rank=0)
+    with dashboard.monitor("Test::bridged"):
+        pass
+    assert [e.name for e in tracing.events()] == ["Test::bridged"]
+
+
+# --------------------------------------------- (b) the profiler's host line
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """Both toy loops under a ``jax.profiler`` session, tracing otherwise
+    off: the names on ``/host:CPU``'s ``python`` line, and the buffer."""
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    tracing.disable()
+    tracing.clear()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+    try:
+        _run_sgns()
+        _run_trainer()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(log_dir + "/plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [e for plane in data.planes if plane.name == "/host:CPU"
+              for line in plane.lines if line.name == "python"
+              for e in line.events]
+    return events, tracing.events()
+
+
+@pytest.mark.parametrize("name", SGNS_SPANS + TRAINER_SPANS)
+def test_spans_reach_the_profiler(profiled, name):
+    events, buffer = profiled
+    assert buffer == []
+    found = [e for e in events if e.name == name]
+    assert found and all(e.duration_ns > 0 for e in found)
+    if name.endswith(".dispatch"):
+        steps = [dict(e.stats)["step"] for e in found]
+        assert steps == list(range(len(steps)))
+
+
+# ------------------------------------------ (c) scopes in the compiled text
+def _op_names(text):
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _holds(op_names, scope):
+    """Some ``op_name`` has ``scope`` as a whole path component, bare or
+    wrapped in transformations (``transpose(jvp(attn))``)."""
+    part = re.compile(rf"(?:^|[/(]){re.escape(scope)}(?:[/)]|$)")
+    return any(part.search(n) for n in op_names)
+
+
+@pytest.fixture(scope="module")
+def trainer_text():
+    return _op_names(_trainer().lowered_step(_tokens()).compile().as_text())
+
+
+@pytest.mark.parametrize("scope", ["embed", "layers", "attn", "mlp", "head",
+                                   "loss", "update", "rematted_computation"])
+def test_trainer_step_holds_scope(trainer_text, scope):
+    assert _holds(trainer_text, scope)
+    assert not _holds(trainer_text, scope + "x")
+
+
+def test_trainer_step_marks_the_backward(trainer_text):
+    assert any("transpose(jvp(" in n for n in trainer_text)
+    # The update is no part of the differentiated function.
+    assert not any("jvp(update)" in n for n in trainer_text)
+
+
+@pytest.fixture(scope="module")
+def sgns_text():
+    import multiverso_tpu as mv
+    from multiverso_tpu.apps import SkipGram
+
+    mv.config.reset()
+    if mv.initialized():
+        mv.shutdown()
+    mv.init(updater_type="sgd")
+    try:
+        sg = SkipGram(vocab_size=64, dim=8, negatives=2, name="scoped_w2v")
+        step, place = sg.make_fused_step()
+        ids = place(np.zeros(16, np.int32))
+        text = step.lower(*sg.table_in.raw_value(), *sg.table_out.raw_value(),
+                          ids, ids, place(np.zeros((16, 2), np.int32))
+                          ).compile().as_text()
+    finally:
+        mv.shutdown()
+        mv.config.reset()
+    return _op_names(text)
+
+
+@pytest.mark.parametrize("scope", ["tables.gather", "sgns.grad",
+                                   "tables.scatter_apply"])
+def test_fused_sgns_step_holds_scope(sgns_text, scope):
+    assert _holds(sgns_text, scope)
+
+
+@pytest.fixture(scope="module")
+def flash_text():
+    from multiverso_tpu.ops.flash_attention import flash_attention
+
+    q = jnp.ones((1, 1, 128, 32), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, interpret=True).sum()
+
+    return _op_names(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, q).compile().as_text())
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+def test_flash_path_holds_kernel_name(flash_text, kernel):
+    assert _holds(flash_text, kernel)
+
+
+# ------------------------------------------- (d) scopes are metadata only
+# lm_loss of CFG on init_params(seed=0) and _tokens(), from the commit
+# before the scopes went in (CPU, f32).
+LOSS_BEFORE_SCOPES = 4.451268196105957
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_loss_with_scopes_is_the_loss_without(monkeypatch, remat):
+    from dataclasses import replace
+
+    cfg = replace(CFG, remat=remat)
+    params = jax.tree_util.tree_map(jnp.asarray, init_params(cfg, seed=0))
+    toks = jnp.asarray(_tokens())
+    with_scopes = jax.value_and_grad(lm_loss)(params, toks, cfg)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = jax.value_and_grad(lm_loss)(params, toks, cfg)
+    assert float(with_scopes[0]) == float(without[0])
+    for a, b in zip(jax.tree_util.tree_leaves(with_scopes[1]),
+                    jax.tree_util.tree_leaves(without[1])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(float(with_scopes[0]), LOSS_BEFORE_SCOPES,
+                               rtol=1e-6)
