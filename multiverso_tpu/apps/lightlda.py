@@ -184,6 +184,7 @@ class LightLDA:
 
         @jax.jit
         def pass_fn(wt, ts, docs, z, doc_topic, key):
+            wt = wt[:, :K]      # the table stores rows padded to the lane tile
             valid = docs != PAD
             w_safe = jnp.where(valid, docs, 0)
             # remove each token's own count (collapsed Gibbs "minus self")
@@ -247,6 +248,7 @@ class LightLDA:
 
         @jax.jit
         def pass_fn(wt, ts, docs, z, doc_topic, key):
+            wt = wt[:, :K]      # the table stores rows padded to the lane tile
             D = docs.shape[0]
             valid = docs != PAD
             w = jnp.where(valid, docs, 0)

@@ -224,12 +224,15 @@ class SkipGramMixture:
         upd_out = self.table_out.updater
         upd_prior = self.table_prior.updater
         opt = self.option
-        S, D = self.senses, self.dim
+        S = self.senses
 
         @partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5))
         def step(ds, ss, do, so, dp, sp_, c, bags, mask, neg):
             B, K = neg.shape
             C = bags.shape[1]
+            # The buffers' width (MatrixTable.stored_cols): zero padding
+            # past self.dim, which every term below leaves zero.
+            D = do.shape[1]
             sense_rows = (c[:, None] * S + jnp.arange(S)).reshape(-1)
             vs = ds[sense_rows].reshape(B, S, D)
             uc = do[bags.reshape(-1)].reshape(B, C, D)

@@ -166,13 +166,17 @@ class SkipGram:
         upd_in = self.table_in.updater
         upd_out = self.table_out.updater
         opt = self.option
-        D = self.dim
 
         from ..updaters.base import scatter_apply
 
         @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def step(din, sin, dout, sout, c, o, neg):
             B, K = neg.shape
+            # The buffers' width, not self.dim: a table stores its rows
+            # padded to the lane tile (MatrixTable.stored_cols).  The
+            # padding is zero, adds nothing to a dot product, gets a zero
+            # gradient and stays zero.
+            D = dout.shape[1]
             with jax.named_scope("tables.gather"):
                 vc = din[c]
                 uo = dout[o]

@@ -177,7 +177,7 @@ class ArrayTable(Table):
 
     # ------------------------------------------------------------ checkpoint
     def store_state(self) -> Any:
-        data, state = self._dense_snapshot(self.size)
+        data, state = self._dense_snapshot((self.size,))
         return {
             "kind": self.kind,
             "size": self.size,
@@ -187,4 +187,4 @@ class ArrayTable(Table):
 
     def load_state(self, snap: Any) -> None:
         assert snap["kind"] == self.kind and snap["size"] == self.size
-        self._dense_restore(snap["data"], snap["state"], self.size)
+        self._dense_restore(snap["data"], snap["state"], (self.size,))

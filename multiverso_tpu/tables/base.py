@@ -43,6 +43,11 @@ __all__ = ["Table", "host_fetch", "host_put", "is_multiprocess",
            "bucket_size", "multihost_allgather_list"]
 
 
+def _upto(extents) -> tuple:
+    """The index of an array's leading region: ``[:n]`` along each axis."""
+    return tuple(slice(0, n) for n in extents)
+
+
 def bucket_size(k: int, floor: int = 8) -> int:
     """Round ``k`` up to a power-of-two bucket (shape-stable collectives:
     ``process_allgather`` jits per shape, so bucketing caps recompiles)."""
@@ -282,7 +287,7 @@ class Table:
             padded = np.ascontiguousarray(delta, dtype=self.dtype)
         else:
             padded = np.zeros(padded_shape, dtype=self.dtype)
-            padded[tuple(slice(0, s) for s in delta.shape)] = delta
+            padded[_upto(delta.shape)] = delta
         if not presummed:
             padded = multihost_sum(padded)
         d = host_put(padded, self._sharding)
@@ -456,23 +461,29 @@ class Table:
             jax.block_until_ready(self._data)
         return True
 
-    def _dense_snapshot(self, live: int):
-        """Checkpoint the LIVE region of ``_data``/``_state``: padding is
-        a mesh-size artifact, and baking it in would pin the snapshot to
-        the process/device count that wrote it."""
-        return self._locked_read(
-            lambda d, s: (host_fetch(d)[:live],
-                          [host_fetch(x)[:live] for x in s]))
+    def _dense_snapshot(self, live: tuple):
+        """Checkpoint the LIVE region (``live``: its extent along each
+        axis) of ``_data``/``_state``: padding is an artifact of the mesh
+        size and of the device's tiling, and baking it in would pin the
+        snapshot to the process/device count that wrote it."""
+        import numpy as np
 
-    def _dense_restore(self, data, state, live: int) -> None:
+        region = _upto(live)
+        return self._locked_read(
+            lambda d, s: (np.ascontiguousarray(host_fetch(d)[region]),
+                          [np.ascontiguousarray(host_fetch(x)[region])
+                           for x in s]))
+
+    def _dense_restore(self, data, state, live: tuple) -> None:
         """Re-pad a live-region snapshot for THIS mesh and place it."""
         import numpy as np
 
         padded_shape = tuple(self._data.shape)
+        region = _upto(live)
 
         def pad(h):
             out = np.zeros(padded_shape, dtype=self.dtype)
-            out[:live] = np.asarray(h, dtype=self.dtype)[:live]
+            out[region] = np.asarray(h, dtype=self.dtype)[region]
             return out
 
         with self._lock:
@@ -511,8 +522,7 @@ class Table:
                 "process_count() > 1 use get() (collective host fetch)")
         fn = self._dense_cache.get(("slice", limits))
         if fn is None:
-            fn = jax.jit(
-                lambda d: d[tuple(slice(0, s) for s in limits)])
+            fn = jax.jit(lambda d: d[_upto(limits)])
             self._dense_cache[("slice", limits)] = fn
         # Under _lock: a concurrent add's donated apply deletes the buffer
         # it replaces, and launching the slice on a deleted Array throws.

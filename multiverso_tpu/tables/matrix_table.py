@@ -12,6 +12,13 @@ padded to power-of-two buckets so shapes stay static for the compiler
 (SURVEY.md §7 hard-parts: "sparse tables on TPU ... padding/bucketing").
 Duplicate rows in a batch are pre-aggregated host-side (segment-sum) so
 stateful updaters see one delta per row.
+
+The device buffer is padded twice: in rows to the mesh (``_padded_rows``)
+and, from ``LANES`` columns up, in columns to the lane tile
+(``stored_cols``; docs/embedding.md "Resting layout"), so that a row is
+contiguous on the device.  The eager API speaks ``num_cols``; a fused step
+sees the buffer (``raw_value()``), whose trailing columns are zero and stay
+zero.
 """
 
 from __future__ import annotations
@@ -29,6 +36,17 @@ from .base import (Table, bucket_size as _bucket, host_fetch, host_put,
 
 __all__ = ["MatrixTable"]
 
+# The TPU tiles an array's two minor dimensions in (8, LANES).  A row whose
+# width is not a multiple of LANES pads to one row-major, so for such a
+# shape the backend's default layout is the compact transposed one, and a
+# row gather or scatter then copies the whole table in and out of every
+# program.  Stored at the padded width, the default layout is row-major:
+# the same bytes a row-major row takes on the device anyway.  Under twice
+# the bytes from LANES columns up (28% at 300); a narrower table would pay
+# LANES / num_cols times and keeps its width (its copies are as small as it
+# is).
+LANES = 128
+
 
 class MatrixTable(Table):
     kind = "matrix"
@@ -43,15 +61,17 @@ class MatrixTable(Table):
         n = self._mesh.devices.size
         self._padded_rows = ((self.num_rows + n - 1) // n) * n
         self._sharding = shard_along(self._mesh, ndim=2, dim=0)
+        self._stored_cols = (((self.num_cols + LANES - 1) // LANES) * LANES
+                             if self.num_cols >= LANES else self.num_cols)
 
-        host = np.zeros((self._padded_rows, self.num_cols), dtype=self.dtype)
+        stored = (self._padded_rows, self._stored_cols)
+        host = np.zeros(stored, dtype=self.dtype)
         if init is not None:
-            host[: self.num_rows] = np.asarray(init, dtype=self.dtype)
+            host[: self.num_rows, : self.num_cols] = np.asarray(
+                init, dtype=self.dtype)
         self._data = host_put(host, self._sharding)
         self._state = tuple(
-            host_put(
-                np.zeros((self._padded_rows, self.num_cols), dtype=self.dtype),
-                self._sharding)
+            host_put(np.zeros(stored, dtype=self.dtype), self._sharding)
             for _ in range(self.updater.num_slots))
         # BSP buffers, bucketed per AddOption so a flush applies each
         # option's aggregate with the right hyper-parameters.
@@ -65,7 +85,8 @@ class MatrixTable(Table):
         # diversity, not data (see base._dense_cache).
         self._rows_cache: Dict[AddOption, Any] = {}  # mvlint: MV007-exempt(jitted-apply memo bounded by call-site diversity)
         # jax.jit caches per input shape internally; one gather fn suffices.
-        self._gather_fn = jax.jit(lambda data, r: data[r])
+        cols = self.num_cols
+        self._gather_fn = jax.jit(lambda data, r: data[r][:, :cols])
 
     # ------------------------------------------------------------------ Get
     def get(self, option=None, device: bool = False, out=None):
@@ -82,8 +103,9 @@ class MatrixTable(Table):
             # (collective-safe — the key is identical on every rank).
             return self._fill_out(out, self._serve_read(
                 ("get",),
-                lambda: self._locked_read(
-                    lambda d, s: host_fetch(d))[: self.num_rows]))
+                lambda: np.ascontiguousarray(self._locked_read(
+                    lambda d, s: host_fetch(d))[: self.num_rows,
+                                                : self.num_cols])))
 
     def get_rows(self, row_ids, option=None, out=None) -> np.ndarray:
         """Row-subset pull — the sparse hot read path.
@@ -308,7 +330,11 @@ class MatrixTable(Table):
         if fn is None:
             updater = self.updater
 
+            pad = self._stored_cols - self.num_cols
+
             def _apply(data, state, r, d):
+                # The deltas ship at num_cols; the row padding is made here.
+                d = jnp.pad(d, ((0, 0), (0, pad))) if pad else d
                 return updater.apply_rows(data, state, r, d, opt)
 
             fn = jax.jit(_apply, donate_argnums=(0, 1))
@@ -341,9 +367,16 @@ class MatrixTable(Table):
     def sharding(self):
         return self._sharding
 
+    @property
+    def stored_cols(self) -> int:
+        """Columns of the device buffers ``raw_value()`` hands out:
+        ``num_cols`` rounded up to the lane tile from ``LANES`` columns up.
+        The columns past ``num_cols`` hold zeros."""
+        return self._stored_cols
+
     # ------------------------------------------------------------ checkpoint
     def store_state(self) -> Any:
-        data, state = self._dense_snapshot(self.num_rows)
+        data, state = self._dense_snapshot((self.num_rows, self.num_cols))
         return {
             "kind": self.kind,
             "shape": (self.num_rows, self.num_cols),
@@ -354,4 +387,5 @@ class MatrixTable(Table):
     def load_state(self, snap: Any) -> None:
         assert snap["kind"] == self.kind
         assert tuple(snap["shape"]) == (self.num_rows, self.num_cols)
-        self._dense_restore(snap["data"], snap["state"], self.num_rows)
+        self._dense_restore(snap["data"], snap["state"],
+                            (self.num_rows, self.num_cols))

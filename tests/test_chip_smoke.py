@@ -105,6 +105,10 @@ def test_phases_run_tiny_on_cpu_mesh(mv, monkeypatch):
     w2v = chip_smoke.phase_w2v(mv, batch=64, vocab=512, dim=16, negatives=2,
                                steps=10)
     assert w2v["loss_last"] < w2v["loss_first"]
+    # The published width: the tables store 384 columns, same checks.
+    w2v = chip_smoke.phase_w2v(mv, batch=64, vocab=512, dim=300, negatives=2,
+                               steps=10)
+    assert w2v["loss_last"] < w2v["loss_first"] and w2v["dim"] == 300
     assert chip_smoke.phase_bsp(mv, size=16)
     # A batch that does not divide the replicas is an error, not a
     # replication.
