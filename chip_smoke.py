@@ -5,8 +5,10 @@ One process, no arguments: ``python chip_smoke.py`` from the repo root, on
 a machine with a TPU (the chip tool runs it there).  It drives the main
 path once through the entry points a user calls — tables → updaters →
 fused app steps → ``TransformerTrainer`` → the Pallas flash kernels — at
-the full width and depth of the flagship configuration, with random
-weights from a seed, and checks what comes out by the repo's own means.
+the full width and depth of the flagship configuration, then a 2-layer,
+8-expert routed model at OLMoE's widths through the grouped (dropless)
+schedule, with random weights from a seed, and checks what comes out by
+the repo's own means.
 
 It refuses to run (non-zero exit, no result line) unless JAX's first
 device is a TPU, and when ``MVTPU_NO_FLASH``/``MVTPU_FORCE_FLASH`` would
@@ -38,6 +40,14 @@ FLAGSHIP = dict(vocab_size=32768, dim=2048, n_layers=16, n_heads=16,
                 hidden=5632, max_seq=2048, scan_layers=True, remat=True,
                 remat_policy="dots")
 FLAGSHIP_BATCH, FLAGSHIP_SEQ = 4, 2048
+# The routed FFN on the normal path at OLMoE-1B-7B's widths (2048, 16 x 128,
+# experts of 1024, QK-norm, unnormalised top-k, z-loss), with 8 experts and
+# 2 layers so that the phase is seconds: the grouped (dropless) schedule.
+MOE = dict(vocab_size=32768, dim=2048, n_layers=2, n_heads=16, hidden=1024,
+           max_seq=2048, num_experts=8, top_k=2, norm_topk_prob=False,
+           qk_norm=True, router_z_loss_coef=0.001, moe_dispatch="grouped",
+           scan_layers=True, remat=True, remat_policy="dots")
+MOE_BATCH, MOE_SEQ = 2, 2048
 # bench.py:bench_lr / bench_w2v shapes.
 LR_SHAPE = dict(batch=8192, features=784, classes=10)
 W2V_SHAPE = dict(batch=8192, vocab=100_000, dim=128, negatives=5)
@@ -414,6 +424,43 @@ def phase_flagship(cfg, batch: int, seq: int, mesh, steps: int = 4,
     }
 
 
+def phase_moe(cfg, batch: int, seq: int, mesh, steps: int = 3,
+              kernels_per_step: int = 3) -> dict:
+    """The grouped expert schedule through ``TransformerTrainer``: steps
+    that fall, a step-0 loss equal to the ``dense`` schedule's (every
+    expert on every token: the oracle) within bf16 tolerance, and how
+    unevenly the routes of this batch fall on the experts."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import init_params
+    from multiverso_tpu.models.transformer import expert_load
+
+    require(cfg.moe_dispatch == "grouped", "phase_moe wants the grouped "
+            f"schedule, got {cfg.moe_dispatch!r}")
+    res = phase_flagship(cfg, batch, seq, mesh, steps=steps,
+                         kernels_per_step=kernels_per_step)
+    dense = phase_flagship(dataclasses.replace(cfg, moe_dispatch="dense"),
+                           batch, seq, mesh, steps=2,
+                           kernels_per_step=kernels_per_step)
+    first, oracle = res["losses"][0], dense["losses"][0]
+    require(abs(first - oracle) <= LOSS_RTOL * abs(oracle),
+            f"grouped step-0 loss {first} vs dense dispatch {oracle}")
+    toks = np.random.RandomState(0).randint(
+        cfg.vocab_size, size=(batch, seq)).astype(np.int32)
+    params = jax.tree_util.tree_map(jnp.asarray, init_params(cfg, seed=0))
+    load = np.asarray(expert_load(params, toks, cfg))
+    require(load.shape == (cfg.n_layers, cfg.num_experts)
+            and (load.sum(axis=1) == batch * seq * cfg.top_k).all(),
+            f"expert_load lost or invented routes: {load.tolist()}")
+    res["dense_dispatch_loss"] = oracle
+    res["expert_load_max_over_mean"] = [
+        round(float(row.max() / row.mean()), 3) for row in load]
+    return res
+
+
 def phase_multichip(cfg, batch: int, seq: int, ref_loss: float,
                     kernels=(3, 15)) -> dict:
     """The flagship on ("dp",)=4 and on ("dp","sp","tp")=(1,2,2): same
@@ -468,6 +515,10 @@ def main() -> int:
     result["flagship"] = phase_flagship(cfg, FLAGSHIP_BATCH, FLAGSHIP_SEQ,
                                         one)
     say(f"flagship: {result['flagship']}")
+
+    result["moe"] = phase_moe(TransformerConfig(**MOE), MOE_BATCH, MOE_SEQ,
+                              one)
+    say(f"moe (grouped, {MOE['num_experts']} experts): {result['moe']}")
 
     if jax.device_count() >= 4:
         result["multichip"] = phase_multichip(

@@ -1,32 +1,59 @@
-"""Mixture-of-Experts feed-forward with expert parallelism.
+"""Mixture-of-Experts feed-forward: one router, three schedules.
 
-Not in the reference (a 2016 parameter server predates MoE); included
-because expert parallelism is a first-class layout for this framework.
+Not in the reference (a 2016 parameter server predates MoE).  The expert
+leaves sit at a layer's top level beside the attention weights (``router
+[D, E]``, ``w1``/``w3 [E, D, H]``, ``w2 [E, H, D]``); ``moe_ffn`` reads
+those four keys of whatever dict it is given.  Routing is shared and stays
+in float32: softmax over the router's logits, top-k, the weights
+renormalised or not (``norm_topk_prob``; OLMoE does not), the
+load-balancing term ``E * sum_e f_e P_e`` and the router z-loss
+``mean_tokens logsumexp(logits)^2``.  What differs is how the ``N*k``
+routes reach their experts:
 
-TPU-first design choices:
+- ``"grouped"``: what a benchmark cell runs
+  (``olmoe-1b-7b-e64.zipf-seq4k-b2``).  Dropless: the routes are
+  stable-sorted by expert, the rows gathered, three grouped matmuls
+  (``jax.lax.ragged_dot``, which XLA:TPU lowers to its own grouped-matmul
+  custom call) run over the ``[E, D, H]`` weights with the group sizes,
+  and each token sums its ``k`` weighted rows.  Static shapes, FLOPs of
+  exactly the routes, no ``[N*k, E]`` one-hot and no capacity.  It does not
+  run over an ``ep`` mesh axis: ``transformer_forward`` refuses that by
+  name.
+- ``"dense"``: every expert computes every token, scaled afterwards by the
+  combine weights.  Exact, ``E/k`` times the useful FLOPs: the oracle the
+  tests hold the other two to, and the one schedule GSPMD partitions over
+  ``ep`` (expert-indexed weights carry a ``NamedSharding`` over it and XLA
+  turns the einsums into all-to-alls).
+- ``"capacity"``: GShard-style static buckets; a route beyond its expert's
+  capacity is **dropped** (``moe.dropped_routes`` counts them off the
+  step).  No cell runs it: a candidate for deletion with
+  ``capacity_factor`` in a later ``simplicity`` PR.
 
-- **Dense dispatch**: routing uses a top-k one-hot combine tensor and two
-  einsums instead of gather/scatter of token buckets — static shapes, no
-  capacity overflow logic, MXU-friendly, and GSPMD partitions it cleanly.
-  (At trillion-scale one would move to a Pallas a2a pipeline; dense
-  dispatch is the right first rung and exact.)
-- **Expert parallelism**: expert-indexed weights [E, ...] carry a
-  ``NamedSharding`` over the ``ep`` mesh axis; XLA turns the token-expert
-  einsums into all-to-alls over ICI.  Token activations stay sharded over
-  ``dp``/``sp`` as in the dense path.
+Scopes for the chip trace (docs/observability.md, "Chip plane"):
+``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``; the
+schedule a step was traced with is counted in ``moe.traced{dispatch=}``.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["init_moe_params", "moe_capacity", "moe_ffn", "moe_pspecs",
-           "moe_shardings"]
+from .. import metrics
+
+__all__ = ["GROUPED_SAVED", "dropped_routes", "init_moe_params",
+           "moe_capacity", "moe_ffn", "moe_pspecs", "moe_shardings"]
+
+# ``checkpoint_name``s of the three grouped-matmul outputs.  A grouped matmul
+# is not a ``dot_general``, so remat policy "dots" saves them by name
+# (``transformer.py``); without the names the backward runs all three again.
+GROUPED_SAVED = ("moe_gate", "moe_up", "moe_down")
 
 
 def init_moe_params(dim: int, hidden: int, num_experts: int,
@@ -60,24 +87,33 @@ def moe_shardings(mesh: Mesh) -> Dict[str, Any]:
     return {k: NamedSharding(mesh, s) for k, s in moe_pspecs(mesh).items()}
 
 
-def _routing(params, x, top_k: int):
-    """Shared router: probs, normalized top-k weights/indices, aux loss.
-
-    Aux is the standard switch/GShard load-balancing term
-    (E · Σ_e fraction_e · prob_e), computed on the routing decisions
-    (pre-drop, so the capacity path optimizes the same objective).
-    """
-    E = params["router"].shape[1]
+def _routing(params, x, top_k: int, norm_topk_prob: bool):
+    """The shared router, in float32: ``(probs, logits, top_p, top_idx)``
+    with ``top_p`` renormalised to sum to 1 over the k routes if asked."""
     logits = (x.astype(jnp.float32)
               @ params["router"].astype(jnp.float32))        # [B,T,E]
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_idx = jax.lax.top_k(probs, top_k)             # [B,T,k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    routed = jnp.sum(jax.nn.one_hot(top_idx, E, dtype=jnp.float32), axis=2)
-    frac_tokens = jnp.mean((routed > 0).astype(jnp.float32), axis=(0, 1))
-    frac_prob = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(frac_tokens * frac_prob)
-    return probs, top_p, top_idx, aux
+    if norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return probs, logits, top_p, top_idx
+
+
+def _aux_losses(probs, logits, load):
+    """``(balance, z)`` of one layer.  ``balance`` is the switch/GShard
+    load-balancing term ``E * sum_e f_e P_e``: ``f_e`` the share of tokens
+    with a route to expert ``e`` (``load`` counts routes, and a token's k
+    routes go to k different experts), ``P_e`` the mean router probability;
+    on the routing decisions, before any drop, so every schedule optimises
+    the same objective.  ``z`` is the router z-loss, the mean over tokens of
+    ``logsumexp(logits)^2`` (arXiv:2409.02060, section 3.4)."""
+    E = probs.shape[-1]
+    tokens = probs.size // E
+    frac_tokens = load.astype(jnp.float32) / tokens
+    frac_prob = jnp.mean(probs.reshape(tokens, E), axis=0)
+    balance = E * jnp.sum(frac_tokens * frac_prob)
+    z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+    return balance, z
 
 
 def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
@@ -87,38 +123,84 @@ def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
     return max(8, -(-c // 8) * 8)
 
 
+def dropped_routes(load, capacity: int) -> int:
+    """Routes the ``capacity`` schedule drops given per-expert route counts
+    ``load [..., E]``: what lies beyond each bucket.  The grouped and dense
+    schedules drop none."""
+    return int(np.maximum(np.asarray(load) - capacity, 0).sum())
+
+
 def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
             compute_dtype=None, dispatch: str = "dense",
-            capacity_factor: float = 1.25) -> tuple[jax.Array, jax.Array]:
-    """x [B, T, dim] → (out [B, T, dim], aux_loss scalar).
-
-    Two dispatch schedules:
-
-    - ``"dense"`` — every expert computes every token, scaled post-hoc by
-      the combine weights.  Exact (no token ever dropped), E/top_k× the
-      useful FLOPs; the correctness oracle the capacity path is tested
-      against.
-    - ``"capacity"`` — GShard-style static buckets: each expert takes at
-      most C = ceil(N·top_k/E · capacity_factor) tokens (scatter in,
-      batched [E, C, ·] expert FFN on the MXU, gather out).  FLOPs scale
-      with top_k·capacity_factor/E instead of 1; tokens overflowing a
-      bucket lose that expert's contribution (their other routes and the
-      residual still apply).  Static shapes throughout — the capacity is
-      a trace-time constant, so this jits/scans/pjits like any dense op.
-    """
-    if dispatch == "dense":
-        return _moe_dense(params, x, top_k, compute_dtype)
-    if dispatch == "capacity":
-        return _moe_capacity_dispatch(params, x, top_k, compute_dtype,
-                                      capacity_factor)
-    raise ValueError(f"unknown moe dispatch '{dispatch}' "
-                     "(expected dense|capacity)")
+            capacity_factor: float = 1.25, norm_topk_prob: bool = True
+            ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """x [B, T, dim] → ``(out [B, T, dim], balance, z, load [E])``: the
+    layer's output, its two auxiliary loss terms (``_aux_losses``, scalars,
+    unweighted) and the routes each expert was sent (int32, before any
+    drop).  ``dispatch`` picks the schedule (this file's header); the
+    choice is taken at trace time and counted in ``moe.traced``."""
+    schedules = {"grouped": _moe_grouped, "dense": _moe_dense,
+                 "capacity": partial(_moe_capacity_dispatch,
+                                     capacity_factor=capacity_factor)}
+    if dispatch not in schedules:
+        raise ValueError(f"unknown moe dispatch '{dispatch}' "
+                         "(expected grouped|dense|capacity)")
+    metrics.counter("moe.traced", {"dispatch": dispatch}).inc()
+    return schedules[dispatch](params, x, top_k, compute_dtype or x.dtype,
+                               norm_topk_prob)
 
 
-def _moe_dense(params, x, top_k, compute_dtype):
-    dt = compute_dtype or x.dtype
+def _route(params, x, top_k: int, norm_topk_prob: bool):
+    """Routing with its losses for the schedules that do not sort:
+    ``(top_p, top_idx, balance, z, load)``, the load by ``bincount``."""
+    probs, logits, top_p, top_idx = _routing(params, x, top_k,
+                                             norm_topk_prob)
+    load = jnp.bincount(top_idx.reshape(-1), length=probs.shape[-1]
+                        ).astype(jnp.int32)
+    return (top_p, top_idx, *_aux_losses(probs, logits, load), load)
+
+
+def _moe_grouped(params, x, top_k, dt, norm_topk_prob):
+    B, T, D = x.shape
+    N = B * T
     E = params["router"].shape[1]
-    probs, top_p, top_idx, aux = _routing(params, x, top_k)
+    with jax.named_scope("moe.route"):
+        probs, logits, top_p, top_idx = _routing(params, x, top_k,
+                                                 norm_topk_prob)
+    with jax.named_scope("moe.dispatch"):
+        # Route r = n*k + j is token n's j-th expert.  Sorted by expert
+        # (stable: a group keeps token order), a group's rows are contiguous
+        # and its size is the distance between two boundaries.
+        expert = top_idx.reshape(-1)                              # [N*k]
+        order = jnp.argsort(expert, stable=True)
+        bounds = jnp.searchsorted(expert[order], jnp.arange(E + 1),
+                                  side="left")
+        sizes = jnp.diff(bounds).astype(jnp.int32)                # [E]
+        token = order // top_k
+        rows = x.reshape(N, D).astype(dt)[token]                  # [N*k, D]
+    with jax.named_scope("moe.route"):
+        balance, z = _aux_losses(probs, logits, sizes)
+    with jax.named_scope("moe.experts"):
+        w1, w3, w2 = (checkpoint_name(params[k].astype(dt), "wcast")
+                      for k in ("w1", "w3", "w2"))
+        gate = checkpoint_name(jax.lax.ragged_dot(rows, w1, sizes),
+                               GROUPED_SAVED[0])
+        up = checkpoint_name(jax.lax.ragged_dot(rows, w3, sizes),
+                             GROUPED_SAVED[1])
+        down = checkpoint_name(
+            jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes),
+            GROUPED_SAVED[2])                                     # [N*k, D]
+    with jax.named_scope("moe.combine"):
+        weight = top_p.reshape(-1)[order]
+        out = jnp.zeros((N, D), jnp.float32).at[token].add(
+            down.astype(jnp.float32) * weight[:, None])
+    return out.reshape(B, T, D).astype(x.dtype), balance, z, sizes
+
+
+def _moe_dense(params, x, top_k, dt, norm_topk_prob):
+    E = params["router"].shape[1]
+    top_p, top_idx, balance, z, load = _route(params, x, top_k,
+                                              norm_topk_prob)
     # combine [B,T,E]: routing weight per expert (0 for unrouted)
     combine = jnp.sum(
         jax.nn.one_hot(top_idx, E, dtype=jnp.float32)
@@ -133,16 +215,16 @@ def _moe_dense(params, x, top_k, compute_dtype):
                             params["w2"].astype(dt))          # [B,E,T,d]
     out = jnp.einsum("betd,bte->btd", expert_out,
                      combine.astype(dt))
-    return out.astype(x.dtype), aux
+    return out.astype(x.dtype), balance, z, load
 
 
-def _moe_capacity_dispatch(params, x, top_k, compute_dtype,
+def _moe_capacity_dispatch(params, x, top_k, dt, norm_topk_prob,
                            capacity_factor):
-    dt = compute_dtype or x.dtype
     B, T, D = x.shape
     N = B * T
     E = params["router"].shape[1]
-    _, top_p, top_idx, aux = _routing(params, x, top_k)
+    top_p, top_idx, balance, z, load = _route(params, x, top_k,
+                                              norm_topk_prob)
     C = moe_capacity(N, E, top_k, capacity_factor)
 
     # Slot assignment, token-major (earlier tokens win bucket slots, the
@@ -170,4 +252,4 @@ def _moe_capacity_dispatch(params, x, top_k, compute_dtype,
     w = (top_p.reshape(-1) * valid.astype(jnp.float32)).astype(dt)
     y_tok = ye[jnp.minimum(slot, E * C - 1)] * w[:, None]
     out = jnp.sum(y_tok.reshape(N, top_k, D), axis=1).reshape(B, T, D)
-    return out.astype(x.dtype), aux
+    return out.astype(x.dtype), balance, z, load

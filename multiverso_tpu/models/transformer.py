@@ -25,10 +25,12 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..updaters import AddOption, get_updater
-from .. import dashboard, tracing
+from .. import dashboard, metrics, tracing
+from .moe import (GROUPED_SAVED, dropped_routes, init_moe_params, moe_capacity,
+                  moe_ffn, moe_pspecs)
 
 __all__ = ["TransformerConfig", "init_params", "stack_layer_params",
-           "transformer_forward", "TransformerTrainer"]
+           "transformer_forward", "expert_load", "TransformerTrainer"]
 
 
 @dataclass(frozen=True)
@@ -48,10 +50,20 @@ class TransformerConfig:
     num_experts: int = 0
     top_k: int = 2
     aux_loss_coef: float = 0.01
-    # "dense" = exact all-experts dispatch (the oracle); "capacity" =
-    # GShard-style static buckets, FLOPs ∝ top_k·capacity_factor/E.
+    # Router z-loss (mean logsumexp(router logits)^2 a layer): OLMoE 0.001.
+    router_z_loss_coef: float = 0.0
+    # Renormalise a token's top_k router weights to sum to 1 (OLMoE: no).
+    norm_topk_prob: bool = True
+    # models/moe.py's schedules: "grouped" = dropless sort + grouped matmul,
+    # FLOPs of exactly the routes (what the OLMoE cell runs; no ``ep``
+    # axis); "dense" = exact all-experts dispatch (the tests' oracle);
+    # "capacity" = GShard-style static buckets that drop overflow,
+    # FLOPs ∝ top_k·capacity_factor/E.
     moe_dispatch: str = "dense"
     capacity_factor: float = 1.25
+    # QK-norm (OLMoE): an RMSNorm with its own gain over the whole
+    # dim-wide q and k projections, before the split into heads and rotary.
+    qk_norm: bool = False
     # remat: gradient checkpointing — recompute each layer's forward during
     # the backward pass instead of saving activations.  Trades ~1/3 more
     # matmul FLOPs for O(layers·B·T·dim) activation memory, the knob that
@@ -105,12 +117,14 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
             "attn_norm": np.ones(cfg.dim, np.float32),
             "mlp_norm": np.ones(cfg.dim, np.float32),
         }
+        if cfg.qk_norm:
+            lyr.update(q_norm=np.ones(cfg.dim, np.float32),
+                       k_norm=np.ones(cfg.dim, np.float32))
         if cfg.num_experts:
-            from .moe import init_moe_params
-
-            lyr["moe"] = init_moe_params(cfg.dim, cfg.hidden,
-                                         cfg.num_experts,
-                                         seed=rng.randint(2 ** 31))
+            # router, w1, w3, w2 at the layer's top level, expert-indexed
+            lyr.update(init_moe_params(cfg.dim, cfg.hidden,
+                                       cfg.num_experts,
+                                       seed=rng.randint(2 ** 31)))
         else:
             lyr.update({
                 "w1": w(cfg.dim, cfg.hidden),   # gate
@@ -132,8 +146,8 @@ def stack_layer_params(layers):
     """List of per-layer param dicts → one dict of stacked [L, ...] arrays.
 
     The scan-format params: leaf k holds ``stack([lyr[k] for lyr in
-    layers])``.  Works on numpy or jax leaves (nested dicts included, e.g.
-    MoE); used by ``init_params(scan_layers=True)`` and by tests converting
+    layers])``.  Works on numpy or jax leaves (nested dicts included);
+    used by ``init_params(scan_layers=True)`` and by tests converting
     loop-format params for parity checks.
     """
     return jax.tree_util.tree_map(
@@ -144,7 +158,10 @@ def stack_layer_params(layers):
 def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
     """Per-layer weight PartitionSpecs for the Megatron-style tp layout:
     attention io dims and MLP hidden shard over ``tp`` (column-parallel
-    wq/wk/wv/w1/w3, row-parallel wo/w2); norms replicated."""
+    wq/wk/wv/w1/w3, row-parallel wo/w2); norms replicated.  The QK-norm
+    gains are replicated too: its mean of squares runs over the whole
+    tp-sharded projection, which GSPMD completes with an all-reduce (the
+    manual tp layout inside a pipeline stage refuses QK-norm by name)."""
     tp = "tp" if "tp" in mesh.shape else None
 
     layer = {
@@ -152,10 +169,10 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
         "wo": P(tp, None),
         "attn_norm": P(None), "mlp_norm": P(None),
     }
+    if cfg.qk_norm:
+        layer.update(q_norm=P(None), k_norm=P(None))
     if cfg.num_experts:
-        from .moe import moe_pspecs
-
-        layer["moe"] = moe_pspecs(mesh)
+        layer.update(moe_pspecs(mesh))
     else:
         layer.update({"w1": P(None, tp), "w3": P(None, tp),
                       "w2": P(tp, None)})
@@ -219,8 +236,35 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
                         return_aux: bool = False):
     """tokens [B, T] int32 → logits [B, T, vocab] (compute dtype).
 
-    With ``return_aux=True`` also returns the summed MoE load-balancing
-    auxiliary loss (zero for dense configs)."""
+    With ``return_aux=True`` also returns the MoE auxiliary loss summed
+    over the layers, weighted as ``lm_loss`` adds it: ``aux_loss_coef`` x
+    the load-balancing term + ``router_z_loss_coef`` x the router z-loss
+    (zero for dense configs)."""
+    logits, aux, _ = _forward(params, tokens, cfg, mesh)
+    return (logits, aux) if return_aux else logits
+
+
+def expert_load(params, tokens, cfg: TransformerConfig,
+                mesh: Optional[Mesh] = None):
+    """Routes each expert of each layer is sent for ``tokens`` [B, T]:
+    int32 ``[n_layers, num_experts]``, every row summing to ``B*T*top_k``.
+    A diagnostic off the step (one forward pass): how uneven the groups of
+    the grouped schedule are, and, for the ``capacity`` schedule, how many
+    routes it drops (added to the counter ``moe.dropped_routes``)."""
+    if not cfg.num_experts:
+        raise ValueError("expert_load: the configuration has no experts")
+    load = jax.jit(lambda p, t: _forward(p, t, cfg, mesh)[2])(
+        params, jnp.asarray(tokens, jnp.int32))
+    if cfg.moe_dispatch == "capacity":
+        capacity = moe_capacity(int(np.prod(tokens.shape)), cfg.num_experts,
+                                cfg.top_k, cfg.capacity_factor)
+        metrics.counter("moe.dropped_routes").inc(
+            dropped_routes(load, capacity))
+    return load
+
+
+def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
+    """``(logits, weighted auxiliary loss, expert load [L, E] or None)``."""
     from ..parallel.ring_attention import blockwise_attention_local, ring_attention
 
     if tokens.shape[1] > cfg.max_seq:
@@ -241,6 +285,13 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
     # shard_map and call the local kernel directly.
     attn_in_shard_map = (mesh is not None and mesh.size > 1
                          and not use_pp)
+    if (cfg.num_experts and cfg.moe_dispatch == "grouped"
+            and mesh is not None and int(mesh.shape.get("ep", 1)) > 1):
+        raise ValueError(
+            "moe_dispatch='grouped' does not run over an 'ep' mesh axis "
+            f"(ep={mesh.shape['ep']}): its grouped matmul wants every "
+            "expert's weights on the chip that holds the rows; use "
+            "moe_dispatch='dense', which GSPMD partitions over 'ep'")
 
     def make_block(local_heads: int, reduce=None):
         """Build one decoder-layer fn (with the remat wrapper applied).
@@ -272,10 +323,12 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
 
             with jax.named_scope("attn"):
                 h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
-                q = (h @ wc(lyr["wq"])).reshape(Bb, Tb, local_heads,
-                                                cfg.head_dim)
-                k = (h @ wc(lyr["wk"])).reshape(Bb, Tb, local_heads,
-                                                cfg.head_dim)
+                q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
+                if cfg.qk_norm:
+                    q = _rms_norm(q, lyr["q_norm"].astype(dt), cfg.norm_eps)
+                    k = _rms_norm(k, lyr["k_norm"].astype(dt), cfg.norm_eps)
+                q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
+                k = k.reshape(Bb, Tb, local_heads, cfg.head_dim)
                 v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_heads,
                                                 cfg.head_dim)
                 q = _rope(q.transpose(0, 2, 1, 3), cfg.rope_theta)
@@ -294,16 +347,17 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
             with jax.named_scope("mlp"):
                 h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
                 if cfg.num_experts:
-                    from .moe import moe_ffn
-
-                    out, aux = moe_ffn(lyr["moe"], h, top_k=cfg.top_k,
-                                       compute_dtype=dt,
-                                       dispatch=cfg.moe_dispatch,
-                                       capacity_factor=cfg.capacity_factor)
-                    return x + out, aux
+                    out, balance, z, load = moe_ffn(
+                        lyr, h, top_k=cfg.top_k, compute_dtype=dt,
+                        dispatch=cfg.moe_dispatch,
+                        capacity_factor=cfg.capacity_factor,
+                        norm_topk_prob=cfg.norm_topk_prob)
+                    aux = (cfg.aux_loss_coef * balance
+                           + cfg.router_z_loss_coef * z)
+                    return x + out, aux, load
                 gated = (jax.nn.silu(h @ wc(lyr["w1"]))
                          * (h @ wc(lyr["w3"])))
-                return x + red(gated @ wc(lyr["w2"])), jnp.float32(0)
+                return x + red(gated @ wc(lyr["w2"])), jnp.float32(0), None
 
         if cfg.remat:
             # Under scan the body already blocks CSE, so the anti-CSE
@@ -316,13 +370,16 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
                 # directly instead of replaying the forward kernel —
                 # the recompute tax drops to the cheap tensor ops
                 # (norms, rope) for ~one extra o-sized buffer per layer.
+                # The grouped schedule's three matmuls are no dot_general
+                # either and are saved by name (moe.GROUPED_SAVED).
                 block = jax.checkpoint(
                     block,
                     policy=jax.checkpoint_policies.save_from_both_policies(
                         jax.checkpoint_policies
                         .dots_with_no_batch_dims_saveable,
                         jax.checkpoint_policies.save_only_these_names(
-                            "flash_out", "flash_lse", "wcast")),
+                            "flash_out", "flash_lse", "wcast",
+                            *GROUPED_SAVED)),
                     prevent_cse=not cfg.scan_layers)
             elif cfg.remat_policy == "full":
                 block = jax.checkpoint(block,
@@ -359,6 +416,11 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
                 f"n_layers ({cfg.n_layers}) must divide into pp ({pp}) "
                 f"stages and batch ({B}) into {M} microbatches x dp "
                 f"({dp}) shards")
+        if tp > 1 and cfg.qk_norm:
+            raise ValueError(
+                "qk_norm inside a pipeline stage with tp > 1 is unsupported: "
+                "the stage shards wq/wk by hand and the norm's mean of "
+                "squares would need a psum over 'tp'")
         if cfg.n_heads % tp or cfg.hidden % tp or cfg.dim % tp:
             raise ValueError(
                 f"pp x tp needs n_heads ({cfg.n_heads}), hidden "
@@ -382,7 +444,7 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
 
         def stage_fn(stage_params, h):
             def body(h, lyr):
-                h, _ = stage_block(h, lyr)
+                h, _, _ = stage_block(h, lyr)
                 return h, None
 
             h, _ = jax.lax.scan(body, h, stage_params)
@@ -399,29 +461,29 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
                        param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
                                     else None))
         x = xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
-        aux_total = jnp.float32(0)
+        aux_total, load = jnp.float32(0), None
     elif cfg.scan_layers:
         def scan_body(carry, lyr):
             x, aux = carry
-            x, a = block(x, lyr)
-            return (x, aux + a), None
+            x, a, load = block(x, lyr)
+            return (x, aux + a), load
 
         with jax.named_scope("layers"):
-            (x, aux_total), _ = jax.lax.scan(
+            (x, aux_total), load = jax.lax.scan(
                 scan_body, (x, jnp.float32(0)), params["layers"])
     else:
-        aux_total = jnp.float32(0)
+        aux_total, loads = jnp.float32(0), []
         with jax.named_scope("layers"):
             for lyr in params["layers"]:
-                x, a = block(x, lyr)
+                x, a, load = block(x, lyr)
                 aux_total = aux_total + a
+                loads.append(load)
+        load = jnp.stack(loads) if cfg.num_experts else None
 
     with jax.named_scope("head"):
         x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
         logits = x @ params["head"].astype(dt)
-    if return_aux:
-        return logits, aux_total
-    return logits
+    return logits, aux_total, load
 
 
 def _ce_value(logits, targets):
@@ -463,7 +525,7 @@ def lm_loss(params, tokens, cfg: TransformerConfig,
             mesh: Optional[Mesh] = None):
     """Next-token cross-entropy, mean over all positions (float32).
 
-    MoE configs add ``aux_loss_coef`` × the summed load-balancing loss.
+    MoE configs add their weighted auxiliary loss (``transformer_forward``).
 
     Two CE lowerings, picked by head size (all v5e-measured): the
     ``_ce`` custom_vjp wins at vocab 32k (+0.9 MFU points on the ~1B
@@ -479,9 +541,7 @@ def lm_loss(params, tokens, cfg: TransformerConfig,
     ce_fn = _ce if cfg.vocab_size >= 16384 else _ce_value
     with jax.named_scope("loss"):
         ce = ce_fn(logits[:, :-1], tokens[:, 1:])
-    if cfg.num_experts:
-        return ce + cfg.aux_loss_coef * aux
-    return ce
+    return ce + aux if cfg.num_experts else ce
 
 
 class TransformerTrainer:

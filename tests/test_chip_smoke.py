@@ -71,6 +71,7 @@ def test_last_line_is_the_verdict_alone(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "phase_flash_reference", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_flagship",
                         lambda *a, **k: {"losses": [2.0, 1.0]})
+    monkeypatch.setattr(chip_smoke, "phase_moe", lambda *a, **k: {})
     monkeypatch.setattr(chip_smoke, "phase_multichip", lambda *a, **k: {})
     assert chip_smoke.main() == 0
     lines = capsys.readouterr().out.splitlines()
@@ -129,6 +130,13 @@ def test_phases_run_tiny_on_cpu_mesh(mv, monkeypatch):
     assert res["kernels"] == {"tpu_custom_call": 0, "score_tensors": [],
                               "jnp_traces": 0}
     assert res["losses"][-1] < res["losses"][0]
+    moe = chip_smoke.phase_moe(
+        TransformerConfig(**dict(chip_smoke.MOE, vocab_size=256, dim=64,
+                                 n_heads=2, hidden=32, max_seq=256)),
+        2, 256, one, kernels_per_step=0)
+    assert moe["losses"][-1] < moe["losses"][0]
+    assert len(moe["expert_load_max_over_mean"]) == 2
+    assert all(1.0 <= r < 8.0 for r in moe["expert_load_max_over_mean"])
     multi = chip_smoke.phase_multichip(cfg, 4, 256, res["losses"][0],
                                        kernels=(0, 0))
     assert set(multi) == {"dp4", "dp1_sp2_tp2"}

@@ -39,7 +39,7 @@ def test_moe_matches_per_token_reference(top_k):
     rng = np.random.RandomState(0)
     params = init_moe_params(dim=16, hidden=32, num_experts=4, seed=1)
     x = rng.randn(2, 8, 16).astype(np.float32) * 0.5
-    got, _ = moe_ffn(params, jnp.asarray(x), top_k=top_k)
+    got, *_ = moe_ffn(params, jnp.asarray(x), top_k=top_k)
     want = _moe_reference(params, x, top_k)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
 
@@ -51,7 +51,7 @@ def test_moe_topk_equals_experts_is_full_softmax_mix():
     E = 4
     params = init_moe_params(dim=16, hidden=32, num_experts=E, seed=2)
     x = jnp.asarray(rng.randn(1, 6, 16).astype(np.float32) * 0.5)
-    got, _ = moe_ffn(params, x, top_k=E)
+    got, *_ = moe_ffn(params, x, top_k=E)
     probs = jax.nn.softmax(x @ params["router"], axis=-1)
     gate = jax.nn.silu(jnp.einsum("btd,edh->beth", x, params["w1"]))
     up = jnp.einsum("btd,edh->beth", x, params["w3"])
@@ -69,12 +69,12 @@ def test_moe_aux_loss_balanced_vs_skewed():
     x = jnp.asarray(rng.randn(2, 32, 16).astype(np.float32))
 
     balanced = dict(params, router=jnp.zeros((16, E)))
-    _, aux_bal = moe_ffn(balanced, x, top_k=k)
+    _, aux_bal, *_ = moe_ffn(balanced, x, top_k=k)
     assert abs(float(aux_bal) - k) < 0.05, float(aux_bal)
 
     skew = np.zeros((16, E), np.float32)
     skew[:, 0] = 100.0   # every token -> expert 0 (positive x => +logit)
-    _, aux_skew = moe_ffn(dict(params, router=jnp.asarray(skew)),
+    _, aux_skew, *_ = moe_ffn(dict(params, router=jnp.asarray(skew)),
                           jnp.abs(x), top_k=k)
     assert float(aux_skew) > 0.9 * E, float(aux_skew)
 
@@ -87,14 +87,18 @@ _MOE_CFG = TransformerConfig(vocab_size=64, dim=32, n_layers=2, n_heads=4,
 def test_transformer_moe_forward_and_aux():
     params = jax.tree_util.tree_map(jnp.asarray,
                                     init_params(_MOE_CFG, seed=0))
-    assert "moe" in params["layers"][0] and "w1" not in params["layers"][0]
+    # the expert leaves sit at the layer's top level, expert-indexed
+    assert params["layers"][0]["w1"].shape == (4, 32, 64)
+    assert params["layers"][0]["router"].shape == (32, 4)
     toks = jnp.asarray(np.random.RandomState(0).randint(
         64, size=(2, 16)).astype(np.int32))
     logits, aux = transformer_forward(params, toks, _MOE_CFG,
                                       return_aux=True)
     assert logits.shape == (2, 16, 64)
-    # aux is the sum over layers; each layer's aux >= top_k (its minimum)
-    assert float(aux) >= _MOE_CFG.n_layers * _MOE_CFG.top_k * 0.99
+    # aux is the weighted sum over layers; each layer's balancing term
+    # >= top_k (its minimum)
+    assert float(aux) >= (_MOE_CFG.aux_loss_coef * _MOE_CFG.n_layers
+                          * _MOE_CFG.top_k * 0.99)
     loss_with_aux = lm_loss(params, toks, _MOE_CFG)
     assert np.isfinite(float(loss_with_aux))
 
@@ -108,7 +112,7 @@ def test_transformer_moe_trains_on_ep_mesh():
     assert shard["w1"].spec == jax.sharding.PartitionSpec("ep", None, None)
     tr = TransformerTrainer(_MOE_CFG, mesh, updater_type="sgd")
     # expert weights really live sharded over ep
-    w1 = tr.params["layers"][0]["moe"]["w1"]
+    w1 = tr.params["layers"][0]["w1"]
     assert w1.sharding.spec[0] == "ep"
     toks = np.random.RandomState(3).randint(
         64, size=(2, 32)).astype(np.int32)
@@ -124,7 +128,7 @@ def test_moe_grad_flows_to_all_routed_experts():
                     .astype(np.float32))
 
     def loss(p):
-        out, aux = moe_ffn(p, x, top_k=2)
+        out, aux, *_ = moe_ffn(p, x, top_k=2)
         return jnp.sum(jnp.square(out)) + 0.01 * aux
 
     g = jax.grad(loss)(params)
@@ -144,8 +148,8 @@ def test_moe_capacity_matches_dense_with_ample_capacity():
     E, k = 4, 2
     params = init_moe_params(dim=16, hidden=32, num_experts=E, seed=6)
     x = jnp.asarray(rng.randn(2, 16, 16).astype(np.float32) * 0.5)
-    want, aux_d = moe_ffn(params, x, top_k=k, dispatch="dense")
-    got, aux_c = moe_ffn(params, x, top_k=k, dispatch="capacity",
+    want, aux_d, *_ = moe_ffn(params, x, top_k=k, dispatch="dense")
+    got, aux_c, *_ = moe_ffn(params, x, top_k=k, dispatch="capacity",
                          capacity_factor=E / k)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5)
@@ -162,7 +166,7 @@ def test_moe_capacity_drops_overflow_tokens():
     skew[:, 0] = 100.0
     params = dict(params, router=skew)
     x = jnp.abs(jnp.asarray(rng.randn(1, 64, 16).astype(np.float32)))
-    out, _ = moe_ffn(params, x, top_k=1, dispatch="capacity",
+    out, *_ = moe_ffn(params, x, top_k=1, dispatch="capacity",
                      capacity_factor=0.5)
     from multiverso_tpu.models.moe import moe_capacity
 
@@ -179,7 +183,7 @@ def test_moe_capacity_grads_flow():
                     .astype(np.float32))
 
     def loss(p):
-        out, aux = moe_ffn(p, x, top_k=2, dispatch="capacity")
+        out, aux, *_ = moe_ffn(p, x, top_k=2, dispatch="capacity")
         return jnp.sum(jnp.square(out)) + 0.01 * aux
 
     g = jax.grad(loss)(params)

@@ -227,13 +227,13 @@ def test_scan_remat_trainer_sharded():
 
 
 def test_scan_remat_moe():
-    """MoE layers stack and scan too (nested dict leaves)."""
+    """MoE layers stack and scan too."""
     from dataclasses import replace
 
     cfg = replace(_CFG, scan_layers=True, remat=True, num_experts=4,
                   top_k=2)
     params = jax.tree_util.tree_map(jnp.asarray, init_params(cfg, seed=5))
-    assert params["layers"]["moe"]["w1"].shape[0] == cfg.n_layers
+    assert params["layers"]["w1"].shape[:2] == (cfg.n_layers, 4)
     toks = jnp.asarray(np.random.RandomState(5).randint(
         128, size=(2, 16)).astype(np.int32))
     loss = lm_loss(params, toks, cfg)
