@@ -31,6 +31,9 @@ from ..util import AsyncBuffer
 
 __all__ = ["SkipGram", "synthetic_corpus"]
 
+# Tokens whose pairs ``SkipGram.batches`` expands in one numpy pass.
+_EXPAND_TOKENS = 1024
+
 
 def synthetic_corpus(num_tokens: int, vocab_size: int, seed: int = 0,
                      zipf_a: float = 1.1) -> np.ndarray:
@@ -83,27 +86,60 @@ class SkipGram:
         self._fused_cache = {}
 
     # ------------------------------------------------------------- batching
+    @staticmethod
+    def _pair_streams(seed: int):
+        """The two independent streams of one ``batches`` call, both children
+        of ``seed``: the windows' and the negatives'."""
+        return tuple(np.random.default_rng([k, seed]) for k in (0, 1))
+
+    def _draw_windows(self, rng: np.random.Generator,
+                      count: int) -> np.ndarray:
+        """The next ``count`` positions' windows, uniform on 1..window.
+        The same stream whatever the counts it is asked for in."""
+        return rng.integers(1, self.window + 1, size=count)
+
+    def _expand(self, corpus: np.ndarray, start: int,
+                windows: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """Every (corpus[i], corpus[j]) with 0 < |i - j| <= windows[i - start]
+        inside the corpus, for i from ``start``, ordered by i, then by j."""
+        stop = start + windows.shape[0]
+        off = np.arange(-self.window, self.window)
+        off += off >= 0                 # -window..-1, 1..window
+        j = np.arange(start, stop)[:, None] + off
+        keep = (np.abs(off) <= windows[:, None]) & (j >= 0) \
+            & (j < corpus.shape[0])
+        # Boolean indexing reads row-major: by i, then by j ascending.
+        centers = np.broadcast_to(corpus[start:stop, None], keep.shape)[keep]
+        return (centers.astype(np.int32, copy=False),
+                corpus[j[keep]].astype(np.int32, copy=False))
+
     def batches(self, corpus: np.ndarray, batch_size: int,
                 seed: int = 0) -> Iterator[Tuple[np.ndarray, np.ndarray,
                                                  np.ndarray]]:
-        """Static-shaped (centers [B], contexts [B], negatives [B,K])."""
-        rng = np.random.RandomState(seed)
+        """Static-shaped (centers [B], contexts [B], negatives [B,K]).
+
+        Pairs are expanded ``_EXPAND_TOKENS`` tokens at a time and handed
+        out as slices; what a block leaves over is carried into the next,
+        and the corpus's last partial batch is dropped.
+        """
+        corpus = np.asarray(corpus)
+        win_rng, neg_rng = self._pair_streams(seed)
         n = corpus.shape[0]
-        centers, contexts = [], []
-        for i in range(n):
-            w = 1 + rng.randint(self.window)
-            for j in range(max(0, i - w), min(n, i + w + 1)):
-                if j != i:
-                    centers.append(corpus[i])
-                    contexts.append(corpus[j])
-            while len(centers) >= batch_size:
-                c = np.asarray(centers[:batch_size], np.int32)
-                o = np.asarray(contexts[:batch_size], np.int32)
-                del centers[:batch_size], contexts[:batch_size]
-                neg = rng.randint(self.vocab_size,
-                                  size=(batch_size, self.negatives)
-                                  ).astype(np.int32)
-                yield c, o, neg
+        centers = contexts = np.empty(0, np.int32)
+        for start in range(0, n, _EXPAND_TOKENS):
+            count = min(_EXPAND_TOKENS, n - start)
+            with tracing.span("mv.sgns.expand", tokens=count):
+                c, o = self._expand(corpus, start,
+                                    self._draw_windows(win_rng, count))
+                centers = np.concatenate([centers, c])
+                contexts = np.concatenate([contexts, o])
+            full = centers.shape[0] - centers.shape[0] % batch_size
+            for a in range(0, full, batch_size):
+                neg = neg_rng.integers(self.vocab_size, dtype=np.int32,
+                                       size=(batch_size, self.negatives))
+                yield (centers[a:a + batch_size],
+                       contexts[a:a + batch_size], neg)
+            centers, contexts = centers[full:], contexts[full:]
 
     # ------------------------------------------------ parity push-pull path
     def train_batch(self, centers: np.ndarray, contexts: np.ndarray,

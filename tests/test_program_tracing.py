@@ -22,7 +22,8 @@ CFG = TransformerConfig(vocab_size=64, dim=32, n_layers=2, n_heads=2,
                         remat_policy="dots")
 TRAINER_STEPS = 3
 SGNS_SPANS = ("mv.input.next", "mv.input.place", "mv.sgns.dispatch",
-              "mv.sgns.sync", "mv.sgns.epoch")
+              "mv.sgns.sync", "mv.sgns.epoch", "mv.sgns.expand")
+SGNS_TOKENS = 300
 TRAINER_SPANS = ("mv.trainer.place", "mv.trainer.dispatch")
 
 
@@ -57,8 +58,8 @@ def _run_sgns():
     try:
         sg = SkipGram(vocab_size=64, dim=8, window=3, negatives=2,
                       learning_rate=0.1, name="traced_w2v")
-        steps, _ = sg.train_epoch_fused(synthetic_corpus(300, 64, seed=1),
-                                        batch_size=128, seed=1)
+        steps, _ = sg.train_epoch_fused(
+            synthetic_corpus(SGNS_TOKENS, 64, seed=1), batch_size=128, seed=1)
         return steps, tracing.events()
     finally:
         mv.shutdown()
@@ -100,10 +101,15 @@ def test_fused_epoch_leaves_its_spans(buffered, name):
     events, steps = buffered
     assert steps >= 2
     # One pull a step and the pull that finds the batcher dry; one
-    # placement and one dispatch a step; one sync and one epoch a call.
+    # placement and one dispatch a step; one sync and one epoch a call;
+    # one expansion a block of the batcher's tokens.
+    from multiverso_tpu.apps import word2vec
+
     want = {"mv.input.next": steps + 1, "mv.input.place": steps,
             "mv.sgns.dispatch": steps, "mv.sgns.sync": 1,
-            "mv.sgns.epoch": 1}[name]
+            "mv.sgns.epoch": 1,
+            "mv.sgns.expand": -(-SGNS_TOKENS // word2vec._EXPAND_TOKENS),
+            }[name]
     found = _named(events, name)
     assert len(found) == want
     (epoch,) = _named(events, "mv.sgns.epoch")
@@ -113,6 +119,8 @@ def test_fused_epoch_leaves_its_spans(buffered, name):
         assert e.ts_us + e.dur_us <= epoch.ts_us + epoch.dur_us + 1
     if name == "mv.sgns.dispatch":
         assert [e.args["step"] for e in found] == list(range(steps))
+    if name == "mv.sgns.expand":
+        assert sum(e.args["tokens"] for e in found) == SGNS_TOKENS
 
 
 @pytest.mark.parametrize("name", TRAINER_SPANS)
