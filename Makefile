@@ -191,11 +191,12 @@ demos: metrics-demo serve-demo wire-demo fanin-demo ops-demo skew-demo \
        embedding-demo bridge-demo latency-demo audit-demo \
        capacity-demo failover-demo doctor-demo
 
-# Continuous perf gate (docs/PERF.md): diff ONE named bench JSON line
+# Continuous gate of the host and native-fleet sections (bench.py; chip
+# speed is benchmarks/run.py's): diff ONE named bench JSON line
 # (`python bench.py wire_micro > line.json; make bench-gate
 # LINE=line.json`) against the committed BENCH_BASELINE.json with
 # per-key noise bands; exits nonzero on an out-of-band regression (serve
-# p50, wire RTT, codec byte ratio, lr/w2v ratios) and 2, saying so,
+# p50, wire RTT, codec byte ratio) and 2, saying so,
 # when no LINE is given — no bench record is committed to fall back on.
 bench-gate:
 	$(PYTHON) tools/bench_compare.py $(if $(LINE),--line $(LINE))

@@ -1,46 +1,26 @@
 #!/usr/bin/env python
-"""Benchmark harness — prints ONE JSON line.
+"""Host and native-fleet gate — prints ONE JSON line.
 
-Measures the BASELINE.json headline configs.  The chip sections (LR,
-word2vec, Add/Get, transformer, MoE, LightLDA, long context) run on a TPU
-or refuse; the host/native sections run on any CPU host (``python
-bench.py wire_micro``).  Every emitted line names ``platform``,
-``device_kind`` and ``device_count``:
+What this file measures runs on any CPU host: the native C++ runtime over
+its loopback wire (transport sweep, SSP, the 8-process LR and word2vec
+push-pull fleets of BASELINE.md, the serve tier and its fan-in, tail,
+ops, latency, audit, failover, skew, capacity, health and embedding
+planes) and the host bridge.  ``make bench-gate LINE=<file>`` holds such
+a line against ``BENCH_BASELINE.json``; ``python bench.py wire_micro``
+runs only the sections whose names contain ``wire_micro``.
 
-- **LR** (ArrayTable, dense): fused-step training throughput, samples/sec.
-- **word2vec** (MatrixTable, sparse rows): fused-step pairs/sec.
-- **Add/Get bandwidth**: three tiers on a large ArrayTable — the
-  device-resident eager path (``add_gbps``/``get_gbps``; REDEFINED in
-  round 3: rounds 1-2 reported the host parity path under these keys,
-  which now reports as ``add_host_gbps``/``get_host_gbps``), plus a raw
-  host<->device link calibration to set beside the host tier.
-- **Transformer** (flagship LM): train-step tokens/sec plus an MFU
-  estimate (model FLOPs from the config / a matmul-calibrated device
-  peak measured in the same run), at a toy config and at an MXU-sized
-  ~1B-param config (scan + remat).
-- **MoE**: dense-dispatch oracle vs the capacity schedule, same model.
-- **LightLDA**: fused Gibbs sweep tokens/sec (the reference lineage's
-  flagship app).
-- **Long context**: seq-16384 train-step tokens/sec through the Pallas
-  flash kernel.
+Chip speed is not measured here.  It is ``python benchmarks/run.py``
+(``BENCHMARK.json``; read in ``PERF.md`` and ``PERF_LEDGER.jsonl``).
 
 Each section runs under its own try/except — a single regression can cost
-that section's numbers but never the whole JSON line (round-1 lesson) —
-and every failure lands in ``errors``: a non-empty ``errors`` list is
-exit code 1.
+that section's numbers but never the whole JSON line — and every failure
+lands in ``errors``: a non-empty ``errors`` list is exit code 1.  Every
+emitted line names ``platform``, ``device_kind`` and ``device_count``,
+and every child a section spawns is pinned to the CPU.
 
-``vs_baseline`` (schema 5) compares the fused TPU path against a real
-distributed parameter-server run measured in the same invocation: 8
-worker+server PROCESSES over the native TcpNet wire doing the
-per-batch Get -> local grad -> Add loop the reference's ``mpirun -n 8``
-job does (``bench_lr_native8``; workers in
-``apps/lr_native_worker.py``).  The reference's own binary stays
-unmeasurable (empty mount, no egress — see BASELINE.md's caveats), so
-this measured-mechanism ratio is the honest stand-in; the older
-same-chip loop ratio still rides along as ``lr_fused_vs_pushpull``.
-
-Primary metric: LR samples/sec (headline config #1). Extras ride along in
-the same JSON object.
+Primary metric: the 8-process native-wire LR rate
+(``lr_native8_samples_per_sec``).  Extras ride along in the same JSON
+object.
 """
 
 from __future__ import annotations
@@ -85,7 +65,7 @@ def _budget_left() -> float:
 # parseable stdout line is always the freshest state, no matter how the
 # process dies.  Each section's measured iteration times also feed a
 # metrics histogram, so the line carries p50/p95/p99 per benchmark
-# (docs/observability.md; PERF.md).
+# (docs/observability.md).
 # ---------------------------------------------------------------------------
 _CURRENT_SECTION = None
 # platform / device_kind / device_count as JAX reports them; filled by
@@ -102,18 +82,6 @@ def _soft_fail(what: str) -> None:
     exc = sys.exc_info()[1]
     traceback.print_exc()
     _ERRORS.append(f"{what}: {type(exc).__name__}: {exc}")
-
-
-def _require_tpu(section: str) -> None:
-    """Chip sections refuse any other backend: a CPU number is never
-    written under a device metric's name."""
-    import jax
-
-    platform = jax.devices()[0].platform
-    if platform != "tpu":
-        raise RuntimeError(
-            f"{section} is a chip section and JAX's first device is on "
-            f"platform '{platform}': refusing to measure")
 
 
 def _observe_iter(seconds: float) -> None:
@@ -138,20 +106,17 @@ def _section_percentiles(name: str, results: dict,
 
 
 def _render_line(results: dict, errors: list) -> dict:
-    for metric, unit, ratio_key in _PRIMARY:
+    for metric, unit in _PRIMARY:
         if metric in results:
             line = {
                 "metric": metric,
                 "value": round(results[metric], 1),
                 "unit": unit,
                 **_DEVICE,
-                # LR: fused TPU path vs the measured 8-process
-                # native-wire run (the reference-mechanism baseline,
-                # bench_lr_native8); other primaries keep the
-                # same-hardware push-pull ratio.  The reference's OWN
-                # binary stays unmeasurable (mount empty).
-                "vs_baseline": round(results[ratio_key], 2)
-                if ratio_key and ratio_key in results else None,
+                # The line's shape since schema 5; its ratios were chip
+                # rates over these host rates and went with the chip
+                # sections (schema 22).
+                "vs_baseline": None,
                 "extras": {k: round(v, 2) for k, v in results.items()},
             }
             if errors:
@@ -188,69 +153,6 @@ def _time_loop(fn, *, warmup: int = 3, iters: int = 10) -> float:
         times.append(time.perf_counter() - t0)
         _observe_iter(times[-1])
     return float(np.median(times))
-
-
-def _time_pipelined(enqueue, *, steps: int = 50, warmup: int = 5,
-                    reps: int = 3) -> float:
-    """Seconds per step for an async-dispatching fn.
-
-    ``enqueue`` must return a tiny device array that depends on the
-    step's result.  We enqueue ``steps`` dispatches and fetch only the
-    last result: the device stream executes in order, so one host sync
-    covers the whole chain and the fixed host cost of a sync is paid
-    once per ``steps``, not once per step.
-    """
-    r = None
-    for _ in range(warmup):
-        r = enqueue()
-    np.asarray(r)
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            r = enqueue()
-        np.asarray(r)
-        times.append((time.perf_counter() - t0) / steps)
-        _observe_iter(times[-1])
-    return float(np.median(times))
-
-
-def bench_lr(batch: int = 8192, features: int = 784, classes: int = 10):
-    from multiverso_tpu.apps import LogisticRegression, synthetic_classification
-
-    _require_tpu("bench_lr")
-
-    x, y = synthetic_classification(batch, features, classes, seed=0)
-
-    # Fused path.
-    lr = LogisticRegression(features, classes, learning_rate=0.1,
-                            name="bench_lr")
-    step, place = lr.make_fused_step()
-    data, state = lr.table.raw_value()
-    xb, yb = place(x), place(y)
-
-    def fused_once():
-        nonlocal data, state
-        data, state, loss = step(data, state, xb, yb)
-        return loss
-
-    fused_s = _time_pipelined(fused_once, steps=100)
-    lr.table.raw_assign(data, state)
-
-    # Reference-shaped push-pull loop (per-batch Get -> grad -> Add).
-    pp = LogisticRegression(features, classes, learning_rate=0.1,
-                            name="bench_lr_pp")
-
-    def pushpull_once():
-        pp.train_batch(x, y)
-
-    pushpull_s = _time_loop(pushpull_once, warmup=2, iters=5)
-
-    return {
-        "lr_fused_samples_per_sec": batch / fused_s,
-        "lr_pushpull_samples_per_sec": batch / pushpull_s,
-        "lr_fused_vs_pushpull": pushpull_s / fused_s,
-    }
 
 
 def _spawn_native_workers(script_name: str, procs: int, marker: str,
@@ -539,10 +441,8 @@ def bench_lr_native8(procs: int = 8, steps: int = 60, batch: int = 1024):
     host — mechanically the reference's ``mpirun -n 8`` LR job
     (push/pull per batch through a wire into C++ updaters), minus the
     reference binary itself (unbuildable, mount empty rounds 1-4).
-    Aggregate samples/s over the max per-rank barrier-to-barrier window;
-    ``main`` derives ``lr_fused_vs_native8`` = TPU-fused / this — a
-    distributed-wire denominator instead of the same-chip push-pull
-    loop."""
+    Aggregate samples/s over the max per-rank barrier-to-barrier
+    window."""
     wall = _run_native_workers("lr_native_worker.py", procs,
                                "NATIVE_LR_OK", (steps, batch))
     out = {
@@ -574,8 +474,7 @@ def bench_w2v_native8(procs: int = 8, steps: int = 20, batch: int = 512):
     connection serves it post-add and the prefetch A/B runs under the
     same staleness regime as the blocking path), push row deltas back
     through non-blocking adds, the reference's
-    distributed-word-embedding mechanism (SURVEY.md §2.36).  ``main``
-    derives ``w2v_fused_vs_native8`` = TPU-fused pairs/s / this.
+    distributed-word-embedding mechanism (SURVEY.md §2.36).
 
     ``w2v_native8_prefetch_speedup`` compares the same job with the
     double-buffer off (blocking gets).  Caveat: on a single-core host
@@ -964,62 +863,6 @@ def bench_embedding(rows: int = 1 << 16, reqs: int = 512):
     return res
 
 
-def bench_w2v(batch: int = 8192, vocab: int = 100_000, dim: int = 128,
-              negatives: int = 5):
-    from multiverso_tpu.apps import SkipGram
-
-    _require_tpu("bench_w2v")
-    rng = np.random.RandomState(0)
-    c = rng.randint(vocab, size=batch).astype(np.int32)
-    o = rng.randint(vocab, size=batch).astype(np.int32)
-    neg = rng.randint(vocab, size=(batch, negatives)).astype(np.int32)
-
-    sg = SkipGram(vocab, dim, negatives=negatives, learning_rate=0.025)
-    step, place = sg.make_fused_step()
-    din, sin = sg.table_in.raw_value()
-    dout, sout = sg.table_out.raw_value()
-    cb, ob, negb = place(c), place(o), place(neg)
-
-    def fused_once():
-        nonlocal din, sin, dout, sout
-        din, sin, dout, sout, loss = step(din, sin, dout, sout, cb, ob, negb)
-        return loss
-
-    fused_s = _time_pipelined(fused_once, steps=100)
-    sg.table_in.raw_assign(din, sin)
-    sg.table_out.raw_assign(dout, sout)
-
-    def pushpull_once():
-        sg.train_batch(c, o, neg)
-
-    pushpull_s = _time_loop(pushpull_once, warmup=2, iters=5)
-
-    return {
-        "w2v_fused_pairs_per_sec": batch / fused_s,
-        "w2v_pushpull_pairs_per_sec": batch / pushpull_s,
-        "w2v_fused_vs_pushpull": pushpull_s / fused_s,
-    }
-
-
-def _slope_seconds(timed, lo: int, hi: int, reduce=min,
-                   nslopes: int = 3) -> float:
-    """Per-unit seconds via two-point slope — cancels any fixed cost
-    (the host's dispatch + sync round-trip) from ``timed(n)``.
-
-    ``nslopes`` independent slopes, reduced with ``reduce``: every noise
-    source here (dispatch overhead, link jitter, host scheduling) ADDS
-    time, so for device-rate estimates ``min`` is the least-contaminated
-    sample; pass ``np.median`` where the payload itself dominates."""
-    slopes = []
-    for _ in range(nslopes):
-        t_lo, t_hi = timed(lo), timed(hi)
-        if t_hi <= t_lo:
-            slopes.append(t_hi / hi)
-        else:
-            slopes.append((t_hi - t_lo) / (hi - lo))
-    return float(reduce(slopes))
-
-
 def _diff_gbps(bytes_diff: float, t_full: float, t_half: float,
                bytes_full: float) -> float:
     """Two-point-slope GB/s with a conservative fallback: if timing noise
@@ -1038,9 +881,9 @@ def bench_bridge(size: int = 16 * 1024 * 1024):
       ``out=`` gets on a single-process native runtime (``assign``
       updater), slope-corrected half-vs-full so fixed per-call cost
       cancels.  REDEFINITION at schema 13: through schema 12 these keys
-      named the JAX-plane parity path (now ``add_jax_host_gbps``/
-      ``get_jax_host_gbps`` in bench_add_get); the unqualified names now
-      mean the native host bridge the tentpole built.  Also emitted as
+      named the JAX-plane parity path (a chip section, gone at schema
+      22); the unqualified names now mean the native host bridge.  Also
+      emitted as
       ``bridge_add_host_gbps``/``bridge_get_host_gbps`` — the NEW,
       collision-free names the bench gate pins (old rounds' identically
       named keys measured a different path and must not gate these).
@@ -1158,643 +1001,17 @@ def bench_bridge(size: int = 16 * 1024 * 1024):
     return out
 
 
-def bench_add_get(size: int = 16 * 1024 * 1024):
-    """Add/Get param-sync bandwidth on a 64 MiB float32 ArrayTable.
-
-    Three tiers, all slope-corrected so the fixed host cost per call
-    cancels:
-
-    - ``add_dev_gbps``/``get_dev_gbps`` — the TPU-native path:
-      device-resident delta into ``add`` (jitted donate-in-place
-      update), compiled-slice ``get(device=True)``.  This is the
-      param-sync rate a training loop on this chip actually sees
-      (HBM-bound).  Also reported under the legacy ``add_gbps``/
-      ``get_gbps`` names (which meant the HOST path in rounds 1-2 and
-      the device path since round 3 — hence the explicit ``_dev`` keys
-      plus the ``bench_schema`` version field for cross-round tooling).
-    - ``add_jax_host_gbps``/``get_jax_host_gbps`` — the eager JAX-plane
-      host parity path (numpy -> device table): bound by the
-      host<->device link.
-      (Schema 13 RENAME: these were ``add_host_gbps``/``get_host_gbps``
-      through schema 12; the unqualified names now belong to
-      ``bench_bridge``'s native host-bridge fast path, which is what
-      "host bridge" means after docs/host_bridge.md.)
-    - ``wire_put_gbps``/``wire_get_gbps``/``wire_rtt_ms`` — raw
-      ``device_put``/fetch calibration, proving the host path runs at the
-      wire limit rather than a table-layer overhead.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from multiverso_tpu.tables import ArrayTable
-
-    _require_tpu("bench_add_get")
-    t = ArrayTable(size, name="bench_bw")
-    nbytes = size * 4
-    out = {}
-
-    # --- device-resident tier ------------------------------------------
-    delta_dev = jax.device_put(np.ones(size, np.float32), t.sharding)
-
-    def timed_dev_add(steps):
-        def once():
-            t.add(delta_dev)
-            return t.raw_value()[0][:1]
-        return _time_pipelined(once, steps=steps, warmup=2, reps=3) * steps
-
-    # Wide step spread: the per-add device time must dominate the fixed
-    # host cost of the sync in the slope, or jitter swamps it.
-    out["add_dev_gbps"] = nbytes / _slope_seconds(timed_dev_add, 8, 88) / 1e9
-
-    def timed_dev_get(steps):
-        def once():
-            return t.get(device=True)[:1]
-        return _time_pipelined(once, steps=steps, warmup=2, reps=3) * steps
-
-    out["get_dev_gbps"] = nbytes / _slope_seconds(timed_dev_get, 8, 88) / 1e9
-    # Legacy names (device tier since round 3); see docstring.
-    out["add_gbps"] = out["add_dev_gbps"]
-    out["get_gbps"] = out["get_dev_gbps"]
-
-    # --- host parity tier (slope over payload size) --------------------
-    half = size // 2
-    host_delta = np.ones(size, np.float32)
-    t_half = ArrayTable(half, name="bench_bw_half")
-
-    def host_add_sec(table, d):
-        def once():
-            table.add(d, sync=True)
-        return _time_loop(once, warmup=1, iters=3)
-
-    sec_full = host_add_sec(t, host_delta)
-    sec_half = host_add_sec(t_half, host_delta[:half])
-    out["add_jax_host_gbps"] = _diff_gbps(nbytes / 2, sec_full, sec_half,
-                                          nbytes)
-
-    bump = jax.jit(lambda d: d + jnp.float32(0))
-
-    def host_get_sec(table):
-        def once():
-            table.raw_assign(bump(table.raw_value()[0]))
-            return np.asarray(table.get())
-        return _time_loop(once, warmup=1, iters=3)
-
-    sec_full = host_get_sec(t)
-    sec_half = host_get_sec(t_half)
-    out["get_jax_host_gbps"] = _diff_gbps(nbytes / 2, sec_full, sec_half,
-                                          nbytes)
-
-    # --- 1-bit compressed host tier (32x fewer wire bytes + feedback) --
-    def host_add_1bit_sec(table, d):
-        def once():
-            table.add(d, sync=True, compress="1bit")
-        return _time_loop(once, warmup=1, iters=3)
-
-    sec_full = host_add_1bit_sec(t, host_delta)
-    sec_half = host_add_1bit_sec(t_half, host_delta[:half])
-    out["add_jax_host_1bit_gbps"] = _diff_gbps(nbytes / 2, sec_full,
-                                               sec_half, nbytes)
-
-    # --- wire calibration ----------------------------------------------
-    probe = jax.device_put(np.zeros(1, np.float32))
-
-    def put_sec(nel):
-        h = np.ones(nel, np.float32)
-        def once():
-            x = jax.device_put(h)
-            return float(x[0])
-        return _time_loop(once, warmup=1, iters=3)
-
-    def get_sec(nel):
-        d = jax.device_put(np.ones(nel, np.float32))
-        def once():
-            return np.asarray(bump(d))
-        return _time_loop(once, warmup=1, iters=3)
-
-    out["wire_put_gbps"] = _diff_gbps(nbytes / 2, put_sec(size),
-                                      put_sec(half), nbytes)
-    out["wire_get_gbps"] = _diff_gbps(nbytes / 2, get_sec(size),
-                                      get_sec(half), nbytes)
-    out["wire_rtt_ms"] = 1e3 * _time_loop(lambda: float(probe[0]),
-                                          warmup=2, iters=5)
-
-    # --- PAIRED host-vs-wire ratio -------------------------------------
-    # The host<->device link's rate can drift between sections, so
-    # comparing the host tier against a link calibration taken minutes
-    # apart partly measures that drift.  Interleave one raw put/fetch
-    # with one table add/get per rep and report the median per-pair
-    # ratio — the table-layer overhead with the link factored OUT.
-    # 1.0 = the parity path runs at the link's limit.
-    def pair_once(wire_fn, table_fn):
-        t0 = time.perf_counter(); wire_fn(); tw = time.perf_counter() - t0
-        t0 = time.perf_counter(); table_fn(); ta = time.perf_counter() - t0
-        return tw / ta
-
-    wire_put_once = lambda: float(jax.device_put(host_delta)[0])
-    add_once = lambda: t.add(host_delta, sync=True)
-    add_once()  # warm the jitted apply out of the measurement
-    out["add_host_vs_wire"] = float(np.median(
-        [pair_once(wire_put_once, add_once) for _ in range(3)]))
-
-    d_wire = jax.device_put(np.ones(size, np.float32))
-    wire_get_once = lambda: np.asarray(bump(d_wire))
-
-    def table_get_once():
-        # Touch the device data first: jax.Array caches its host copy,
-        # so a get() of unchanged data would skip the wire entirely.
-        t.raw_assign(bump(t.raw_value()[0]))
-        return t.get()
-
-    table_get_once()
-    out["get_host_vs_wire"] = float(np.median(
-        [pair_once(wire_get_once, table_get_once) for _ in range(3)]))
-    t.close()        # scratch tables: release the ~100 MB of HBM before
-    t_half.close()   # the multi-GB transformer sections
-    return out
-
-
-def _measured_matmul_peak_flops(dtype_name: str = "bfloat16") -> float:
-    """Device matmul FLOP/s calibrated with a large square bf16 matmul.
-
-    An in-run measurement, not a spec-sheet number: MFU reported against
-    this is 'fraction of what a plain XLA matmul achieves here'.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    import functools
-
-    n = 4096
-    lo, hi = 16, 112
-    rng = np.random.RandomState(0)
-    # Spectral norm ~1 so the chained products neither explode nor vanish.
-    a = jnp.asarray(rng.randn(n, n).astype(np.float32) / np.sqrt(n),
-                    jnp.bfloat16)
-    b = jnp.asarray(rng.randn(n, n).astype(np.float32) / np.sqrt(n),
-                    jnp.bfloat16)
-
-    @functools.partial(jax.jit, static_argnums=2)
-    def mm(a, b, steps):
-        c = jax.lax.fori_loop(0, steps, lambda _, c: (c @ b), a)
-        return jnp.sum(c, dtype=jnp.float32)
-
-    def timed(steps):
-        float(mm(a, b, steps))          # warm (compile) + sync
-        ts = []
-        for _ in range(5):
-            t0 = time.perf_counter()
-            float(mm(a, b, steps))      # value fetch = the only real sync
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts))
-
-    # Two-point slope cancels the fixed host cost of the sync.
-    # Median of 7 slopes: a single noisy pair can swing the implied
-    # peak, and an inflated peak silently deflates every reported MFU,
-    # so the denominator gets the most samples of any number in the
-    # bench.
-    return 2 * n ** 3 / _slope_seconds(timed, lo, hi, reduce=np.median,
-                                       nslopes=7)
-
-
-def _transformer_train_flops(cfg, batch: int, seq: int) -> float:
-    """Model FLOPs per train step (fwd+bwd ≈ 3× fwd matmul FLOPs).
-
-    Weight matmuls: 2·P_mat FLOPs/token forward → 6·P_mat with backward.
-    Attention: QK^T and PV are each 2·B·H·T²·D forward, halved by the
-    causal schedule, tripled for fwd+bwd.
-    """
-    p_mat = cfg.n_layers * (4 * cfg.dim * cfg.dim
-                            + 3 * cfg.dim * cfg.hidden)
-    # Output head only: the embed forward is a gather (no matmul FLOPs)
-    # and its backward a scatter-add, so it contributes no MXU work.
-    p_mat += cfg.vocab_size * cfg.dim
-    tokens = batch * seq
-    weight_flops = 6 * p_mat * tokens
-    attn_flops = (cfg.n_layers * 3
-                  * (4 * batch * cfg.n_heads * seq * seq * cfg.head_dim) / 2)
-    return weight_flops + attn_flops
-
-
-_PEAK_CACHE = {}
-
-
-def _peak_flops() -> float:
-    if "v" not in _PEAK_CACHE:
-        _PEAK_CACHE["v"] = _measured_matmul_peak_flops()
-    return _PEAK_CACHE["v"]
-
-
-def _timed_slope(timed, lo: int, hi: int) -> float:
-    """Per-unit seconds from a warmed two-point slope of ``timed(n)``
-    (cancels fixed per-call costs; falls back to the raw hi-point rate
-    when noise inverts the pair)."""
-    timed(lo)                      # compile + warm
-    t_lo, t_hi = timed(lo), timed(hi)
-    if t_hi <= t_lo:
-        return t_hi / hi
-    return (t_hi - t_lo) / (hi - lo)
-
-
-def _fused_step_seconds(tr, toks, lo: int = 1, hi: int = 5,
-                        reps: int = 2) -> float:
-    """Per-step seconds via the trainer's in-jit multi-step loop.
-
-    Every dispatch carries a fixed host cost — at small step times,
-    per-call timing measures the dispatch, not the step.
-    ``train_steps_fused`` runs n steps in ONE program; the (hi−lo) slope
-    cancels the remaining per-call cost.
-    """
-    def timed(n):
-        ts = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            float(tr.train_steps_fused(toks, n))
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts))
-
-    return _timed_slope(timed, lo, hi)
-
-
-def _bench_transformer_cfg(cfg, batch, seq, prefix, *, steps=10,
-                           with_mfu=True, fused_timing=True):
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from multiverso_tpu.models import TransformerTrainer
-
-    _require_tpu(f"bench_transformer ({prefix})")
-    mesh = Mesh(np.asarray(jax.devices()), ("dp",))
-    tr = TransformerTrainer(cfg, mesh, updater_type="sgd")
-    toks = np.random.RandomState(0).randint(
-        cfg.vocab_size, size=(batch, seq)).astype(np.int32)
-
-    if fused_timing:
-        sec = _fused_step_seconds(tr, toks, lo=1, hi=max(steps // 2, 2))
-    else:
-        # Billion-param configs: the fused-loop program costs minutes to
-        # compile and the per-dispatch host cost is a small share of a
-        # step — per-call pipelined timing is the better trade there.
-        sec = _time_pipelined(lambda: tr.train_step_async(toks),
-                              steps=steps, warmup=2, reps=3)
-    out = {f"{prefix}_tokens_per_sec": batch * seq / sec}
-    if not with_mfu:
-        del tr
-        return out
-    try:
-        peak = _peak_flops()
-        flops = _transformer_train_flops(cfg, batch, seq)
-        out[f"{prefix}_model_tflops_per_sec"] = flops / sec / 1e12
-        out["matmul_peak_tflops_per_sec"] = peak / 1e12
-        out[f"{prefix}_mfu_pct"] = 100.0 * flops / sec / peak
-    except Exception:
-        _soft_fail(f"{prefix} mfu")
-    del tr
-    return out
-
-
-def bench_transformer(batch: int = 8, seq: int = 2048):
-    """Flagship LM train-step throughput, tokens/sec + MFU (bf16)."""
-    from multiverso_tpu.models import TransformerConfig
-
-    cfg = TransformerConfig(vocab_size=8192, dim=512, n_layers=4, n_heads=8,
-                            hidden=1408, max_seq=seq)
-    return _bench_transformer_cfg(cfg, batch, seq, "transformer")
-
-
-def bench_transformer_large(batch: int = 8, seq: int = 2048):
-    """MXU-sized flagship config: ~0.96B params (dim 2048, 16 layers,
-    vocab 32768), bf16, scan-over-layers — the MFU headline.
-
-    Model FLOPs counted at the standard 6·P·tokens (remat recompute is
-    billed as overhead, not as useful FLOPs, so reported MFU is the
-    honest end-to-end number).  Two remat policies:
-
-    - ``transformer_large_mfu_pct`` (headline) — selective remat
-      (remat_policy="dots": matmul outputs saved, attention recomputed)
-      at the batch that fits; recompute tax ≈ attention only.
-    - ``transformer_large_fullremat_mfu_pct`` — full-layer remat at 2×
-      the batch (the rounds-1..3 configuration; billed MFU capped at
-      ~6/8 of hardware utilization by the 2P recompute).
-
-    Plus an in-run roofline decomposition so the MFU gap is numbers,
-    not guesses:
-
-    - ``roofline_fwd_mfu_pct`` — forward-only billed MFU (2P·tokens /
-      fwd time / peak): everything above this lost in the full step is
-      backward/remat-side.
-    - ``roofline_flash_fwd_pct_of_peak`` — the Pallas flash forward
-      kernel alone at this config's [B, H, T, D], its causal FLOPs vs
-      the calibrated matmul peak: how much of the step's attention time
-      is kernel inefficiency vs shape-inherent.
-    - ``roofline_exp_gelem_per_sec`` / ``roofline_flash_fwd_gexp_per_sec``
-      — the chip's streamed elementwise exp rate vs the kernel's achieved
-      exps/s (softmax needs one exp per attention score).  The kernel
-      running at/above the streamed exp rate while far below matmul peak
-      is the decomposition: attention cost on this chip is VPU-class
-      exp/elementwise work that the MXU-peak denominator cannot price —
-      kernel-at-roofline, not kernel deficiency.
-    - ``roofline_remat_tax_pct`` — (full-remat step − selective step) /
-      full-remat step at equal tokens: the wall-clock share full remat
-      burns on recompute.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from multiverso_tpu.models import TransformerConfig
-
-    base = dict(vocab_size=32768, dim=2048, n_layers=16,
-                n_heads=16, hidden=5632, max_seq=seq, scan_layers=True)
-    out = {}
-
-    # Selective remat headline: dots policy fits batch//2 on one v5e.
-    sel_batch = max(batch // 2, 1)
-    cfg_sel = TransformerConfig(**base, remat=True, remat_policy="dots")
-    out.update(_bench_transformer_cfg(cfg_sel, sel_batch, seq,
-                                      "transformer_large", steps=5,
-                                      fused_timing=False))
-
-    cfg_full = TransformerConfig(**base, remat=True)
-    full = _bench_transformer_cfg(cfg_full, batch, seq,
-                                  "transformer_large_fullremat", steps=5,
-                                  fused_timing=False)
-    out.update(full)
-
-    # ---- roofline decomposition ---------------------------------------
-    # Every probe here uses an IN-JIT fori_loop + two-point slope: the
-    # fixed host cost of one dispatch would, at millisecond kernel
-    # times, BE the measurement.
-    def _injit_seconds(make_loop, lo=4, hi=24):
-        def timed(steps):
-            ts = []
-            for _ in range(4):
-                t0 = time.perf_counter()
-                float(make_loop(steps))
-                ts.append(time.perf_counter() - t0)
-            return float(np.median(ts))
-        return _timed_slope(timed, lo, hi)
-
-    try:
-        import functools
-
-        peak = _peak_flops()
-        # Forward-only MFU (selective config's batch; no remat effect in
-        # a pure forward).
-        from multiverso_tpu.models import init_params, transformer_forward
-        toks = np.random.RandomState(0).randint(
-            base["vocab_size"], size=(sel_batch, seq)).astype(np.int32)
-        params = jax.tree_util.tree_map(
-            jnp.asarray, init_params(cfg_sel, seed=0),
-            is_leaf=lambda x: isinstance(x, np.ndarray))
-        tok_dev = jnp.asarray(toks)
-
-        @functools.partial(jax.jit, static_argnums=2)
-        def fwd_many(p, t, steps):
-            def body(i, carry):
-                t_i, acc = carry
-                # Loop-carried token dependency: an invariant body would
-                # be hoisted (computed once) and the slope would read as
-                # a >100% MFU fantasy.
-                out = transformer_forward(p, t_i, cfg_sel)
-                nxt = jnp.roll(t_i, 1, axis=1)
-                return nxt, acc + jnp.sum(out[:, -1, :1]
-                                          .astype(jnp.float32))
-            _, acc = jax.lax.fori_loop(0, steps, body,
-                                       (t, jnp.float32(0)))
-            return acc
-
-        fwd_sec = _injit_seconds(
-            lambda n: fwd_many(params, tok_dev, n), lo=2, hi=8)
-        fwd_flops = _transformer_train_flops(cfg_sel, sel_batch, seq) / 3
-        out["roofline_fwd_mfu_pct"] = 100.0 * fwd_flops / fwd_sec / peak
-        del params
-
-        # Flash forward kernel alone at the config's attention shape.
-        from multiverso_tpu.ops import flash_attention
-        H, D = base["n_heads"], base["dim"] // base["n_heads"]
-        rng = np.random.RandomState(1)
-        q0, k0, v0 = [jnp.asarray(rng.randn(sel_batch, H, seq, D),
-                                  jnp.bfloat16) for _ in range(3)]
-
-        @functools.partial(jax.jit, static_argnums=3)
-        def fa_many(q, k, v, steps):
-            def body(_, c):
-                return flash_attention(c, k, v, causal=True)
-            return jnp.sum(jax.lax.fori_loop(0, steps, body, q)
-                           .astype(jnp.float32))
-
-        fa_sec = _injit_seconds(lambda n: fa_many(q0, k0, v0, n))
-        # Causal QK^T + PV: 2 matmuls × 2·B·H·T²·D flops, halved by mask.
-        fa_flops = 2 * (2 * sel_batch * H * seq * seq * D) / 2
-        out["roofline_flash_fwd_pct_of_peak"] = (100.0 * fa_flops
-                                                 / fa_sec / peak)
-
-        # The BINDING constraint for attention on this chip is the VPU /
-        # transcendental class, not the MXU: softmax needs one exp per
-        # score.  Two rates for the comparison: the XLA elementwise exp
-        # chain (HBM-streamed) and the kernel's achieved exps/s (ideal
-        # causal count / time — a LOWER bound, block rounding computes
-        # more).  The kernel beating the streamed rate while sitting at
-        # single-digit %-of-matmul-peak is the decomposition: attention
-        # cost is exp/VPU-class work the MXU peak cannot price.
-        xe = jnp.asarray(np.random.RandomState(2)
-                         .randn(8, 2048, 2048).astype(np.float32))
-
-        @functools.partial(jax.jit, static_argnums=1)
-        def exp_many(x, steps):
-            def body(_, c):
-                return jnp.exp(c * 0.999)
-            return jnp.sum(jax.lax.fori_loop(0, steps, body, x))
-
-        exp_sec = _injit_seconds(lambda n: exp_many(xe, n))
-        out["roofline_exp_gelem_per_sec"] = xe.size / exp_sec / 1e9
-        causal_exps = sel_batch * H * seq * seq / 2
-        out["roofline_flash_fwd_gexp_per_sec"] = (causal_exps / fa_sec
-                                                  / 1e9)
-
-        # Remat tax at equal tokens/step.
-        sel_sec = sel_batch * seq / out["transformer_large_tokens_per_sec"]
-        full_sec_eq = (sel_batch * seq
-                       / full["transformer_large_fullremat_tokens_per_sec"])
-        out["roofline_remat_tax_pct"] = (100.0 * (full_sec_eq - sel_sec)
-                                         / full_sec_eq)
-    except Exception:
-        _soft_fail("bench_transformer_large roofline")
-    return out
-
-
-def bench_moe(batch: int = 8, seq: int = 1024):
-    """MoE transformer (E=8, top_k=2): dense-dispatch oracle vs the
-    capacity gather/scatter schedule.  Same model, same tokens — the
-    speedup is the FLOP ratio the capacity path realizes in wall-clock."""
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh
-
-    from multiverso_tpu.models import TransformerConfig, TransformerTrainer
-
-    _require_tpu("bench_moe")
-    out = {}
-    sec = {}
-    for disp in ("dense", "capacity"):
-        cfg = TransformerConfig(vocab_size=16384, dim=1024, n_layers=8,
-                                n_heads=8, hidden=2816, max_seq=seq,
-                                num_experts=8, top_k=2,
-                                moe_dispatch=disp, capacity_factor=1.25,
-                                scan_layers=True, remat=True)
-        mesh = Mesh(np.asarray(jax.devices()), ("dp",))
-        tr = TransformerTrainer(cfg, mesh, updater_type="sgd")
-        toks = np.random.RandomState(0).randint(
-            cfg.vocab_size, size=(batch, seq)).astype(np.int32)
-        sec[disp] = _fused_step_seconds(tr, toks, lo=1, hi=4)
-        out[f"moe_{disp}_tokens_per_sec"] = batch * seq / sec[disp]
-        del tr
-    out["moe_capacity_vs_dense"] = sec["dense"] / sec["capacity"]
-    return out
-
-
-def bench_long_context(batch: int = 1, seq: int = 16384):
-    """Long-context capability: seq-16384 causal LM train step through
-    the Pallas flash kernel (O(T) memory).  tokens/s only — at batch 1
-    the MFU framing is dominated by attention-kernel shape effects, not
-    framework overheads, so the throughput is the honest headline."""
-    from multiverso_tpu.models import TransformerConfig
-
-    # Off-TPU the attention would be the jnp path, whose [B,H,T,T] scores
-    # at seq 16384 are not this cell: _bench_transformer_cfg refuses, and
-    # nothing here shrinks seq.
-    cfg = TransformerConfig(vocab_size=8192, dim=1024, n_layers=4,
-                            n_heads=8, hidden=2816, max_seq=seq,
-                            scan_layers=True, remat=True)
-    out = _bench_transformer_cfg(cfg, batch, seq, "longctx", steps=5,
-                                 with_mfu=False)
-    out["longctx_seq"] = float(seq)   # the rate is meaningless without it
-    if seq == 16384:
-        # The longer-seq probes sit near the chip's memory limit, so
-        # each guards itself: a 64k/256k failure must not discard the
-        # measurements already banked above.
-        try:
-            # 4x the headline seq: the flash kernel's O(T) memory is
-            # what makes this fit at all; tokens/s drops with
-            # attention's O(T^2) FLOPs — the honest scaling story.
-            cfg64 = TransformerConfig(vocab_size=8192, dim=1024,
-                                      n_layers=4, n_heads=8, hidden=2816,
-                                      max_seq=65536, scan_layers=True,
-                                      remat=True)
-            out64 = _bench_transformer_cfg(cfg64, batch, 65536,
-                                           "longctx64k", steps=3,
-                                           with_mfu=False)
-            out["longctx64k_tokens_per_sec"] = (
-                out64["longctx64k_tokens_per_sec"])
-            out["longctx64k_seq"] = 65536.0
-        except Exception:
-            _soft_fail("bench_long_context 64k")
-        try:
-            # 16x the headline seq (VERDICT r4 action 9): a 256k-token
-            # causal train step fits on ONE chip only because the flash
-            # kernel's memory is O(T) — the [T, T] score matrix alone
-            # would be 128 GiB in bf16.  Model slimmed (2 layers, dim
-            # 512, vocab 2048: the f32 CE logits at T=262144 are the
-            # actual memory governor) and per-call pipelined timing —
-            # at seconds per step the fused-loop program would pay
-            # minutes of compile for nothing.
-            cfg256 = TransformerConfig(vocab_size=2048, dim=512,
-                                       n_layers=2, n_heads=4, hidden=1408,
-                                       max_seq=262144, scan_layers=True,
-                                       remat=True)
-            out256 = _bench_transformer_cfg(cfg256, 1, 262144,
-                                            "longctx256k", steps=2,
-                                            with_mfu=False,
-                                            fused_timing=False)
-            out["longctx256k_tokens_per_sec"] = (
-                out256["longctx256k_tokens_per_sec"])
-            out["longctx256k_seq"] = 262144.0
-        except Exception:
-            _soft_fail("bench_long_context 256k")
-    return out
-
-
-def bench_lightlda(num_docs: int = 2048, vocab: int = 10000, K: int = 64,
-                   doc_len: int = 64):
-    """LightLDA fused Gibbs sweep — the reference lineage's flagship app.
-
-    tokens/s per full sweep (in-jit sampling + sparse host delta rebuild
-    + table round trips — the end-to-end per-iteration rate)."""
-    from multiverso_tpu.apps import LightLDA, synthetic_documents
-
-    _require_tpu("bench_lightlda")
-    docs, _ = synthetic_documents(num_docs=num_docs, vocab_size=vocab,
-                                  num_topics=K, doc_len=doc_len, seed=0)
-    lda = LightLDA(vocab, K, alpha=0.5, beta=0.1)
-    dt = lda.initialize_counts(docs)
-    dt = lda.run_fused_pass(docs, dt)          # compile + warm
-
-    times = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        dt = lda.run_fused_pass(docs, dt)
-        times.append(time.perf_counter() - t0)
-    sec = float(np.median(times))
-    return {"lda_tokens_per_sec": docs.size / sec}
-
-
-def bench_lightlda_mh(num_docs: int = 2048, vocab: int = 10000,
-                      doc_len: int = 64):
-    """The real LightLDA sampler (WWW'15 MH cycle proposals) at large K.
-
-    Per-token cost is O(mh_steps · log K) element gathers — independent
-    of K up to the CDF build — so tokens/s must hold at K=1024/8192 where
-    the dense kernel's [D·L·K] posterior tensor (0.5–4.3 GB here) is the
-    wall.  Reported per-K so the scaling is auditable."""
-    from multiverso_tpu.apps import LightLDA, synthetic_documents
-
-    _require_tpu("bench_lightlda_mh")
-    out = {}
-    for K in (1024, 8192):
-        docs, _ = synthetic_documents(num_docs=num_docs, vocab_size=vocab,
-                                      num_topics=min(K, 64),
-                                      doc_len=doc_len, seed=0)
-        lda = LightLDA(vocab, K, alpha=0.5, beta=0.1, name=f"lda_mh_k{K}")
-        try:
-            dt = lda.initialize_counts(docs)
-            dt = lda.run_mh_pass(docs, dt)     # compile + warm
-            times = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                dt = lda.run_mh_pass(docs, dt)
-                times.append(time.perf_counter() - t0)
-            sec = float(np.median(times))
-            out[f"lda_mh_k{K}_tokens_per_sec"] = docs.size / sec
-        finally:
-            # The context registry pins tables; close() actually frees
-            # the [V, K] HBM before the long-context section allocates —
-            # including when the large-K pass OOMs (main() swallows the
-            # section error; the leak must not degrade later sections).
-            lda.close()
-    return out
-
-
-# transformer_large runs BEFORE the toy config so its MFU leads the
-# extras: the ~1B-param number is the honest hardware-utilization
-# headline, the dim-512 toy config is overhead-bound by construction
-# (VERDICT r4 weak #1).
-_SECTIONS = [bench_lr, bench_lr_native8, bench_w2v, bench_w2v_native8,
+_SECTIONS = [bench_lr_native8, bench_w2v_native8,
              bench_wire_micro, bench_ssp, bench_serve, bench_serve_fanin,
              bench_tail,
              bench_ops, bench_latency, bench_audit, bench_failover,
              bench_skew, bench_capacity, bench_health,
              bench_embedding,
-             bench_bridge,
-             bench_add_get,
-             bench_transformer_large, bench_transformer, bench_moe,
-             bench_lightlda, bench_lightlda_mh, bench_long_context]
+             bench_bridge]
 
 _PRIMARY = [
-    ("lr_fused_samples_per_sec", "samples/sec", "lr_fused_vs_native8"),
-    ("w2v_fused_pairs_per_sec", "pairs/sec", "w2v_fused_vs_native8"),
-    ("transformer_large_tokens_per_sec", "tokens/sec", None),
-    ("transformer_tokens_per_sec", "tokens/sec", None),
-    ("add_gbps", "GB/s", None),
+    ("lr_native8_samples_per_sec", "samples/sec"),
+    ("w2v_native8_pairs_per_sec", "pairs/sec"),
 ]
 
 
@@ -1802,9 +1019,14 @@ def main() -> None:
     # Schema/partial line FIRST — before any JAX-touching import — so
     # even a backend-init hang killed by `timeout` leaves one parseable
     # line on stdout.  JAX picks the platform (JAX_PLATFORMS or its own
-    # default): nothing here pins one, every later line names it, and
-    # the chip sections refuse anything but a TPU.
-    results = {"bench_schema": 21}
+    # default): nothing here pins one and every later line names it.
+    # Schema 22: the chip sections went (LR, word2vec, Add/Get,
+    # transformer, MoE, long context, LightLDA) and with them every
+    # lr_fused_*, w2v_fused_*, *_pushpull_*, add_*/get_*_gbps of the
+    # JAX plane, wire_{put,get}_gbps, wire_rtt_ms, transformer_*,
+    # roofline_*, matmul_peak_*, moe_*, longctx*, lda_* key and the two
+    # *_fused_vs_native8 ratios; the host keys are schema 21's.
+    results = {"bench_schema": 22}
     errors = _ERRORS
     _emit(results, errors)
 
@@ -1816,98 +1038,6 @@ def main() -> None:
     dev = jax.devices()[0]
     _DEVICE.update(platform=dev.platform, device_kind=dev.device_kind,
                    device_count=len(jax.devices()))
-    # Schema history: 1-2 = add_gbps meant the host parity path;
-    # 3 = add_gbps redefined to the device tier; 4 = explicit
-    # add_dev_gbps/get_dev_gbps keys (legacy names kept as aliases),
-    # transformer_large_mfu_pct = selective-remat headline with
-    # _fullremat_ keys and the roofline_* decomposition alongside;
-    # 5 = lr vs_baseline is lr_fused_vs_native8 (the 8-process
-    # native-wire denominator, BASELINE.md action 2) — the old same-chip
-    # loop ratio stays as lr_fused_vs_pushpull;
-    # 6 = w2v_native8_* + w2v_fused_vs_native8 close the word2vec half
-    # of the north-star ledger the same way (VERDICT r4 action 1); also
-    # adds wire_tcp_*/wire_mpi_* (direct transport sweep),
-    # ssp_vs_bsp_speedup, longctx256k_*, and the w2v primary's
-    # vs_baseline becomes w2v_fused_vs_native8;
-    # 7 = incremental emission (the cumulative line re-prints after
-    # EVERY completed section — the last stdout line survives SIGTERM
-    # and SIGKILL alike) + per-benchmark latency percentiles
-    # (<section>_p50_ms/_p95_ms/_p99_ms from the measured iterations);
-    # 8 = serve section (serve_{cold,cached,coal8}_{p50,p95,p99}_ms/_qps
-    # over the 2-process native wire + serve_cached_vs_cold_p50, the
-    # cached-read speedup headline — docs/serving.md), and `bench.py
-    # <name>` now runs only the sections whose names contain <name>;
-    # 9 = compressed wire data plane (docs/wire_compression.md): the
-    # schema line now prints BEFORE the first JAX-touching import,
-    # wire_{raw,1bit}_{bytes,msgs}_per_s + wire_1bit_bytes_ratio
-    # (codec sweep via net.bytes counters), add_agg_ratio/_adds_per_s
-    # (aggregation collapse), and lr_native_loss_{raw,1bit} +
-    # lr_native_1bit_loss_ratio (equal-steps codec convergence);
-    # 10 = event-driven transport (docs/transport.md): every native
-    # fleet now defaults to -net_engine=epoll (so all lr/w2v/serve
-    # native keys measure the reactor), wire_epoll_* joins wire_tcp_*
-    # in the micro sweep, and bench_serve_fanin adds fanin_{p50,p99}_ms
-    # / fanin_qps / fanin_shed_rate / fanin_accepted — 1000 anonymous
-    # client sockets against one server rank;
-    # 11 = live introspection plane (docs/observability.md): bench_ops
-    # measures in-band OpsQuery scrapes under the 1k fan-in load —
-    # ops_scrape_{p50,p99}_ms (acceptance: p99 < 5 ms) and
-    # ops_overhead_pct (serve QPS cost of a live scraper vs an
-    # unscraped A/B run; acceptance < 1%), gated by make bench-gate;
-    # 12 = workload observability plane (docs/observability.md):
-    # bench_skew drives a zipf(1.0) vs uniform row stream from the 1k
-    # anonymous herd with the hot-key/load sketches armed —
-    # skew_ratio_zipf / skew_ratio_uniform (bucket-load imbalance,
-    # planted heavy hitters must all surface: skew_hot_recall = 1),
-    # and hotkey_track_overhead_pct (armed-vs-disarmed QPS cost of the
-    # accounting; acceptance < 2%), all bench-gated;
-    # 13 = host-bridge fast path (docs/host_bridge.md): bench_bridge
-    # measures the native bridge — borrowed arena adds / out= gets
-    # (add_host_gbps/get_host_gbps REDEFINED to this path; the old
-    # JAX-plane parity keys renamed add_jax_host_*), the borrowed-vs-
-    # copying A/B (bridge_borrow_speedup), and offload_overlap_pct
-    # (share of the bridge round trip hidden by OffloadedState's double
-    # buffering); gate keys bridge_add_host_gbps/bridge_get_host_gbps/
-    # offload_overlap_pct are new names so old rounds cannot collide;
-    # 14 = sparse-embedding serving fast path (docs/embedding.md):
-    # bench_embedding drives a 2-rank sharded embedding table with a
-    # zipf hot-head row-get stream through three serving tiers —
-    # embedding_cold_* (cache off, wire per lookup), embedding_
-    # rowcache_* (row-granular versioned cache; _vs_cold_p50 >= 10x),
-    # embedding_replica_* (native hot-key replica, pinned-buffer call;
-    # _vs_rowcache_p50 >= 1) — plus embedding_zipf_p99_ms,
-    # embedding_sparse_bytes_ratio (all-zero tail rows, sparse reply
-    # codec off/on), and embedding_addrows_borrow_speedup (multi-shard
-    # borrowed run-iovec AddRows vs per-rank staging; >= 2x), all
-    # bench-gated;
-    # 15 = latency-attribution plane (docs/observability.md "latency
-    # plane"): bench_latency sweeps the 1k herd untimed / wire-stamped /
-    # stamped+profiled — latency_stage_*_{p50,p99}_ms breakdown,
-    # latency_stage_sum_ratio (offset-corrected stages telescope to the
-    # e2e), latency_timing_overhead_pct and
-    # latency_profiler_overhead_pct (always-on bars, < 1%);
-    # 16 = delivery-audit plane (docs/observability.md "audit plane"):
-    # bench_audit re-runs the fan-in herd armed vs disarmed
-    # (audit_overhead_pct < 1%), A/Bs an async add stream
-    # (audit_add_overhead_pct — the path the seq stamps ride), and
-    # times one injected duplicate send until the in-band "audit"
-    # scrape names it (audit_detect_ms, audit_dup_named = 1), all
-    # bench-gated
-    # (17 = tail, 18 = replication/failover, 19 = capacity — see those
-    # sections' docstrings);
-    # 20 = closed-loop health plane (docs/observability.md "health
-    # plane"): bench_health A/Bs the timed serve probe stream with the
-    # SLO rule pack + flush-loop evaluation + alerts push armed vs
-    # disarmed (health_overhead_pct < 1%) and times a seeded 25 ms
-    # apply delay until the burn-rate alert FIRES through the real
-    # flush loop (health_alert_detect_ms; health_alert_fired = 1),
-    # bench-gated;
-    # 21 = nothing hides the device: no CPU pin, top-level platform /
-    # device_kind / device_count on every line, chip sections refuse a
-    # non-TPU backend (long context no longer shrinks 16k to 2k), every
-    # sub-measurement failure lands in `errors`, and a non-empty
-    # `errors` list is exit code 1.
-
     # A budget SIGTERM lands mid-section: convert it to an exception so
     # the JSON accumulated so far still prints (the whole point of the
     # one-line contract — a kill costs sections, not the line).  The
@@ -1950,16 +1080,6 @@ def main() -> None:
                 _emit(results, errors)
     finally:
         signal.signal(signal.SIGTERM, prev_sigterm)
-    if {"lr_native8_samples_per_sec",
-            "lr_fused_samples_per_sec"} <= results.keys():
-        results["lr_fused_vs_native8"] = (
-            results["lr_fused_samples_per_sec"]
-            / results["lr_native8_samples_per_sec"])
-    if {"w2v_native8_pairs_per_sec",
-            "w2v_fused_pairs_per_sec"} <= results.keys():
-        results["w2v_fused_vs_native8"] = (
-            results["w2v_fused_pairs_per_sec"]
-            / results["w2v_native8_pairs_per_sec"])
     try:
         mv.shutdown()
     except Exception as exc:

@@ -35,7 +35,9 @@ import time
 
 import numpy as np
 
-# bench.py:bench_transformer_large's headline cell, exactly.
+# BENCHMARK.json's ouro-2.6b-l16-ut1 in width, depth, scan and remat, with an
+# invented vocabulary of 32,768 (the configuration's is 49,152); 4 x 2048 is
+# its seq2k-b4 traffic.
 FLAGSHIP = dict(vocab_size=32768, dim=2048, n_layers=16, n_heads=16,
                 hidden=5632, max_seq=2048, scan_layers=True, remat=True,
                 remat_policy="dots")
@@ -48,7 +50,8 @@ MOE = dict(vocab_size=32768, dim=2048, n_layers=2, n_heads=16, hidden=1024,
            qk_norm=True, router_z_loss_coef=0.001, moe_dispatch="grouped",
            scan_layers=True, remat=True, remat_policy="dots")
 MOE_BATCH, MOE_SEQ = 2, 2048
-# bench.py:bench_lr / bench_w2v shapes.
+# The 784 x 10 LR and the 100,000 x 128 word2vec tables of BASELINE.md's
+# native fleets, at one 8192 batch.
 LR_SHAPE = dict(batch=8192, features=784, classes=10)
 W2V_SHAPE = dict(batch=8192, vocab=100_000, dim=128, negatives=5)
 # The published width (GoogleNews-vectors-negative300): not a multiple of the
@@ -154,7 +157,7 @@ def phase_bsp(mv, size: int = 1024) -> dict:
 
 def phase_lr(mv, batch: int, features: int, classes: int,
              steps: int = 40) -> dict:
-    """The fused LR step (bench.py:bench_lr): loss must fall."""
+    """The fused LR step: loss must fall."""
     from multiverso_tpu.apps import (LogisticRegression,
                                      synthetic_classification)
 
@@ -178,9 +181,9 @@ def phase_lr(mv, batch: int, features: int, classes: int,
 
 def phase_w2v(mv, batch: int, vocab: int, dim: int, negatives: int,
               steps: int = 30) -> dict:
-    """The fused word2vec step (bench.py:bench_w2v's shapes).
+    """The fused word2vec step.
 
-    ``_sgns_loss`` is a batch MEAN, so at the bench's lr 0.025 one step
+    ``_sgns_loss`` is a batch MEAN, so at the paper's lr 0.025 one step
     moves each touched row by lr/batch of its gradient and the float32
     loss does not change in thirty steps.  The smoke steps at
     lr = 0.025 * batch — the per-pair step of the reference's per-sample

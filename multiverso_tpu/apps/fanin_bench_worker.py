@@ -339,7 +339,7 @@ def _audit_bench(endpoint: str, nclients: int, rt, h) -> dict:
     add_stream()                             # first streams pay the
     # post-herd backlog drain, not the audit plane — discard them.
     # Interleaved best-of-3 per arm: loopback add throughput swings
-    # ~2x run to run (PERF.md), and slowdown noise is one-sided.
+    # ~2x run to run, and slowdown noise is one-sided.
     armed_runs, disarmed_runs = [], []
     for _ in range(3):
         rt.set_audit(False)

@@ -11,9 +11,7 @@ wire between them, C++ updaters — SURVEY.md §3.4, ref
 worker+server rank over TcpNet, pulling the dense weight table through
 the C API, computing a softmax-regression gradient on CPU with numpy,
 and pushing it back through a blocking Add.  ``bench.py`` aggregates
-N ranks into ``lr_native8_samples_per_sec`` and reports the TPU fused
-path's speedup over it as ``lr_fused_vs_native8`` — a real
-distributed-wire denominator rather than a same-chip loop.
+N ranks into ``lr_native8_samples_per_sec``.
 
 Run: ``python lr_native_worker.py <machine_file> <rank> <steps>
 <batch> [codec]`` (spawned by ``bench.py``; stands alone for

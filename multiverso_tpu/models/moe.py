@@ -1,4 +1,4 @@
-"""Mixture-of-Experts feed-forward: one router, three schedules.
+"""Mixture-of-Experts feed-forward: one router, two schedules.
 
 Not in the reference (a 2016 parameter server predates MoE).  The expert
 leaves sit at a layer's top level beside the attention weights (``router
@@ -21,13 +21,11 @@ routes reach their experts:
   name.
 - ``"dense"``: every expert computes every token, scaled afterwards by the
   combine weights.  Exact, ``E/k`` times the useful FLOPs: the oracle the
-  tests hold the other two to, and the one schedule GSPMD partitions over
+  tests hold ``grouped`` to, and the one schedule GSPMD partitions over
   ``ep`` (expert-indexed weights carry a ``NamedSharding`` over it and XLA
   turns the einsums into all-to-alls).
-- ``"capacity"``: GShard-style static buckets; a route beyond its expert's
-  capacity is **dropped** (``moe.dropped_routes`` counts them off the
-  step).  No cell runs it: a candidate for deletion with
-  ``capacity_factor`` in a later ``simplicity`` PR.
+
+Neither drops a route.
 
 Scopes for the chip trace (docs/observability.md, "Chip plane"):
 ``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``; the
@@ -36,7 +34,6 @@ schedule a step was traced with is counted in ``moe.traced{dispatch=}``.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Any, Dict
 
 import jax
@@ -47,8 +44,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import metrics
 
-__all__ = ["GROUPED_SAVED", "dropped_routes", "init_moe_params",
-           "moe_capacity", "moe_ffn", "moe_pspecs", "moe_shardings"]
+__all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_pspecs",
+           "moe_shardings"]
 
 # ``checkpoint_name``s of the three grouped-matmul outputs.  A grouped matmul
 # is not a ``dot_general``, so remat policy "dots" saves them by name
@@ -103,9 +100,8 @@ def _aux_losses(probs, logits, load):
     """``(balance, z)`` of one layer.  ``balance`` is the switch/GShard
     load-balancing term ``E * sum_e f_e P_e``: ``f_e`` the share of tokens
     with a route to expert ``e`` (``load`` counts routes, and a token's k
-    routes go to k different experts), ``P_e`` the mean router probability;
-    on the routing decisions, before any drop, so every schedule optimises
-    the same objective.  ``z`` is the router z-loss, the mean over tokens of
+    routes go to k different experts), ``P_e`` the mean router probability.
+    ``z`` is the router z-loss, the mean over tokens of
     ``logsumexp(logits)^2`` (arXiv:2409.02060, section 3.4)."""
     E = probs.shape[-1]
     tokens = probs.size // E
@@ -116,35 +112,19 @@ def _aux_losses(probs, logits, load):
     return balance, z
 
 
-def moe_capacity(num_tokens: int, num_experts: int, top_k: int,
-                 capacity_factor: float) -> int:
-    """Static per-expert bucket size (rounded up to the fp32 sublane 8)."""
-    c = int(np.ceil(num_tokens * top_k / num_experts * capacity_factor))
-    return max(8, -(-c // 8) * 8)
-
-
-def dropped_routes(load, capacity: int) -> int:
-    """Routes the ``capacity`` schedule drops given per-expert route counts
-    ``load [..., E]``: what lies beyond each bucket.  The grouped and dense
-    schedules drop none."""
-    return int(np.maximum(np.asarray(load) - capacity, 0).sum())
-
-
 def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
             compute_dtype=None, dispatch: str = "dense",
-            capacity_factor: float = 1.25, norm_topk_prob: bool = True
+            norm_topk_prob: bool = True
             ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """x [B, T, dim] → ``(out [B, T, dim], balance, z, load [E])``: the
     layer's output, its two auxiliary loss terms (``_aux_losses``, scalars,
-    unweighted) and the routes each expert was sent (int32, before any
-    drop).  ``dispatch`` picks the schedule (this file's header); the
-    choice is taken at trace time and counted in ``moe.traced``."""
-    schedules = {"grouped": _moe_grouped, "dense": _moe_dense,
-                 "capacity": partial(_moe_capacity_dispatch,
-                                     capacity_factor=capacity_factor)}
+    unweighted) and the routes each expert was sent (int32).  ``dispatch``
+    picks the schedule (this file's header); the choice is taken at trace
+    time and counted in ``moe.traced``."""
+    schedules = {"grouped": _moe_grouped, "dense": _moe_dense}
     if dispatch not in schedules:
         raise ValueError(f"unknown moe dispatch '{dispatch}' "
-                         "(expected grouped|dense|capacity)")
+                         "(expected grouped|dense)")
     metrics.counter("moe.traced", {"dispatch": dispatch}).inc()
     return schedules[dispatch](params, x, top_k, compute_dtype or x.dtype,
                                norm_topk_prob)
@@ -215,41 +195,4 @@ def _moe_dense(params, x, top_k, dt, norm_topk_prob):
                             params["w2"].astype(dt))          # [B,E,T,d]
     out = jnp.einsum("betd,bte->btd", expert_out,
                      combine.astype(dt))
-    return out.astype(x.dtype), balance, z, load
-
-
-def _moe_capacity_dispatch(params, x, top_k, dt, norm_topk_prob,
-                           capacity_factor):
-    B, T, D = x.shape
-    N = B * T
-    E = params["router"].shape[1]
-    top_p, top_idx, balance, z, load = _route(params, x, top_k,
-                                              norm_topk_prob)
-    C = moe_capacity(N, E, top_k, capacity_factor)
-
-    # Slot assignment, token-major (earlier tokens win bucket slots, the
-    # reference-free standard tie-break).  [N·k] flat routes.
-    e_flat = top_idx.reshape(-1)                       # [N*k]
-    onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)
-    pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=1) - 1
-    valid = pos < C                                    # dropped = overflow
-    slot = jnp.where(valid, e_flat * C + jnp.minimum(pos, C - 1), E * C)
-
-    # Scatter tokens into [E·C (+1 overflow row), D] buckets.
-    x_rep = jnp.repeat(x.reshape(N, D), top_k, axis=0).astype(dt)
-    buckets = jnp.zeros((E * C + 1, D), dt).at[slot].add(
-        x_rep * valid[:, None].astype(dt))
-    xe = buckets[:E * C].reshape(E, C, D)
-
-    # Batched expert FFN — one [E, C, ·] einsum chain on the MXU.
-    gate = jax.nn.silu(jnp.einsum("ecd,edh->ech", xe,
-                                  params["w1"].astype(dt)))
-    up = jnp.einsum("ecd,edh->ech", xe, params["w3"].astype(dt))
-    ye = jnp.einsum("ech,ehd->ecd", gate * up,
-                    params["w2"].astype(dt)).reshape(E * C, D)
-
-    # Gather back, weight, and sum each token's surviving routes.
-    w = (top_p.reshape(-1) * valid.astype(jnp.float32)).astype(dt)
-    y_tok = ye[jnp.minimum(slot, E * C - 1)] * w[:, None]
-    out = jnp.sum(y_tok.reshape(N, top_k, D), axis=1).reshape(B, T, D)
     return out.astype(x.dtype), balance, z, load

@@ -15,7 +15,6 @@ Design (not in the reference — see models/__init__):
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -25,9 +24,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..updaters import AddOption, get_updater
-from .. import dashboard, metrics, tracing
-from .moe import (GROUPED_SAVED, dropped_routes, init_moe_params, moe_capacity,
-                  moe_ffn, moe_pspecs)
+from .. import dashboard, tracing
+from .moe import GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs
 
 __all__ = ["TransformerConfig", "init_params", "stack_layer_params",
            "transformer_forward", "expert_load", "TransformerTrainer"]
@@ -56,11 +54,9 @@ class TransformerConfig:
     norm_topk_prob: bool = True
     # models/moe.py's schedules: "grouped" = dropless sort + grouped matmul,
     # FLOPs of exactly the routes (what the OLMoE cell runs; no ``ep``
-    # axis); "dense" = exact all-experts dispatch (the tests' oracle);
-    # "capacity" = GShard-style static buckets that drop overflow,
-    # FLOPs ∝ top_k·capacity_factor/E.
+    # axis); "dense" = exact all-experts dispatch (the tests' oracle, and
+    # the one GSPMD partitions over ``ep``).
     moe_dispatch: str = "dense"
-    capacity_factor: float = 1.25
     # QK-norm (OLMoE): an RMSNorm with its own gain over the whole
     # dim-wide q and k projections, before the split into heads and rotary.
     qk_norm: bool = False
@@ -249,18 +245,11 @@ def expert_load(params, tokens, cfg: TransformerConfig,
     """Routes each expert of each layer is sent for ``tokens`` [B, T]:
     int32 ``[n_layers, num_experts]``, every row summing to ``B*T*top_k``.
     A diagnostic off the step (one forward pass): how uneven the groups of
-    the grouped schedule are, and, for the ``capacity`` schedule, how many
-    routes it drops (added to the counter ``moe.dropped_routes``)."""
+    the grouped schedule are."""
     if not cfg.num_experts:
         raise ValueError("expert_load: the configuration has no experts")
-    load = jax.jit(lambda p, t: _forward(p, t, cfg, mesh)[2])(
+    return jax.jit(lambda p, t: _forward(p, t, cfg, mesh)[2])(
         params, jnp.asarray(tokens, jnp.int32))
-    if cfg.moe_dispatch == "capacity":
-        capacity = moe_capacity(int(np.prod(tokens.shape)), cfg.num_experts,
-                                cfg.top_k, cfg.capacity_factor)
-        metrics.counter("moe.dropped_routes").inc(
-            dropped_routes(load, capacity))
-    return load
 
 
 def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
@@ -350,7 +339,6 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                     out, balance, z, load = moe_ffn(
                         lyr, h, top_k=cfg.top_k, compute_dtype=dt,
                         dispatch=cfg.moe_dispatch,
-                        capacity_factor=cfg.capacity_factor,
                         norm_topk_prob=cfg.norm_topk_prob)
                     aux = (cfg.aux_loss_coef * balance
                            + cfg.router_z_loss_coef * z)
@@ -527,14 +515,14 @@ def lm_loss(params, tokens, cfg: TransformerConfig,
 
     MoE configs add their weighted auxiliary loss (``transformer_forward``).
 
-    Two CE lowerings, picked by head size (all v5e-measured): the
-    ``_ce`` custom_vjp wins at vocab 32k (+0.9 MFU points on the ~1B
-    config — its bf16 dlogits keep the model's two largest matmuls on
-    the MXU fast path) and by ~2% at vocab 16k (MoE bench config), but
-    LOSES 40% end-to-end on a small head (dim 512 / vocab 8k toy:
-    525k → 313k tok/s) because the vjp boundary blocks XLA from fusing
-    the CE backward, and those extra HBM passes dwarf the cheap
-    matmul's dtype win."""
+    Two CE lowerings, picked by head size.  Both of ``BENCHMARK.json``'s
+    ``lm_train`` configurations (vocab 49,152 and 50,304) take the
+    ``_ce`` custom_vjp, whose bf16 dlogits keep the model's two largest
+    matmuls on the MXU fast path.  Earlier rounds measured it to win
+    from vocab 16k up and to LOSE 40% end-to-end on a small head (dim
+    512 / vocab 8k) because the vjp boundary blocks XLA from fusing the
+    CE backward, and those extra HBM passes dwarf the cheap matmul's
+    dtype win; no workload sits on that side now (ROADMAP Design 2)."""
     logits, aux = transformer_forward(params, tokens, cfg, mesh,
                                       return_aux=True)
     # Crossover measured between 8192 (big loss) and 16384 (small win).
@@ -598,19 +586,19 @@ class TransformerTrainer:
         ``batch/accum``.  The trade is an extra f32 grad accumulator of
         one full parameter set riding the scan carry, so the knob pays
         off on ACTIVATION-dominated configs (long context, few params);
-        on the ~0.96B bench config the carry (~3.9 GB) was measured to
-        eat the whole 16 GB headroom the smaller microbatch freed.  The
+        at 0.96B parameters the carry (~3.9 GB) was measured in an
+        earlier round to eat the whole 16 GB headroom the smaller
+        microbatch freed.  The
         microbatch must still be divisible by the mesh's dp axis.
         MoE configs are rejected: their load-balancing aux loss is a
-        product of batch MEANS (nonlinear in the batch) and capacity
-        buckets size from N=B·T, so microbatching would silently change
-        the training objective, not just its memory profile."""
+        product of batch MEANS (nonlinear in the batch), so microbatching
+        would silently change the training objective, not just its
+        memory profile."""
         cfg, mesh = self.cfg, self.mesh
         if accum > 1 and cfg.num_experts:
             raise ValueError(
                 "grad accumulation is not equivalence-preserving for MoE "
-                "configs (batch-nonlinear aux loss, capacity buckets "
-                "sized from the microbatch); run MoE at full batch")
+                "configs (batch-nonlinear aux loss); run MoE at full batch")
 
         def step(params, state, tokens):
             if accum == 1:
@@ -645,42 +633,6 @@ class TransformerTrainer:
             return params, state, loss
 
         return step
-
-    def train_steps_fused(self, tokens, n: int) -> jax.Array:
-        """Run ``n`` train steps on one batch inside ONE compiled program
-        (``fori_loop`` over the step body); returns the last device loss.
-
-        Every dispatch carries a fixed host cost, which at small step
-        times IS the measurement; one fused program amortizes it to
-        nothing.  Also useful for burn-in loops where the batch is fixed.
-        """
-        from ..parallel.sharding import batch_placer
-        if self._offload is not None:
-            raise RuntimeError(
-                "train_steps_fused keeps the state on device across the "
-                "whole fused program — incompatible with offload_state "
-                "(use train_step_async)")
-        fn = getattr(self, "_multi_step", None)
-        if fn is None:
-            raw = self._raw_step()
-
-            @partial(jax.jit, donate_argnums=(0, 1))
-            def multi(params, state, tokens, n):
-                def body(_, carry):
-                    p, s, _loss = carry
-                    return raw(p, s, tokens)
-
-                zero = jnp.float32(0)
-                # Dynamic bound: one compile serves every n.
-                return jax.lax.fori_loop(0, n, body,
-                                         (params, state, zero))
-
-            self._multi_step = fn = multi
-        _, place = batch_placer(self.mesh, "dp", dtype=jnp.int32)
-        self.params, self.state, loss = fn(self.params, self.state,
-                                           place(tokens),
-                                           jnp.int32(n))
-        return loss
 
     # ------------------------------------------------------ state offload
     def offload_state(self, bridge) -> None:

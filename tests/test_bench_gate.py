@@ -63,18 +63,18 @@ def test_gate_fails_on_seeded_regression(tmp_path):
     """A line regressing a gated key out of band must exit nonzero and
     name the key — the 'fails on a seeded regression' acceptance bar."""
     line = {"metric": "x", "value": 1, "unit": "u",
-            "extras": {"transformer_large_mfu_pct": 40.0,   # -17 points
+            "extras": {"serve_cached_vs_cold_p50": 4.0,     # floor is 10x
                        "wire_tcp_rtt_ms": 95.0}}            # Nagle is back
     p = tmp_path / "seeded.json"
     p.write_text(json.dumps(line) + "\n")
     rc, out = _gate("--line", str(p))
     assert rc == 1, out
-    assert "transformer_large_mfu_pct" in out and "FAIL" in out, out
+    assert "serve_cached_vs_cold_p50" in out and "FAIL" in out, out
     assert "wire_tcp_rtt_ms" in out, out
 
 
 def test_gate_passes_in_band_line(tmp_path):
-    line = {"extras": {"transformer_large_mfu_pct": 57.0,
+    line = {"extras": {"serve_cached_vs_cold_p50": 25.0,
                        "wire_tcp_rtt_ms": 0.4,
                        "fanin_shed_rate": 0.8,
                        "fanin_accepted": 1000.0,
@@ -423,8 +423,8 @@ def test_gate_skips_absent_uring_keys(tmp_path):
 def test_last_parseable_line_wins(tmp_path):
     """Schema-7 cumulative emission: the LAST line is the freshest
     cumulative state and must shadow earlier partials."""
-    stale = {"extras": {"transformer_large_mfu_pct": 10.0}}
-    fresh = {"extras": {"transformer_large_mfu_pct": 57.0}}
+    stale = {"extras": {"serve_cached_vs_cold_p50": 2.0}}
+    fresh = {"extras": {"serve_cached_vs_cold_p50": 26.0}}
     p = tmp_path / "cumulative.json"
     p.write_text(json.dumps(stale) + "\n" + json.dumps(fresh) + "\n")
     rc, out = _gate("--line", str(p))

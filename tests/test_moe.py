@@ -2,6 +2,8 @@
 reference, load-balancing aux-loss behavior, transformer integration,
 and an 8-device (dp, sp, tp, ep) expert-parallel training run."""
 
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -103,17 +105,23 @@ def test_transformer_moe_forward_and_aux():
     assert np.isfinite(float(loss_with_aux))
 
 
-def test_transformer_moe_trains_on_ep_mesh():
+@pytest.mark.parametrize("stack", ["layers", "scan_remat"])
+def test_transformer_moe_trains_on_ep_mesh(stack):
     """Full 4-axis parallelism: dp x sp x tp x ep on the 8-device mesh,
-    experts sharded over ep, loss decreases through the updater step."""
+    experts sharded over ep, loss decreases through the updater step;
+    as a list of layers and scanned under remat (what
+    ``__graft_entry__``'s ``ep`` arm runs)."""
+    scan = stack == "scan_remat"
+    cfg = replace(_MOE_CFG, scan_layers=scan, remat=scan)
     mesh = Mesh(np.asarray(jax.devices()).reshape(1, 2, 2, 2),
                 ("dp", "sp", "tp", "ep"))
     shard = moe_shardings(mesh)
     assert shard["w1"].spec == jax.sharding.PartitionSpec("ep", None, None)
-    tr = TransformerTrainer(_MOE_CFG, mesh, updater_type="sgd")
-    # expert weights really live sharded over ep
-    w1 = tr.params["layers"][0]["w1"]
-    assert w1.sharding.spec[0] == "ep"
+    tr = TransformerTrainer(cfg, mesh, updater_type="sgd")
+    # expert weights really live sharded over ep (stacked: after the
+    # layer axis)
+    w1 = tr.params["layers"]["w1"] if scan else tr.params["layers"][0]["w1"]
+    assert w1.sharding.spec[int(scan)] == "ep"
     toks = np.random.RandomState(3).randint(
         64, size=(2, 32)).astype(np.int32)
     first = tr.train_step(toks)
@@ -139,71 +147,12 @@ def test_moe_grad_flows_to_all_routed_experts():
     assert float(per_expert.min()) > 0
 
 
-# ------------------------------------------------------ capacity dispatch
-
-def test_moe_capacity_matches_dense_with_ample_capacity():
-    """With capacity_factor = E/top_k the buckets can never overflow, so
-    the capacity schedule must reproduce the dense oracle exactly."""
-    rng = np.random.RandomState(7)
-    E, k = 4, 2
-    params = init_moe_params(dim=16, hidden=32, num_experts=E, seed=6)
-    x = jnp.asarray(rng.randn(2, 16, 16).astype(np.float32) * 0.5)
-    want, aux_d, *_ = moe_ffn(params, x, top_k=k, dispatch="dense")
-    got, aux_c, *_ = moe_ffn(params, x, top_k=k, dispatch="capacity",
-                         capacity_factor=E / k)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5)
-    np.testing.assert_allclose(float(aux_c), float(aux_d), rtol=1e-6)
-
-
-def test_moe_capacity_drops_overflow_tokens():
-    """Routing everything to one expert with a tight capacity drops the
-    overflow routes: late tokens lose that expert's contribution."""
-    rng = np.random.RandomState(8)
-    E = 4
-    params = init_moe_params(dim=16, hidden=32, num_experts=E, seed=9)
-    skew = np.zeros((16, E), np.float32)
-    skew[:, 0] = 100.0
-    params = dict(params, router=skew)
-    x = jnp.abs(jnp.asarray(rng.randn(1, 64, 16).astype(np.float32)))
-    out, *_ = moe_ffn(params, x, top_k=1, dispatch="capacity",
-                     capacity_factor=0.5)
-    from multiverso_tpu.models.moe import moe_capacity
-
-    C = moe_capacity(64, E, 1, 0.5)
-    flat = np.asarray(out).reshape(64, 16)
-    # first C tokens got expert 0; the rest overflowed -> exactly zero
-    assert np.abs(flat[:C]).max() > 0
-    np.testing.assert_allclose(flat[C:], 0.0)
-
-
-def test_moe_capacity_grads_flow():
-    params = init_moe_params(dim=16, hidden=32, num_experts=4, seed=10)
-    x = jnp.asarray(np.random.RandomState(11).randn(2, 16, 16)
-                    .astype(np.float32))
-
-    def loss(p):
-        out, aux, *_ = moe_ffn(p, x, top_k=2, dispatch="capacity")
-        return jnp.sum(jnp.square(out)) + 0.01 * aux
-
-    g = jax.grad(loss)(params)
-    assert float(jnp.abs(g["router"]).max()) > 0
-    assert float(jnp.max(jnp.abs(g["w2"]), axis=(1, 2)).min()) > 0
-
-
-def test_transformer_moe_capacity_trains_on_ep_mesh():
-    """Capacity dispatch through the full 4-axis sharded trainer (with
-    scan+remat — the production MoE configuration)."""
-    from dataclasses import replace
-
-    cfg = replace(_MOE_CFG, moe_dispatch="capacity", capacity_factor=2.0,
-                  scan_layers=True, remat=True)
-    mesh = Mesh(np.asarray(jax.devices()).reshape(1, 2, 2, 2),
-                ("dp", "sp", "tp", "ep"))
-    tr = TransformerTrainer(cfg, mesh, updater_type="sgd")
-    toks = np.random.RandomState(12).randint(
-        64, size=(2, 32)).astype(np.int32)
-    first = tr.train_step(toks)
-    for _ in range(10):
-        last = tr.train_step(toks)
-    assert last < first, (first, last)
+@pytest.mark.parametrize("dispatch", ["capacity", "nonesuch"])
+def test_moe_refuses_a_schedule_it_does_not_have(dispatch):
+    """``capacity`` (static buckets that dropped overflow routes) went in
+    PR 28; it is refused like any other unknown name, with the two that
+    exist."""
+    params = init_moe_params(dim=16, hidden=32, num_experts=4, seed=0)
+    x = jnp.zeros((1, 4, 16), jnp.float32)
+    with pytest.raises(ValueError, match=r"grouped\|dense\)"):
+        moe_ffn(params, x, dispatch=dispatch)
