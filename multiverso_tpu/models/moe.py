@@ -12,13 +12,17 @@ routes reach their experts:
 
 - ``"grouped"``: what a benchmark cell runs
   (``olmoe-1b-7b-e64.zipf-seq4k-b2``).  Dropless: the routes are
-  stable-sorted by expert, the rows gathered, three grouped matmuls
-  (``jax.lax.ragged_dot``, which XLA:TPU lowers to its own grouped-matmul
-  custom call) run over the ``[E, D, H]`` weights with the group sizes,
-  and each token sums its ``k`` weighted rows.  Static shapes, FLOPs of
-  exactly the routes, no ``[N*k, E]`` one-hot and no capacity.  It does not
-  run over an ``ep`` mesh axis: ``transformer_forward`` refuses that by
-  name.
+  stable-sorted by expert, the rows gathered in that order, three grouped
+  matmuls (``jax.lax.ragged_dot``, which XLA:TPU lowers to its own
+  grouped-matmul custom call) run over the ``[E, D, H]`` weights with the
+  group sizes, the rows are gathered back by the inverse permutation and
+  each token sums its ``k`` weighted rows in float32.  The sort order is a
+  permutation of the routes, so the transpose of either gather is a gather
+  by the other index (``_dispatch``, ``_combine``: hand-written
+  ``custom_vjp`` rules): no row moves by scatter, forward or backward.
+  Static shapes, FLOPs of exactly the routes, no ``[N*k, E]`` one-hot and
+  no capacity.  It does not run over an ``ep`` mesh axis:
+  ``transformer_forward`` refuses that by name.
 - ``"dense"``: every expert computes every token, scaled afterwards by the
   combine weights.  Exact, ``E/k`` times the useful FLOPs: the oracle the
   tests hold ``grouped`` to, and the one schedule GSPMD partitions over
@@ -28,12 +32,15 @@ routes reach their experts:
 Neither drops a route.
 
 Scopes for the chip trace (docs/observability.md, "Chip plane"):
-``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``; the
-schedule a step was traced with is counted in ``moe.traced{dispatch=}``.
+``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``; the two
+backward rules open ``moe.dispatch`` / ``moe.combine`` themselves, so their
+rows are booked where the forward's are.  The schedule a step was traced
+with is counted in ``moe.traced{dispatch=}``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
@@ -140,6 +147,71 @@ def _route(params, x, top_k: int, norm_topk_prob: bool):
     return (top_p, top_idx, *_aux_losses(probs, logits, load), load)
 
 
+def _sort_routes(top_idx):
+    """The routes of ``top_idx [N, k]`` stable-sorted by expert (a group keeps
+    token order): ``(expert[order], order, inv [N, k])`` with ``inv[order[i]]
+    = i`` over the flat routes.  Two sorts, the second of the pairs
+    ``(order[i], i)``.  On the v5e a sort of 65,536 routes inside the step is
+    0.05-0.07 ms, a gather of as many scalars 0.47; alone, the second sort
+    took 0.58-0.62 ms and an int32 scatter of the iota 0.88-0.91 (PERF.md
+    section 6, PR 29)."""
+    routes = jnp.arange(top_idx.size, dtype=jnp.int32)
+    sorted_expert, order = jax.lax.sort((top_idx.reshape(-1), routes),
+                                        num_keys=1, is_stable=True)
+    _, inv = jax.lax.sort((order, routes), num_keys=1)
+    return sorted_expert, order, inv.reshape(top_idx.shape)
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inv):
+    """Rows of ``x [N, D]`` in expert order, ``[N*k, D]``: route ``order[i]``
+    is a row of token ``order[i] // k``.  ``inv [N, k]`` is where each of a
+    token's routes went."""
+    return x[order // inv.shape[1]]
+
+
+def _dispatch_fwd(x, order, inv):
+    return _dispatch(x, order, inv), inv
+
+
+def _dispatch_bwd(inv, d_rows):
+    # The transpose of a gather by a permutation is a gather by its
+    # inverse: token n's k cotangent rows, summed in float32.
+    with jax.named_scope("moe.dispatch"):
+        d_x = jnp.sum(d_rows[inv], axis=1, dtype=jnp.float32)
+    return d_x.astype(d_rows.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(down, top_p, order, inv, dtype):
+    """``out[n] = sum_j top_p[n, j] * down[inv[n, j]]``, summed in float32
+    and cast to ``dtype``: the expert-order rows ``down [N*k, D]`` gathered
+    back into route order.  The cast is in here so that the transpose is
+    handed ``d_out`` as narrow as it was made."""
+    return jnp.einsum("nkd,nk->nd", down[inv], top_p,
+                      preferred_element_type=jnp.float32).astype(dtype)
+
+
+def _combine_fwd(down, top_p, order, inv, dtype):
+    return _combine(down, top_p, order, inv, dtype), (down, top_p, order, inv)
+
+
+def _combine_bwd(dtype, res, d_out):
+    down, top_p, order, inv = res
+    with jax.named_scope("moe.combine"):
+        # In expert order, one pass over the rows for both cotangents.
+        d_rows = d_out[order // inv.shape[1]].astype(jnp.float32)
+        d_down = d_rows * top_p.reshape(-1)[order][:, None]
+        d_weight = jnp.sum(d_rows * down.astype(jnp.float32), axis=-1)
+    return d_down.astype(down.dtype), d_weight[inv], None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _moe_grouped(params, x, top_k, dt, norm_topk_prob):
     B, T, D = x.shape
     N = B * T
@@ -148,16 +220,16 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob):
         probs, logits, top_p, top_idx = _routing(params, x, top_k,
                                                  norm_topk_prob)
     with jax.named_scope("moe.dispatch"):
-        # Route r = n*k + j is token n's j-th expert.  Sorted by expert
-        # (stable: a group keeps token order), a group's rows are contiguous
-        # and its size is the distance between two boundaries.
-        expert = top_idx.reshape(-1)                              # [N*k]
-        order = jnp.argsort(expert, stable=True)
-        bounds = jnp.searchsorted(expert[order], jnp.arange(E + 1),
+        # Route r = n*k + j is token n's j-th expert.  Sorted by expert, a
+        # group's rows are contiguous and its size is the distance between
+        # two boundaries.  ``order`` is a permutation of the routes, so rows
+        # go out by it and come back by its inverse, and neither way nor
+        # either transpose is a scatter.
+        sorted_expert, order, inv = _sort_routes(top_idx.reshape(N, top_k))
+        bounds = jnp.searchsorted(sorted_expert, jnp.arange(E + 1),
                                   side="left")
         sizes = jnp.diff(bounds).astype(jnp.int32)                # [E]
-        token = order // top_k
-        rows = x.reshape(N, D).astype(dt)[token]                  # [N*k, D]
+        rows = _dispatch(x.reshape(N, D).astype(dt), order, inv)  # [N*k, D]
     with jax.named_scope("moe.route"):
         balance, z = _aux_losses(probs, logits, sizes)
     with jax.named_scope("moe.experts"):
@@ -171,10 +243,8 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob):
             jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes),
             GROUPED_SAVED[2])                                     # [N*k, D]
     with jax.named_scope("moe.combine"):
-        weight = top_p.reshape(-1)[order]
-        out = jnp.zeros((N, D), jnp.float32).at[token].add(
-            down.astype(jnp.float32) * weight[:, None])
-    return out.reshape(B, T, D).astype(x.dtype), balance, z, sizes
+        out = _combine(down, top_p.reshape(N, top_k), order, inv, x.dtype)
+    return out.reshape(B, T, D), balance, z, sizes
 
 
 def _moe_dense(params, x, top_k, dt, norm_topk_prob):

@@ -5,6 +5,7 @@ small, on the CPU."""
 
 import json
 import os
+import re
 import sys
 from dataclasses import replace
 
@@ -22,7 +23,9 @@ from benchmarks.reference import olmoe_lm  # noqa: E402
 from multiverso_tpu import metrics  # noqa: E402
 from multiverso_tpu.models import (TransformerConfig,  # noqa: E402
                                    TransformerTrainer, init_params)
-from multiverso_tpu.models.moe import init_moe_params, moe_ffn  # noqa: E402
+from multiverso_tpu.models.moe import (_combine, _dispatch,  # noqa: E402
+                                       _sort_routes,
+                                       init_moe_params, moe_ffn)
 from multiverso_tpu.models.transformer import (expert_load,  # noqa: E402
                                                lm_loss)
 
@@ -231,6 +234,14 @@ def test_no_route_is_dropped_when_one_expert_takes_every_token():
     assert per_token.min() > 0                       # no token without output
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
 def test_grouped_schedule_holds_no_route_by_expert_matrix():
     """No ``[N*k, E]`` (one-hot, cumsum) intermediate: routes are sorted,
     not expanded against the experts, as ``dense``'s one-hot combine
@@ -241,11 +252,8 @@ def test_grouped_schedule_holds_no_route_by_expert_matrix():
         lambda p, x: moe_ffn(p, x, top_k=3, dispatch="grouped"))(params, x)
 
     def shapes(jp):
-        for eqn in jp.eqns:
-            for v in eqn.outvars:
-                yield tuple(getattr(v.aval, "shape", ()))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from shapes(sub)
+        return (tuple(getattr(v.aval, "shape", ()))
+                for eqn in _eqns(jp) for v in eqn.outvars)
 
     seen = set(shapes(jaxpr.jaxpr))
     assert (routes, 16) in seen                      # the gathered rows
@@ -253,6 +261,167 @@ def test_grouped_schedule_holds_no_route_by_expert_matrix():
     dense = jax.make_jaxpr(
         lambda p, x: moe_ffn(p, x, top_k=3, dispatch="dense"))(params, x)
     assert (2, 24, 3, experts) in set(shapes(dense.jaxpr))
+
+
+def _grouped_loss(p, x):
+    out, balance, z, _ = moe_ffn(p, x, top_k=3, dispatch="grouped",
+                                 norm_topk_prob=False)
+    return jnp.sum(jnp.sin(out)) + 0.3 * balance + 0.2 * z
+
+
+def test_grouped_schedule_moves_no_row_by_scatter():
+    """Forward and backward: rows of width ``D`` go out and come back by
+    gathers (a permutation and its inverse), so no ``scatter`` or
+    ``scatter-add`` has them as operand or updates.  ``top_k``'s own
+    transpose scatters scalars and stays."""
+    params, x = _ffn_case(E=8, k=3, dim=16, tokens=(2, 24))
+    D, routes = 16, 2 * 24 * 3
+    jaxpr = jax.make_jaxpr(jax.grad(_grouped_loss, argnums=(0, 1)))(params, x)
+
+    def last_dims(eqn):
+        return [v.aval.shape[-1] for v in (*eqn.invars, *eqn.outvars)
+                if getattr(v.aval, "shape", ())]
+
+    found = list(_eqns(jaxpr.jaxpr))
+    scatters = [e for e in found if e.primitive.name.startswith("scatter")]
+    assert not [e for e in scatters if D in last_dims(e)], scatters
+    # out by ``order``, back by ``inv``, and the two transposes
+    row_gathers = [e for e in found if e.primitive.name == "gather"
+                   and e.outvars[0].aval.shape in ((routes, D),
+                                                   (routes // 3, 3, D))]
+    assert len(row_gathers) >= 4
+    # the walk does see a row scatter where there is one: the plain spelling
+    plain = jax.make_jaxpr(jax.grad(
+        lambda x, token: jnp.sum(jnp.sin(x[token]))))(
+            x.reshape(-1, D), jnp.arange(routes) // 3)
+    assert [e for e in _eqns(plain.jaxpr)
+            if e.primitive.name.startswith("scatter") and D in last_dims(e)]
+
+
+def _routes(k: int, collapsed: bool, N=24, E=8, seed=0):
+    """``(order, inv [N, k])`` of a random router's top-k, or of one that
+    sends every route to expert 0."""
+    rng = np.random.RandomState(seed)
+    if collapsed:
+        top_idx = jnp.zeros((N, k), jnp.int32)
+    else:
+        _, top_idx = jax.lax.top_k(jnp.asarray(rng.randn(N, E)), k)
+    sorted_expert, order, inv = _sort_routes(top_idx)
+    expert = top_idx.reshape(-1)
+    np.testing.assert_array_equal(order, jnp.argsort(expert, stable=True))
+    np.testing.assert_array_equal(sorted_expert, expert[order])
+    return order, inv
+
+
+@pytest.mark.parametrize("collapsed", [False, True],
+                         ids=["random", "collapsed"])
+@pytest.mark.parametrize("k", [1, 3, 8])
+def test_routes_go_out_and_come_back_as_permutations(k, collapsed):
+    """``x[order][inv] == x``, and the hand-written transposes of
+    ``_dispatch`` and ``_combine`` equal autodiff's of the plain spelling
+    (a gather by token, ``zeros.at[token].add``) to float32 round-off."""
+    N, D = 24, 16
+    order, inv = _routes(k, collapsed, N=N)
+    rng = np.random.RandomState(k)
+    routes = jnp.arange(N * k)
+    np.testing.assert_array_equal(routes[order][inv.reshape(-1)], routes)
+    np.testing.assert_array_equal(inv.reshape(-1)[order], routes)
+    if collapsed:                     # stable: one group keeps token order
+        np.testing.assert_array_equal(order, routes)
+    token = order // k
+
+    x, d_rows = (jnp.asarray(rng.randn(*shape).astype(np.float32))
+                 for shape in ((N, D), (N * k, D)))
+    rows, pull = jax.vjp(lambda x: _dispatch(x, order, inv), x)
+    want_rows, want_pull = jax.vjp(lambda x: x[token], x)
+    np.testing.assert_array_equal(rows, want_rows)
+    np.testing.assert_allclose(pull(d_rows)[0], want_pull(d_rows)[0],
+                               rtol=1e-6, atol=1e-6)
+
+    down, top_p, d_out = (jnp.asarray(rng.randn(*shape).astype(np.float32))
+                          for shape in ((N * k, D), (N, k), (N, D)))
+
+    def plain(down, top_p):
+        weight = top_p.reshape(-1)[order]
+        return jnp.zeros((N, D), jnp.float32).at[token].add(
+            down * weight[:, None])
+
+    out, pull = jax.vjp(lambda d, p: _combine(d, p, order, inv, jnp.float32),
+                        down, top_p)
+    want_out, want_pull = jax.vjp(plain, down, top_p)
+    np.testing.assert_allclose(out, want_out, rtol=1e-6, atol=1e-6)
+    for got, want in zip(pull(d_out), want_pull(d_out)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_dispatch_transpose_sums_a_tokens_rows_in_float32():
+    """bfloat16 rows: a token's ``k`` cotangent rows are added in float32
+    and rounded once."""
+    N, D, k = 24, 16, 8
+    order, inv = _routes(k, collapsed=False, N=N)
+    rng = np.random.RandomState(3)
+    d_rows = jnp.asarray(rng.randn(N * k, D), jnp.bfloat16)
+    x = jnp.zeros((N, D), jnp.bfloat16)
+    got = jax.vjp(lambda x: _dispatch(x, order, inv), x)[1](d_rows)[0]
+    want = np.zeros((N, D), np.float32)
+    np.add.at(want, np.asarray(order) // k, np.asarray(d_rows, np.float32))
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(got, jnp.asarray(want).astype(jnp.bfloat16))
+
+
+def test_combine_in_bfloat16_is_the_plain_spelling_bit_for_bit():
+    """bfloat16 rows and a bfloat16 output, as the cell runs: the cast to
+    the output's type sits inside ``_combine`` so that its transpose gathers
+    ``d_out`` as bfloat16 rows; value and ``d_down`` equal the plain
+    spelling's (float32 ``zeros.at[token].add``, cast outside) to the bit,
+    ``d_top_p`` to float32 round-off."""
+    N, D, k = 24, 16, 8
+    order, inv = _routes(k, collapsed=False, N=N)
+    token = order // k
+    rng = np.random.RandomState(4)
+    down = jnp.asarray(rng.randn(N * k, D), jnp.bfloat16)
+    top_p = jnp.asarray(rng.rand(N, k).astype(np.float32))
+    d_out = jnp.asarray(rng.randn(N, D), jnp.bfloat16)
+
+    def plain(down, top_p):
+        weight = top_p.reshape(-1)[order]
+        return jnp.zeros((N, D), jnp.float32).at[token].add(
+            down.astype(jnp.float32) * weight[:, None]).astype(jnp.bfloat16)
+
+    out, pull = jax.vjp(lambda d, p: _combine(d, p, order, inv, jnp.bfloat16),
+                        down, top_p)
+    want_out, want_pull = jax.vjp(plain, down, top_p)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want_out, np.float32),
+                               rtol=2 ** -7)      # sum order: a last bit
+    (d_down, d_top_p), (want_down, want_top_p) = pull(d_out), want_pull(d_out)
+    assert d_down.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(d_down, want_down)
+    np.testing.assert_allclose(d_top_p, want_top_p, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("scope", ["moe.dispatch", "moe.combine"])
+def test_backward_rules_open_their_own_scope(scope):
+    """Called under no scope at all, each hand-written transpose still
+    books its rows to ``moe.dispatch`` / ``moe.combine``: the chip trace's
+    ``model.moe_dispatch_ms_per_step`` goes on seeing the backward."""
+    N, D, k = 24, 16, 3
+    order, inv = _routes(k, collapsed=False, N=N)
+    if scope == "moe.dispatch":
+        f = jax.grad(lambda x: jnp.sum(jnp.sin(_dispatch(x, order, inv))))
+        args = (jnp.ones((N, D)),)
+    else:
+        f = jax.grad(lambda d, p: jnp.sum(jnp.sin(_combine(d, p, order, inv,
+                                                            jnp.float32))),
+                     argnums=(0, 1))
+        args = (jnp.ones((N * k, D)), jnp.ones((N, k)))
+    op_names = set(re.findall(r'op_name="([^"]*)"',
+                              jax.jit(f).lower(*args).compile().as_text()))
+    part = re.compile(rf"transpose\(.*[/(]{re.escape(scope)}[/)].*gather")
+    assert any(part.search(n) for n in op_names), op_names
+    assert not any("scatter" in n for n in op_names), op_names
 
 
 def test_auxiliary_terms_by_hand():
