@@ -1,0 +1,20 @@
+"""``kernel.flash_win_dq_roofline``: the windowed flash dq kernel's share of
+its roofline: a third of the banded attention a step requires at the bf16
+peak, or the kernel's least bytes at the HBM peak, the larger, over the time
+in the Mosaic call named ``flash_win_bwd_dq`` (``ops/flash_attention.py``;
+``benchmarks/trace/kinds.py:kernel_roofline``, counts in
+``benchmarks/flops_laguna.py``)."""
+
+from benchmarks.trace import kinds
+
+NAME = "kernel.flash_win_dq_roofline"
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "device_trace"
+LAYER = "kernels"
+MOVES = "tokens_per_chip_s"
+APPLIES = {"runner": "lm_train_kinds"}
+
+
+def read(reading):
+    return kinds.kernel_roofline(reading, "flash_win_bwd_dq")

@@ -31,11 +31,29 @@ routes reach their experts:
 
 Neither drops a route.
 
+**A share of the experts.**  ``held=(first, count)`` tells the layer which
+experts it holds, as one of the chips that share a layer would be told
+(expert parallelism without its exchange): the router keeps every column,
+top-k and the renormalisation run over all experts, ``w1``/``w3``/``w2``
+hold ``count`` experts, and the layer returns its own experts' part of the
+result.  In ``grouped`` the routes to experts held elsewhere are sorted
+behind the held groups, no grouped matmul touches their rows, and the two
+gathers by the inverse permutation mask them (whatever the compiler's
+grouped matmul leaves in rows beyond its groups, a NaN too, counts exactly
+zero, forward and backward).  The row buffer stays ``N*k`` rows: a token may
+send all its k routes here, so no smaller static buffer is exact.  ``load``
+is then ``[count + 1]``: the routes each held expert received, and last the
+routes that went elsewhere.  Holding all experts (``held=None``) traces the
+program as it was.  ``routed_scale`` multiplies the k weights after the
+renormalisation; ``shared_expert`` is the always-on SwiGLU beside the
+routed ones, under its own scope ``moe.shared``.
+
 Scopes for the chip trace (docs/observability.md, "Chip plane"):
-``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``; the two
-backward rules open ``moe.dispatch`` / ``moe.combine`` themselves, so their
-rows are booked where the forward's are.  The schedule a step was traced
-with is counted in ``moe.traced{dispatch=}``.
+``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
+``moe.shared``; the two backward rules open ``moe.dispatch`` /
+``moe.combine`` themselves, so their rows are booked where the forward's
+are.  The schedule a step was traced with is counted in
+``moe.traced{dispatch=}``, a share in ``moe.held{held=,of=}``.
 """
 
 from __future__ import annotations
@@ -52,7 +70,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import metrics
 
 __all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_pspecs",
-           "moe_shardings"]
+           "moe_shardings", "shared_expert"]
 
 # ``checkpoint_name``s of the three grouped-matmul outputs.  A grouped matmul
 # is not a ``dot_general``, so remat policy "dots" saves them by name
@@ -61,17 +79,20 @@ GROUPED_SAVED = ("moe_gate", "moe_up", "moe_down")
 
 
 def init_moe_params(dim: int, hidden: int, num_experts: int,
-                    seed: int = 0) -> Dict[str, Any]:
+                    seed: int = 0, held: int = 0) -> Dict[str, Any]:
+    """``held`` experts' weights (0 = all) under a router of ``num_experts``
+    columns."""
     rng = np.random.RandomState(seed)
+    held = held or num_experts
 
     def w(*shape, scale):
         return (scale * rng.randn(*shape)).astype(np.float32)
 
     return {
         "router": w(dim, num_experts, scale=0.02),
-        "w1": w(num_experts, dim, hidden, scale=dim ** -0.5),   # gate
-        "w3": w(num_experts, dim, hidden, scale=dim ** -0.5),   # up
-        "w2": w(num_experts, hidden, dim, scale=hidden ** -0.5),
+        "w1": w(held, dim, hidden, scale=dim ** -0.5),   # gate
+        "w3": w(held, dim, hidden, scale=dim ** -0.5),   # up
+        "w2": w(held, hidden, dim, scale=hidden ** -0.5),
     }
 
 
@@ -91,15 +112,19 @@ def moe_shardings(mesh: Mesh) -> Dict[str, Any]:
     return {k: NamedSharding(mesh, s) for k, s in moe_pspecs(mesh).items()}
 
 
-def _routing(params, x, top_k: int, norm_topk_prob: bool):
+def _routing(params, x, top_k: int, norm_topk_prob: bool,
+             routed_scale: float = 1.0):
     """The shared router, in float32: ``(probs, logits, top_p, top_idx)``
-    with ``top_p`` renormalised to sum to 1 over the k routes if asked."""
+    with ``top_p`` renormalised to sum to 1 over the k routes if asked, then
+    scaled by ``routed_scale``."""
     logits = (x.astype(jnp.float32)
               @ params["router"].astype(jnp.float32))        # [B,T,E]
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_idx = jax.lax.top_k(probs, top_k)             # [B,T,k]
     if norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if routed_scale != 1.0:
+        top_p = top_p * routed_scale
     return probs, logits, top_p, top_idx
 
 
@@ -119,119 +144,178 @@ def _aux_losses(probs, logits, load):
     return balance, z
 
 
+def _share(params, held):
+    """``(first, count)`` of the experts held, or ``None`` when ``held`` is
+    all of the router's: checked against the weights, counted once a
+    trace."""
+    E = params["router"].shape[1]
+    count = params["w1"].shape[0]
+    first, said = held if held is not None else (0, E)
+    if said != count or first < 0 or first + count > E:
+        raise ValueError(
+            f"held=({first}, {said}) but the layer's weights hold {count} "
+            f"experts under a router of {E}")
+    if count == E:
+        return None
+    metrics.counter("moe.held", {"held": str(count), "of": str(E)}).inc()
+    return first, count
+
+
 def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
             compute_dtype=None, dispatch: str = "dense",
-            norm_topk_prob: bool = True
+            norm_topk_prob: bool = True, held=None,
+            routed_scale: float = 1.0, aux: bool = True
             ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """x [B, T, dim] → ``(out [B, T, dim], balance, z, load [E])``: the
+    """x [B, T, dim] → ``(out [B, T, dim], balance, z, load)``: the
     layer's output, its two auxiliary loss terms (``_aux_losses``, scalars,
-    unweighted) and the routes each expert was sent (int32).  ``dispatch``
-    picks the schedule (this file's header); the choice is taken at trace
-    time and counted in ``moe.traced``."""
+    unweighted; zeros and nothing computed with ``aux=False``) and the
+    routes each expert was sent (int32 ``[E]``; with a share of the experts,
+    ``held=(first, count)``, ``[count + 1]``: the held experts' and, last,
+    the routes that went elsewhere; this file's header).  ``dispatch`` picks
+    the schedule; the choice is taken at trace time and counted in
+    ``moe.traced``."""
     schedules = {"grouped": _moe_grouped, "dense": _moe_dense}
     if dispatch not in schedules:
         raise ValueError(f"unknown moe dispatch '{dispatch}' "
                          "(expected grouped|dense)")
     metrics.counter("moe.traced", {"dispatch": dispatch}).inc()
     return schedules[dispatch](params, x, top_k, compute_dtype or x.dtype,
-                               norm_topk_prob)
+                               norm_topk_prob, _share(params, held),
+                               routed_scale, aux)
 
 
-def _route(params, x, top_k: int, norm_topk_prob: bool):
-    """Routing with its losses for the schedules that do not sort:
-    ``(top_p, top_idx, balance, z, load)``, the load by ``bincount``."""
-    probs, logits, top_p, top_idx = _routing(params, x, top_k,
-                                             norm_topk_prob)
-    load = jnp.bincount(top_idx.reshape(-1), length=probs.shape[-1]
-                        ).astype(jnp.int32)
-    return (top_p, top_idx, *_aux_losses(probs, logits, load), load)
+def shared_expert(params: Dict[str, Any], h: jax.Array, dt) -> jax.Array:
+    """The always-on SwiGLU beside the routed experts (``shared_w1``,
+    ``shared_w3``, ``shared_w2`` of the layer), ungated."""
+    with jax.named_scope("moe.shared"):
+        w1, w3, w2 = (checkpoint_name(params[k].astype(dt), "wcast")
+                      for k in ("shared_w1", "shared_w3", "shared_w2"))
+        return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
 
 
-def _sort_routes(top_idx):
-    """The routes of ``top_idx [N, k]`` stable-sorted by expert (a group keeps
-    token order): ``(expert[order], order, inv [N, k])`` with ``inv[order[i]]
-    = i`` over the flat routes.  Two sorts, the second of the pairs
-    ``(order[i], i)``.  On the v5e a sort of 65,536 routes inside the step is
-    0.05-0.07 ms, a gather of as many scalars 0.47; alone, the second sort
-    took 0.58-0.62 ms and an int32 scatter of the iota 0.88-0.91 (PERF.md
-    section 6, PR 29)."""
-    routes = jnp.arange(top_idx.size, dtype=jnp.int32)
-    sorted_expert, order = jax.lax.sort((top_idx.reshape(-1), routes),
-                                        num_keys=1, is_stable=True)
+def _all_load(top_idx, E):
+    return jnp.bincount(top_idx.reshape(-1), length=E).astype(jnp.int32)
+
+
+def _share_load(load, routes: int):
+    """``[count + 1]`` from the held experts' routes ``load [count]``."""
+    return jnp.concatenate([load, (routes - jnp.sum(load))[None]])
+
+
+def _sort_routes(key):
+    """The routes of ``key [N, k]`` (a route's expert, or with a share its
+    place among the held experts and ``count`` for the rest) stable-sorted
+    (a group keeps token order): ``(key[order], order, inv [N, k])`` with
+    ``inv[order[i]] = i`` over the flat routes.  Two sorts, the second of
+    the pairs ``(order[i], i)``.  On the v5e a sort of 65,536 routes inside
+    the step is 0.05-0.07 ms, a gather of as many scalars 0.47; alone, the
+    second sort took 0.58-0.62 ms and an int32 scatter of the iota 0.88-0.91
+    (PERF.md section 6, PR 29)."""
+    routes = jnp.arange(key.size, dtype=jnp.int32)
+    sorted_key, order = jax.lax.sort((key.reshape(-1), routes),
+                                     num_keys=1, is_stable=True)
     _, inv = jax.lax.sort((order, routes), num_keys=1)
-    return sorted_expert, order, inv.reshape(top_idx.shape)
+    return sorted_key, order, inv.reshape(key.shape)
 
 
 @jax.custom_vjp
-def _dispatch(x, order, inv):
+def _dispatch(x, order, inv, mine=None):
     """Rows of ``x [N, D]`` in expert order, ``[N*k, D]``: route ``order[i]``
     is a row of token ``order[i] // k``.  ``inv [N, k]`` is where each of a
-    token's routes went."""
+    token's routes went; ``mine [N, k]`` (or ``None``: all) marks the routes
+    to experts held here, the only ones whose cotangent rows count."""
     return x[order // inv.shape[1]]
 
 
-def _dispatch_fwd(x, order, inv):
-    return _dispatch(x, order, inv), inv
+def _dispatch_fwd(x, order, inv, mine):
+    return _dispatch(x, order, inv, mine), (inv, mine)
 
 
-def _dispatch_bwd(inv, d_rows):
+def _dispatch_bwd(res, d_rows):
     # The transpose of a gather by a permutation is a gather by its
     # inverse: token n's k cotangent rows, summed in float32.
+    inv, mine = res
     with jax.named_scope("moe.dispatch"):
-        d_x = jnp.sum(d_rows[inv], axis=1, dtype=jnp.float32)
-    return d_x.astype(d_rows.dtype), None, None
+        back = d_rows[inv]
+        if mine is not None:
+            back = jnp.where(mine[..., None], back, 0)
+        d_x = jnp.sum(back, axis=1, dtype=jnp.float32)
+    return d_x.astype(d_rows.dtype), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine(down, top_p, order, inv, dtype):
+def _combine(down, top_p, order, inv, dtype, mine=None):
     """``out[n] = sum_j top_p[n, j] * down[inv[n, j]]``, summed in float32
     and cast to ``dtype``: the expert-order rows ``down [N*k, D]`` gathered
     back into route order.  The cast is in here so that the transpose is
-    handed ``d_out`` as narrow as it was made."""
-    return jnp.einsum("nkd,nk->nd", down[inv], top_p,
+    handed ``d_out`` as narrow as it was made.  With ``mine [N, k]`` the sum
+    runs over the routes it marks alone: the rows of the others were never
+    computed, and are masked, not weighted by zero (0 x NaN is NaN)."""
+    back = down[inv]
+    if mine is not None:
+        back = jnp.where(mine[..., None], back, 0)
+    return jnp.einsum("nkd,nk->nd", back, top_p,
                       preferred_element_type=jnp.float32).astype(dtype)
 
 
-def _combine_fwd(down, top_p, order, inv, dtype):
-    return _combine(down, top_p, order, inv, dtype), (down, top_p, order, inv)
+def _combine_fwd(down, top_p, order, inv, dtype, mine):
+    return (_combine(down, top_p, order, inv, dtype, mine),
+            (down, top_p, order, inv, mine))
 
 
 def _combine_bwd(dtype, res, d_out):
-    down, top_p, order, inv = res
+    down, top_p, order, inv, mine = res
     with jax.named_scope("moe.combine"):
         # In expert order, one pass over the rows for both cotangents.
         d_rows = d_out[order // inv.shape[1]].astype(jnp.float32)
-        d_down = d_rows * top_p.reshape(-1)[order][:, None]
+        weight = top_p if mine is None else jnp.where(mine, top_p, 0)
+        d_down = d_rows * weight.reshape(-1)[order][:, None]
         d_weight = jnp.sum(d_rows * down.astype(jnp.float32), axis=-1)
-    return d_down.astype(down.dtype), d_weight[inv], None, None
+    d_down, d_weight = d_down.astype(down.dtype), d_weight[inv]
+    if mine is not None:
+        d_weight = jnp.where(mine, d_weight, 0)
+    return d_down, d_weight, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _moe_grouped(params, x, top_k, dt, norm_topk_prob):
+def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
+                 aux):
     B, T, D = x.shape
     N = B * T
     E = params["router"].shape[1]
     with jax.named_scope("moe.route"):
         probs, logits, top_p, top_idx = _routing(params, x, top_k,
-                                                 norm_topk_prob)
+                                                 norm_topk_prob,
+                                                 routed_scale)
     with jax.named_scope("moe.dispatch"):
         # Route r = n*k + j is token n's j-th expert.  Sorted by expert, a
         # group's rows are contiguous and its size is the distance between
         # two boundaries.  ``order`` is a permutation of the routes, so rows
         # go out by it and come back by its inverse, and neither way nor
-        # either transpose is a scatter.
-        sorted_expert, order, inv = _sort_routes(top_idx.reshape(N, top_k))
-        bounds = jnp.searchsorted(sorted_expert, jnp.arange(E + 1),
+        # either transpose is a scatter.  With a share, the routes to the
+        # experts held elsewhere sort behind the ``count`` held groups.
+        key, mine, groups = top_idx.reshape(N, top_k), None, E
+        if share is not None:
+            first, groups = share
+            mine = (key >= first) & (key < first + groups)
+            key = jnp.where(mine, key - first, groups)
+        sorted_key, order, inv = _sort_routes(key)
+        bounds = jnp.searchsorted(sorted_key, jnp.arange(groups + 1),
                                   side="left")
-        sizes = jnp.diff(bounds).astype(jnp.int32)                # [E]
-        rows = _dispatch(x.reshape(N, D).astype(dt), order, inv)  # [N*k, D]
-    with jax.named_scope("moe.route"):
-        balance, z = _aux_losses(probs, logits, sizes)
+        sizes = jnp.diff(bounds).astype(jnp.int32)           # [groups]
+        rows = _dispatch(x.reshape(N, D).astype(dt), order, inv,
+                         mine)                               # [N*k, D]
+    balance = z = jnp.float32(0)
+    if aux:
+        with jax.named_scope("moe.route"):
+            balance, z = _aux_losses(
+                probs, logits,
+                sizes if share is None else _all_load(top_idx, E))
     with jax.named_scope("moe.experts"):
         w1, w3, w2 = (checkpoint_name(params[k].astype(dt), "wcast")
                       for k in ("w1", "w3", "w2"))
@@ -243,20 +327,32 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob):
             jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes),
             GROUPED_SAVED[2])                                     # [N*k, D]
     with jax.named_scope("moe.combine"):
-        out = _combine(down, top_p.reshape(N, top_k), order, inv, x.dtype)
+        out = _combine(down, top_p.reshape(N, top_k), order, inv, x.dtype,
+                       mine)
+    if share is not None:
+        sizes = _share_load(sizes, N * top_k)
     return out.reshape(B, T, D), balance, z, sizes
 
 
-def _moe_dense(params, x, top_k, dt, norm_topk_prob):
+def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
+               aux):
     E = params["router"].shape[1]
-    top_p, top_idx, balance, z, load = _route(params, x, top_k,
-                                              norm_topk_prob)
+    probs, logits, top_p, top_idx = _routing(params, x, top_k,
+                                             norm_topk_prob, routed_scale)
+    load = _all_load(top_idx, E)
+    balance = z = jnp.float32(0)
+    if aux:
+        balance, z = _aux_losses(probs, logits, load)
     # combine [B,T,E]: routing weight per expert (0 for unrouted)
     combine = jnp.sum(
         jax.nn.one_hot(top_idx, E, dtype=jnp.float32)
         * top_p[..., None], axis=2)
+    if share is not None:
+        first, count = share
+        combine = combine[..., first:first + count]
+        load = _share_load(load[first:first + count], top_idx.size)
 
-    # dense dispatch: every expert sees every token, scaled post-hoc.
+    # dense dispatch: every (held) expert sees every token, scaled post-hoc.
     xc = x.astype(dt)
     gate = jax.nn.silu(jnp.einsum("btd,edh->beth", xc,
                                   params["w1"].astype(dt)))

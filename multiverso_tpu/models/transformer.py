@@ -7,6 +7,13 @@ Design (not in the reference — see models/__init__):
 - mesh-aware: batch shards over ``dp``, attention heads + MLP hidden +
   vocab shard over ``tp`` (GSPMD inserts the collectives), sequence shards
   over ``sp`` with ring attention (``parallel/ring_attention.py``);
+- layers of different kinds (``LayerKind``: full or sliding-window
+  attention with its own query heads over grouped K/V heads and its own
+  ``Rope`` recipe, a per-head output gate, a dense or routed FFN that may
+  hold a share of the experts beside a shared one), held as ``Layout``
+  writes them: a leading group, a period whose slots are stacked over its
+  repetitions and scanned, a trailing part.  Every layer alike is the
+  one-slot case;
 - updater integration: the train step applies the framework's server-side
   updaters (SURVEY.md §2.16) per parameter leaf, so a Multiverso user's
   ``-updater_type`` flag means the same thing here.
@@ -14,8 +21,11 @@ Design (not in the reference — see models/__init__):
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,10 +35,82 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..updaters import AddOption, get_updater
 from .. import dashboard, tracing
-from .moe import GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs
+from .moe import (GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs,
+                  shared_expert)
 
-__all__ = ["TransformerConfig", "init_params", "stack_layer_params",
-           "transformer_forward", "expert_load", "TransformerTrainer"]
+__all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
+           "stack_layer_params", "transformer_forward", "expert_load",
+           "TransformerTrainer"]
+
+FULL, SLIDING = "full_attention", "sliding_attention"
+DENSE, SPARSE = "dense", "sparse"
+
+
+@dataclass(frozen=True)
+class Rope:
+    """One rotary recipe.  The first ``rotary_factor`` of every head's dims
+    are rotated (split in halves, as ``_rope`` always has), the rest pass.
+    ``yarn_factor`` > 0 blends interpolated and extrapolated inverse
+    frequencies as HF's ``_compute_yarn_parameters`` does (ramp between the
+    dims that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_seq`` positions, truncated); cos and sin are multiplied
+    by ``attention_factor``."""
+    theta: float = 10000.0
+    rotary_factor: float = 1.0
+    yarn_factor: float = 0.0
+    original_max_seq: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+class LayerKind(NamedTuple):
+    """What one layer is made of: its attention (``full_attention`` |
+    ``sliding_attention``), its query heads, its FFN (``dense`` |
+    ``sparse``).  Window, rotary recipe and widths follow from these and
+    the configuration."""
+    attn: str
+    heads: int
+    ffn: str
+
+
+class Layout(NamedTuple):
+    """The layers as a leading group, a period repeated ``n_periods`` times
+    and a trailing part of one more period: ``lead + period * n_periods +
+    period[:n_trail]``.  Every layer alike is one slot and no lead."""
+    lead: Tuple[LayerKind, ...]
+    period: Tuple[LayerKind, ...]
+    n_periods: int
+    n_trail: int
+
+    @property
+    def uniform(self) -> bool:
+        return not self.lead and len(self.period) == 1
+
+    @property
+    def kinds(self) -> Tuple[LayerKind, ...]:
+        return (self.lead + self.period * self.n_periods
+                + self.period[:self.n_trail])
+
+
+def _layout(kinds: Tuple[LayerKind, ...], period: int = 0) -> Layout:
+    """The shortest ``lead + period`` that writes ``kinds`` (of several as
+    short, the shortest lead), or with ``period`` given the shortest lead
+    before layers of that period."""
+    L = len(kinds)
+
+    def periodic(rest, p):
+        return all(k == rest[i % p] for i, k in enumerate(rest))
+
+    found = [(n_lead + p, n_lead, p)
+             for n_lead in range(L)
+             for p in ([period] if period else range(1, L - n_lead + 1))
+             if p <= L - n_lead and periodic(kinds[n_lead:], p)]
+    if not found:
+        raise ValueError(f"the layers {kinds} have no period {period}")
+    _, n_lead, p = min(found)
+    return Layout(kinds[:n_lead], kinds[n_lead:n_lead + p],
+                  (L - n_lead) // p, (L - n_lead) % p)
 
 
 @dataclass(frozen=True)
@@ -89,47 +171,155 @@ class TransformerConfig:
     # scan_layers (stages slice the stacked params), dense MLPs, sp == 1,
     # and batch divisible by M.
     pipeline_microbatches: int = 0
+    # ---- Layers of different kinds.  The defaults are every layer alike:
+    # multi-head attention of ``dim // n_heads`` wide heads, one rotary
+    # base, no window, no gate.
+    # head_dim: 0 = ``dim // n_heads``; else ``wq``/``wo`` are ``dim x
+    # heads*head_dim`` whatever ``dim`` is.
+    head_dim: int = 0
+    # n_kv_heads: 0 = as many as query heads; else grouped K/V heads, query
+    # head j reading K/V head ``j // (heads // n_kv_heads)``.
+    n_kv_heads: int = 0
+    # Per layer (tuples of n_layers, or None = all alike): the attention
+    # kind, the query heads (None = n_heads) and the FFN kind (None =
+    # ``sparse`` everywhere if num_experts else ``dense``).  The layers are
+    # held as ``layout`` writes them: a leading group, then a period whose
+    # slots are stacked over its repetitions and scanned.
+    layer_types: Optional[Tuple[str, ...]] = None
+    heads_per_layer: Optional[Tuple[int, ...]] = None
+    mlp_layer_types: Optional[Tuple[str, ...]] = None
+    # The pattern's period where the layers cannot say it themselves (a
+    # model cut to its leading layer and one period); 0 = the shortest.
+    layer_period: int = 0
+    # sliding_attention layers see the keys ``t - sliding_window < s <= t``.
+    sliding_window: int = 0
+    # Rotary recipe by attention kind (a ``Rope``, or its fields as a dict);
+    # None = ``Rope(theta=rope_theta)``.
+    rope_full: Optional[Rope] = None
+    rope_sliding: Optional[Rope] = None
+    # "per_head": the attention output of every query head is multiplied by
+    # sigmoid(h wg), one scalar a head and position, before ``wo``.
+    attn_gate: str = ""
+    # Width of the ``dense`` layers' SwiGLU where it differs from the
+    # experts' ``hidden`` (0 = ``hidden``).
+    dense_hidden: int = 0
+    # A share of the experts (models/moe.py): the router keeps
+    # ``num_experts`` columns, the layer holds ``experts_held`` of them from
+    # ``experts_first`` on (0 = all).  ``routed_scale`` multiplies the top-k
+    # weights; ``shared_expert_hidden`` > 0 adds the always-on SwiGLU.
+    experts_held: int = 0
+    experts_first: int = 0
+    routed_scale: float = 1.0
+    shared_expert_hidden: int = 0
+
+    def __post_init__(self):
+        def put(name, value):
+            object.__setattr__(self, name, value)
+
+        if not self.head_dim:
+            put("head_dim", self.dim // self.n_heads)
+        for name in ("layer_types", "heads_per_layer", "mlp_layer_types"):
+            value = getattr(self, name)
+            if value is not None:
+                if len(value) != self.n_layers:
+                    raise ValueError(f"{name} lists {len(value)} layers, "
+                                     f"n_layers is {self.n_layers}")
+                put(name, tuple(value))
+        for name in ("rope_full", "rope_sliding"):
+            if isinstance(getattr(self, name), dict):
+                put(name, Rope(**getattr(self, name)))
+        kv = self.n_kv_heads
+        for k in self.layout.kinds:
+            if k.attn not in (FULL, SLIDING) or k.ffn not in (DENSE, SPARSE):
+                raise ValueError(f"unknown layer kind {k}")
+            if kv and k.heads % kv:
+                raise ValueError(f"{k.heads} query heads do not divide into "
+                                 f"{kv} K/V heads")
+            if k.attn == SLIDING and self.sliding_window < 1:
+                raise ValueError("sliding_attention layers need "
+                                 "sliding_window >= 1")
+            if k.ffn == SPARSE and not self.num_experts:
+                raise ValueError("sparse layers need num_experts > 0")
+        if self.attn_gate not in ("", "per_head"):
+            raise ValueError(f"unknown attn_gate '{self.attn_gate}'")
+
+    @functools.cached_property
+    def layout(self) -> Layout:
+        ffn = SPARSE if self.num_experts else DENSE
+        return _layout(tuple(
+            LayerKind((self.layer_types or (FULL,) * self.n_layers)[i],
+                      (self.heads_per_layer
+                       or (self.n_heads,) * self.n_layers)[i],
+                      (self.mlp_layer_types or (ffn,) * self.n_layers)[i])
+            for i in range(self.n_layers)), self.layer_period)
 
     @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+    def held(self) -> Optional[Tuple[int, int]]:
+        """``(first, count)`` of the experts a layer holds, or None = all."""
+        if not self.experts_held:
+            return None
+        return (self.experts_first, self.experts_held)
+
+    @property
+    def counts_routes(self) -> bool:
+        """Whether the train step hands back each routed layer's counted
+        routes: where only a share of the experts is held, how many routes
+        reached it is something no trace knows."""
+        return bool(self.experts_held) and self.experts_held < self.num_experts
+
+    def rope(self, attn: str) -> Rope:
+        given = self.rope_sliding if attn == SLIDING else self.rope_full
+        return given or Rope(theta=self.rope_theta)
+
+
+def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
+    heads, kv = kind.heads, cfg.n_kv_heads or kind.heads
+    q_width, kv_width = heads * cfg.head_dim, kv * cfg.head_dim
+    lyr = {
+        "wq": w(cfg.dim, q_width),
+        "wk": w(cfg.dim, kv_width),
+        "wv": w(cfg.dim, kv_width),
+        "wo": w(q_width, cfg.dim),
+        "attn_norm": np.ones(cfg.dim, np.float32),
+        "mlp_norm": np.ones(cfg.dim, np.float32),
+    }
+    if cfg.qk_norm:
+        lyr.update(q_norm=np.ones(q_width, np.float32),
+                   k_norm=np.ones(kv_width, np.float32))
+    if kind.ffn == SPARSE:
+        # router, w1, w3, w2 at the layer's top level, expert-indexed
+        lyr.update(init_moe_params(cfg.dim, cfg.hidden, cfg.num_experts,
+                                   seed=rng.randint(2 ** 31),
+                                   held=cfg.experts_held))
+    else:
+        hidden = cfg.dense_hidden or cfg.hidden
+        lyr.update({
+            "w1": w(cfg.dim, hidden),   # gate
+            "w3": w(cfg.dim, hidden),   # up
+            "w2": w(hidden, cfg.dim),   # down
+        })
+    if cfg.attn_gate:
+        lyr["wg"] = w(cfg.dim, heads)
+    if kind.ffn == SPARSE and cfg.shared_expert_hidden:
+        shared = cfg.shared_expert_hidden
+        lyr.update(shared_w1=w(cfg.dim, shared), shared_w3=w(cfg.dim, shared),
+                   shared_w2=w(shared, cfg.dim))
+    return lyr
 
 
 def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
-    """Float32 master weights, truncated-normal-ish init."""
+    """Float32 master weights, truncated-normal-ish init.  ``layers`` is a
+    list of per-layer dicts, or under ``scan_layers`` the layers as
+    ``group_layers`` holds them."""
     rng = np.random.RandomState(seed)
 
     def w(*shape, scale=None):
         scale = scale or (shape[0] ** -0.5)
         return (scale * rng.randn(*shape)).astype(np.float32)
 
-    layers = []
-    for _ in range(cfg.n_layers):
-        lyr = {
-            "wq": w(cfg.dim, cfg.dim),
-            "wk": w(cfg.dim, cfg.dim),
-            "wv": w(cfg.dim, cfg.dim),
-            "wo": w(cfg.dim, cfg.dim),
-            "attn_norm": np.ones(cfg.dim, np.float32),
-            "mlp_norm": np.ones(cfg.dim, np.float32),
-        }
-        if cfg.qk_norm:
-            lyr.update(q_norm=np.ones(cfg.dim, np.float32),
-                       k_norm=np.ones(cfg.dim, np.float32))
-        if cfg.num_experts:
-            # router, w1, w3, w2 at the layer's top level, expert-indexed
-            lyr.update(init_moe_params(cfg.dim, cfg.hidden,
-                                       cfg.num_experts,
-                                       seed=rng.randint(2 ** 31)))
-        else:
-            lyr.update({
-                "w1": w(cfg.dim, cfg.hidden),   # gate
-                "w3": w(cfg.dim, cfg.hidden),   # up
-                "w2": w(cfg.hidden, cfg.dim),   # down
-            })
-        layers.append(lyr)
+    layers = [_init_layer(cfg, kind, rng, w) for kind in cfg.layout.kinds]
     if cfg.scan_layers:
-        layers = stack_layer_params(layers)
+        layers = group_layers(cfg, layers)
     return {
         "embed": w(cfg.vocab_size, cfg.dim, scale=0.02),
         "out_norm": np.ones(cfg.dim, np.float32),
@@ -151,14 +341,46 @@ def stack_layer_params(layers):
                      else jnp.stack(xs)), *layers)
 
 
-def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
+def group_layers(cfg: TransformerConfig, layers):
+    """A list of per-layer dicts as the scan holds them.  Every layer alike
+    (``layout.uniform``): one dict of leaves stacked ``[L, ...]``
+    (``stack_layer_params``).  Else ``{"lead": [layer, ...], "period":
+    [slot, ...], "trail": [layer, ...]}``, slot ``s`` holding the s-th layer
+    of every period stacked ``[n_periods, ...]``."""
+    lay = cfg.layout
+    if lay.uniform:
+        return stack_layer_params(layers)
+    n_lead, p = len(lay.lead), len(lay.period)
+    body = layers[n_lead:n_lead + p * lay.n_periods]
+    return {"lead": list(layers[:n_lead]),
+            "period": [stack_layer_params(body[s::p]) for s in range(p)],
+            "trail": list(layers[n_lead + p * lay.n_periods:])}
+
+
+def _grouped(cfg: TransformerConfig, layers):
+    """``(lead, period, trail)`` of scan-format ``layers``: lists of layer
+    dicts around a tuple of stacked slots (one slot where every layer is
+    alike)."""
+    if cfg.layout.uniform:
+        return [], (layers,), []
+    return layers["lead"], tuple(layers["period"]), layers["trail"]
+
+
+def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
+                  kind: Optional[LayerKind] = None) -> Dict[str, Any]:
     """Per-layer weight PartitionSpecs for the Megatron-style tp layout:
     attention io dims and MLP hidden shard over ``tp`` (column-parallel
-    wq/wk/wv/w1/w3, row-parallel wo/w2); norms replicated.  The QK-norm
+    wq/wk/wv/w1/w3 and the per-head gate, row-parallel wo/w2); norms
+    replicated.  The QK-norm
     gains are replicated too: its mean of squares runs over the whole
     tp-sharded projection, which GSPMD completes with an all-reduce (the
     manual tp layout inside a pipeline stage refuses QK-norm by name)."""
+    kind = kind or cfg.layout.period[0]
     tp = "tp" if "tp" in mesh.shape else None
+    if tp and (cfg.n_kv_heads or kind.heads) % mesh.shape["tp"]:
+        raise ValueError(
+            f"{cfg.n_kv_heads or kind.heads} K/V heads do not divide over "
+            f"the 'tp' axis ({mesh.shape['tp']}): wk/wv shard by head")
 
     layer = {
         "wq": P(None, tp), "wk": P(None, tp), "wv": P(None, tp),
@@ -167,8 +389,13 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
     }
     if cfg.qk_norm:
         layer.update(q_norm=P(None), k_norm=P(None))
-    if cfg.num_experts:
+    if cfg.attn_gate:
+        layer["wg"] = P(None, tp)
+    if kind.ffn == SPARSE:
         layer.update(moe_pspecs(mesh))
+        if cfg.shared_expert_hidden:
+            layer.update(shared_w1=P(None, tp), shared_w3=P(None, tp),
+                         shared_w2=P(tp, None))
     else:
         layer.update({"w1": P(None, tp), "w3": P(None, tp),
                       "w2": P(tp, None)})
@@ -182,22 +409,29 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
     Scan-format params get the same per-layer specs with an unsharded
     leading layer dim."""
     tp = "tp" if "tp" in mesh.shape else None
-    layer = _layer_pspecs(cfg, mesh)
-
+    lay = cfg.layout
     is_spec = lambda x: isinstance(x, P)
+
+    def sharded(kind, *lead):
+        return jax.tree_util.tree_map(
+            lambda spec: NamedSharding(mesh, P(*lead, *spec)),
+            _layer_pspecs(cfg, mesh, kind), is_leaf=is_spec)
+
     if cfg.scan_layers:
         # With pipeline parallelism the stacked layer dim shards over
         # ``pp`` (each stage holds its own layers); otherwise replicated.
         lead = ("pp" if ("pp" in mesh.shape and cfg.pipeline_microbatches
                          and cfg.n_layers % mesh.shape["pp"] == 0)
                 else None)
-        layers = jax.tree_util.tree_map(
-            lambda spec: NamedSharding(mesh, P(lead, *spec)), layer,
-            is_leaf=is_spec)
+        if lay.uniform:
+            layers = sharded(lay.period[0], lead)
+        else:
+            layers = {"lead": [sharded(k) for k in lay.lead],
+                      "period": [sharded(k, None) for k in lay.period],
+                      "trail": [sharded(k)
+                                for k in lay.period[:lay.n_trail]]}
     else:
-        layers = [jax.tree_util.tree_map(
-            lambda spec: NamedSharding(mesh, spec), layer, is_leaf=is_spec)
-            for _ in range(cfg.n_layers)]
+        layers = [sharded(kind) for kind in lay.kinds]
 
     def s(*spec):
         return NamedSharding(mesh, P(*spec))
@@ -215,16 +449,43 @@ def _rms_norm(x, gain, eps):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * gain
 
 
-def _rope(x, theta: float):
+def _rope_freqs(rope: Rope, half: int):
+    """Inverse frequencies ``[half]`` of a recipe that rotates ``2 * half``
+    dims."""
+    if not rope.yarn_factor:
+        return rope.theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    # YaRN, as HF's _compute_yarn_parameters: constants of the trace.
+    rot = 2 * half
+    plain = rope.theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def turns_at(turns):      # the dim that turns ``turns`` times
+        return (rot * math.log(rope.original_max_seq / (turns * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(turns_at(rope.beta_fast)), 0)
+    high = min(math.ceil(turns_at(rope.beta_slow)), rot - 1)
+    ramp = np.clip((np.arange(half) - low) / (max(high - low, 0.001)), 0, 1)
+    extrapolated = 1.0 - ramp
+    freqs = (plain / rope.yarn_factor * (1 - extrapolated)
+             + plain * extrapolated)
+    return jnp.asarray(freqs, jnp.float32)
+
+
+def _rope(x, rope: Rope):
     """Rotary embedding over global positions; x [B, H, T, D]."""
     B, H, T, D = x.shape
-    half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    rot = int(D * rope.rotary_factor)
+    half = rot // 2
+    freqs = _rope_freqs(rope, half)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]  # [T,half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    rot = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-    return rot.astype(x.dtype)
+    if rope.attention_factor != 1.0:
+        cos, sin = cos * rope.attention_factor, sin * rope.attention_factor
+    x1, x2 = x[..., :half], x[..., half:rot]
+    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if rot < D:
+        parts.append(x[..., rot:].astype(cos.dtype))
+    return jnp.concatenate(parts, -1).astype(x.dtype)
 
 
 def transformer_forward(params, tokens, cfg: TransformerConfig,
@@ -242,8 +503,12 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
 
 def expert_load(params, tokens, cfg: TransformerConfig,
                 mesh: Optional[Mesh] = None):
-    """Routes each expert of each layer is sent for ``tokens`` [B, T]:
-    int32 ``[n_layers, num_experts]``, every row summing to ``B*T*top_k``.
+    """Routes each expert of each routed layer is sent for ``tokens``
+    [B, T]: int32 ``[routed layers, num_experts]``, every row summing to
+    ``B*T*top_k``.  Where the layers hold a share of the experts
+    (``experts_held``) a row is ``[experts_held + 1]``: the held experts'
+    routes and, last, the routes that went elsewhere, which is what the
+    train step counts too (``TransformerTrainer.routes``).
     A diagnostic off the step (one forward pass): how uneven the groups of
     the grouped schedule are."""
     if not cfg.num_experts:
@@ -253,8 +518,11 @@ def expert_load(params, tokens, cfg: TransformerConfig,
 
 
 def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
-    """``(logits, weighted auxiliary loss, expert load [L, E] or None)``."""
+    """``(logits, weighted auxiliary loss, expert load [routed layers, E]
+    or None)``."""
     from ..parallel.ring_attention import blockwise_attention_local, ring_attention
+
+    lay = cfg.layout
 
     if tokens.shape[1] > cfg.max_seq:
         raise ValueError(
@@ -282,18 +550,37 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             "expert's weights on the chip that holds the rows; use "
             "moe_dispatch='dense', which GSPMD partitions over 'ep'")
 
-    def make_block(local_heads: int, reduce=None):
-        """Build one decoder-layer fn (with the remat wrapper applied).
+    if cfg.sliding_window and any(k.attn == SLIDING for k in lay.kinds):
+        if mesh is not None and int(mesh.shape.get("sp", 1)) > 1:
+            raise ValueError(
+                f"sliding_attention layers (window {cfg.sliding_window}) do "
+                f"not run over an 'sp' ring (sp={mesh.shape['sp']})")
+    use_aux = bool(cfg.aux_loss_coef or cfg.router_z_loss_coef)
 
-        ``local_heads``/``reduce`` specialize it for manual tensor
+    def make_block(kind: LayerKind, tp: int = 1, reduce=None):
+        """Build one decoder-layer fn of ``kind`` (with the remat wrapper
+        applied).
+
+        ``tp``/``reduce`` specialize it for manual tensor
         parallelism inside a pipeline stage: the block then sees
-        tp-local column shards of wq/wk/wv/w1/w3 (so ``local_heads =
-        n_heads/tp`` and the io width is ``dim/tp``) and ``reduce`` —
+        tp-local column shards of wq/wk/wv/w1/w3 (so it has ``heads/tp``
+        heads and the io width is ``dim/tp``) and ``reduce`` —
         a ``psum`` over the tp axis — completes the row-parallel
         wo/w2 matmuls (the Megatron two-all-reduce-per-layer pattern).
         Default (GSPMD paths): full heads, no explicit collective.
         """
         red = reduce if reduce is not None else (lambda t: t)
+        local_heads = kind.heads // tp
+        local_kv = (cfg.n_kv_heads or kind.heads) // tp
+        window = cfg.sliding_window if kind.attn == SLIDING else None
+        rope = cfg.rope(kind.attn)
+
+        def kind_scope():
+            # The kind's own scope inside ``attn`` where the layers differ.
+            if cfg.layer_types is None:
+                return contextlib.nullcontext()
+            return jax.named_scope("attn.sliding" if kind.attn == SLIDING
+                                   else "attn.full")
 
         def block(x, lyr):
             """One decoder layer: attn + residual, MLP/MoE + residual.
@@ -310,36 +597,44 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 # bf16 copy of the layer weights of residency.
                 return checkpoint_name(w.astype(dt), "wcast")
 
-            with jax.named_scope("attn"):
+            with jax.named_scope("attn"), kind_scope():
                 h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
                 q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
                 if cfg.qk_norm:
                     q = _rms_norm(q, lyr["q_norm"].astype(dt), cfg.norm_eps)
                     k = _rms_norm(k, lyr["k_norm"].astype(dt), cfg.norm_eps)
                 q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
-                k = k.reshape(Bb, Tb, local_heads, cfg.head_dim)
-                v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_heads,
+                k = k.reshape(Bb, Tb, local_kv, cfg.head_dim)
+                v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_kv,
                                                 cfg.head_dim)
-                q = _rope(q.transpose(0, 2, 1, 3), cfg.rope_theta)
-                k = _rope(k.transpose(0, 2, 1, 3), cfg.rope_theta)
+                q = _rope(q.transpose(0, 2, 1, 3), rope)
+                k = _rope(k.transpose(0, 2, 1, 3), rope)
                 v = v.transpose(0, 2, 1, 3)
                 if attn_in_shard_map:
                     o = ring_attention(q, k, v, mesh, axis_name="sp",
-                                       causal=True, scale=scale)
+                                       causal=True, scale=scale,
+                                       window=window)
                 else:
                     o = blockwise_attention_local(q, k, v, scale,
-                                                  causal=True)
-                o = o.transpose(0, 2, 1, 3).reshape(
-                    Bb, Tb, local_heads * cfg.head_dim)
+                                                  causal=True, window=window)
+                o = o.transpose(0, 2, 1, 3)                  # [B,T,H,D]
+                if cfg.attn_gate:
+                    gate = jax.nn.sigmoid(
+                        (h @ wc(lyr["wg"])).astype(jnp.float32))
+                    o = o * gate.astype(dt)[..., None]
+                o = o.reshape(Bb, Tb, local_heads * cfg.head_dim)
                 x = x + red(o @ wc(lyr["wo"]))
 
             with jax.named_scope("mlp"):
                 h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
-                if cfg.num_experts:
+                if kind.ffn == SPARSE:
                     out, balance, z, load = moe_ffn(
                         lyr, h, top_k=cfg.top_k, compute_dtype=dt,
                         dispatch=cfg.moe_dispatch,
-                        norm_topk_prob=cfg.norm_topk_prob)
+                        norm_topk_prob=cfg.norm_topk_prob, held=cfg.held,
+                        routed_scale=cfg.routed_scale, aux=use_aux)
+                    if cfg.shared_expert_hidden:
+                        out = out + shared_expert(lyr, h, dt)
                     aux = (cfg.aux_loss_coef * balance
                            + cfg.router_z_loss_coef * z)
                     return x + out, aux, load
@@ -378,7 +673,7 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                     "(expected 'full' or 'dots')")
         return block
 
-    block = make_block(cfg.n_heads)
+    blocks = {kind: make_block(kind) for kind in set(lay.kinds)}
 
     if use_pp:
         # GPipe over the layer stack: embed/head stay replicated, the
@@ -390,6 +685,12 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             raise ValueError(
                 "pipeline_microbatches requires scan_layers=True and a "
                 "dense MLP (num_experts=0)")
+        if not lay.uniform:
+            raise ValueError(
+                "pipeline_microbatches requires every layer alike: stages "
+                f"slice one stacked tree, and the layers are {lay.lead} + "
+                f"{lay.n_periods} x {lay.period}")
+        kind = lay.period[0]
         if int(mesh.shape.get("sp", 1)) > 1:
             # Ring attention's own shard_map cannot nest inside gpipe's.
             raise ValueError(
@@ -409,9 +710,11 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 "qk_norm inside a pipeline stage with tp > 1 is unsupported: "
                 "the stage shards wq/wk by hand and the norm's mean of "
                 "squares would need a psum over 'tp'")
-        if cfg.n_heads % tp or cfg.hidden % tp or cfg.dim % tp:
+        if (kind.heads % tp or (cfg.n_kv_heads or kind.heads) % tp
+                or cfg.hidden % tp or cfg.dim % tp):
             raise ValueError(
-                f"pp x tp needs n_heads ({cfg.n_heads}), hidden "
+                f"pp x tp needs n_heads ({kind.heads}), K/V heads "
+                f"({cfg.n_kv_heads or kind.heads}), hidden "
                 f"({cfg.hidden}) and dim ({cfg.dim}) divisible by tp "
                 f"({tp}) — the stage body shards them manually")
         stages = jax.tree_util.tree_map(
@@ -425,10 +728,9 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             # arrive via param_specs, and the block psums the
             # row-parallel wo/w2 outputs over "tp".
             stage_block = make_block(
-                cfg.n_heads // tp,
-                reduce=lambda t: jax.lax.psum(t, "tp"))
+                kind, tp, reduce=lambda t: jax.lax.psum(t, "tp"))
         else:
-            stage_block = block
+            stage_block = blocks[kind]
 
         def stage_fn(stage_params, h):
             def body(h, lyr):
@@ -450,23 +752,49 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                                     else None))
         x = xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
         aux_total, load = jnp.float32(0), None
-    elif cfg.scan_layers:
-        def scan_body(carry, lyr):
-            x, aux = carry
-            x, a, load = block(x, lyr)
-            return (x, aux + a), load
+    else:
+        # A leading group, a scan over the periods whose body runs one layer
+        # of each slot, a trailing part of a period; every layer alike is
+        # one slot and nothing around the scan.  Without ``scan_layers`` the
+        # layers are a list and the loop below is all there is.
+        aux_total, loads = jnp.float32(0), []
+
+        def run(x, aux, kinds, layers):
+            for kind, lyr in zip(kinds, layers):
+                x, a, load = blocks[kind](x, lyr)
+                aux = aux + a
+                if load is not None:
+                    loads.append(load[None])
+            return x, aux
 
         with jax.named_scope("layers"):
-            (x, aux_total), load = jax.lax.scan(
-                scan_body, (x, jnp.float32(0)), params["layers"])
-    else:
-        aux_total, loads = jnp.float32(0), []
-        with jax.named_scope("layers"):
-            for lyr in params["layers"]:
-                x, a, load = block(x, lyr)
-                aux_total = aux_total + a
-                loads.append(load)
-        load = jnp.stack(loads) if cfg.num_experts else None
+            if not cfg.scan_layers:
+                x, aux_total = run(x, aux_total, lay.kinds, params["layers"])
+            else:
+                lead, period, trail = _grouped(cfg, params["layers"])
+                x, aux_total = run(x, aux_total, lay.lead, lead)
+
+                def scan_body(carry, slots):
+                    x, aux = carry
+                    slot_loads = []
+                    for kind, lyr in zip(lay.period, slots):
+                        x, a, load = blocks[kind](x, lyr)
+                        aux = aux + a
+                        slot_loads.append(load)
+                    return (x, aux), tuple(slot_loads)
+
+                (x, aux_total), slot_loads = jax.lax.scan(
+                    scan_body, (x, aux_total), period)
+                routed = [l for l in slot_loads if l is not None]
+                if len(routed) == 1:
+                    loads.append(routed[0])
+                elif routed:        # [periods, slots, E] in layer order
+                    loads.append(jnp.stack(routed, axis=1).reshape(
+                        -1, routed[0].shape[-1]))
+                x, aux_total = run(x, aux_total, lay.period[:lay.n_trail],
+                                   trail)
+        load = (None if not loads else loads[0] if len(loads) == 1
+                else jnp.concatenate(loads))
 
     with jax.named_scope("head"):
         x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
@@ -516,20 +844,33 @@ def lm_loss(params, tokens, cfg: TransformerConfig,
     MoE configs add their weighted auxiliary loss (``transformer_forward``).
 
     Two CE lowerings, picked by head size.  Both of ``BENCHMARK.json``'s
-    ``lm_train`` configurations (vocab 49,152 and 50,304) take the
+    uniform ``lm_train`` configurations (vocab 49,152 and 50,304) take the
     ``_ce`` custom_vjp, whose bf16 dlogits keep the model's two largest
     matmuls on the MXU fast path.  Earlier rounds measured it to win
     from vocab 16k up and to LOSE 40% end-to-end on a small head (dim
     512 / vocab 8k) because the vjp boundary blocks XLA from fusing the
     CE backward, and those extra HBM passes dwarf the cheap matmul's
-    dtype win; no workload sits on that side now (ROADMAP Design 2)."""
-    logits, aux = transformer_forward(params, tokens, cfg, mesh,
-                                      return_aux=True)
-    # Crossover measured between 8192 (big loss) and 16384 (small win).
-    ce_fn = _ce if cfg.vocab_size >= 16384 else _ce_value
+    dtype win.  The one head in between, 3072 x 12,544 (the Laguna cell's
+    slice of its vocabulary), was run both ways on the v5e (PR 30, one
+    seed, median of 10 steps of 1 x 8192): ``_ce`` 0.42367 s a step,
+    ``_ce_value`` 0.42753 s, so the vjp wins there too, by 0.9%, and the
+    crossover stands at 12,288 (ROADMAP Design 2)."""
+    return _loss_and_routes(params, tokens, cfg, mesh)[0]
+
+
+def _loss_and_routes(params, tokens, cfg: TransformerConfig,
+                     mesh: Optional[Mesh] = None):
+    """``(lm_loss, routes)``: ``routes`` is the routed layers' counted
+    routes (``expert_load``'s array) where the configuration holds a share
+    of the experts (``cfg.counts_routes``), else ``None``."""
+    logits, aux, load = _forward(params, tokens, cfg, mesh)
+    # Crossover measured between 8192 (big loss at dim 512) and 12,544 (a
+    # small win at dim 3072).
+    ce_fn = _ce if cfg.vocab_size >= 12288 else _ce_value
     with jax.named_scope("loss"):
         ce = ce_fn(logits[:, :-1], tokens[:, 1:])
-    return ce + aux if cfg.num_experts else ce
+    return (ce + aux if cfg.num_experts else ce,
+            load if cfg.counts_routes else None)
 
 
 class TransformerTrainer:
@@ -558,6 +899,10 @@ class TransformerTrainer:
                             for _ in range(self.updater.num_slots)),
             self.params)
         self._step = None
+        # The last step's counted routes, on the device (``cfg.
+        # counts_routes``: int32 [routed layers, experts_held + 1], the held
+        # experts' routes and the routes that went elsewhere), else None.
+        self.routes = None
         self._steps_dispatched = 0     # the ``step`` of mv.trainer.dispatch
         self._eval = None
         self._offload = None  # (bridge, leaf shapes/shardings) — see below
@@ -576,7 +921,8 @@ class TransformerTrainer:
         return params, state
 
     def _raw_step(self, accum: int = 1):
-        """Un-jitted (params, state, tokens) -> (params, state, loss).
+        """Un-jitted (params, state, tokens) -> (params, state, loss,
+        routes); ``routes`` is ``None`` unless ``cfg.counts_routes``.
 
         ``accum > 1`` splits the batch into that many microbatches,
         accumulates their gradients in float32 (a ``lax.scan`` so the
@@ -601,9 +947,11 @@ class TransformerTrainer:
                 "configs (batch-nonlinear aux loss); run MoE at full batch")
 
         def step(params, state, tokens):
+            routes = None
             if accum == 1:
-                loss, grads = jax.value_and_grad(lm_loss)(params, tokens,
-                                                          cfg, mesh)
+                (loss, routes), grads = jax.value_and_grad(
+                    _loss_and_routes, has_aux=True)(params, tokens, cfg,
+                                                    mesh)
             else:
                 B, T = tokens.shape
                 if B % accum:
@@ -630,7 +978,7 @@ class TransformerTrainer:
                     lambda g: (g / accum), g_sum)
                 loss = jnp.mean(losses)
             params, state = self._apply_updates(params, state, grads)
-            return params, state, loss
+            return params, state, loss, routes
 
         return step
 
@@ -749,8 +1097,8 @@ class TransformerTrainer:
         self._steps_dispatched += 1
         if self._offload is None:
             with dispatch:
-                self.params, self.state, loss = step(self.params,
-                                                     self.state, placed)
+                self.params, self.state, loss, self.routes = step(
+                    self.params, self.state, placed)
             return loss
         # Offloaded state (docs/host_bridge.md): the vector prefetched
         # during the previous step's tail is ready (or fetched now on
@@ -760,7 +1108,8 @@ class TransformerTrainer:
         with dashboard.monitor("Transformer::offload_wait"):
             state = self._flat_to_state(self._offload.wait())
         with dispatch:
-            self.params, new_state, loss = step(self.params, state, placed)
+            self.params, new_state, loss, self.routes = step(
+                self.params, state, placed)
         with dashboard.monitor("Transformer::offload_push"):
             self._offload.push(self._state_to_flat(new_state))
             self._offload.prefetch()

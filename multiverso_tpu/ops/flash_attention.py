@@ -1,4 +1,15 @@
-"""Flash attention as a differentiable Pallas TPU kernel.
+"""Flash attention as a differentiable Pallas TPU kernel: causal or full,
+with grouped K/V heads and a sliding window.
+
+**Grouped K/V heads**: ``k``/``v`` may hold fewer heads than ``q``; query
+head ``j`` reads K/V head ``j // group`` through the kernels' index maps
+(nothing is repeated in HBM), and the dkv kernel's grid has an axis over the
+group's query heads, so one K/V head's dk/dv stay in VMEM while all of them
+pass (no ``[H, T, D]`` dk/dv summed afterwards).  **A window** keeps key
+``s`` for query ``t`` iff ``t - window < s <= t``: the three kernels' grids
+then run over the blocks of that band alone (``_band_steps``), mask the two
+partial diagonals, and are named ``flash_win_fwd``, ``flash_win_bwd_dq``,
+``flash_win_bwd_dkv``.  With neither, the kernels are what they were.
 
 Causal/full attention with O(T) memory: the forward grid walks (batch·head,
 q-block, k-block) with the k dimension innermost; per q-block the kernel
@@ -70,25 +81,82 @@ def scale_cap_for_head_dim(cap: int, head_dim: int) -> int:
 
 _NEG = -1e30
 _LANES = 128
+_WINDOW_BLOCK_CAP = 512
 
 
-def _causal_mask(s, qi, ki, block_q, block_k):
+def _causal_mask(s, qi, ki, block_q, block_k, window=None):
+    """Keep key s for query t iff ``s <= t`` and, with a window, ``t -
+    window < s``.  A row of a band's first block may keep nothing: its
+    ``m`` stays ``_NEG`` and what it accumulates is zeroed by ``corr`` when
+    its first real score arrives (the diagonal is always kept)."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG)
+    visible = q_pos >= k_pos
+    if window is not None:
+        visible = visible & (k_pos > q_pos - window)
+    return jnp.where(visible, s, _NEG)
+
+
+# ---- the band ``t - window < s <= t`` in blocks.  A windowed call's grid
+# runs over the blocks of the band alone: step j of q block i is k block
+# ``_first_k(i) + j``, and the steps past ``_last_k(i)`` (the grid is as long
+# as the longest row of blocks) are skipped with their index clamped, so
+# that no block is fetched for them.  The dkv kernel walks the q blocks of
+# a k block the same way.
+def _first_k(qi, block_q, block_k, window):
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _last_k(qi, block_q, block_k):
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _first_q(ki, block_q, block_k):
+    return (ki * block_k) // block_q
+
+
+def _last_q(ki, block_q, block_k, window, num_q):
+    return jnp.minimum((ki * block_k + block_k - 1 + window - 1) // block_q,
+                       num_q - 1)
+
+
+def _band_steps(num_q, num_k, block_q, block_k, window):
+    """``(k steps a q block, q steps a k block)`` of the band's grids."""
+    import numpy as np
+
+    qi, ki = np.arange(num_q), np.arange(num_k)
+    k_steps = (_last_k(qi, block_q, block_k)
+               - np.maximum(qi * block_q - (window - 1), 0) // block_k + 1)
+    q_steps = (np.minimum((ki * block_k + block_k + window - 2) // block_q,
+                          num_q - 1) - _first_q(ki, block_q, block_k) + 1)
+    return int(k_steps.max()), int(q_steps.max())
+
+
+def _in_band(computed, full, qi, ki, block_q, block_k, window):
+    """The causal ``(computed, full)`` of block (qi, ki) narrowed to the
+    band: computed if its last key is inside the first query's window, full
+    if its first key is inside the last query's."""
+    q_lo, k_lo = qi * block_q, ki * block_k
+    computed = computed & (k_lo + block_k - 1 > q_lo - window)
+    full = full & (k_lo > q_lo + block_q - 1 - window)
+    return computed, full
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
-                causal, block_q, block_k, num_k):
+                causal, block_q, block_k, num_k, window=None):
     # q arrives PRE-SCALED (softmax scale folded into the [T, D] input —
     # one multiply per q element instead of one per [Bq, Bk] score; the
     # kernel is VPU-bound on exactly that elementwise tile, measured).
+    # ``num_k`` is the grid's extent: every k block, or the band's steps.
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    ki = step
+    if window is not None:
+        ki = _first_k(qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _NEG)
@@ -102,7 +170,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [Bq, Bk]
         if masked:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qi, ki, block_q, block_k, window)
         m_prev = m_scr[:, 0:1]                          # [Bq, 1]
         l_prev = l_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -122,12 +190,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         # only diagonal-straddling blocks pay for masking.
         computed = ki * block_k <= qi * block_q + block_q - 1
         full = qi * block_q >= ki * block_k + block_k - 1
+        if window is not None:
+            computed, full = _in_band(computed, full, qi, ki, block_q,
+                                      block_k, window)
         pl.when(computed & full)(lambda: _compute(False))
         pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
     else:
         _compute(False)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:, 0:1], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
@@ -136,14 +207,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_acc, *, scale, causal, block_q, block_k, num_k):
+               dq_acc, *, scale, causal, block_q, block_k, num_k,
+               window=None):
     # q arrives PRE-SCALED, so s needs no per-element scale and
     # ds = p·(dp−δ) carries none either; the missing factor lands once on
     # the [Bq, D] accumulator at finalize (dq = scale·ds@k).
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    ki = step
+    if window is not None:
+        ki = _first_k(qi, block_q, block_k, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
@@ -158,7 +233,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if masked:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qi, ki, block_q, block_k, window)
         p = jnp.exp(s - lse)                            # [Bq, Bk] f32
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -171,26 +246,40 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     if causal:
         computed = ki * block_k <= qi * block_q + block_q - 1
         full = qi * block_q >= ki * block_k + block_k - 1
+        if window is not None:
+            computed, full = _in_band(computed, full, qi, ki, block_q,
+                                      block_k, window)
         pl.when(computed & full)(lambda: _compute(False))
         pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
     else:
         _compute(False)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
-                block_q, block_k, num_q):
+                block_q, block_k, num_q, window=None, group=1,
+                q_blocks=None):
     # q arrives PRE-SCALED: s needs no per-element scale, and
     # dk = scale·(dsᵀ@q_unscaled) = dsᵀ@q_scaled — the factor is already
     # in the q operand, so no fixup anywhere.
+    # ``num_q`` is the grid's extent: every q block, or the band's steps.
+    # With grouped KV heads the grid has one more axis, the group's query
+    # heads, between the k block and the q steps: one K/V head's dk and dv
+    # stay in the accumulators while all of them pass.
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2 if group == 1 else 3)
+    qi = step
+    if window is not None:
+        qi = _first_q(ki, block_q, block_k) + step
+    first = step == 0
+    if group > 1:
+        first = first & (pl.program_id(2) == 0)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -206,7 +295,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [Bq, Bk]
         if masked:
-            s = _causal_mask(s, qi, ki, block_q, block_k)
+            s = _causal_mask(s, qi, ki, block_q, block_k, window)
         p = jnp.exp(s - lse)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -222,12 +311,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if causal:
         computed = qi * block_q + block_q - 1 >= ki * block_k
         full = qi * block_q >= ki * block_k + block_k - 1
+        if window is not None:
+            # a step past the sequence's last q block is no block at all
+            computed, full = _in_band(computed & (qi < q_blocks), full, qi,
+                                      ki, block_q, block_k, window)
         pl.when(computed & full)(lambda: _compute(False))
         pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
     else:
         _compute(False)
 
-    @pl.when(qi == num_q - 1)
+    last = step == num_q - 1
+    if group > 1:
+        last = last & (pl.program_id(2) == group - 1)
+
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -246,26 +343,45 @@ def _named_call(name, kernel, **kwargs):
     return scoped
 
 
-def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
-    """q [bh, Tq, D], k/v [bh, Tk, D] → (o [bh, Tq, D], lse [bh, Tq] f32)."""
+def _kv_index(block_q, block_k, window, group):
+    """Index map of a K/V block for the grids ``(head, q block, k step)``:
+    query head ``b`` reads K/V head ``b // group`` (nothing is repeated in
+    HBM); with a window, step ``j`` is the band's j-th block, clamped."""
+    def index(b, i, j):
+        if window is not None:
+            j = jnp.minimum(_first_k(i, block_q, block_k, window) + j,
+                            _last_k(i, block_q, block_k))
+        return (b if group == 1 else b // group, j, 0)
+
+    return index
+
+
+def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
+              window=None, group=1):
+    """q [bh, Tq, D], k/v [bh / group, Tk, D] → (o [bh, Tq, D], lse [bh, Tq]
+    f32)."""
     bh, Tq, D = q.shape
     Tk = k.shape[1]
     num_q = Tq // block_q
     num_k = Tk // block_k
+    if window is not None:
+        num_k, _ = _band_steps(num_q, num_k, block_q, block_k, window)
     # Scale folded into q ([T, D] once) — the kernel tile is VPU-bound,
     # so per-score multiplies are the scarce resource.
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     kernel = functools.partial(_fwd_kernel, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               num_k=num_k)
+                               num_k=num_k, window=window)
+    kv_spec = pl.BlockSpec((1, block_k, D),
+                           _kv_index(block_q, block_k, window, group))
     o, lse = _named_call(
-        "flash_fwd",
+        "flash_fwd" if window is None else "flash_win_fwd",
         kernel,
         grid=(bh, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -285,15 +401,18 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret):
     return o, lse[:, :, 0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
-           block_k_bwd, interpret):
-    return _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret)
+           block_k_bwd, interpret, window, group):
+    return _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
+                     window, group)
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
-               block_k_bwd, interpret):
-    o, lse = _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret)
+               block_k_bwd, interpret, window, group):
+    o, lse = _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
+                       window, group)
     # Remat seam: under jax.checkpoint the partial-eval inlines this fwd
     # rule, so naming the kernel outputs lets a policy SAVE them — the
     # backward then feeds the dq/dkv kernels directly instead of
@@ -308,7 +427,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
 
 
 def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
-               interpret, res, cts):
+               interpret, window, group, res, cts):
     # The dq/dkv kernels run their own (larger) blocks: each revisits
     # the [Bq, Bk] tile space with heavier per-tile state than the
     # forward, and the measured v5e sweet spot is 1024×1024 (~12% over
@@ -320,6 +439,10 @@ def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
     Tk = k.shape[1]
     num_q = Tq // block_q
     num_k = Tk // block_k
+    k_steps, q_steps = num_k, num_q
+    if window is not None:
+        k_steps, q_steps = _band_steps(num_q, num_k, block_q, block_k,
+                                       window)
 
     # Δ_i = Σ_d do·o − dlse: the lse cotangent enters exactly where the
     # softmax normalizer does (∂lse/∂s_ij = p_ij), so it folds into delta.
@@ -333,15 +456,18 @@ def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, block_k, D),
+                           _kv_index(block_q, block_k, window, group))
     dq = _named_call(
-        "flash_bwd_dq",
+        "flash_bwd_dq" if window is None else "flash_win_bwd_dq",
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_k=num_k),
-        grid=(bh, num_q, num_k),
+                          block_q=block_q, block_k=block_k, num_k=k_steps,
+                          window=window),
+        grid=(bh, num_q, k_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+            kv_spec,
+            kv_spec,
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             row_spec,
             row_spec,
@@ -352,23 +478,36 @@ def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
         interpret=interpret,
     )(q, k, v, do, lse_b, delta_b)
 
-    row_spec_j = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, j, 0))
+    # The dkv grid is (K/V head, k block, [query head of the group,] q
+    # step): the index maps below take ``*g`` so that one set serves both.
+    def q_index(b, i, *g_j):
+        j = g_j[-1]
+        if window is not None:
+            j = jnp.minimum(_first_q(i, block_q, block_k) + j,
+                            _last_q(i, block_q, block_k, window, num_q))
+        return (b if group == 1 else b * group + g_j[0], j, 0)
+
+    def k_index(b, i, *g_j):
+        return (b, i, 0)
+
     dk, dv = _named_call(
-        "flash_bwd_dkv",
+        "flash_bwd_dkv" if window is None else "flash_win_bwd_dkv",
         functools.partial(_dkv_kernel, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q=num_q),
-        grid=(bh, num_k, num_q),
+                          block_q=block_q, block_k=block_k, num_q=q_steps,
+                          window=window, group=group, q_blocks=num_q),
+        grid=((bh, num_k, q_steps) if group == 1
+              else (bh // group, num_k, group, q_steps)),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0)),
-            row_spec_j,
-            row_spec_j,
+            pl.BlockSpec((1, block_q, D), q_index),
+            pl.BlockSpec((1, block_k, D), k_index),
+            pl.BlockSpec((1, block_k, D), k_index),
+            pl.BlockSpec((1, block_q, D), q_index),
+            pl.BlockSpec((1, block_q, _LANES), q_index),
+            pl.BlockSpec((1, block_q, _LANES), q_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, D), k_index),
+            pl.BlockSpec((1, block_k, D), k_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -392,8 +531,19 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     interpret: bool = False,
-                    return_lse: bool = False):
-    """q [B,H,Tq,D], k/v [B,H,Tk,D] → [B,H,Tq,D] (and lse [B,H,Tq] f32).
+                    return_lse: bool = False,
+                    window: Optional[int] = None,
+                    kv_heads: Optional[int] = None):
+    """q [B,H,Tq,D], k/v [B,KV,Tk,D] → [B,H,Tq,D] (and lse [B,H,Tq] f32).
+
+    ``kv_heads`` (``KV``; ``None`` = ``H``) divides ``H``: query head ``j``
+    reads K/V head ``j // (H // KV)`` through the kernels' index maps, and
+    dk/dv come back ``[B,KV,Tk,D]``, summed over a group's query heads
+    inside the dkv kernel.  ``window`` (with ``causal``) keeps key ``s`` for
+    query ``t`` iff ``t - window < s <= t``; the three kernels then walk the
+    band's blocks alone and are named ``flash_win_*``.  Blocks as wide as
+    the window are the default there (block_k and the backward's blocks are
+    capped at 512): a wider block is mostly outside a 512-key band.
 
     ``causal=True`` requires Tq == Tk (the standard aligned causal mask);
     cross-length blocks (ring attention's low/high steps) use
@@ -415,6 +565,21 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
                          f"{Tq} != {Tk}")
     if scale is None:
         scale = D ** -0.5
+    KV = k.shape[1]
+    if kv_heads is not None and kv_heads != KV:
+        raise ValueError(f"kv_heads={kv_heads} but k holds {KV} heads")
+    if H % KV or v.shape[1] != KV:
+        raise ValueError(f"{H} query heads do not divide into k's {KV} and "
+                         f"v's {v.shape[1]} K/V heads")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window needs causal=True and window >= 1, "
+                             f"got causal={causal}, window={window}")
+        block_k = min(block_k, _WINDOW_BLOCK_CAP)
+        block_q_bwd = min(block_q_bwd or _WINDOW_BLOCK_CAP,
+                          _WINDOW_BLOCK_CAP)
+        block_k_bwd = min(block_k_bwd or _WINDOW_BLOCK_CAP,
+                          _WINDOW_BLOCK_CAP)
 
     block_q = fit_block(block_q, Tq)
     block_k = fit_block(block_k, Tk)
@@ -439,10 +604,11 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
                              f"Tq={Tq}, Tk={Tk}")
         block_q_bwd, block_k_bwd = block_q, block_k
     bh = B * H
-    o, lse = _flash(q.reshape(bh, Tq, D), k.reshape(bh, Tk, D),
-                    v.reshape(bh, Tk, D), float(scale), bool(causal),
+    o, lse = _flash(q.reshape(bh, Tq, D), k.reshape(B * KV, Tk, D),
+                    v.reshape(B * KV, Tk, D), float(scale), bool(causal),
                     int(block_q), int(block_k), int(block_q_bwd),
-                    int(block_k_bwd), bool(interpret))
+                    int(block_k_bwd), bool(interpret),
+                    None if window is None else int(window), H // KV)
     o = o.reshape(B, H, Tq, D)
     if return_lse:
         return o, lse.reshape(B, H, Tq)

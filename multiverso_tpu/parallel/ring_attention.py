@@ -35,18 +35,28 @@ __all__ = ["ring_attention", "blockwise_attention_local"]
 _NEG = -1e30  # finite mask sentinel: exp(_NEG - m) underflows to exactly 0
 
 
-def _online_block(q, k_blk, v_blk, o, m, l, q_pos, k_pos, scale, causal):
+def _online_block(q, k_blk, v_blk, o, m, l, q_pos, k_pos, scale, causal,
+                  window=None):
     """One streaming-softmax accumulation step over a K/V block.
 
-    q [B,H,T,D]; k_blk/v_blk [B,H,Tb,D]; o [B,H,T,D] f32; m,l [B,H,T,1]
-    f32; q_pos [T], k_pos [Tb] are GLOBAL positions for causal masking.
+    q [B,H,T,D]; k_blk/v_blk [B,KV,Tb,D], KV dividing H (query head j reads
+    K/V head ``j // (H // KV)``: the heads are repeated here, this being the
+    path off the chip); o [B,H,T,D] f32; m,l [B,H,T,1] f32; q_pos [T], k_pos
+    [Tb] are GLOBAL positions for causal masking, which a ``window`` narrows
+    to the keys ``t - window < s <= t``.
     The block matmul runs in the compute dtype (MXU); the softmax
     statistics and the output accumulate in float32 — bf16 accumulation
     across ring steps would compound rounding error.
     """
+    group = q.shape[1] // k_blk.shape[1]
+    if group > 1:
+        k_blk = jnp.repeat(k_blk, group, axis=1)
+        v_blk = jnp.repeat(v_blk, group, axis=1)
     s = jnp.einsum("bhtd,bhsd->bhts", q, k_blk).astype(jnp.float32) * scale
     if causal:
         mask = q_pos[:, None] >= k_pos[None, :]                # [T,Tb]
+        if window is not None:
+            mask = mask & (k_pos[None, :] > q_pos[:, None] - window)
         s = jnp.where(mask[None, None], s, _NEG)
     blk_max = jnp.max(s, axis=-1, keepdims=True)               # [B,H,T,1]
     new_m = jnp.maximum(m, blk_max)
@@ -77,8 +87,9 @@ def _flash_block(t: int, cap: int, head_dim: int) -> int:
     return b if b >= 64 else 0
 
 
-def _flash_dispatch(tq: int, tk: int,
-                    head_dim: int) -> Optional[Tuple[int, int, bool]]:
+def _flash_dispatch(tq: int, tk: int, head_dim: int,
+                    window: Optional[int] = None
+                    ) -> Optional[Tuple[int, int, bool]]:
     """``(block_q, block_k, interpret)`` for the Pallas flash kernel, or
     ``None`` for the jnp streaming path.
 
@@ -87,6 +98,8 @@ def _flash_dispatch(tq: int, tk: int,
     and never switches; every decision is therefore counted in
     ``attention.traced{path=mosaic|interpret|jnp}`` (``metrics``), and a
     TPU trace that lands on the O(T²) jnp body is logged with its shapes.
+    A windowed trace is counted in ``attention.window_traced{window=}`` as
+    well, whichever body it got.
 
     - TPU backend, blocks fit: the compiled Mosaic kernel.
     - ``MVTPU_FORCE_FLASH`` (any non-empty value) off-TPU: the same
@@ -108,12 +121,18 @@ def _flash_dispatch(tq: int, tk: int,
                      tq, tk, head_dim, bq, bk,
                      os.environ.get("MVTPU_NO_FLASH", ""))
     metrics.counter("attention.traced", {"path": path}).inc()
+    if window is not None:
+        metrics.counter("attention.window_traced",
+                        {"window": str(window)}).inc()
     return None if path == "jnp" else (bq, bk, not on_tpu)
 
 
 def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
-                              q_offset: int = 0, k_offset: int = 0):
-    """Single-device attention (the ring's degenerate case).
+                              q_offset: int = 0, k_offset: int = 0,
+                              window: Optional[int] = None):
+    """Single-device attention (the ring's degenerate case).  q [B,H,T,D];
+    k/v [B,KV,T,D] with KV dividing H (grouped K/V heads); ``window`` keeps
+    the keys ``t - window < s <= t``.
 
     Aligned shapes dispatch to the Pallas flash kernel
     (``ops/flash_attention.py``) — O(T) memory, causal-block skipping,
@@ -124,19 +143,21 @@ def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
     B, H, T, D = q.shape
     flash = None
     if q_offset == 0 and k_offset == 0 and T == k.shape[2]:
-        flash = _flash_dispatch(T, T, D)
+        flash = _flash_dispatch(T, T, D, window)
     if flash is not None:
         from ..ops import flash_attention
 
         bq, bk, interpret = flash
         return flash_attention(q, k, v, scale=scale, causal=causal,
-                               block_q=bq, block_k=bk, interpret=interpret)
+                               block_q=bq, block_k=bk, interpret=interpret,
+                               window=window)
     o = jnp.zeros(q.shape, jnp.float32)
     m = jnp.full((B, H, T, 1), _NEG, jnp.float32)
     l = jnp.zeros((B, H, T, 1), jnp.float32)
     q_pos = q_offset + jnp.arange(T)
     k_pos = k_offset + jnp.arange(k.shape[2])
-    o, m, l = _online_block(q, k, v, o, m, l, q_pos, k_pos, scale, causal)
+    o, m, l = _online_block(q, k, v, o, m, l, q_pos, k_pos, scale, causal,
+                            window)
     return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 
@@ -189,7 +210,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
                    batch_axis: Optional[str] = "dp",
                    head_axis: Optional[str] = "tp",
                    scale: Optional[float] = None,
-                   layout: str = "auto"):
+                   layout: str = "auto",
+                   window: Optional[int] = None):
     """Causal self-attention with sequences sharded over ``axis_name``.
 
     ``q``/``k``/``v``: [B, H, T_global, D] jax.Arrays (sharded or not —
@@ -207,16 +229,27 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     the FLOPs of the contiguous schedule — at the cost of one global
     sequence permutation on the way in and out.  ``"auto"`` picks zigzag
     for causal attention whenever 2*sp divides T.
+
+    ``k``/``v`` may hold fewer heads than ``q`` (grouped K/V heads); the
+    head axis then has to divide them too.  ``window`` (keys ``t - window <
+    s <= t``) runs wherever the sequence is not sharded; a ring over ``sp``
+    with a window is refused.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if mesh.size == 1:
-        return blockwise_attention_local(q, k, v, scale, causal)
+        return blockwise_attention_local(q, k, v, scale, causal,
+                                         window=window)
     # Every multi-device mesh goes through shard_map (all axes manual),
     # ring or not: a Mosaic kernel under plain jit on >1 device fails to
     # lower ("cannot be automatically partitioned").
     axes = dict(mesh.shape)
     sp = int(axes.get(axis_name, 1))
+    if window is not None and sp > 1:
+        raise ValueError(
+            f"ring attention over '{axis_name}' ({sp}) with a sliding "
+            f"window ({window}) is unsupported: the ring's steps know full "
+            "and causal blocks only")
     b_ax = batch_axis if (batch_axis and batch_axis in axes) else None
     if b_ax and q.shape[0] % axes[b_ax]:
         Log.info("ring_attention: batch %d does not divide mesh axis '%s' "
@@ -224,6 +257,10 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
                  q.shape[0], b_ax, axes[b_ax])
         b_ax = None
     h_ax = head_axis if (head_axis and head_axis in axes) else None
+    if h_ax and k.shape[1] % axes[h_ax]:
+        raise ValueError(
+            f"{k.shape[1]} K/V heads do not divide over mesh axis '{h_ax}' "
+            f"({axes[h_ax]})")
     spec = P(b_ax, h_ax, axis_name if sp > 1 else None, None)
 
     if layout not in ("auto", "zigzag", "contiguous"):
@@ -251,7 +288,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis_name: str = "sp",
     def local_contiguous(q_l, k_l, v_l):
         B, H, T, D = q_l.shape
         if sp == 1:
-            return blockwise_attention_local(q_l, k_l, v_l, scale, causal)
+            return blockwise_attention_local(q_l, k_l, v_l, scale, causal,
+                                             window=window)
         idx = jax.lax.axis_index(axis_name)
         o_acc = jnp.zeros(q_l.shape, jnp.float32)
         lse_acc = jnp.full((B, H, T), _NEG, jnp.float32)
