@@ -12,6 +12,8 @@ from typing import Dict, Optional, Tuple, Type
 import jax
 import jax.numpy as jnp
 
+from .. import metrics
+
 __all__ = ["AddOption", "GetOption", "Updater", "register_updater",
            "get_updater", "updater_names", "aggregate_rows",
            "scatter_apply"]
@@ -67,6 +69,16 @@ class Updater:
         return w + delta, state
 
     # -- sparse (row) path --------------------------------------------------
+    def row_increment(self, delta: jax.Array, opt: AddOption) -> jax.Array:
+        """What a ``linear`` updater adds to a row for its ``delta``.
+
+        A linear updater with no state is this function and nothing else:
+        ``apply_rows`` scatter-adds it, and a fused step may add it to the
+        rows by another route (``scatter_apply``).  Non-linear updaters
+        override ``apply_rows`` and never see this called.
+        """
+        return delta
+
     def apply_rows(self, w: jax.Array, state: State, rows: jax.Array,
                    delta: jax.Array, opt: AddOption,
                    mask: Optional[jax.Array] = None
@@ -78,7 +90,8 @@ class Updater:
         state). Default: plain scatter-add, duplicate rows accumulate.
         """
         rows = effective_rows(rows, mask, w.shape[0])
-        return w.at[rows].add(masked(delta, mask), mode="drop"), state
+        return w.at[rows].add(self.row_increment(masked(delta, mask), opt),
+                              mode="drop"), state
 
 
 _REGISTRY: Dict[str, Type[Updater]] = {}
@@ -129,21 +142,71 @@ def aggregate_rows(rows: jax.Array, delta: jax.Array
     return uniq, agg, mask
 
 
+def _row_kernel() -> Optional[bool]:
+    """Whether this backend has the row-update kernel: ``None`` for no,
+    else the kernel's ``interpret``.  A TPU compiles it; the tests run it
+    in interpret mode by patching this."""
+    return False if jax.default_backend() == "tpu" else None
+
+
+def _one_device() -> bool:
+    """Tables live on the context's mesh; a Mosaic call under plain ``jit``
+    does not lower on a mesh of several devices."""
+    from ..core import context
+
+    try:
+        return context.get_context().mesh.size == 1
+    except RuntimeError:                        # no init(): jit's default
+        return True
+
+
 def scatter_apply(upd: "Updater", data, state, rows, delta, opt: AddOption):
-    """In-jit row scatter with the linear/non-linear dispatch.
+    """In-jit row scatter through an updater.
 
     THE one spelling of "apply a row batch through an updater inside a
-    fused step": linear updaters scatter duplicates directly (adds
-    commute); non-linear ones get duplicates segment-summed first via
-    ``aggregate_rows`` — matching the eager path's host-side np.unique
-    aggregation.  Used by every app's fused step, so the scope it opens
-    names the scatter in each of their compiled programs.
+    fused step" (its callers: ``apps/word2vec.py``,
+    ``apps/skipgram_mixture.py``); the scope it opens names all of it in
+    their compiled programs, and ``tables.scatter_traced{path=}`` counts,
+    at trace time, which body a compiled step holds:
+
+    - ``kernel``: a linear updater's batch is applied **in row order**.
+      The ids are sorted once (stable, so equal ids keep their batch order)
+      and ``ops/row_update.py`` adds the permuted increments group of rows
+      by group of rows, in place.  Taken where the code can see it is safe:
+      on a TPU, the table on one device, float32 rows a whole number of
+      lanes wide (a ``MatrixTable``'s ``stored_cols``), an updater that is
+      its ``row_increment`` alone.
+    - ``xla``: every other linear update, as ``Updater.apply_rows`` spells
+      it: ``.at[rows].add``, batch order, duplicates the scatter's business.
+    - ``aggregated``: a non-linear updater gets its duplicates
+      segment-summed first (``aggregate_rows``), matching the eager path's
+      host-side ``np.unique`` aggregation.
     """
     with jax.named_scope("tables.scatter_apply"):
-        if upd.linear:
+        if not upd.linear:
+            metrics.counter("tables.scatter_traced",
+                            {"path": "aggregated"}).inc()
+            uniq, agg, mask = aggregate_rows(rows, delta)
+            return upd.apply_rows(data, state, uniq, agg, opt, mask=mask)
+        from ..ops import row_update
+
+        interpret = _row_kernel()
+        if (interpret is None
+                or type(upd).apply_rows is not Updater.apply_rows
+                or not row_update.usable(data, rows, delta)
+                or not _one_device()):
+            metrics.counter("tables.scatter_traced", {"path": "xla"}).inc()
             return upd.apply_rows(data, state, rows, delta, opt)
-        uniq, agg, mask = aggregate_rows(rows, delta)
-        return upd.apply_rows(data, state, uniq, agg, opt, mask=mask)
+        metrics.counter("tables.scatter_traced", {"path": "kernel"}).inc()
+        # jnp's own reading of an id: negative ids count from the end, and
+        # what is still out of range is dropped (it sorts last).
+        num_rows = data.shape[0]
+        rows = jnp.where(rows < 0, rows + num_rows, rows)
+        rows = jnp.where((rows < 0) | (rows >= num_rows), num_rows, rows)
+        order = jnp.argsort(rows, stable=True)
+        return row_update.row_update(
+            data, rows[order], upd.row_increment(delta, opt)[order],
+            interpret=interpret), state
 
 
 def masked(delta: jax.Array, mask: Optional[jax.Array]) -> jax.Array:

@@ -2,11 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Optional
-
-import jax
-
-from .base import AddOption, Updater, effective_rows, masked, register_updater
+from .base import AddOption, Updater, register_updater
 
 
 @register_updater
@@ -19,8 +15,5 @@ class SGDUpdater(Updater):
     def apply_dense(self, w, state, delta, opt: AddOption):
         return w - opt.learning_rate * delta, state
 
-    def apply_rows(self, w, state, rows, delta, opt: AddOption,
-                   mask: Optional[jax.Array] = None):
-        rows = effective_rows(rows, mask, w.shape[0])
-        d = masked(delta, mask)
-        return w.at[rows].add(-opt.learning_rate * d, mode="drop"), state
+    def row_increment(self, delta, opt: AddOption):
+        return -opt.learning_rate * delta

@@ -4,6 +4,7 @@ Models the reference's updater unit tests; the math is checked against
 closed-form numpy (reference src/updater/*.cpp semantics, SURVEY.md §2.16).
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -117,3 +118,123 @@ def test_rows_padding_dropped(name):
         st = np.asarray(st)
         np.testing.assert_allclose(st[0], 0.0)
         np.testing.assert_allclose(st[2:], 0.0)
+
+
+# -------------------------------------------------- scatter_apply, in a jit
+
+def _ids(pattern, n, rows, rng):
+    """Row ids of a batch: ``n`` of them over ``rows`` rows."""
+    if pattern == "distinct":
+        return rng.permutation(rows)[:n]
+    if pattern == "one_id":
+        return np.full(n, 77)
+    if pattern == "zipf":
+        return (rng.zipf(1.1, n) - 1) % rows
+    if pattern == "boundary_runs":
+        # Sorted, the kernel cuts the batch into blocks of 256 ids.  Runs of
+        # one row and of one group of 8 rows laid across the cuts at 256,
+        # 512 and 768, one of them longer than a block.
+        ids = np.concatenate([
+            np.arange(0, 250 * 8, 8),             # 250 groups, one id each
+            np.full(16, 3000),                    # one row over the first cut
+            np.arange(3008, 3008 + 100),          # whole groups, 8 ids each
+            np.full(300, 5000),                   # a run longer than a block
+            5008 + np.arange(40) % 8,             # one group, all its rows
+            np.arange(6000, 6000 + 318 * 3, 3)])  # runs of 2 or 3 ids
+        assert ids.shape[0] == n == 1024
+        return rng.permutation(ids)               # the batch comes unsorted
+    if pattern == "dropped":
+        # Out of range ids are dropped; negative ones count from the end.
+        ids = rng.integers(0, rows, n)
+        ids[::7] = rows + 3
+        ids[1::7] = -ids[1::7] - 1
+        ids[2::50] = -rows - 5
+        return ids
+    raise AssertionError(pattern)
+
+
+def _applied(updater, path, pattern, cols, n=1024, rows=8192):
+    """``scatter_apply`` under ``jit`` against ``np.add.at``; every number
+    is a small integer (and the learning rate a power of two), so the sums
+    are exact in float32 whatever their order."""
+    from multiverso_tpu import metrics
+    from multiverso_tpu.updaters.base import scatter_apply
+
+    rng = np.random.default_rng(31)
+    ids = _ids(pattern, n, rows, rng).astype(np.int32)
+    w0 = rng.integers(-5, 6, (rows, cols)).astype(np.float32)
+    delta = rng.integers(-3, 4, (n, cols)).astype(np.float32)
+    upd, opt = get_updater(updater), AddOption(learning_rate=0.5)
+    counter = metrics.counter("tables.scatter_traced", {"path": path})
+    before = counter.value
+    step = jax.jit(lambda w, r, d: scatter_apply(upd, w, (), r, d, opt))
+    got, state = step(jnp.asarray(w0), jnp.asarray(ids), jnp.asarray(delta))
+    assert state == () and counter.value == before + 1
+
+    want = w0.copy()
+    ids = np.where(ids < 0, ids + rows, ids)
+    keep = (ids >= 0) & (ids < rows)
+    np.add.at(want, ids[keep],
+              (-0.5 * delta if updater == "sgd" else delta)[keep])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("updater", ["sgd", "default"])
+@pytest.mark.parametrize("pattern", ["distinct", "one_id", "zipf",
+                                     "boundary_runs", "dropped"])
+@pytest.mark.parametrize("cols, kernel", [
+    (384, "one_call"),  # the row-update kernel, in interpret mode here
+    (384, "calls"),     # a batch of more ids than one call of it takes
+    (384, None),        # what every backend but the TPU runs
+    (96, "one_call"),   # rows no whole number of lanes wide: XLA's scatter
+])
+def test_scatter_apply_linear_equals_add_at(monkeypatch, mv, updater,
+                                            pattern, cols, kernel):
+    # ``mv``: no runtime is up, so no mesh of several devices is in sight.
+    from multiverso_tpu.ops import row_update
+    from multiverso_tpu.updaters import base
+
+    if kernel:
+        monkeypatch.setattr(base, "_row_kernel", lambda: True)
+    if kernel == "calls":
+        monkeypatch.setattr(row_update, "_MAX_IDS", 384)
+    path = "kernel" if kernel and cols % 128 == 0 else "xla"
+    _applied(updater, path, pattern, cols)
+
+
+@pytest.mark.parametrize("why", ["batch_not_in_eights", "rows_not_in_eights",
+                                 "several_devices", "own_apply_rows",
+                                 "not_linear"])
+def test_scatter_apply_keeps_xla_where_the_kernel_cannot_go(
+        monkeypatch, mv, why):
+    from multiverso_tpu import metrics
+    from multiverso_tpu.updaters import base
+
+    monkeypatch.setattr(base, "_row_kernel", lambda: True)
+    upd, n, rows = get_updater("sgd"), 64, 256
+    if why == "batch_not_in_eights":
+        n = 60
+    elif why == "rows_not_in_eights":
+        rows = 250
+    elif why == "several_devices":
+        from multiverso_tpu.core import context
+
+        mv.init(updater_type="sgd")
+        assert context.get_context().mesh.size > 1
+    elif why == "own_apply_rows":
+        class Own(base.Updater):
+            def apply_rows(self, w, state, rows, delta, opt, mask=None):
+                return w.at[rows].add(2 * delta, mode="drop"), state
+        upd = Own()
+    else:
+        upd = get_updater("adagrad")
+    path = "aggregated" if why == "not_linear" else "xla"
+    counter = metrics.counter("tables.scatter_traced", {"path": path})
+    kernel = metrics.counter("tables.scatter_traced", {"path": "kernel"})
+    before = counter.value, kernel.value
+    state = upd.init_state((rows, 128), jnp.float32)
+    jax.eval_shape(
+        lambda w, s, r, d: base.scatter_apply(upd, w, s, r, d, OPT),
+        jnp.zeros((rows, 128)), state, jnp.zeros(n, jnp.int32),
+        jnp.zeros((n, 128)))
+    assert (counter.value, kernel.value) == (before[0] + 1, before[1])
