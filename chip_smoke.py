@@ -324,6 +324,79 @@ def phase_flash_reference(batch: int = 2, heads: int = 4, seq: int = 1024,
     return {"shape": list(shape), "max_rel_err": errs}
 
 
+def phase_flash_latent(heads: int = 32, seq: int = 8192, nope: int = 128,
+                       rope: int = 64, v_dim: int = 128,
+                       tol: float = 3e-2) -> dict:
+    """The three two-width kernels (``flash_mla_fwd``, ``flash_mla_bwd_dq``,
+    ``flash_mla_bwd_dkv``) at the shape of the cell
+    ``xing4.0-29b-a4b-e8.zipf-seq8k-b1`` (1 x 32 x 8192, scores 128 + 64
+    wide, values 128, the rotated key one head for all 32) against a dense
+    float32 reference computed a head at a time.  Errors as in
+    ``phase_flash_reference``."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.parallel.ring_attention import (
+        blockwise_attention_local)
+
+    rng = np.random.RandomState(1)
+
+    def draw(h, width):
+        return jnp.asarray(0.5 * rng.randn(1, h, seq, width), jnp.bfloat16)
+
+    args = (draw(heads, nope), draw(heads, rope), draw(heads, nope),
+            draw(1, rope), draw(heads, v_dim))
+    w = draw(heads, v_dim)
+    scale = (nope + rope) ** -0.5
+    mask = jnp.tril(jnp.ones((seq, seq), bool))
+
+    def dense(qn, qr, kn, kr, v):
+        hi = jax.lax.Precision.HIGHEST
+        qn, qr, kn, kr, v = (x.astype(jnp.float32)
+                             for x in (qn, qr, kn, kr, v))
+
+        @jax.checkpoint
+        def one(head):                      # [T, width] each
+            qn, qr, kn, v = head
+            s = (jnp.dot(qn, kn.T, precision=hi)
+                 + jnp.dot(qr, kr[0, 0].T, precision=hi)) * scale
+            return jnp.dot(jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1),
+                           v, precision=hi)
+
+        return jax.lax.map(one, (qn[0], qr[0], kn[0], v[0]))[None]
+
+    def kernel(qn, qr, kn, kr, v):
+        return blockwise_attention_local(qn, kn, v, scale, causal=True,
+                                         q_rope=qr, k_rope=kr)
+
+    def out_and_grads(attn):
+        def loss(*a):
+            o = attn(*a).astype(jnp.float32)
+            return jnp.sum(o * w.astype(jnp.float32)), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(5)), has_aux=True))(*args)
+        return (o,) + grads
+
+    before = jnp_traces()
+    got_all = out_and_grads(kernel)
+    require(jnp_traces() == before,
+            "the dispatcher sent the two-width check to the jnp body")
+    errs = {}
+    for name, got, want in zip(("o", "dq_nope", "dq_rope", "dk_nope",
+                                "dk_rope", "dv"),
+                               got_all, out_and_grads(dense)):
+        got = np.asarray(got, np.float32)
+        want = np.asarray(want, np.float32)
+        require(got.shape == want.shape and bool(np.all(np.isfinite(got))),
+                f"flash_mla {name}: shape {got.shape} or non-finite values")
+        errs[name] = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+        require(errs[name] <= tol,
+                f"flash_mla {name} differs from the dense f32 reference by "
+                f"{errs[name]:.4f} of its largest entry (tol {tol})")
+    return {"shape": [1, heads, seq, nope + rope, v_dim],
+            "max_rel_err": errs}
+
+
 # -------------------------------------------------------------------- flagship
 def held_kernels(text: str, cfg, batch: int, seq: int, mesh_shape) -> dict:
     """Which attention body a lowered step holds, read from its text.
@@ -510,6 +583,8 @@ def main() -> int:
 
     result["flash_reference"] = phase_flash_reference()
     say(f"flash vs dense f32 reference: {result['flash_reference']}")
+    result["flash_latent"] = phase_flash_latent()
+    say(f"two-width flash vs dense f32 reference: {result['flash_latent']}")
 
     # One chip first, whatever the host holds: its step-0 loss is what the
     # four-chip layouts are held to.

@@ -53,7 +53,15 @@ Scopes for the chip trace (docs/observability.md, "Chip plane"):
 ``moe.shared``; the two backward rules open ``moe.dispatch`` /
 ``moe.combine`` themselves, so their rows are booked where the forward's
 are.  The schedule a step was traced with is counted in
-``moe.traced{dispatch=}``, a share in ``moe.held{held=,of=}``.
+``moe.traced{dispatch=}`` (``{dispatch=,scoring=sigmoid}`` where the router
+scores by sigmoid), a share in ``moe.held{held=,of=}``.
+
+**Sigmoid scores and the correction bias** (``scoring="sigmoid"``): the
+scores are the logits' sigmoids over all experts, the k experts are the
+top of score + ``router_bias``, the weights are the chosen scores
+(renormalised, scaled) without the bias.  The bias is a leaf of the layer
+that gets no gradient; ``TransformerTrainer`` moves it by rule from the
+step's own ``all_load`` (``models/transformer.py:_bias_rule``).
 """
 
 from __future__ import annotations
@@ -79,21 +87,26 @@ GROUPED_SAVED = ("moe_gate", "moe_up", "moe_down")
 
 
 def init_moe_params(dim: int, hidden: int, num_experts: int,
-                    seed: int = 0, held: int = 0) -> Dict[str, Any]:
+                    seed: int = 0, held: int = 0,
+                    scoring: str = "softmax") -> Dict[str, Any]:
     """``held`` experts' weights (0 = all) under a router of ``num_experts``
-    columns."""
+    columns; with ``scoring="sigmoid"`` the correction bias ``router_bias
+    [num_experts]`` too, zeros."""
     rng = np.random.RandomState(seed)
     held = held or num_experts
 
     def w(*shape, scale):
         return (scale * rng.randn(*shape)).astype(np.float32)
 
-    return {
+    params = {
         "router": w(dim, num_experts, scale=0.02),
         "w1": w(held, dim, hidden, scale=dim ** -0.5),   # gate
         "w3": w(held, dim, hidden, scale=dim ** -0.5),   # up
         "w2": w(held, hidden, dim, scale=hidden ** -0.5),
     }
+    if scoring == "sigmoid":
+        params["router_bias"] = np.zeros(num_experts, np.float32)
+    return params
 
 
 def moe_pspecs(mesh: Mesh) -> Dict[str, Any]:
@@ -113,14 +126,28 @@ def moe_shardings(mesh: Mesh) -> Dict[str, Any]:
 
 
 def _routing(params, x, top_k: int, norm_topk_prob: bool,
-             routed_scale: float = 1.0):
+             routed_scale: float = 1.0, scoring: str = "softmax"):
     """The shared router, in float32: ``(probs, logits, top_p, top_idx)``
     with ``top_p`` renormalised to sum to 1 over the k routes if asked, then
-    scaled by ``routed_scale``."""
+    scaled by ``routed_scale``.  ``scoring="sigmoid"``: ``probs`` are the
+    logits' sigmoids, the k experts are the top of ``probs + router_bias``
+    (the layer's per-expert correction bias, which a rule moves and no
+    gradient does: arXiv:2412.19437, section 2.1.2) and ``top_p`` are the
+    chosen experts' ``probs``, the bias left out."""
     logits = (x.astype(jnp.float32)
               @ params["router"].astype(jnp.float32))        # [B,T,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_idx = jax.lax.top_k(probs, top_k)             # [B,T,k]
+    if scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(
+            params["router_bias"].astype(jnp.float32))
+        _, top_idx = jax.lax.top_k(probs + bias, top_k)
+        top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
+    elif scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_idx = jax.lax.top_k(probs, top_k)         # [B,T,k]
+    else:
+        raise ValueError(f"unknown router scoring '{scoring}' "
+                         "(expected softmax|sigmoid)")
     if norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     if routed_scale != 1.0:
@@ -164,24 +191,35 @@ def _share(params, held):
 def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
             compute_dtype=None, dispatch: str = "dense",
             norm_topk_prob: bool = True, held=None,
-            routed_scale: float = 1.0, aux: bool = True
+            routed_scale: float = 1.0, aux: bool = True,
+            scoring: str = "softmax", all_load: bool = False
             ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """x [B, T, dim] → ``(out [B, T, dim], balance, z, load)``: the
     layer's output, its two auxiliary loss terms (``_aux_losses``, scalars,
     unweighted; zeros and nothing computed with ``aux=False``) and the
     routes each expert was sent (int32 ``[E]``; with a share of the experts,
     ``held=(first, count)``, ``[count + 1]``: the held experts' and, last,
-    the routes that went elsewhere; this file's header).  ``dispatch`` picks
-    the schedule; the choice is taken at trace time and counted in
-    ``moe.traced``."""
+    the routes that went elsewhere; this file's header; with ``all_load``
+    every expert's routes ``[E]`` whatever is held, which is what the bias
+    rule reads).  ``dispatch`` picks the schedule and ``scoring`` the
+    router's scores (``_routing``); both are taken at trace time and counted
+    in ``moe.traced`` (``scoring`` only where it is not softmax, so that the
+    counter softmax configurations read keeps its labels)."""
     schedules = {"grouped": _moe_grouped, "dense": _moe_dense}
     if dispatch not in schedules:
         raise ValueError(f"unknown moe dispatch '{dispatch}' "
                          "(expected grouped|dense)")
-    metrics.counter("moe.traced", {"dispatch": dispatch}).inc()
+    if scoring != "softmax" and aux:
+        raise ValueError(f"the auxiliary losses are softmax routing's; "
+                         f"scoring='{scoring}' balances by its bias rule "
+                         "(aux=False)")
+    labels = {"dispatch": dispatch}
+    if scoring != "softmax":
+        labels["scoring"] = scoring
+    metrics.counter("moe.traced", labels).inc()
     return schedules[dispatch](params, x, top_k, compute_dtype or x.dtype,
                                norm_topk_prob, _share(params, held),
-                               routed_scale, aux)
+                               routed_scale, aux, scoring, all_load)
 
 
 def shared_expert(params: Dict[str, Any], h: jax.Array, dt) -> jax.Array:
@@ -195,6 +233,12 @@ def shared_expert(params: Dict[str, Any], h: jax.Array, dt) -> jax.Array:
 
 def _all_load(top_idx, E):
     return jnp.bincount(top_idx.reshape(-1), length=E).astype(jnp.int32)
+
+
+def _count_load(top_idx, E):
+    """``_all_load`` by compare and sum: no scatter inside the step."""
+    return jnp.sum(top_idx.reshape(-1, 1) == jnp.arange(E), axis=0,
+                   dtype=jnp.int32)
 
 
 def _share_load(load, routes: int):
@@ -284,14 +328,14 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
-                 aux):
+                 aux, scoring="softmax", all_load=False):
     B, T, D = x.shape
     N = B * T
     E = params["router"].shape[1]
     with jax.named_scope("moe.route"):
         probs, logits, top_p, top_idx = _routing(params, x, top_k,
                                                  norm_topk_prob,
-                                                 routed_scale)
+                                                 routed_scale, scoring)
     with jax.named_scope("moe.dispatch"):
         # Route r = n*k + j is token n's j-th expert.  Sorted by expert, a
         # group's rows are contiguous and its size is the distance between
@@ -329,16 +373,20 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
     with jax.named_scope("moe.combine"):
         out = _combine(down, top_p.reshape(N, top_k), order, inv, x.dtype,
                        mine)
-    if share is not None:
+    if all_load:
+        with jax.named_scope("moe.route"):
+            sizes = _count_load(top_idx, E)
+    elif share is not None:
         sizes = _share_load(sizes, N * top_k)
     return out.reshape(B, T, D), balance, z, sizes
 
 
 def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
-               aux):
+               aux, scoring="softmax", all_load=False):
     E = params["router"].shape[1]
     probs, logits, top_p, top_idx = _routing(params, x, top_k,
-                                             norm_topk_prob, routed_scale)
+                                             norm_topk_prob, routed_scale,
+                                             scoring)
     load = _all_load(top_idx, E)
     balance = z = jnp.float32(0)
     if aux:
@@ -350,7 +398,8 @@ def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
     if share is not None:
         first, count = share
         combine = combine[..., first:first + count]
-        load = _share_load(load[first:first + count], top_idx.size)
+        if not all_load:
+            load = _share_load(load[first:first + count], top_idx.size)
 
     # dense dispatch: every (held) expert sees every token, scaled post-hoc.
     xc = x.astype(dt)

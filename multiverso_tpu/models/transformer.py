@@ -9,11 +9,16 @@ Design (not in the reference — see models/__init__):
   over ``sp`` with ring attention (``parallel/ring_attention.py``);
 - layers of different kinds (``LayerKind``: full or sliding-window
   attention with its own query heads over grouped K/V heads and its own
-  ``Rope`` recipe, a per-head output gate, a dense or routed FFN that may
-  hold a share of the experts beside a shared one), held as ``Layout``
-  writes them: a leading group, a period whose slots are stacked over its
-  repetitions and scanned, a trailing part.  Every layer alike is the
-  one-slot case;
+  ``Rope`` recipe, or latent attention, whose queries and keys/values pass
+  a low-rank bottleneck and whose scores add a rotated part shared by the
+  heads to an unrotated one; a per-head output gate, a dense or routed FFN
+  that may hold a share of the experts beside a shared one), held as
+  ``Layout`` writes them: a leading group, a period whose slots are stacked
+  over its repetitions and scanned, a trailing part.  Every layer alike is
+  the one-slot case;
+- a residual of ``hc_mult`` streams mixed by hyper-connections
+  (``_hc_gates``, ``_hc_read``, ``_hc_write``), and a multi-token-prediction
+  module (``mtp_layers``), both off by default;
 - updater integration: the train step applies the framework's server-side
   updaters (SURVEY.md §2.16) per parameter leaf, so a Multiverso user's
   ``-updater_type`` flag means the same thing here.
@@ -34,7 +39,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..updaters import AddOption, get_updater
-from .. import dashboard, tracing
+from .. import dashboard, metrics, tracing
 from .moe import (GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs,
                   shared_expert)
 
@@ -43,6 +48,7 @@ __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
            "TransformerTrainer"]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
+LATENT = "latent_attention"
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -66,8 +72,8 @@ class Rope:
 
 class LayerKind(NamedTuple):
     """What one layer is made of: its attention (``full_attention`` |
-    ``sliding_attention``), its query heads, its FFN (``dense`` |
-    ``sparse``).  Window, rotary recipe and widths follow from these and
+    ``sliding_attention`` | ``latent_attention``), its query heads, its FFN
+    (``dense`` | ``sparse``).  Window, rotary recipe and widths follow from these and
     the configuration."""
     attn: str
     heads: int
@@ -211,6 +217,47 @@ class TransformerConfig:
     experts_first: int = 0
     routed_scale: float = 1.0
     shared_expert_hidden: int = 0
+    # The router's scores (models/moe.py:_routing): "softmax", or "sigmoid"
+    # with a per-expert correction bias (leaf ``router_bias``) that enters
+    # the top-k choice and not the weights and that the train step moves by
+    # rule, ``b_e += router_bias_rate * sign(mean(load) - load_e)`` over the
+    # step's own counted routes, and no gradient does.
+    router_scoring: str = "softmax"
+    router_bias_rate: float = 0.001
+    # ---- ``latent_attention`` layers (arXiv:2405.04434, decompressed form):
+    # ``c_q = norm(h wq_a)`` [q_lora_rank], ``q = c_q wq_b`` [heads,
+    # qk_nope_dim + qk_rope_dim]; ``h wkv_a`` = ``c_kv`` [kv_lora_rank] and
+    # one rotated key head [qk_rope_dim]; ``norm(c_kv) wkv_b`` [heads,
+    # qk_nope_dim + v_head_dim].  The rotated parts take ``rope_latent``;
+    # the softmax scale is ``(qk_nope_dim + qk_rope_dim) ** -0.5 *
+    # attn_mscale ** 2`` (YaRN's mscale on q and k both).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    attn_mscale: float = 1.0
+    rope_latent: Optional[Rope] = None
+    # ---- Hyper-connections (arXiv:2512.24880 over arXiv:2409.19606): 0 =
+    # the residual ``x + f(x)``; n > 0 = n residual streams ``[B, T, dim]``
+    # (a tuple: the scan's carry), every sub-layer reading ``u = sum_i
+    # H_pre[i] X[i]`` and writing
+    # ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] f(u)`` with ``H_pre``,
+    # ``H_post`` sigmoid gates and ``H_res`` made doubly stochastic by
+    # ``hc_sinkhorn_iters`` Sinkhorn-Knopp iterations of ``exp(clip(.,
+    # hc_res_clamp_min, hc_res_clamp_max))``, all three linear in the
+    # token's RMS-normed streams (``_hc_gates``).
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp_min: float = -30.0
+    hc_res_clamp_max: float = 30.0
+    # ---- Multi-token prediction (arXiv:2412.19437, section 2.2): 0 = none;
+    # 1 = one module: ``[norm(x_t) | norm(E[tok_{t+1}])] proj`` through one
+    # layer of its own (the last layer's kind), its own final norm, the
+    # shared embedding and head; ``loss += mtp_loss_coef * CE(token t+2)``.
+    mtp_layers: int = 0
+    mtp_loss_coef: float = 0.3
 
     def __post_init__(self):
         def put(name, value):
@@ -225,13 +272,26 @@ class TransformerConfig:
                     raise ValueError(f"{name} lists {len(value)} layers, "
                                      f"n_layers is {self.n_layers}")
                 put(name, tuple(value))
-        for name in ("rope_full", "rope_sliding"):
+        for name in ("rope_full", "rope_sliding", "rope_latent"):
             if isinstance(getattr(self, name), dict):
                 put(name, Rope(**getattr(self, name)))
         kv = self.n_kv_heads
         for k in self.layout.kinds:
-            if k.attn not in (FULL, SLIDING) or k.ffn not in (DENSE, SPARSE):
+            if (k.attn not in (FULL, SLIDING, LATENT)
+                    or k.ffn not in (DENSE, SPARSE)):
                 raise ValueError(f"unknown layer kind {k}")
+            if k.attn == LATENT:
+                widths = ("q_lora_rank", "kv_lora_rank", "qk_nope_dim",
+                          "qk_rope_dim", "v_head_dim")
+                if not all(getattr(self, w) > 0 for w in widths):
+                    raise ValueError(
+                        f"latent_attention layers need {widths} > 0")
+                if kv or self.qk_norm or self.attn_gate:
+                    raise ValueError(
+                        "latent_attention layers take no n_kv_heads, "
+                        "qk_norm or attn_gate: their K/V are per head and "
+                        "their norms are the two latent ones")
+                continue
             if kv and k.heads % kv:
                 raise ValueError(f"{k.heads} query heads do not divide into "
                                  f"{kv} K/V heads")
@@ -242,6 +302,22 @@ class TransformerConfig:
                 raise ValueError("sparse layers need num_experts > 0")
         if self.attn_gate not in ("", "per_head"):
             raise ValueError(f"unknown attn_gate '{self.attn_gate}'")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown router_scoring '{self.router_scoring}'")
+        if self.rule_bias and (self.aux_loss_coef
+                               or self.router_z_loss_coef):
+            raise ValueError(
+                "router_scoring='sigmoid' balances by its bias rule: set "
+                "aux_loss_coef and router_z_loss_coef to 0 (they are "
+                "softmax routing's)")
+        if self.hc_mult < 0 or self.hc_mult == 1:
+            raise ValueError(f"hc_mult={self.hc_mult}: 0 (one stream, no "
+                             "hyper-connections) or at least 2 streams")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                f"mtp_layers={self.mtp_layers}: one prediction depth beyond "
+                "the next token is what there is (0 or 1)")
 
     @functools.cached_property
     def layout(self) -> Layout:
@@ -267,22 +343,56 @@ class TransformerConfig:
         reached it is something no trace knows."""
         return bool(self.experts_held) and self.experts_held < self.num_experts
 
+    @property
+    def rule_bias(self) -> bool:
+        """Whether the routed layers hold a ``router_bias`` that the train
+        step moves by rule."""
+        return bool(self.num_experts) and self.router_scoring == "sigmoid"
+
     def rope(self, attn: str) -> Rope:
-        given = self.rope_sliding if attn == SLIDING else self.rope_full
+        given = {SLIDING: self.rope_sliding,
+                 LATENT: self.rope_latent}.get(attn, self.rope_full)
         return given or Rope(theta=self.rope_theta)
+
+
+def _hc_init(cfg: TransformerConfig, w):
+    """One sub-layer's hyper-connection leaves: ``phi [n*dim, 2n + n*n]``
+    (columns: pre, post, res row-major), ``alpha [3]``, ``b [2n + n*n]``.
+    With ``alpha`` 0 these are ``H_pre = 1/n``, ``H_post = 1``, ``H_res``
+    the identity to 1e-3: the one-stream pre-norm model on n equal
+    streams."""
+    n = cfg.hc_mult
+    return {"phi": w(n * cfg.dim, 2 * n + n * n,
+                     scale=0.02 * (n * cfg.dim) ** -0.5),
+            "alpha": np.full(3, 0.01, np.float32),
+            "b": np.concatenate([np.full(n, math.log(1 / (n - 1))),
+                                 np.zeros(n),
+                                 8.0 * np.eye(n).ravel()]).astype(np.float32)}
 
 
 def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
     heads, kv = kind.heads, cfg.n_kv_heads or kind.heads
     q_width, kv_width = heads * cfg.head_dim, kv * cfg.head_dim
-    lyr = {
-        "wq": w(cfg.dim, q_width),
-        "wk": w(cfg.dim, kv_width),
-        "wv": w(cfg.dim, kv_width),
-        "wo": w(q_width, cfg.dim),
-        "attn_norm": np.ones(cfg.dim, np.float32),
-        "mlp_norm": np.ones(cfg.dim, np.float32),
-    }
+    if kind.attn == LATENT:
+        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        lyr = {
+            "wq_a": w(cfg.dim, cfg.q_lora_rank),
+            "q_a_norm": np.ones(cfg.q_lora_rank, np.float32),
+            "wq_b": w(cfg.q_lora_rank, heads * (dn + dr)),
+            "wkv_a": w(cfg.dim, cfg.kv_lora_rank + dr),
+            "kv_a_norm": np.ones(cfg.kv_lora_rank, np.float32),
+            "wkv_b": w(cfg.kv_lora_rank, heads * (dn + dv)),
+            "wo": w(heads * dv, cfg.dim),
+        }
+    else:
+        lyr = {
+            "wq": w(cfg.dim, q_width),
+            "wk": w(cfg.dim, kv_width),
+            "wv": w(cfg.dim, kv_width),
+            "wo": w(q_width, cfg.dim),
+        }
+    lyr.update(attn_norm=np.ones(cfg.dim, np.float32),
+               mlp_norm=np.ones(cfg.dim, np.float32))
     if cfg.qk_norm:
         lyr.update(q_norm=np.ones(q_width, np.float32),
                    k_norm=np.ones(kv_width, np.float32))
@@ -290,7 +400,8 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
         # router, w1, w3, w2 at the layer's top level, expert-indexed
         lyr.update(init_moe_params(cfg.dim, cfg.hidden, cfg.num_experts,
                                    seed=rng.randint(2 ** 31),
-                                   held=cfg.experts_held))
+                                   held=cfg.experts_held,
+                                   scoring=cfg.router_scoring))
     else:
         hidden = cfg.dense_hidden or cfg.hidden
         lyr.update({
@@ -304,6 +415,8 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
         shared = cfg.shared_expert_hidden
         lyr.update(shared_w1=w(cfg.dim, shared), shared_w3=w(cfg.dim, shared),
                    shared_w2=w(shared, cfg.dim))
+    if cfg.hc_mult:
+        lyr.update(hc_attn=_hc_init(cfg, w), hc_mlp=_hc_init(cfg, w))
     return lyr
 
 
@@ -320,12 +433,20 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
     layers = [_init_layer(cfg, kind, rng, w) for kind in cfg.layout.kinds]
     if cfg.scan_layers:
         layers = group_layers(cfg, layers)
-    return {
+    params = {
         "embed": w(cfg.vocab_size, cfg.dim, scale=0.02),
         "out_norm": np.ones(cfg.dim, np.float32),
         "head": w(cfg.dim, cfg.vocab_size),
         "layers": layers,
     }
+    if cfg.mtp_layers:
+        params["mtp"] = {
+            "proj": w(2 * cfg.dim, cfg.dim),
+            "h_norm": np.ones(cfg.dim, np.float32),
+            "e_norm": np.ones(cfg.dim, np.float32),
+            "out_norm": np.ones(cfg.dim, np.float32),
+            "layer": _init_layer(cfg, cfg.layout.kinds[-1], rng, w)}
+    return params
 
 
 def stack_layer_params(layers):
@@ -382,23 +503,39 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
             f"{cfg.n_kv_heads or kind.heads} K/V heads do not divide over "
             f"the 'tp' axis ({mesh.shape['tp']}): wk/wv shard by head")
 
-    layer = {
-        "wq": P(None, tp), "wk": P(None, tp), "wv": P(None, tp),
-        "wo": P(tp, None),
-        "attn_norm": P(None), "mlp_norm": P(None),
-    }
+    if kind.attn == LATENT:
+        if tp and mesh.shape["tp"] > 1:
+            raise ValueError(
+                f"latent_attention does not shard over 'tp' "
+                f"(tp={mesh.shape['tp']}): the heads leave wq_b/wkv_b "
+                "interleaved with the low-rank norms' inputs whole, and no "
+                "tp layout of the latent projections is written")
+        layer = {"wq_a": P(None, None), "q_a_norm": P(None),
+                 "wq_b": P(None, None), "wkv_a": P(None, None),
+                 "kv_a_norm": P(None), "wkv_b": P(None, None),
+                 "wo": P(None, None)}
+    else:
+        layer = {"wq": P(None, tp), "wk": P(None, tp), "wv": P(None, tp),
+                 "wo": P(tp, None)}
+    layer.update(attn_norm=P(None), mlp_norm=P(None))
     if cfg.qk_norm:
         layer.update(q_norm=P(None), k_norm=P(None))
     if cfg.attn_gate:
         layer["wg"] = P(None, tp)
     if kind.ffn == SPARSE:
         layer.update(moe_pspecs(mesh))
+        if cfg.rule_bias:
+            layer["router_bias"] = P(None)
         if cfg.shared_expert_hidden:
             layer.update(shared_w1=P(None, tp), shared_w3=P(None, tp),
                          shared_w2=P(tp, None))
     else:
         layer.update({"w1": P(None, tp), "w3": P(None, tp),
                       "w2": P(tp, None)})
+    if cfg.hc_mult:              # small, float32, replicated
+        for sub in ("hc_attn", "hc_mlp"):
+            layer[sub] = {"phi": P(None, None), "alpha": P(None),
+                          "b": P(None)}
     return layer
 
 
@@ -436,12 +573,17 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
     def s(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    return {
+    out = {
         "embed": s(None, None),
         "out_norm": s(None),
         "head": s(None, tp),
         "layers": layers,
     }
+    if cfg.mtp_layers:
+        out["mtp"] = {"proj": s(None, None), "h_norm": s(None),
+                      "e_norm": s(None), "out_norm": s(None),
+                      "layer": sharded(lay.kinds[-1])}
+    return out
 
 
 def _rms_norm(x, gain, eps):
@@ -488,6 +630,65 @@ def _rope(x, rope: Rope):
     return jnp.concatenate(parts, -1).astype(x.dtype)
 
 
+# ---- hyper-connections.  The streams are a tuple of n ``[B, T, dim]``
+# arrays (not one ``[B, T, n, dim]``: no tile is padded from n rows, and the
+# n outputs of a mix are siblings that read each input stream once), the
+# gates ``[n, B, T]`` and ``[n, n, B, T]`` float32, planes of tokens, so
+# that every Sinkhorn step is elementwise over whole planes.
+def _hc_gates(X, hc, cfg: TransformerConfig):
+    """``(H_pre [n, B, T], H_post [n, B, T], H_res [n, n, B, T])``, float32,
+    for the streams ``X`` from one sub-layer's leaves ``hc``.  The RMS norm
+    over a token's ``n * dim`` values is a scalar a token, so it is applied
+    to the ``2n + n*n`` products and not to the streams (the same number,
+    without a normed copy of X).  The matmuls are dots without batch dims:
+    remat policy "dots" saves their ``[B, T, 2n + n*n]`` outputs, and gates
+    and Sinkhorn are recomputed from them."""
+    n, f32 = cfg.hc_mult, jnp.float32
+    metrics.counter("hc.traced", {"n": str(n)}).inc()
+    with jax.named_scope("hc.gates"):
+        dim = X[0].shape[-1]
+        phi = hc["phi"].astype(X[0].dtype).reshape(n, dim, -1)
+        raw = sum(jnp.dot(x, phi[i], preferred_element_type=f32)
+                  for i, x in enumerate(X))                  # [B,T,2n+n*n]
+        var = sum(jnp.mean(jnp.square(x.astype(f32)), axis=-1)
+                  for x in X) / n                            # [B,T]
+        a = jnp.moveaxis(raw, -1, 0) * jax.lax.rsqrt(var + cfg.norm_eps)
+        alpha = hc["alpha"].astype(f32)
+        b = hc["b"].astype(f32)[:, None, None]
+        pre = jax.nn.sigmoid(alpha[0] * a[:n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * a[n:2 * n] + b[n:2 * n])
+        res = jnp.exp(jnp.clip(alpha[2] * a[2 * n:] + b[2 * n:],
+                               cfg.hc_res_clamp_min, cfg.hc_res_clamp_max)
+                      ).reshape(n, n, *var.shape)
+        for _ in range(cfg.hc_sinkhorn_iters):
+            res = res / (jnp.sum(res, axis=1, keepdims=True) + cfg.hc_eps)
+            res = res / (jnp.sum(res, axis=0, keepdims=True) + cfg.hc_eps)
+    return pre, post, res
+
+
+def _hc_read(X, pre):
+    """The sub-layer's input ``sum_i H_pre[i] X[i]`` [B, T, dim]."""
+    with jax.named_scope("hc.mix"):
+        u = sum(pre[i][..., None] * x.astype(jnp.float32)
+                for i, x in enumerate(X))
+        return u.astype(X[0].dtype)
+
+
+def _hc_write(X, post, res, y):
+    """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``, y [B, T, dim]."""
+    with jax.named_scope("hc.mix"):
+        Xf, yf = [x.astype(jnp.float32) for x in X], y.astype(jnp.float32)
+        return tuple(
+            (sum(res[i, j][..., None] * xj for j, xj in enumerate(Xf))
+             + post[i][..., None] * yf).astype(y.dtype)
+            for i in range(len(X)))
+
+
+def _hc_mean(X):
+    """The streams' mean: what goes on to a final norm."""
+    return (sum(x.astype(jnp.float32) for x in X) / len(X)).astype(X[0].dtype)
+
+
 def transformer_forward(params, tokens, cfg: TransformerConfig,
                         mesh: Optional[Mesh] = None,
                         return_aux: bool = False):
@@ -497,7 +698,7 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
     over the layers, weighted as ``lm_loss`` adds it: ``aux_loss_coef`` x
     the load-balancing term + ``router_z_loss_coef`` x the router z-loss
     (zero for dense configs)."""
-    logits, aux, _ = _forward(params, tokens, cfg, mesh)
+    logits, aux, _, _ = _forward(params, tokens, cfg, mesh)
     return (logits, aux) if return_aux else logits
 
 
@@ -513,13 +714,28 @@ def expert_load(params, tokens, cfg: TransformerConfig,
     the grouped schedule are."""
     if not cfg.num_experts:
         raise ValueError("expert_load: the configuration has no experts")
-    return jax.jit(lambda p, t: _forward(p, t, cfg, mesh)[2])(
+    return jax.jit(lambda p, t: _routes(cfg, _forward(p, t, cfg, mesh)[2]))(
         params, jnp.asarray(tokens, jnp.int32))
+
+
+def _routes(cfg: TransformerConfig, load):
+    """The rows ``expert_load`` and the train step hand back, from the
+    layers' ``load``: itself, but where a bias rule had every expert's
+    routes counted (``[layers, E]``) and a share is held, the share's rows
+    ``[layers, experts_held + 1]``."""
+    if load is None or not (cfg.rule_bias and cfg.counts_routes):
+        return load
+    first, count = cfg.held
+    mine = load[:, first:first + count]
+    return jnp.concatenate(
+        [mine, jnp.sum(load, axis=1, keepdims=True)
+         - jnp.sum(mine, axis=1, keepdims=True)], axis=1)
 
 
 def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
     """``(logits, weighted auxiliary loss, expert load [routed layers, E]
-    or None)``."""
+    or None, the prediction module's logits or None)``.  With ``mtp_layers``
+    the module's layer is the load's last row."""
     from ..parallel.ring_attention import blockwise_attention_local, ring_attention
 
     lay = cfg.layout
@@ -556,6 +772,25 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 f"sliding_attention layers (window {cfg.sliding_window}) do "
                 f"not run over an 'sp' ring (sp={mesh.shape['sp']})")
     use_aux = bool(cfg.aux_loss_coef or cfg.router_z_loss_coef)
+    n_streams = cfg.hc_mult
+    if any(k.attn == LATENT for k in lay.kinds) and mesh is not None \
+            and mesh.size > 1:
+        for axis, why in (("sp", "its two-part scores do not ride the 'sp' "
+                                 "ring"),
+                          ("tp", "no tp layout of the latent projections "
+                                 "is written")):
+            if int(mesh.shape.get(axis, 1)) > 1:
+                raise ValueError(f"latent_attention does not run over "
+                                 f"{axis}={mesh.shape[axis]}: {why}")
+        raise ValueError(
+            f"latent_attention runs on one device: on a mesh of {mesh.size} "
+            "the Mosaic kernel sits inside ring_attention's shard_map, "
+            "which carries one width for q, k and v")
+    if use_pp and (n_streams or cfg.mtp_layers):
+        raise ValueError(
+            "pipeline_microbatches does not compose with hc_mult or "
+            "mtp_layers: stages pass one [B, T, dim] stream and the "
+            "prediction module reads the last stage's hidden state")
 
     def make_block(kind: LayerKind, tp: int = 1, reduce=None):
         """Build one decoder-layer fn of ``kind`` (with the remat wrapper
@@ -575,30 +810,59 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
         window = cfg.sliding_window if kind.attn == SLIDING else None
         rope = cfg.rope(kind.attn)
 
+        latent = kind.attn == LATENT
+        if latent:
+            scale_l = ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+                       * cfg.attn_mscale ** 2)
+
         def kind_scope():
             # The kind's own scope inside ``attn`` where the layers differ.
             if cfg.layer_types is None:
                 return contextlib.nullcontext()
-            return jax.named_scope("attn.sliding" if kind.attn == SLIDING
-                                   else "attn.full")
+            return jax.named_scope({SLIDING: "attn.sliding",
+                                    LATENT: "attn.latent"}.get(kind.attn,
+                                                               "attn.full"))
 
-        def block(x, lyr):
-            """One decoder layer: attn + residual, MLP/MoE + residual.
+        def wc(w):
+            # Named so the "dots" policy SAVES the bf16 weight cast:
+            # the cast is not a dot, so without the name the
+            # backward re-reads the f32 masters and recasts every
+            # big weight per layer — avoidable HBM traffic for one
+            # bf16 copy of the layer weights of residency.
+            return checkpoint_name(w.astype(dt), "wcast")
 
-            Shapes derive from ``x`` itself — under pipeline parallelism
-            the block sees microbatches, not the full batch."""
+        def latent_heads(h, lyr):
+            """Latent attention's heads from the normed input ``h``:
+            [B, T, heads * v_head_dim]."""
+            Bb, Tb, _ = h.shape
+            dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+            c_q = _rms_norm(h @ wc(lyr["wq_a"]), lyr["q_a_norm"].astype(dt),
+                            cfg.norm_eps)
+            q = (c_q @ wc(lyr["wq_b"])).reshape(
+                Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
+            kv_a = h @ wc(lyr["wkv_a"])
+            c_kv = _rms_norm(kv_a[..., :cfg.kv_lora_rank],
+                             lyr["kv_a_norm"].astype(dt), cfg.norm_eps)
+            kv = (c_kv @ wc(lyr["wkv_b"])).reshape(
+                Bb, Tb, local_heads, dn + dv).transpose(0, 2, 1, 3)
+            # the rotated key part is one head, whatever the query heads
+            k_r = _rope(kv_a[..., cfg.kv_lora_rank:][:, None], rope)
+            o = blockwise_attention_local(
+                q[..., :dn], kv[..., :dn], kv[..., dn:], scale_l,
+                causal=True, q_rope=_rope(q[..., dn:], rope), k_rope=k_r)
+            return o.transpose(0, 2, 1, 3).reshape(Bb, Tb, local_heads * dv)
+
+        def attn_sub(x, lyr, residual=True):
+            """The attention sub-layer of ``x`` [B, T, dim]: with its
+            residual, or (hyper-connections) its output alone.  Shapes
+            derive from ``x`` itself — under pipeline parallelism the
+            block sees microbatches, not the full batch."""
             Bb, Tb, _ = x.shape
-
-            def wc(w):
-                # Named so the "dots" policy SAVES the bf16 weight cast:
-                # the cast is not a dot, so without the name the
-                # backward re-reads the f32 masters and recasts every
-                # big weight per layer — avoidable HBM traffic for one
-                # bf16 copy of the layer weights of residency.
-                return checkpoint_name(w.astype(dt), "wcast")
-
             with jax.named_scope("attn"), kind_scope():
                 h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
+                if latent:
+                    out = red(latent_heads(h, lyr) @ wc(lyr["wo"]))
+                    return x + out if residual else out
                 q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
                 if cfg.qk_norm:
                     q = _rms_norm(q, lyr["q_norm"].astype(dt), cfg.norm_eps)
@@ -623,8 +887,12 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                         (h @ wc(lyr["wg"])).astype(jnp.float32))
                     o = o * gate.astype(dt)[..., None]
                 o = o.reshape(Bb, Tb, local_heads * cfg.head_dim)
-                x = x + red(o @ wc(lyr["wo"]))
+                out = red(o @ wc(lyr["wo"]))
+                return x + out if residual else out
 
+        def mlp_sub(x, lyr, residual=True):
+            """``(the FFN sub-layer of x, its weighted auxiliary loss, its
+            load or None)``."""
             with jax.named_scope("mlp"):
                 h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
                 if kind.ffn == SPARSE:
@@ -632,15 +900,30 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                         lyr, h, top_k=cfg.top_k, compute_dtype=dt,
                         dispatch=cfg.moe_dispatch,
                         norm_topk_prob=cfg.norm_topk_prob, held=cfg.held,
-                        routed_scale=cfg.routed_scale, aux=use_aux)
+                        routed_scale=cfg.routed_scale, aux=use_aux,
+                        scoring=cfg.router_scoring, all_load=cfg.rule_bias)
                     if cfg.shared_expert_hidden:
                         out = out + shared_expert(lyr, h, dt)
                     aux = (cfg.aux_loss_coef * balance
                            + cfg.router_z_loss_coef * z)
-                    return x + out, aux, load
+                    return (x + out if residual else out), aux, load
                 gated = (jax.nn.silu(h @ wc(lyr["w1"]))
                          * (h @ wc(lyr["w3"])))
-                return x + red(gated @ wc(lyr["w2"])), jnp.float32(0), None
+                out = red(gated @ wc(lyr["w2"]))
+                return (x + out if residual else out), jnp.float32(0), None
+
+        def block(x, lyr):
+            """One decoder layer: attn + residual, MLP/MoE + residual; with
+            hyper-connections ``x`` is the n streams (a tuple) and
+            each sub-layer reads and writes them through its own gates."""
+            if not n_streams:
+                return mlp_sub(attn_sub(x, lyr), lyr)
+            pre, post, res = _hc_gates(x, lyr["hc_attn"], cfg)
+            x = _hc_write(x, post, res,
+                          attn_sub(_hc_read(x, pre), lyr, residual=False))
+            pre, post, res = _hc_gates(x, lyr["hc_mlp"], cfg)
+            out, aux, load = mlp_sub(_hc_read(x, pre), lyr, residual=False)
+            return _hc_write(x, post, res, out), aux, load
 
         if cfg.remat:
             # Under scan the body already blocks CSE, so the anti-CSE
@@ -751,13 +1034,15 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                        param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
                                     else None))
         x = xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
-        aux_total, load = jnp.float32(0), None
+        aux_total, load, mtp_logits = jnp.float32(0), None, None
     else:
         # A leading group, a scan over the periods whose body runs one layer
         # of each slot, a trailing part of a period; every layer alike is
         # one slot and nothing around the scan.  Without ``scan_layers`` the
         # layers are a list and the loop below is all there is.
         aux_total, loads = jnp.float32(0), []
+        if n_streams:       # the embedding row copied into the n streams
+            x = (x,) * n_streams
 
         def run(x, aux, kinds, layers):
             for kind, lyr in zip(kinds, layers):
@@ -793,13 +1078,42 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                         -1, routed[0].shape[-1]))
                 x, aux_total = run(x, aux_total, lay.period[:lay.n_trail],
                                    trail)
+        if n_streams:       # the streams' mean goes on to the final norm
+            x = _hc_mean(x)
+        mtp_logits = None
+        if cfg.mtp_layers:
+            # One more depth: position t pairs its hidden state with the
+            # embedding of token t+1 and predicts token t+2.  It runs over
+            # all T positions so that the kernel's blocks divide; the last
+            # has no next token (a zero row), takes no loss and, being last
+            # under a causal mask, moves no other position.
+            with jax.named_scope("mtp"):
+                m = params["mtp"]
+                nxt = params["embed"][jnp.roll(tokens, -1, axis=1)]
+                nxt = nxt.astype(dt) * (jnp.arange(T) < T - 1
+                                        ).astype(dt)[None, :, None]
+                g = jnp.concatenate(
+                    [_rms_norm(x, m["h_norm"].astype(dt), cfg.norm_eps),
+                     _rms_norm(nxt, m["e_norm"].astype(dt), cfg.norm_eps)],
+                    axis=-1) @ m["proj"].astype(dt)
+                if n_streams:
+                    g = (g,) * n_streams
+                g, a, m_load = blocks[lay.kinds[-1]](g, m["layer"])
+                aux_total = aux_total + a
+                if m_load is not None:
+                    loads.append(m_load[None])
+                if n_streams:
+                    g = _hc_mean(g)
+                with jax.named_scope("head"):
+                    g = _rms_norm(g, m["out_norm"].astype(dt), cfg.norm_eps)
+                    mtp_logits = g @ params["head"].astype(dt)
         load = (None if not loads else loads[0] if len(loads) == 1
                 else jnp.concatenate(loads))
 
     with jax.named_scope("head"):
         x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
         logits = x @ params["head"].astype(dt)
-    return logits, aux_total, load
+    return logits, aux_total, load, mtp_logits
 
 
 def _ce_value(logits, targets):
@@ -854,7 +1168,10 @@ def lm_loss(params, tokens, cfg: TransformerConfig,
     slice of its vocabulary), was run both ways on the v5e (PR 30, one
     seed, median of 10 steps of 1 x 8192): ``_ce`` 0.42367 s a step,
     ``_ce_value`` 0.42753 s, so the vjp wins there too, by 0.9%, and the
-    crossover stands at 12,288 (ROADMAP Design 2)."""
+    crossover stands at 12,288 (ROADMAP Design 2).  A 3584 x 16,384 head
+    (one chip's eighth of a 131,072-id vocabulary) is above it and takes
+    ``_ce``; where a prediction module (``mtp_layers``) takes a second
+    cross-entropy from the same head, that one takes the same lowering."""
     return _loss_and_routes(params, tokens, cfg, mesh)[0]
 
 
@@ -863,14 +1180,86 @@ def _loss_and_routes(params, tokens, cfg: TransformerConfig,
     """``(lm_loss, routes)``: ``routes`` is the routed layers' counted
     routes (``expert_load``'s array) where the configuration holds a share
     of the experts (``cfg.counts_routes``), else ``None``."""
-    logits, aux, load = _forward(params, tokens, cfg, mesh)
+    loss, (routes, _) = _loss_routes_loads(params, tokens, cfg, mesh)
+    return loss, routes
+
+
+def _loss_routes_loads(params, tokens, cfg: TransformerConfig,
+                       mesh: Optional[Mesh] = None):
+    """``(lm_loss, (routes, loads))``: ``_loss_and_routes`` and, where the
+    step moves a router bias by rule (``cfg.rule_bias``), every expert's
+    counted routes ``[routed layers (+ the prediction module's), E]``, else
+    ``None``."""
+    ce, ce_mtp, aux, load = _ce_parts(params, tokens, cfg, mesh)
+    if ce_mtp is not None:
+        ce = ce + cfg.mtp_loss_coef * ce_mtp
+    return (ce + aux if cfg.num_experts else ce,
+            (_routes(cfg, load) if cfg.counts_routes else None,
+             load if cfg.rule_bias else None))
+
+
+def _ce_parts(params, tokens, cfg: TransformerConfig,
+              mesh: Optional[Mesh] = None):
+    """``(next-token cross-entropy, the prediction module's or None,
+    weighted auxiliary loss, load)``."""
+    logits, aux, load, mtp_logits = _forward(params, tokens, cfg, mesh)
     # Crossover measured between 8192 (big loss at dim 512) and 12,544 (a
     # small win at dim 3072).
     ce_fn = _ce if cfg.vocab_size >= 12288 else _ce_value
     with jax.named_scope("loss"):
         ce = ce_fn(logits[:, :-1], tokens[:, 1:])
-    return (ce + aux if cfg.num_experts else ce,
-            load if cfg.counts_routes else None)
+    ce_mtp = None
+    if mtp_logits is not None:       # position t predicts token t + 2
+        with jax.named_scope("mtp"), jax.named_scope("loss"):
+            ce_mtp = ce_fn(mtp_logits[:, :-2], tokens[:, 2:])
+    return ce, ce_mtp, aux, load
+
+
+def _ruled(path) -> bool:
+    """Whether the leaf at ``path`` is one a rule moves and no updater."""
+    return getattr(path[-1], "key", None) == "router_bias"
+
+
+def _bias_rule(cfg: TransformerConfig, params, loads):
+    """``params`` with every ``router_bias`` moved by the rule ``b_e +=
+    router_bias_rate * sign(mean(load) - load_e)``, ``loads [routed layers
+    (+ the prediction module's), E]`` the routes this step counted, in layer
+    order.  The deployment sums the load over the chips that share the
+    data; here it is this program's tokens."""
+    lay = cfg.layout
+    with jax.named_scope("update"):
+        loads = loads.astype(jnp.float32)
+        move = cfg.router_bias_rate * jnp.sign(
+            jnp.mean(loads, axis=1, keepdims=True) - loads)
+
+        def moved(lyr, rows):
+            return {**lyr, "router_bias": lyr["router_bias"] + move[rows]}
+
+        rows = iter(range(loads.shape[0]))
+        row_of = [next(rows) if k.ffn == SPARSE else None for k in lay.kinds]
+        at = lambda i, lyr: (lyr if row_of[i] is None
+                             else moved(lyr, row_of[i]))
+        layers = params["layers"]
+        if not cfg.scan_layers:
+            layers = [at(i, lyr) for i, lyr in enumerate(layers)]
+        else:
+            lead, period, trail = _grouped(cfg, layers)
+            n_lead, p = len(lay.lead), len(lay.period)
+            period = [
+                slot if lay.period[s].ffn != SPARSE else moved(
+                    slot, np.asarray([row_of[n_lead + q * p + s]
+                                      for q in range(lay.n_periods)]))
+                for s, slot in enumerate(period)]
+            layers = (period[0] if lay.uniform else {
+                "lead": [at(i, lyr) for i, lyr in enumerate(lead)],
+                "period": period,
+                "trail": [at(n_lead + p * lay.n_periods + i, lyr)
+                          for i, lyr in enumerate(trail)]})
+        params = {**params, "layers": layers}
+        if cfg.mtp_layers and lay.kinds[-1].ffn == SPARSE:
+            params["mtp"] = {**params["mtp"],
+                             "layer": moved(params["mtp"]["layer"], -1)}
+    return params
 
 
 class TransformerTrainer:
@@ -905,17 +1294,22 @@ class TransformerTrainer:
         self.routes = None
         self._steps_dispatched = 0     # the ``step`` of mv.trainer.dispatch
         self._eval = None
+        self._eval_parts = None
+        self._balance = None
         self._offload = None  # (bridge, leaf shapes/shardings) — see below
 
     def _apply_updates(self, params, state, grads):
         """One updater application over the whole param pytree."""
         updater, opt = self.updater, self.option
-        flat_p, tree = jax.tree_util.tree_flatten(params)
+        with_path, tree = jax.tree_util.tree_flatten_with_path(params)
+        flat_p = [p for _, p in with_path]
+        # A leaf a rule moves (``_bias_rule``) is not the updater's.
+        ruled = [_ruled(path) for path, _ in with_path]
         flat_s = tree.flatten_up_to(state)
         flat_g = tree.flatten_up_to(grads)
         with jax.named_scope("update"):
-            out = [updater.apply_dense(p, s, g, opt)
-                   for p, s, g in zip(flat_p, flat_s, flat_g)]
+            out = [(p, s) if r else updater.apply_dense(p, s, g, opt)
+                   for p, s, g, r in zip(flat_p, flat_s, flat_g, ruled)]
         params = jax.tree_util.tree_unflatten(tree, [p for p, _ in out])
         state = jax.tree_util.tree_unflatten(tree, [s for _, s in out])
         return params, state
@@ -947,11 +1341,11 @@ class TransformerTrainer:
                 "configs (batch-nonlinear aux loss); run MoE at full batch")
 
         def step(params, state, tokens):
-            routes = None
+            routes = loads = None
             if accum == 1:
-                (loss, routes), grads = jax.value_and_grad(
-                    _loss_and_routes, has_aux=True)(params, tokens, cfg,
-                                                    mesh)
+                (loss, (routes, loads)), grads = jax.value_and_grad(
+                    _loss_routes_loads, has_aux=True)(params, tokens, cfg,
+                                                      mesh)
             else:
                 B, T = tokens.shape
                 if B % accum:
@@ -978,9 +1372,39 @@ class TransformerTrainer:
                     lambda g: (g / accum), g_sum)
                 loss = jnp.mean(losses)
             params, state = self._apply_updates(params, state, grads)
+            if loads is not None:
+                params = _bias_rule(cfg, params, loads)
             return params, state, loss, routes
 
         return step
+
+    def balance_router_bias(self, tokens, steps: int) -> None:
+        """``steps`` applications of the bias rule alone on ``tokens``
+        [B, T]: a forward pass counts every routed layer's load and
+        ``_bias_rule`` moves the biases; no weight moves.  With the weights
+        still, the rule walks the loads toward their mean at its own speed
+        (``router_bias_rate`` a pass), which in training it does against a
+        router that keeps learning."""
+        if not self.cfg.rule_bias:
+            raise ValueError("balance_router_bias: the configuration has no "
+                             "router bias (router_scoring='sigmoid')")
+        if self._balance is None:
+            cfg, mesh = self.cfg, self.mesh
+            self._balance = jax.jit(
+                lambda p, t: _bias_rule(cfg, p,
+                                        _ce_parts(p, t, cfg, mesh)[3]),
+                donate_argnums=(0,))
+        tokens = jnp.asarray(tokens, jnp.int32)
+        for _ in range(steps):
+            self.params = self._balance(self.params, tokens)
+
+    def router_bias_absmax(self) -> float:
+        """The largest ``|router_bias|`` of any routed layer (0.0 where the
+        configuration has none): how far the rule has moved the choice."""
+        with_path, _ = jax.tree_util.tree_flatten_with_path(self.params)
+        found = [jnp.max(jnp.abs(leaf)) for path, leaf in with_path
+                 if _ruled(path)]
+        return float(max(found)) if found else 0.0
 
     # ------------------------------------------------------ state offload
     def offload_state(self, bridge) -> None:
@@ -1127,6 +1551,17 @@ class TransformerTrainer:
                 lambda p, t: lm_loss(p, t, cfg, mesh))
         return float(self._eval(self.params,
                                 jnp.asarray(tokens, jnp.int32)))
+
+    def loss_parts(self, tokens) -> Tuple[float, Optional[float]]:
+        """``(next-token cross-entropy, the prediction module's or None)``
+        of ``tokens``, unweighted: the two terms ``loss`` sums."""
+        if self._eval_parts is None:
+            cfg, mesh = self.cfg, self.mesh
+            self._eval_parts = jax.jit(
+                lambda p, t: _ce_parts(p, t, cfg, mesh)[:2])
+        ce, ce_mtp = self._eval_parts(self.params,
+                                      jnp.asarray(tokens, jnp.int32))
+        return float(ce), None if ce_mtp is None else float(ce_mtp)
 
     # ------------------------------------------------------------ checkpoint
     def save(self, uri: str) -> None:

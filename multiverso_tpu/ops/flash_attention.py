@@ -11,6 +11,17 @@ then run over the blocks of that band alone (``_band_steps``), mask the two
 partial diagonals, and are named ``flash_win_fwd``, ``flash_win_bwd_dq``,
 ``flash_win_bwd_dkv``.  With neither, the kernels are what they were.
 
+**Two widths** (``flash_attention_latent``, latent attention's decompressed
+form): a score is the sum of two products, ``q_n . k_n`` over the unrotated
+dims of a head and ``q_r . k_r`` over the rotated ones, and the values have
+a width of their own.  The rotated key part is ONE head that every query
+head reads through the index maps (nothing is repeated in HBM), and its
+gradient is summed over the query heads inside the dkv kernel, whose grid
+has an axis over them as the grouped one has.  The two products stay two
+(128 + 64 wide: no operand is padded to 256); causal only.  Kernels
+``flash_mla_fwd``, ``flash_mla_bwd_dq``, ``flash_mla_bwd_dkv``; a trace is
+counted in ``attention.latent_traced{qk=,v=}``.
+
 Causal/full attention with O(T) memory: the forward grid walks (batch·head,
 q-block, k-block) with the k dimension innermost; per q-block the kernel
 keeps the output accumulator and the streaming-softmax statistics (m, l)
@@ -53,7 +64,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "fit_block", "scale_cap_for_head_dim"]
+__all__ = ["flash_attention", "flash_attention_latent", "fit_block",
+           "scale_cap_for_head_dim"]
 
 
 def fit_block(block: int, t: int) -> int:
@@ -613,3 +625,315 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
     if return_lse:
         return o, lse.reshape(B, H, Tq)
     return o
+
+
+# ---------------------------------------------------------------- two widths
+# Latent attention's decompressed form: scores from an unrotated part a head
+# and a rotated part whose key is one head shared by every query head;
+# values of their own width.  Causal, no window.  Same streaming softmax,
+# same pre-scaled q (both parts), same three block classes as above.
+def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                    acc, m_scr, l_scr, *, block_q, block_k, num_k):
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc[:] = jnp.zeros_like(acc)
+        m_scr[:] = jnp.full_like(m_scr, _NEG)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    def _compute(masked):
+        v = v_ref[0]
+        s = _mla_scores(qn_ref[0], qr_ref[0], kn_ref[0], kr_ref[0])
+        if masked:
+            s = _causal_mask(s, qi, ki, block_q, block_k)
+        m_prev = m_scr[:, 0:1]
+        l_prev = l_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc[:] = acc[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:, 0:1] = m_new
+        l_scr[:, 0:1] = l_new
+
+    computed = ki * block_k <= qi * block_q + block_q - 1
+    full = qi * block_q >= ki * block_k + block_k - 1
+    pl.when(computed & full)(lambda: _compute(False))
+    pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+
+    @pl.when(ki == num_k - 1)
+    def _finalize():
+        l = jnp.maximum(l_scr[:, 0:1], 1e-30)
+        o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
+        lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+
+
+def _mla_scores(qn, qr, kn, kr):
+    """``q_n k_n^T + q_r k_r^T`` of one tile, float32 [Bq, Bk]."""
+    dims = (((1,), (1,)), ((), ()))
+    return (jax.lax.dot_general(qn, kn, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(qr, kr, dims,
+                                  preferred_element_type=jnp.float32))
+
+
+def _mla_p_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+              delta_ref, qi, ki, block_q, block_k, masked):
+    """The probabilities and ``ds = p (dp - delta)`` of one tile, rebuilt
+    from the saved row statistics; both float32 [Bq, Bk]."""
+    s = _mla_scores(qn_ref[0], qr_ref[0], kn_ref[0], kr_ref[0])
+    if masked:
+        s = _causal_mask(s, qi, ki, block_q, block_k)
+    p = jnp.exp(s - lse_ref[0][:, 0:1])
+    dp = jax.lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p, p * (dp - delta_ref[0][:, 0:1])
+
+
+def _mla_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dqn_ref, dqr_ref, dqn_acc, dqr_acc, *, scale,
+                   block_q, block_k, num_k):
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dqn_acc[:] = jnp.zeros_like(dqn_acc)
+        dqr_acc[:] = jnp.zeros_like(dqr_acc)
+
+    def _compute(masked):
+        _, ds = _mla_p_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, qi, ki, block_q, block_k,
+                          masked)
+        kn, kr = kn_ref[0], kr_ref[0]
+        ds = ds.astype(kn.dtype)
+        dims = (((1,), (0,)), ((), ()))
+        dqn_acc[:] += jax.lax.dot_general(
+            ds, kn, dims, preferred_element_type=jnp.float32)
+        dqr_acc[:] += jax.lax.dot_general(
+            ds, kr, dims, preferred_element_type=jnp.float32)
+
+    computed = ki * block_k <= qi * block_q + block_q - 1
+    full = qi * block_q >= ki * block_k + block_k - 1
+    pl.when(computed & full)(lambda: _compute(False))
+    pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+
+    @pl.when(ki == num_k - 1)
+    def _finalize():
+        dqn_ref[0] = (dqn_acc[:] * scale).astype(dqn_ref.dtype)
+        dqr_ref[0] = (dqr_acc[:] * scale).astype(dqr_ref.dtype)
+
+
+def _mla_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dkn_ref, dkr_ref, dv_ref, dkn_acc, dkr_acc,
+                    dv_acc, *, block_q, block_k, num_q, heads):
+    # Grid (batch, k block, query head, q block): a head's dk_n and dv are
+    # written when its q blocks are through; the shared rotated part's dk_r
+    # stays in its accumulator while all the heads pass.
+    ki = pl.program_id(1)
+    head = pl.program_id(2)
+    qi = pl.program_id(3)
+
+    @pl.when(qi == 0)
+    def _init():
+        dkn_acc[:] = jnp.zeros_like(dkn_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when((qi == 0) & (head == 0))
+    def _init_shared():
+        dkr_acc[:] = jnp.zeros_like(dkr_acc)
+
+    def _compute(masked):
+        p, ds = _mla_p_ds(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, qi, ki, block_q, block_k,
+                          masked)
+        qn, qr, do = qn_ref[0], qr_ref[0], do_ref[0]
+        dims = (((0,), (0,)), ((), ()))
+        dv_acc[:] += jax.lax.dot_general(
+            p.astype(do.dtype), do, dims, preferred_element_type=jnp.float32)
+        ds = ds.astype(qn.dtype)
+        dkn_acc[:] += jax.lax.dot_general(
+            ds, qn, dims, preferred_element_type=jnp.float32)
+        dkr_acc[:] += jax.lax.dot_general(
+            ds, qr, dims, preferred_element_type=jnp.float32)
+
+    computed = qi * block_q + block_q - 1 >= ki * block_k
+    full = qi * block_q >= ki * block_k + block_k - 1
+    pl.when(computed & full)(lambda: _compute(False))
+    pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+
+    @pl.when(qi == num_q - 1)
+    def _finalize():
+        dkn_ref[0] = dkn_acc[:].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((qi == num_q - 1) & (head == heads - 1))
+    def _finalize_shared():
+        dkr_ref[0] = dkr_acc[:].astype(dkr_ref.dtype)
+
+
+def _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
+                  interpret):
+    """qn/kn [bh, T, Dn], qr [bh, T, Dr], kr [b, T, Dr], v [bh, T, Dv] →
+    (o [bh, T, Dv], lse [bh, T] f32)."""
+    bh, T, Dn = qn.shape
+    Dr, Dv = qr.shape[-1], v.shape[-1]
+    num_q, num_k = T // block_q, T // block_k
+    qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
+    qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
+
+    def k_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, i, j: (b, j, 0))
+
+    o, lse = _named_call(
+        "flash_mla_fwd",
+        functools.partial(_mla_fwd_kernel, block_q=block_q, block_k=block_k,
+                          num_k=num_k),
+        grid=(bh, num_q, num_k),
+        in_specs=[q_spec(Dn), q_spec(Dr), k_spec(Dn),
+                  pl.BlockSpec((1, block_k, Dr),
+                               lambda b, i, j: (b // heads, j, 0)),
+                  k_spec(Dv)],
+        out_specs=[q_spec(Dv), q_spec(_LANES)],
+        out_shape=[jax.ShapeDtypeStruct((bh, T, Dv), v.dtype),
+                   jax.ShapeDtypeStruct((bh, T, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        interpret=interpret,
+    )(qn, qr, kn, kr, v)
+    return o, lse[:, :, 0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def _flash_mla(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
+               block_q_bwd, block_k_bwd, interpret):
+    return _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
+                         interpret)
+
+
+def _flash_mla_fwd(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
+                   block_q_bwd, block_k_bwd, interpret):
+    o, lse = _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
+                           interpret)
+    # the same remat seam as ``_flash_fwd``'s
+    o = checkpoint_name(o, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return (o, lse), (qn, qr, kn, kr, v, o, lse)
+
+
+def _flash_mla_bwd(scale, heads, block_q, block_k, block_q_bwd, block_k_bwd,
+                   interpret, res, cts):
+    block_q, block_k = block_q_bwd, block_k_bwd
+    qn, qr, kn, kr, v, o, lse = res
+    do, dlse = cts
+    bh, T, Dn = qn.shape
+    Dr, Dv = qr.shape[-1], v.shape[-1]
+    num_q, num_k = T // block_q, T // block_k
+    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+             - dlse.astype(jnp.float32))
+    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, T, _LANES))
+    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, T, _LANES))
+    qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
+    qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
+    operands = (qn, qr, kn, kr, v, do, lse_b, delta_b)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
+
+    def k_spec(width):
+        return pl.BlockSpec((1, block_k, width), lambda b, i, j: (b, j, 0))
+
+    dqn, dqr = _named_call(
+        "flash_mla_bwd_dq",
+        functools.partial(_mla_dq_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, num_k=num_k),
+        grid=(bh, num_q, num_k),
+        in_specs=[q_spec(Dn), q_spec(Dr), k_spec(Dn),
+                  pl.BlockSpec((1, block_k, Dr),
+                               lambda b, i, j: (b // heads, j, 0)),
+                  k_spec(Dv), q_spec(Dv), q_spec(_LANES), q_spec(_LANES)],
+        out_specs=[q_spec(Dn), q_spec(Dr)],
+        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, qr.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_q, Dn), jnp.float32),
+                        pltpu.VMEM((block_q, Dr), jnp.float32)],
+        interpret=interpret,
+    )(*operands)
+
+    # grid (batch, k block, query head, q block)
+    def of_q(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, i, h, j: (b * heads + h, j, 0))
+
+    def of_k(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, i, h, j: (b * heads + h, i, 0))
+
+    shared = pl.BlockSpec((1, block_k, Dr), lambda b, i, h, j: (b, i, 0))
+    dkn, dkr, dv = _named_call(
+        "flash_mla_bwd_dkv",
+        functools.partial(_mla_dkv_kernel, block_q=block_q, block_k=block_k,
+                          num_q=num_q, heads=heads),
+        grid=(bh // heads, num_k, heads, num_q),
+        in_specs=[of_q(Dn), of_q(Dr), of_k(Dn), shared, of_k(Dv), of_q(Dv),
+                  of_q(_LANES), of_q(_LANES)],
+        out_specs=[of_k(Dn), shared, of_k(Dv)],
+        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, Dn), jnp.float32),
+                        pltpu.VMEM((block_k, Dr), jnp.float32),
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
+        interpret=interpret,
+    )(*operands)
+    return dqn, dqr, dkn, dkr, dv
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def flash_attention_latent(q_nope, q_rope, k_nope, k_rope, v,
+                           scale: Optional[float] = None,
+                           block_q: int = 512, block_k: int = 1024,
+                           block_q_bwd: int = 1024, block_k_bwd: int = 1024,
+                           interpret: bool = False):
+    """Causal attention whose scores are ``q_nope . k_nope + q_rope .
+    k_rope``: q_nope/k_nope [B,H,T,Dn], q_rope [B,H,T,Dr], k_rope [B,1,T,Dr]
+    (one head for all H), v [B,H,T,Dv] → [B,H,T,Dv].  ``scale`` defaults to
+    ``(Dn + Dr) ** -0.5``.  Differentiable in all five (``jax.custom_vjp``);
+    ``k_rope``'s gradient comes back ``[B,1,T,Dr]``, summed over the heads
+    inside the dkv kernel.  Blocks shrink to divide ``T`` as
+    ``flash_attention``'s do."""
+    from .. import metrics
+
+    B, H, T, Dn = q_nope.shape
+    Dr, Dv = q_rope.shape[-1], v.shape[-1]
+    if (k_nope.shape != (B, H, T, Dn) or q_rope.shape != (B, H, T, Dr)
+            or k_rope.shape != (B, 1, T, Dr) or v.shape[:3] != (B, H, T)):
+        raise ValueError(
+            "flash_attention_latent wants q_nope/k_nope [B,H,T,Dn], q_rope "
+            f"[B,H,T,Dr], k_rope [B,1,T,Dr], v [B,H,T,Dv]; got "
+            f"{q_nope.shape}, {k_nope.shape}, {q_rope.shape}, "
+            f"{k_rope.shape}, {v.shape}")
+    if scale is None:
+        scale = (Dn + Dr) ** -0.5
+    blocks = [fit_block(b, T) for b in (block_q, block_k, block_q_bwd,
+                                        block_k_bwd)]
+    if min(blocks) < 8:
+        raise ValueError(f"no usable block size (>=8) divides T={T}")
+    metrics.counter("attention.latent_traced",
+                    {"qk": str(Dn + Dr), "v": str(Dv)}).inc()
+    o, _ = _flash_mla(q_nope.reshape(B * H, T, Dn),
+                      q_rope.reshape(B * H, T, Dr),
+                      k_nope.reshape(B * H, T, Dn), k_rope.reshape(B, T, Dr),
+                      v.reshape(B * H, T, Dv), float(scale), int(H),
+                      *(int(b) for b in blocks), bool(interpret))
+    return o.reshape(B, H, T, Dv)

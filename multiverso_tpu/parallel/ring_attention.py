@@ -36,7 +36,7 @@ _NEG = -1e30  # finite mask sentinel: exp(_NEG - m) underflows to exactly 0
 
 
 def _online_block(q, k_blk, v_blk, o, m, l, q_pos, k_pos, scale, causal,
-                  window=None):
+                  window=None, rope=None):
     """One streaming-softmax accumulation step over a K/V block.
 
     q [B,H,T,D]; k_blk/v_blk [B,KV,Tb,D], KV dividing H (query head j reads
@@ -47,12 +47,17 @@ def _online_block(q, k_blk, v_blk, o, m, l, q_pos, k_pos, scale, causal,
     The block matmul runs in the compute dtype (MXU); the softmax
     statistics and the output accumulate in float32 — bf16 accumulation
     across ring steps would compound rounding error.
+    ``rope = (q_rope [B,H,T,Dr], k_rope [B,1,Tb,Dr])`` adds a second product
+    to the scores, its key one head for all H (latent attention).
     """
     group = q.shape[1] // k_blk.shape[1]
     if group > 1:
         k_blk = jnp.repeat(k_blk, group, axis=1)
         v_blk = jnp.repeat(v_blk, group, axis=1)
     s = jnp.einsum("bhtd,bhsd->bhts", q, k_blk).astype(jnp.float32) * scale
+    if rope is not None:
+        s = s + jnp.einsum("bhtd,bsd->bhts", rope[0], rope[1][:, 0]
+                           ).astype(jnp.float32) * scale
     if causal:
         mask = q_pos[:, None] >= k_pos[None, :]                # [T,Tb]
         if window is not None:
@@ -129,10 +134,14 @@ def _flash_dispatch(tq: int, tk: int, head_dim: int,
 
 def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
                               q_offset: int = 0, k_offset: int = 0,
-                              window: Optional[int] = None):
+                              window: Optional[int] = None,
+                              q_rope=None, k_rope=None):
     """Single-device attention (the ring's degenerate case).  q [B,H,T,D];
     k/v [B,KV,T,D] with KV dividing H (grouped K/V heads); ``window`` keeps
-    the keys ``t - window < s <= t``.
+    the keys ``t - window < s <= t``.  ``q_rope [B,H,T,Dr]`` and ``k_rope
+    [B,1,T,Dr]`` (latent attention: causal, aligned, no window) add ``q_rope
+    . k_rope`` to every score, and ``v`` may then be another width than
+    ``q``; the kernel is ``flash_attention_latent``.
 
     Aligned shapes dispatch to the Pallas flash kernel
     (``ops/flash_attention.py``) — O(T) memory, causal-block skipping,
@@ -141,9 +150,25 @@ def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
     path, which XLA fuses.
     """
     B, H, T, D = q.shape
+    latent = q_rope is not None
+    if latent and (not causal or window is not None or q_offset or k_offset
+                   or T != k.shape[2]):
+        raise ValueError("a rotated score part (q_rope, k_rope) needs "
+                         "causal, aligned attention without a window")
     flash = None
     if q_offset == 0 and k_offset == 0 and T == k.shape[2]:
-        flash = _flash_dispatch(T, T, D, window)
+        # Latent attention keeps the D=128 blocks: no operand's tile is
+        # wider than 128 (the rotated parts are 64), and the v5e compiler
+        # takes the three kernels at 512x1024 / 1024x1024.
+        flash = _flash_dispatch(T, T, max(D, v.shape[-1]) if latent else D,
+                                window)
+    if flash is not None and latent:
+        from ..ops.flash_attention import flash_attention_latent
+
+        bq, bk, interpret = flash
+        return flash_attention_latent(q, q_rope, k, k_rope, v, scale=scale,
+                                      block_q=bq, block_k=bk,
+                                      interpret=interpret)
     if flash is not None:
         from ..ops import flash_attention
 
@@ -151,13 +176,13 @@ def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
         return flash_attention(q, k, v, scale=scale, causal=causal,
                                block_q=bq, block_k=bk, interpret=interpret,
                                window=window)
-    o = jnp.zeros(q.shape, jnp.float32)
+    o = jnp.zeros(q.shape[:3] + v.shape[-1:], jnp.float32)
     m = jnp.full((B, H, T, 1), _NEG, jnp.float32)
     l = jnp.zeros((B, H, T, 1), jnp.float32)
     q_pos = q_offset + jnp.arange(T)
     k_pos = k_offset + jnp.arange(k.shape[2])
     o, m, l = _online_block(q, k, v, o, m, l, q_pos, k_pos, scale, causal,
-                            window)
+                            window, (q_rope, k_rope) if latent else None)
     return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
 

@@ -69,6 +69,7 @@ def test_last_line_is_the_verdict_alone(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "check_device", lambda: dict(device))
     monkeypatch.setattr(chip_smoke, "phase_paper_surface", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_flash_reference", lambda: {})
+    monkeypatch.setattr(chip_smoke, "phase_flash_latent", lambda: {})
     monkeypatch.setattr(chip_smoke, "phase_flagship",
                         lambda *a, **k: {"losses": [2.0, 1.0]})
     monkeypatch.setattr(chip_smoke, "phase_moe", lambda *a, **k: {})
@@ -120,6 +121,11 @@ def test_phases_run_tiny_on_cpu_mesh(mv, monkeypatch):
     ref = chip_smoke.phase_flash_reference(batch=1, heads=2, seq=256,
                                            head_dim=64)
     assert max(ref["max_rel_err"].values()) < 3e-2, ref
+    latent = chip_smoke.phase_flash_latent(heads=2, seq=256, nope=32,
+                                           rope=16, v_dim=32)
+    assert sorted(latent["max_rel_err"]) == [
+        "dk_nope", "dk_rope", "dq_nope", "dq_rope", "dv", "o"]
+    assert max(latent["max_rel_err"].values()) < 3e-2, latent
 
     cfg = TransformerConfig(vocab_size=256, dim=64, n_layers=2, n_heads=2,
                             hidden=128, max_seq=256, scan_layers=True,
