@@ -37,24 +37,37 @@ experts it holds, as one of the chips that share a layer would be told
 top-k and the renormalisation run over all experts, ``w1``/``w3``/``w2``
 hold ``count`` experts, and the layer returns its own experts' part of the
 result.  In ``grouped`` the routes to experts held elsewhere are sorted
-behind the held groups, no grouped matmul touches their rows, and the two
-gathers by the inverse permutation mask them (whatever the compiler's
-grouped matmul leaves in rows beyond its groups, a NaN too, counts exactly
-zero, forward and backward).  The row buffer stays ``N*k`` rows: a token may
-send all its k routes here, so no smaller static buffer is exact.  ``load``
-is then ``[count + 1]``: the routes each held expert received, and last the
-routes that went elsewhere.  Holding all experts (``held=None``) traces the
-program as it was.  ``routed_scale`` multiplies the k weights after the
-renormalisation; ``shared_expert`` is the always-on SwiGLU beside the
-routed ones, under its own scope ``moe.shared``.
+behind the held groups, so the held routes are the first ``sum(sizes)`` rows
+of the order, and **the row buffers are as long as the routes that arrived**:
+``route_rungs`` lists the row counts a buffer may take from what a trace sees
+(``N*k`` and ``count / E``: twice the even share, and ``N*k``), and
+``jax.lax.switch`` runs, on the device, the branch of the smallest rung that
+holds this step's held routes (``_held_ffn``).  A branch below the top gathers, multiplies and weights its
+first ``C`` rows alone and adds them into their tokens in float32
+(``_ffn_first``: a scatter-add of ``C`` rows, cheaper than ``N*k`` row
+gathers while ``C`` is a small part of ``N*k``); the top branch is the layer
+that holds every expert with its two gathers masked (``_ffn_all``), because a
+batch may send every route here and then no smaller buffer is exact.  No
+route is dropped at any fill.  Whatever the compiler's grouped matmul leaves
+in a buffer's rows beyond its groups, a NaN too, counts exactly zero, forward
+and backward (masked, never weighted by zero).  ``_held_ffn`` carries its own
+backward: it keeps the layer's inputs and the rung, and builds the rows again
+inside the same rung's branch, so no branch's residuals lie beside another's.
+``load`` is then ``[count + 1]``: the routes each held expert received, and
+last the routes that went elsewhere.  Holding all experts (``held=None``)
+traces the program as it was, straight-line.  ``routed_scale`` multiplies the
+k weights after the renormalisation; ``shared_expert`` is the always-on
+SwiGLU beside the routed ones, under its own scope ``moe.shared``.
 
 Scopes for the chip trace (docs/observability.md, "Chip plane"):
 ``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``,
-``moe.shared``; the two backward rules open ``moe.dispatch`` /
+``moe.shared``; the backward rules open ``moe.dispatch`` /
 ``moe.combine`` themselves, so their rows are booked where the forward's
 are.  The schedule a step was traced with is counted in
 ``moe.traced{dispatch=}`` (``{dispatch=,scoring=sigmoid}`` where the router
-scores by sigmoid), a share in ``moe.held{held=,of=}``.
+scores by sigmoid), a share in ``moe.held{held=,of=}`` and its ladder in
+``moe.route_rows{rungs=,of=}`` (how many rungs, of how many routes); off the
+step ``TransformerTrainer.route_rows()`` says which rung each layer took.
 
 **Sigmoid scores and the correction bias** (``scoring="sigmoid"``): the
 scores are the logits' sigmoids over all experts, the k experts are the
@@ -78,7 +91,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import metrics
 
 __all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_pspecs",
-           "moe_shardings", "shared_expert"]
+           "moe_shardings", "route_rungs", "shared_expert"]
 
 # ``checkpoint_name``s of the three grouped-matmul outputs.  A grouped matmul
 # is not a ``dot_general``, so remat policy "dots" saves them by name
@@ -327,6 +340,168 @@ def _combine_bwd(dtype, res, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def route_rungs(routes: int, count: int, experts: int) -> tuple:
+    """The row counts a share's route buffers may take, rising, the last
+    ``routes`` (every route held here: the buffers of the layer that holds
+    all experts).  From what a trace sees and nothing else: ``routes = N*k``
+    and the share ``count / experts``, whose even part of the routes is
+    ``routes * count // experts``.  One rung below the last, twice the even
+    part: a step's held routes lie under it but for a rare batch, a buffer
+    that long costs a tenth more than one cut to the row, and every rung is
+    one more branch for the compiler to build, to load at start-up (1.3 s a
+    MB of program) and to hold against the memory limit (PERF.md section 6,
+    PR 33)."""
+    rung = -(-2 * max(routes * count // experts, 1) // 8) * 8    # sublanes
+    return (rung, routes) if rung < routes else (routes,)
+
+
+def _experts(rows, w1, w3, w2, sizes):
+    """The three grouped matmuls over ``rows`` in expert order, the groups
+    ``sizes`` long from row 0; rows beyond the groups are left to chance."""
+    gate = checkpoint_name(jax.lax.ragged_dot(rows, w1, sizes),
+                           GROUPED_SAVED[0])
+    up = checkpoint_name(jax.lax.ragged_dot(rows, w3, sizes),
+                         GROUPED_SAVED[1])
+    return checkpoint_name(
+        jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes),
+        GROUPED_SAVED[2])
+
+
+def _sum_to_tokens(rows, tok, N: int):
+    """``out[n]`` = the sum of the float32 ``rows [C, D]`` whose token
+    ``tok [C]`` is ``n``: a scatter-add of C rows, which under ~10,000 rows
+    costs less than a gather of ``N*k``.  In slices of 512 columns, which
+    XLA:TPU's scatter takes faster than whole wide rows (5,120 rows of 3,584:
+    1.19 ms for 2.22; of 3,072: 1.03 for 1.14; PERF.md section 6, PR 33)."""
+    return jnp.concatenate(
+        [jnp.zeros((N, part.shape[1]), jnp.float32).at[tok].add(part)
+         for part in jnp.split(rows, list(range(512, rows.shape[1], 512)),
+                               axis=1)], axis=1)
+
+
+@jax.custom_vjp
+def _dispatch_first(x, tok, live):
+    """``_dispatch`` for the first C rows of the expert order: row ``i`` is
+    token ``tok[i]``'s, and ``live [C]`` marks the rows of held routes, the
+    only ones whose cotangent counts (masked, summed in float32)."""
+    return x[tok]
+
+
+def _dispatch_first_fwd(x, tok, live):
+    return x[tok], (tok, live, x.shape[0])
+
+
+def _dispatch_first_bwd(res, d_rows):
+    tok, live, N = res
+    with jax.named_scope("moe.dispatch"):
+        d_x = _sum_to_tokens(
+            jnp.where(live[:, None], d_rows, 0).astype(jnp.float32), tok, N)
+    return d_x.astype(d_rows.dtype), None, None
+
+
+_dispatch_first.defvjp(_dispatch_first_fwd, _dispatch_first_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _combine_first(down, weight, tok, live, N, dtype):
+    """``_combine`` for the first C rows of the expert order: ``out[n]`` is
+    the float32 sum of ``weight[i] * down[i]`` over the live rows of token
+    ``n``, cast to ``dtype``.  A row that is not live is masked, not weighted
+    by zero, forward and backward."""
+    rows = down.astype(jnp.float32) * weight[:, None]
+    return _sum_to_tokens(jnp.where(live[:, None], rows, 0), tok,
+                          N).astype(dtype)
+
+
+def _combine_first_fwd(down, weight, tok, live, N, dtype):
+    return (_combine_first(down, weight, tok, live, N, dtype),
+            (down, weight, tok, live))
+
+
+def _combine_first_bwd(N, dtype, res, d_out):
+    down, weight, tok, live = res
+    with jax.named_scope("moe.combine"):
+        d_rows = d_out[tok].astype(jnp.float32)
+        d_down = jnp.where(live[:, None], d_rows * weight[:, None], 0)
+        d_weight = jnp.where(
+            live, jnp.sum(d_rows * down.astype(jnp.float32), axis=-1), 0)
+    return d_down.astype(down.dtype), d_weight, None, None
+
+
+_combine_first.defvjp(_combine_first_fwd, _combine_first_bwd)
+
+
+def _ffn_all(dtype, floats, ints):
+    """A share's routed part over all ``N*k`` rows: the top rung, and the
+    layer that holds every expert but for its masks.  ``floats = (x, top_p,
+    w1, w3, w2)``, ``ints = (order, inv, mine, sizes)``."""
+    (x, top_p, w1, w3, w2), (order, inv, mine, sizes) = floats, ints
+    with jax.named_scope("moe.dispatch"):
+        rows = _dispatch(x, order, inv, mine)
+    with jax.named_scope("moe.experts"):
+        down = _experts(rows, w1, w3, w2, sizes)
+    with jax.named_scope("moe.combine"):
+        return _combine(down, top_p, order, inv, dtype, mine)
+
+
+def _ffn_first(C: int, dtype, floats, ints):
+    """The same over the first ``C`` rows of the expert order, which hold
+    every held route when ``sum(sizes) <= C``: the gathers, the grouped
+    matmuls' rows and the elementwise passes are ``C`` long, and the rows
+    come back to their tokens by a sum over ``C`` rows, not by ``N*k``
+    gathers."""
+    (x, top_p, w1, w3, w2), (order, _, _, sizes) = floats, ints
+    with jax.named_scope("moe.dispatch"):
+        head = order[:C]
+        tok = head // top_p.shape[1]
+        live = jnp.arange(C) < jnp.sum(sizes)
+        rows = _dispatch_first(x, tok, live)
+    with jax.named_scope("moe.experts"):
+        down = _experts(rows, w1, w3, w2, sizes)
+    with jax.named_scope("moe.combine"):
+        weight = jnp.where(live, top_p.reshape(-1)[head], 0)
+        return _combine_first(down, weight, tok, live, x.shape[0], dtype)
+
+
+def _branches(rungs, dtype):
+    """One exact way to run a share's routed part a rung: the last over all
+    ``N*k`` rows, the others over their first ``C``."""
+    return [functools.partial(_ffn_first, C, dtype) for C in rungs[:-1]] + [
+        functools.partial(_ffn_all, dtype)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_ffn(rungs, dtype, floats, ints):
+    """A share's routed part, ``out [N, D]``, its row buffers as long as the
+    smallest of ``rungs`` that holds this step's held routes (chosen on the
+    device: ``jax.lax.switch`` over ``_branches``).  The backward keeps
+    ``floats``, ``ints`` and the rung alone and builds the rows again in the
+    branch of the same rung, so no branch's residuals lie beside another's."""
+    return _held_ffn_fwd(rungs, dtype, floats, ints)[0]
+
+
+def _held_ffn_fwd(rungs, dtype, floats, ints):
+    rung = jnp.sum(jnp.sum(ints[3]) > jnp.asarray(rungs[:-1], jnp.int32),
+                   dtype=jnp.int32)
+    out = jax.lax.switch(rung, _branches(rungs, dtype), floats, ints)
+    return out, (floats, ints, rung)
+
+
+def _held_ffn_bwd(rungs, dtype, res, d_out):
+    floats, ints, rung = res
+
+    def transposed(run):
+        return lambda floats, ints, d_out: jax.vjp(
+            lambda floats: run(floats, ints), floats)[1](d_out)[0]
+
+    return jax.lax.switch(rung, [transposed(run)
+                                 for run in _branches(rungs, dtype)],
+                          floats, ints, d_out), None
+
+
+_held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
+
+
 def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
                  aux, scoring="softmax", all_load=False):
     B, T, D = x.shape
@@ -352,8 +527,12 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
         bounds = jnp.searchsorted(sorted_key, jnp.arange(groups + 1),
                                   side="left")
         sizes = jnp.diff(bounds).astype(jnp.int32)           # [groups]
-        rows = _dispatch(x.reshape(N, D).astype(dt), order, inv,
-                         mine)                               # [N*k, D]
+        x2 = x.reshape(N, D).astype(dt)
+        # Holding every expert traces the ops in the order it always did
+        # (OLMoE's program is the parent's, byte for byte); a share's gather,
+        # matmuls and combine are ``_held_ffn``'s, further down.
+        if share is None:
+            rows = _dispatch(x2, order, inv)                 # [N*k, D]
     balance = z = jnp.float32(0)
     if aux:
         with jax.named_scope("moe.route"):
@@ -363,16 +542,20 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
     with jax.named_scope("moe.experts"):
         w1, w3, w2 = (checkpoint_name(params[k].astype(dt), "wcast")
                       for k in ("w1", "w3", "w2"))
-        gate = checkpoint_name(jax.lax.ragged_dot(rows, w1, sizes),
-                               GROUPED_SAVED[0])
-        up = checkpoint_name(jax.lax.ragged_dot(rows, w3, sizes),
-                             GROUPED_SAVED[1])
-        down = checkpoint_name(
-            jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes),
-            GROUPED_SAVED[2])                                     # [N*k, D]
-    with jax.named_scope("moe.combine"):
-        out = _combine(down, top_p.reshape(N, top_k), order, inv, x.dtype,
-                       mine)
+        if share is None:
+            down = _experts(rows, w1, w3, w2, sizes)         # [N*k, D]
+    top_p = top_p.reshape(N, top_k)
+    if share is None:
+        with jax.named_scope("moe.combine"):
+            out = _combine(down, top_p, order, inv, x.dtype)
+    else:
+        # The held routes are the first sum(sizes) rows of the order: the
+        # buffers are cut to the rung that holds them (this file's header).
+        rungs = route_rungs(N * top_k, groups, E)
+        metrics.counter("moe.route_rows", {"rungs": str(len(rungs)),
+                                           "of": str(N * top_k)}).inc()
+        out = _held_ffn(rungs, x.dtype, (x2, top_p, w1, w3, w2),
+                        (order, inv, mine, sizes))
     if all_load:
         with jax.named_scope("moe.route"):
             sizes = _count_load(top_idx, E)

@@ -41,7 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..updaters import AddOption, get_updater
 from .. import dashboard, metrics, tracing
 from .moe import (GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs,
-                  shared_expert)
+                  route_rungs, shared_expert)
 
 __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
            "stack_layer_params", "transformer_forward", "expert_load",
@@ -1397,6 +1397,22 @@ class TransformerTrainer:
         tokens = jnp.asarray(tokens, jnp.int32)
         for _ in range(steps):
             self.params = self._balance(self.params, tokens)
+
+    def route_rows(self) -> Optional[np.ndarray]:
+        """Of the last step, the rows each routed layer's route buffers held
+        over the layer's ``N*k`` routes (float ``[routed layers]``, from
+        ``routes``; ``None`` where the step counts none, or the schedule is
+        not ``grouped``): the rung of ``moe.route_rungs`` that held the
+        layer's held routes.  About the held share where the buffers followed
+        the routes, 1.0 where every route's rows were walked."""
+        cfg = self.cfg
+        if self.routes is None or cfg.moe_dispatch != "grouped":
+            return None
+        routes = np.asarray(self.routes, np.int64)
+        of, held = routes.sum(axis=1), routes[:, :-1].sum(axis=1)
+        rungs = np.asarray(route_rungs(int(of[0]), cfg.experts_held,
+                                       cfg.num_experts))
+        return rungs[np.searchsorted(rungs, held)] / of
 
     def router_bias_absmax(self) -> float:
         """The largest ``|router_bias|`` of any routed layer (0.0 where the

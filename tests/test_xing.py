@@ -557,7 +557,11 @@ def test_sinkhorn_is_doubly_stochastic_and_the_clamp_binds():
 # float32 loss on RandomState(5) tokens and the summed |gradient|, taken from
 # the tree before this change (commit a6fe6a6) on this machine's CPU backend;
 # the lowered train step's text was also compared once, byte for byte
-# (CHANGES.md, PR 32).
+# (CHANGES.md, PR 32).  "laguna" holds a share of the experts: its summed
+# |gradient| is PR 33's (commit a6fe6a6 read 0x1.7b64bc0000000p+10, one
+# float32 step away: a share's rows now come back to their tokens by a
+# float32 scatter-add over the held rows, which sums a token's rows in
+# another order); tree and loss are as they were.
 _KINDS = ["full_attention"] + ["sliding_attention"] * 3 + ["full_attention"]
 BEFORE = {
     "dense": ("8f356aa74a38b7395ded65881e4c6be016f9b1af4336f9a00707f0217b3bbd16",
@@ -573,7 +577,7 @@ BEFORE = {
                    aux_loss_coef=0.01, moe_dispatch="grouped",
                    scan_layers=True, remat=True, remat_policy="full")),
     "laguna": ("9f2b30bfbeadbc2f24c02474af7e768e67ded66ff0483b5c40f9c6105c179afd",
-               "0x1.31816c0000000p+2", "0x1.7b64bc0000000p+10",
+               "0x1.31816c0000000p+2", "0x1.7b64ba0000000p+10",
                dict(vocab_size=96, dim=32, n_layers=5, n_heads=4, head_dim=8,
                     n_kv_heads=2, hidden=16, dense_hidden=48,
                     shared_expert_hidden=16, max_seq=64, norm_eps=1e-6,
