@@ -18,6 +18,10 @@ Top-level API mirrors the reference Python binding
 
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()    # monitor ``mv::import``, booked below
+
 from . import (checkpoint, config, dashboard, fault, io, metrics, serve,
                tracing)
 from .core import (
@@ -87,3 +91,8 @@ __all__ = [
     "config", "dashboard", "Log", "checkpoint", "io", "fault",
     "metrics", "tracing", "BarrierTimeout",
 ]
+
+# What importing the package cost this process (docs/observability.md,
+# "Start-up"); jax's own import is in it only if nothing imported jax first.
+dashboard.get_monitor("mv::import").observe(
+    _time.perf_counter() - _IMPORT_T0)

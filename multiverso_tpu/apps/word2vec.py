@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import tracing
+from .. import dashboard, tracing
 from ..core import context as core_context
 from ..tables import MatrixTable
 from ..updaters import AddOption
@@ -71,7 +71,9 @@ class SkipGram:
         self.window = int(window)
         self.option = AddOption(learning_rate=learning_rate)
         rng = np.random.RandomState(seed)
-        init_in = ((rng.rand(vocab_size, dim) - 0.5) / dim).astype(np.float32)
+        with dashboard.monitor("SkipGram::init_draw"):
+            init_in = ((rng.rand(vocab_size, dim) - 0.5)
+                       / dim).astype(np.float32)
         self.table_in = MatrixTable(vocab_size, dim, init=init_in,
                                     updater_type=updater_type,
                                     name=f"{name}_in",

@@ -340,6 +340,7 @@ def shutdown(finalize: bool = True) -> None:
             os.makedirs(trace_dir, exist_ok=True)
             tracing.save(tracing.default_trace_path(trace_dir))
         dashboard.report(log=True)
+        compile_cache.report()
         if finalize:
             dashboard.reset()
             tracing.clear()

@@ -24,12 +24,12 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, Iterator
+from typing import Any, Dict, Iterator
 
 from . import metrics, tracing
 from .log import Log
 
-__all__ = ["Monitor", "monitor", "get_monitor", "report", "reset"]
+__all__ = ["Monitor", "monitor", "get_monitor", "report", "reset", "ended"]
 
 
 class Monitor:
@@ -43,18 +43,10 @@ class Monitor:
         self.name = name
         self._hist = metrics.histogram(name)
 
-    def begin(self) -> float:
-        return time.perf_counter()
-
-    def end(self, t0: float) -> None:
-        dt = time.perf_counter() - t0
-        self._hist.observe(dt)
-        if tracing.enabled():
-            tracing.record_span(self.name,
-                                int((time.time() - dt) * 1e6),
-                                int(dt * 1e6),
-                                trace_id=tracing.current_trace_id()
-                                or tracing.new_trace_id())
+    def observe(self, seconds: float) -> None:
+        """Book a section timed elsewhere (the package's import, a
+        duration JAX reports): ``monitor()`` without the body."""
+        self._hist.observe(seconds)
 
     @property
     def count(self) -> int:
@@ -96,6 +88,9 @@ class Monitor:
 
 _LOCK = threading.Lock()
 _MONITORS: Dict[str, Monitor] = {}
+# What the last reset() cleared: one lifecycle's monitors, replaced by
+# the next reset().
+_ENDED: Dict[str, Monitor] = {}
 
 
 def get_monitor(name: str) -> Monitor:
@@ -107,15 +102,16 @@ def get_monitor(name: str) -> Monitor:
 
 
 @contextmanager
-def monitor(name: str) -> Iterator[Monitor]:
+def monitor(name: str, **args: Any) -> Iterator[Monitor]:
     """``with dashboard.monitor("Worker::Get"):`` — the MONITOR macro.
 
     The section runs under a span context, so with tracing armed
     nested monitors (and native calls the caller stamps via
-    ``NativeRuntime.set_trace_id``) share its trace id.
+    ``NativeRuntime.set_trace_id``) share its trace id.  ``args`` go to
+    the span (``rows=``, ``steps=``); the timer keeps none.
     """
     m = get_monitor(name)
-    with tracing.span(name):
+    with tracing.span(name, **args):
         t0 = time.perf_counter()
         try:
             yield m
@@ -137,7 +133,21 @@ def report(log: bool = True) -> Dict[str, Monitor]:
 
 
 def reset() -> None:
+    """Drop every monitor from the table and the registry.  What they had
+    accumulated stays readable through :func:`ended` until the next
+    reset."""
+    global _ENDED
     with _LOCK:
         for name in _MONITORS:
             metrics.REGISTRY.remove(name)
+        _ENDED = dict(_MONITORS)
         _MONITORS.clear()
+
+
+def ended() -> Dict[str, Monitor]:
+    """The monitors of the lifecycle the last :func:`reset` closed:
+    ``shutdown()`` resets, and a job's start-up sections (the package's
+    import, a table's placement) are still a fact of the process after
+    it."""
+    with _LOCK:
+        return dict(_ENDED)

@@ -1279,14 +1279,20 @@ class TransformerTrainer:
         self.updater = get_updater(updater_type)
         self.option = option or AddOption(learning_rate=0.1)
         shardings = param_shardings(cfg, mesh)
-        host = init_params(cfg, seed)
-        self.params = jax.tree_util.tree_map(
-            lambda a, s: jax.device_put(a, s), host, shardings,
-            is_leaf=lambda x: isinstance(x, np.ndarray))
-        self.state = jax.tree_util.tree_map(
-            lambda p: tuple(jnp.zeros_like(p)
-                            for _ in range(self.updater.num_slots)),
-            self.params)
+        # Start-up sections (docs/observability.md, "Start-up"): the numpy
+        # draw on the host, then the leaves' transfer and the state's zeros
+        # to their completion.
+        with dashboard.monitor("Transformer::init_draw"):
+            host = init_params(cfg, seed)
+        with dashboard.monitor("Transformer::init_place"):
+            self.params = jax.tree_util.tree_map(
+                lambda a, s: jax.device_put(a, s), host, shardings,
+                is_leaf=lambda x: isinstance(x, np.ndarray))
+            self.state = jax.tree_util.tree_map(
+                lambda p: tuple(jnp.zeros_like(p)
+                                for _ in range(self.updater.num_slots)),
+                self.params)
+            jax.block_until_ready((self.params, self.state))
         self._step = None
         # The last step's counted routes, on the device (``cfg.
         # counts_routes``: int32 [routed layers, experts_held + 1], the held
@@ -1394,9 +1400,12 @@ class TransformerTrainer:
                 lambda p, t: _bias_rule(cfg, p,
                                         _ce_parts(p, t, cfg, mesh)[3]),
                 donate_argnums=(0,))
-        tokens = jnp.asarray(tokens, jnp.int32)
-        for _ in range(steps):
-            self.params = self._balance(self.params, tokens)
+        with dashboard.monitor("Transformer::balance_router_bias",
+                               steps=steps):
+            tokens = jnp.asarray(tokens, jnp.int32)
+            for _ in range(steps):
+                self.params = self._balance(self.params, tokens)
+            jax.block_until_ready(self.params)
 
     def route_rows(self) -> Optional[np.ndarray]:
         """Of the last step, the rows each routed layer's route buffers held

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import dashboard
 from ..parallel.sharding import shard_along, table_mesh
 from ..updaters import AddOption
 from .base import (Table, bucket_size as _bucket, host_fetch, host_put,
@@ -65,14 +66,22 @@ class MatrixTable(Table):
                              if self.num_cols >= LANES else self.num_cols)
 
         stored = (self._padded_rows, self._stored_cols)
-        host = np.zeros(stored, dtype=self.dtype)
-        if init is not None:
-            host[: self.num_rows, : self.num_cols] = np.asarray(
-                init, dtype=self.dtype)
-        self._data = host_put(host, self._sharding)
-        self._state = tuple(
-            host_put(np.zeros(stored, dtype=self.dtype), self._sharding)
-            for _ in range(self.updater.num_slots))
+        # A start-up section (docs/observability.md, "Start-up"): the host
+        # buffer at the stored width, then its transfer and the slots'
+        # enqueued.  It does not wait for them: a second table's transfer
+        # runs beside the first's (on a v5e two tables of 3,000,000 x 384
+        # were ready in 63 s for 71 s with a wait here; PERF.md, PR 34).
+        with dashboard.monitor("MatrixTable::init_place",
+                               rows=self._padded_rows,
+                               stored_cols=self._stored_cols):
+            host = np.zeros(stored, dtype=self.dtype)
+            if init is not None:
+                host[: self.num_rows, : self.num_cols] = np.asarray(
+                    init, dtype=self.dtype)
+            self._data = host_put(host, self._sharding)
+            self._state = tuple(
+                host_put(np.zeros(stored, dtype=self.dtype), self._sharding)
+                for _ in range(self.updater.num_slots))
         # BSP buffers, bucketed per AddOption so a flush applies each
         # option's aggregate with the right hyper-parameters.
         self._pending_dense: Dict[Optional[AddOption], np.ndarray] = {}

@@ -49,6 +49,7 @@ from .log import Log
 
 __all__ = [
     "SpanEvent", "enabled", "enable", "disable", "span", "record_span",
+    "record_ended",
     "current_trace_id", "set_trace_id", "new_trace_id", "events",
     "trace_ids",
     "clear", "to_chrome", "save", "merge_dir", "add_native_spans",
@@ -136,6 +137,15 @@ def record_span(name: str, ts_us: int, dur_us: int,
                    args=dict(args or {}))
     with _LOCK:
         _EVENTS.append(ev)
+
+
+def record_ended(name: str, dur_s: float,
+                 args: Optional[Dict[str, Any]] = None) -> None:
+    """Append a span that has just ended and whose length alone is known
+    (a duration JAX reports after the fact): it began ``dur_s`` ago."""
+    if _ENABLED:
+        record_span(name, int((time.time() - dur_s) * 1e6),
+                    int(dur_s * 1e6), args=args)
 
 
 @contextmanager

@@ -817,7 +817,10 @@ def test_benchmark_lists_the_cell_where_its_readers_are_right():
                          "kernel.flash_roofline", "kernel.flash_fwd_roofline",
                          "kernel.flash_dq_roofline",
                          "kernel.flash_dkv_roofline"}
-    assert [m["name"] for m in bench["per_layer"][-6:]] == [
+    # appended in this order; later PRs' entries follow them
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index("kernel.flash_mla_fwd_roofline")
+    assert names[first:first + 6] == [
         "kernel.flash_mla_fwd_roofline", "kernel.flash_mla_dq_roofline",
         "kernel.flash_mla_dkv_roofline", "model.attn_latent_ms_per_step",
         "model.hc_ms_per_step", "model.mtp_ms_per_step"]
