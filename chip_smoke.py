@@ -270,33 +270,55 @@ def phase_paper_surface() -> dict:
 
 # ------------------------------------------------------------ kernel numerics
 def phase_flash_reference(batch: int = 2, heads: int = 4, seq: int = 1024,
-                          head_dim: int = 128, tol: float = 3e-2) -> dict:
+                          head_dim: int = 128, kv_heads: int | None = None,
+                          window: int | None = None,
+                          tol: float = 3e-2) -> dict:
     """The attention the trainer dispatches to (on the chip: the compiled
-    forward, dq and dkv kernels) against a dense float32 reference on a
-    small input.  Errors are relative to the reference's largest entry;
-    bf16 rounding of p and of the outputs is ~2^-8 of that."""
+    forward kernel and the one backward call, ``flash_bwd`` or with a
+    ``window`` ``flash_win_bwd``) against a dense float32 reference computed
+    a head at a time, so that a cell's own shape fits (1 x 16 x 8192;
+    Laguna's 72 query over 8 K/V heads with window 512).  Errors are
+    relative to the reference's largest entry; bf16 rounding of p and of the
+    outputs is ~2^-8 of that."""
     import jax
     import jax.numpy as jnp
 
+    from multiverso_tpu import metrics
     from multiverso_tpu.parallel.ring_attention import (
         blockwise_attention_local)
 
     rng = np.random.RandomState(0)
-    shape = (batch, heads, seq, head_dim)
-    q, k, v, w = (jnp.asarray(0.5 * rng.randn(*shape), jnp.bfloat16)
-                  for _ in range(4))
+    kv_heads = kv_heads or heads
+    group = heads // kv_heads
+
+    def draw(h):
+        return jnp.asarray(0.5 * rng.randn(batch, h, seq, head_dim),
+                           jnp.bfloat16)
+
+    q, k, v, w = draw(heads), draw(kv_heads), draw(kv_heads), draw(heads)
     scale = head_dim ** -0.5
+    t = jnp.arange(seq)
+    visible = t[:, None] >= t[None, :]
+    if window is not None:
+        visible = visible & (t[None, :] > t[:, None] - window)
 
     def dense(q, k, v):
         hi = jax.lax.Precision.HIGHEST
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-        s = jnp.einsum("bhtd,bhsd->bhts", q, k, precision=hi) * scale
-        s = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), s, -jnp.inf)
-        return jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(s, -1), v,
-                          precision=hi)
+
+        @jax.checkpoint
+        def one(i):                  # a (batch, query head) pair: [T, D]
+            b, h = i // heads, i % heads
+            s = jnp.dot(q[b, h], k[b, h // group].T, precision=hi) * scale
+            return jnp.dot(jax.nn.softmax(jnp.where(visible, s, -jnp.inf),
+                                          -1), v[b, h // group],
+                           precision=hi)
+
+        return jax.lax.map(one, jnp.arange(batch * heads)).reshape(q.shape)
 
     def kernel(q, k, v):
-        return blockwise_attention_local(q, k, v, scale, causal=True)
+        return blockwise_attention_local(q, k, v, scale, causal=True,
+                                         window=window)
 
     def out_and_grads(attn):
         def loss(q, k, v):
@@ -306,10 +328,18 @@ def phase_flash_reference(batch: int = 2, heads: int = 4, seq: int = 1024,
             loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return (o,) + grads
 
-    before = jnp_traces()
+    def bwd_traces():
+        return [metrics.counter("attention.bwd_traced", {"path": p}).value
+                for p in ("fused", "split")]
+
+    before, bwd_before = jnp_traces(), bwd_traces()
     got_all = out_and_grads(kernel)
     require(jnp_traces() == before,
             "the dispatcher sent the reference check to the jnp body")
+    fused, split = (a - b for a, b in zip(bwd_traces(), bwd_before))
+    require(fused == 1 and split == 0,
+            f"the backward of {(batch, heads, seq, head_dim)} was traced "
+            f"fused {fused} and split {split} times, not once fused")
     errs = {}
     for name, got, want in zip(("o", "dq", "dk", "dv"),
                                got_all, out_and_grads(dense)):
@@ -321,7 +351,8 @@ def phase_flash_reference(batch: int = 2, heads: int = 4, seq: int = 1024,
         require(errs[name] <= tol,
                 f"flash {name} differs from the dense f32 reference by "
                 f"{errs[name]:.4f} of its largest entry (tol {tol})")
-    return {"shape": list(shape), "max_rel_err": errs}
+    return {"shape": [batch, heads, kv_heads, seq, head_dim],
+            "window": window, "max_rel_err": errs}
 
 
 def phase_flash_latent(heads: int = 32, seq: int = 8192, nope: int = 128,
@@ -402,7 +433,8 @@ def held_kernels(text: str, cfg, batch: int, seq: int, mesh_shape) -> dict:
     """Which attention body a lowered step holds, read from its text.
 
     With ``remat_policy="dots"`` saving the kernel's (o, lse), the scanned
-    layer's grad holds exactly the forward, dq and dkv Mosaic kernels.
+    layer's grad holds exactly two Mosaic kernels: the forward and the one
+    backward call (``flash_bwd``: dq, dk and dv together).
     The jnp body's mark is a [batch, heads, T, T] score tensor, at global
     or per-shard sizes (a ring piece is T/sp or T/2sp long)."""
     dp, sp, tp = (int(mesh_shape.get(a, 1)) for a in ("dp", "sp", "tp"))
@@ -419,13 +451,14 @@ def held_kernels(text: str, cfg, batch: int, seq: int, mesh_shape) -> dict:
 
 
 def phase_flagship(cfg, batch: int, seq: int, mesh, steps: int = 4,
-                   kernels_per_step: int = 3) -> dict:
+                   kernels_per_step: int = 2) -> dict:
     """``steps`` train steps of ``cfg`` on one repeated batch over ``mesh``.
 
     Returns the losses, the compile and per-step seconds, the per-device
     memory the backend reports, and what the lowered step holds.
     ``kernels_per_step``: Mosaic custom calls expected in the lowered step
-    (forward, dq, dkv of the scanned layer; a ring over sp holds more)."""
+    (the forward and the backward of the scanned layer; a ring over sp holds
+    more)."""
     import jax
 
     from multiverso_tpu.models import TransformerTrainer
@@ -452,7 +485,7 @@ def phase_flagship(cfg, batch: int, seq: int, mesh, steps: int = 4,
     held["jnp_traces"] = jnp_traces() - jnp_before
     require(held["tpu_custom_call"] == kernels_per_step,
             f"lowered step holds {held['tpu_custom_call']} Mosaic custom "
-            f"calls, expected {kernels_per_step} (fwd, dq, dkv)")
+            f"calls, expected {kernels_per_step} (fwd and bwd a piece)")
     require(held["jnp_traces"] == 0 and not held["score_tensors"],
             f"lowered step holds the O(T^2) jnp attention body: {held}")
 
@@ -501,7 +534,7 @@ def phase_flagship(cfg, batch: int, seq: int, mesh, steps: int = 4,
 
 
 def phase_moe(cfg, batch: int, seq: int, mesh, steps: int = 3,
-              kernels_per_step: int = 3) -> dict:
+              kernels_per_step: int = 2) -> dict:
     """The grouped expert schedule through ``TransformerTrainer``: steps
     that fall, a step-0 loss equal to the ``dense`` schedule's (every
     expert on every token: the oracle) within bf16 tolerance, and how
@@ -538,14 +571,14 @@ def phase_moe(cfg, batch: int, seq: int, mesh, steps: int = 3,
 
 
 def phase_multichip(cfg, batch: int, seq: int, ref_loss: float,
-                    kernels=(3, 15)) -> dict:
+                    kernels=(2, 10)) -> dict:
     """The flagship on ("dp",)=4 and on ("dp","sp","tp")=(1,2,2): same
     batch, step-0 loss equal to the one-chip value within bf16 tolerance.
 
     ``kernels``: Mosaic custom calls each layout's step holds.  (1,2,2)
     is a zigzag ring over sp=2: the self step runs three aligned pieces,
     the low and the high step one each; every piece is a forward kernel
-    plus dq and dkv in the grad — fifteen."""
+    plus one backward kernel in the grad: ten."""
     import jax
     from jax.sharding import Mesh
 
@@ -583,6 +616,14 @@ def main() -> int:
 
     result["flash_reference"] = phase_flash_reference()
     say(f"flash vs dense f32 reference: {result['flash_reference']}")
+    # the backward at two cells' own shapes: seq8k-b1's, Laguna's sliding
+    result["flash_reference_8k"] = phase_flash_reference(
+        batch=1, heads=16, seq=8192)
+    say(f"flash at 1 x 16 x 8192: {result['flash_reference_8k']}")
+    result["flash_reference_sliding"] = phase_flash_reference(
+        batch=1, heads=72, seq=8192, kv_heads=8, window=512)
+    say(f"flash at 72 / 8 heads, window 512: "
+        f"{result['flash_reference_sliding']}")
     result["flash_latent"] = phase_flash_latent()
     say(f"two-width flash vs dense f32 reference: {result['flash_latent']}")
 

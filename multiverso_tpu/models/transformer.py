@@ -932,7 +932,7 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             if cfg.remat_policy == "dots":
                 # Dot outputs PLUS the flash kernel's named (o, lse)
                 # residuals (ops/flash_attention.py `_flash_fwd`): with
-                # them saved, the backward calls the dq/dkv kernels
+                # them saved, the backward calls its own flash kernel
                 # directly instead of replaying the forward kernel —
                 # the recompute tax drops to the cheap tensor ops
                 # (norms, rope) for ~one extra o-sized buffer per layer.

@@ -3,13 +3,13 @@ with grouped K/V heads and a sliding window.
 
 **Grouped K/V heads**: ``k``/``v`` may hold fewer heads than ``q``; query
 head ``j`` reads K/V head ``j // group`` through the kernels' index maps
-(nothing is repeated in HBM), and the dkv kernel's grid has an axis over the
+(nothing is repeated in HBM), and the backward's grid has an axis over the
 group's query heads, so one K/V head's dk/dv stay in VMEM while all of them
 pass (no ``[H, T, D]`` dk/dv summed afterwards).  **A window** keeps key
-``s`` for query ``t`` iff ``t - window < s <= t``: the three kernels' grids
-then run over the blocks of that band alone (``_band_steps``), mask the two
-partial diagonals, and are named ``flash_win_fwd``, ``flash_win_bwd_dq``,
-``flash_win_bwd_dkv``.  With neither, the kernels are what they were.
+``s`` for query ``t`` iff ``t - window < s <= t``: the kernels' grids then
+run over the blocks of that band alone (``_band_steps``), mask the two
+partial diagonals, and are named ``flash_win_fwd`` and ``flash_win_bwd``
+(``flash_win_bwd_dq``, ``flash_win_bwd_dkv`` where the backward is split).
 
 **Two widths** (``flash_attention_latent``, latent attention's decompressed
 form): a score is the sum of two products, ``q_n . k_n`` over the unrotated
@@ -32,12 +32,30 @@ Fully-masked causal blocks are skipped with ``pl.when`` — the causal
 schedule does half the FLOPs, which the XLA dense path cannot do.
 
 Differentiation is a ``jax.custom_vjp``: the forward saves (q, k, v, o,
-lse) and the backward recomputes the probability blocks from lse in two
-Pallas kernels — one accumulating dq over k-blocks, one accumulating
-dk/dv over q-blocks — instead of materializing the T×T score matrix.
-Per-row stats (lse, delta) ride in lane-broadcast [*, T, 128] buffers, the
-TPU-safe layout for per-row scalars (the vector unit has 128 lanes; a
-[T]-shaped block cannot be tiled).
+lse) and the backward rebuilds the probability blocks from lse instead of
+materializing the T×T score matrix.  **One kernel a call** (``flash_bwd``,
+PR 35) builds each tile's ``s``, mask, ``p = exp(s - lse)``, ``dp`` and
+``ds = p (dp - delta)`` once and adds ``dv += p^T do``, ``dk += ds^T q``,
+``dq += ds k``: five matmuls a tile.  A tile adds along a row of tiles (dq)
+and along a column (dk, dv), so one of the two cannot be the block the inner
+grid axis stays on: dk and dv are that block, and dq is held in VMEM for
+the whole sequence of one query head, float32 ``[Tq, D]`` (4 MiB at 8192 x
+128); with grouped K/V heads dk and dv are held whole as well, for the K/V
+head while its query heads pass.  The tile is built transposed, ``[Bk,
+Bq]``, so that dv and dk are plain matmuls and the row statistics (lse,
+delta) arrive as rows ``[1, Bq]``.  Where those residents do not fit
+(``_fused_fits``: one rule, from the shapes alone; 32,768 tokens at D=128
+in bfloat16 is the longest that does, 10,922 with a group) the backward is
+the two kernels it was: ``flash_bwd_dq`` accumulating
+dq over k blocks and ``flash_bwd_dkv`` accumulating dk/dv over q blocks,
+which between them build every tile twice and issue seven matmuls; their
+per-row stats ride in lane-broadcast [*, T, 128] buffers.  Which ran is
+counted at trace time in ``attention.bwd_traced{path=fused|split}``.
+
+What bounds these kernels is the matmuls they issue, not the vector unit:
+on a v5e at 1024 x 1024 x 128 tiles the dq kernel reads 76% of the MXU's
+peak over its three, the dkv kernel 70% over its four and the fused one 89%
+over its five (PERF.md section 6, PR 35).
 
 The kernel also returns ``lse`` on request so sequence-parallel callers
 can combine normalized partial results across ring steps: ``lse =
@@ -96,15 +114,17 @@ _LANES = 128
 _WINDOW_BLOCK_CAP = 512
 
 
-def _causal_mask(s, qi, ki, block_q, block_k, window=None):
+def _causal_mask(s, qi, ki, block_q, block_k, window=None, q_axis=0):
     """Keep key s for query t iff ``s <= t`` and, with a window, ``t -
     window < s``.  A row of a band's first block may keep nothing: its
     ``m`` stays ``_NEG`` and what it accumulates is zeroed by ``corr`` when
-    its first real score arrives (the diagonal is always kept)."""
+    its first real score arrives (the diagonal is always kept).  The
+    queries run along ``q_axis`` of ``s`` (1: a transposed tile, ``[Bk,
+    Bq]``)."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
+        jnp.int32, s.shape, q_axis)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, s.shape, 1 - q_axis)
     visible = q_pos >= k_pos
     if window is not None:
         visible = visible & (k_pos > q_pos - window)
@@ -159,8 +179,7 @@ def _in_band(computed, full, qi, ki, block_q, block_k, window):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
                 causal, block_q, block_k, num_k, window=None):
     # q arrives PRE-SCALED (softmax scale folded into the [T, D] input —
-    # one multiply per q element instead of one per [Bq, Bk] score; the
-    # kernel is VPU-bound on exactly that elementwise tile, measured).
+    # one multiply per q element instead of one per [Bq, Bk] score).
     # ``num_k`` is the grid's extent: every k block, or the band's steps.
     qi = pl.program_id(1)
     step = pl.program_id(2)
@@ -342,6 +361,94 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, scale,
+                causal, block_q, block_k, num_q, num_k, window=None,
+                group=1, q_blocks=None):
+    # The whole backward of one tile: s, the mask, p, dp and ds are built
+    # ONCE and feed all three gradients (five matmuls where the dq and dkv
+    # kernels issue seven, one pass of exp where they make two).  Grid (K/V
+    # head, query head of its group, k block, q step).  A tile adds along a
+    # row of tiles (dq) and along a column (dk, dv), so dq is held for the
+    # WHOLE sequence of the query head, float32 [Tq, D], and a tile adds
+    # into its q block's rows.  dk and dv are the block the q steps stay on;
+    # with grouped K/V heads their k blocks come round once a query head, so
+    # they too are held whole, [Tk, D] each, while the group passes.
+    # ``num_q`` is the grid's extent: every q block, or the band's steps.
+    # The tile is built TRANSPOSED, [Bk, Bq]: dv += p^T do and dk += ds^T q
+    # are then plain matmuls, only dq contracts a leading axis, and lse and
+    # delta arrive as rows [1, Bq] (no lane-broadcast copies in HBM).
+    # q arrives PRE-SCALED as in the two kernels above.
+    g = pl.program_id(1)
+    ki = pl.program_id(2)
+    step = pl.program_id(3)
+    qi = step
+    if window is not None:
+        qi = _first_q(ki, block_q, block_k) + step
+    first, last = step == 0, step == num_q - 1
+    kv_rows = slice(None)
+    if group > 1:
+        first = first & (g == 0) & (ki == 0)
+        last = last & (g == group - 1) & (ki == num_k - 1)
+        kv_rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+
+    @pl.when((ki == 0) & (step == 0))
+    def _init_q():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first)
+    def _init_kv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _compute(masked):
+        q = q_ref[0]                                     # [Bq, D]
+        k = k_ref[0]                                     # [Bk, D]
+        v = v_ref[0]
+        do = do_ref[0]
+        nt = (((1,), (1,)), ((), ()))
+        st = jax.lax.dot_general(k, q, nt,
+                                 preferred_element_type=jnp.float32)
+        if masked:
+            st = _causal_mask(st, qi, ki, block_q, block_k, window,
+                              q_axis=1)
+        pt = jnp.exp(st - lse_ref[0])                    # [Bk, Bq] f32
+        dpt = jax.lax.dot_general(v, do, nt,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0])).astype(q.dtype)
+        dv_acc[kv_rows, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [Bk, D]
+        dk_acc[kv_rows, :] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[q_rows, :] += jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [Bq, D]
+
+    if causal:
+        computed = qi * block_q + block_q - 1 >= ki * block_k
+        full = qi * block_q >= ki * block_k + block_k - 1
+        if window is not None:
+            # a step past the sequence's last q block is no block at all
+            computed, full = _in_band(computed & (qi < q_blocks), full, qi,
+                                      ki, block_q, block_k, window)
+        pl.when(computed & full)(lambda: _compute(False))
+        pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+    else:
+        _compute(False)
+
+    @pl.when(last)
+    def _finalize_kv():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((ki == num_k - 1) & (step == num_q - 1))
+    def _finalize_q():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
+
+
 def _named_call(name, kernel, **kwargs):
     """``pl.pallas_call`` named ``name`` under a scope of the same name:
     the compiled program names the custom call after the innermost scope
@@ -378,8 +485,7 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
     num_k = Tk // block_k
     if window is not None:
         num_k, _ = _band_steps(num_q, num_k, block_q, block_k, window)
-    # Scale folded into q ([T, D] once) — the kernel tile is VPU-bound,
-    # so per-score multiplies are the scarce resource.
+    # Scale folded into q ([T, D] once), not into every [Bq, Bk] score.
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     kernel = functools.partial(_fwd_kernel, causal=causal,
                                block_q=block_q, block_k=block_k,
@@ -427,7 +533,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
                        window, group)
     # Remat seam: under jax.checkpoint the partial-eval inlines this fwd
     # rule, so naming the kernel outputs lets a policy SAVE them — the
-    # backward then feeds the dq/dkv kernels directly instead of
+    # backward then feeds its kernel directly instead of
     # replaying the forward kernel to regenerate its residuals (a ~12%
     # remat tax: pre-round figure, record removed in PR 21 — a claim to
     # re-measure).  models/transformer.py's
@@ -438,15 +544,126 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
     return (o, lse), (q, k, v, o, lse)
 
 
+# What the fused backward may hold in VMEM for as long as a head takes: dq,
+# float32 [Tq, D], and the output block it is cast into (double-buffered);
+# with grouped K/V heads dk and dv and their blocks as well.  32 MiB admits
+# 32,768 tokens at D=128 in bfloat16 (10,922 with groups; the cells hold 2
+# MiB at 2048 tokens, 8 at 8192, Laguna's grouped layers 24) and leaves the
+# tile's own intermediates (some 20 MiB at 1024 x 1024) inside the limit the
+# call asks of a v5e core's 128 MiB.
+_FUSED_RESIDENT_BYTES = 32 * 2 ** 20
+_FUSED_VMEM_LIMIT = 100 * 2 ** 20
+
+
+def _fused_fits(Tq, Tk, D, group, dtype, block_q):
+    """The ONE rule that places the backward's accumulators, from the
+    shapes alone: fused (dq resident for a whole query head; dk and dv too
+    for a K/V head with a group) when they fit ``_FUSED_RESIDENT_BYTES`` and
+    a q block's row statistics make whole lanes; the dq and dkv kernels
+    otherwise."""
+    itemsize = jnp.dtype(dtype).itemsize
+    resident = Tq * D * (4 + 2 * itemsize)
+    if group > 1:
+        resident += Tk * D * 2 * (4 + 2 * itemsize)
+    return (resident <= _FUSED_RESIDENT_BYTES
+            and (block_q % _LANES == 0 or block_q == Tq))
+
+
 def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
                interpret, window, group, res, cts):
-    # The dq/dkv kernels run their own (larger) blocks: each revisits
-    # the [Bq, Bk] tile space with heavier per-tile state than the
-    # forward, and the measured v5e sweet spot is 1024×1024 (~12% over
-    # the forward's 512×1024 — fewer tile passes beats smaller tiles).
+    # The backward runs its own (larger) blocks: 1024 x 1024 is the fused
+    # call's fastest at every cell's shape, 512 x 512 under a 512-key window
+    # (swept on a v5e: PERF.md section 6, PR 35).
+    from .. import metrics
+
     block_q, block_k = block_q_bwd, block_k_bwd
     q, k, v, o, lse = res
     do, dlse = cts
+    # Δ_i = Σ_d do·o − dlse: the lse cotangent enters exactly where the
+    # softmax normalizer does (∂lse/∂s_ij = p_ij), so it folds into delta.
+    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+             - dlse.astype(jnp.float32))                 # [bh, Tq]
+    # Same pre-scaled-q convention as the forward (see kernel docstrings:
+    # dq re-applies the factor at finalize; dk absorbs it via the q
+    # operand; dv never needs it).
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    fused = _fused_fits(q.shape[1], k.shape[1], q.shape[2], group, q.dtype,
+                        block_q)
+    metrics.counter("attention.bwd_traced",
+                    {"path": "fused" if fused else "split"}).inc()
+    return (_bwd_fused if fused else _bwd_split)(
+        q, k, v, do, lse, delta, scale, causal, block_q, block_k, interpret,
+        window, group)
+
+
+def _bwd_fused(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
+               interpret, window, group):
+    """dq, dk, dv from ONE call (``flash_bwd``; with a window
+    ``flash_win_bwd``); q pre-scaled, lse and delta [bh, Tq] float32."""
+    bh, Tq, D = q.shape
+    Tk = k.shape[1]
+    num_q = Tq // block_q
+    num_k = Tk // block_k
+    q_steps = num_q
+    if window is not None:
+        _, q_steps = _band_steps(num_q, num_k, block_q, block_k, window)
+
+    # grid (K/V head, query head of its group, k block, q step)
+    def q_block(i, j):
+        # a step outside the column's computed blocks keeps the nearest of
+        # them: it is skipped, and nothing is fetched for it
+        if window is not None:
+            return jnp.minimum(_first_q(i, block_q, block_k) + j,
+                               _last_q(i, block_q, block_k, window, num_q))
+        return jnp.maximum(j, _first_q(i, block_q, block_k)) if causal else j
+
+    def q_index(b, g, i, j):
+        return (b * group + g, q_block(i, j), 0)
+
+    def row_index(b, g, i, j):
+        return (b * group + g, 0, q_block(i, j))
+
+    def k_index(b, g, i, j):
+        return (b, i, 0)
+
+    q_spec = pl.BlockSpec((1, block_q, D), q_index)
+    k_spec = pl.BlockSpec((1, block_k, D), k_index)
+    row_spec = pl.BlockSpec((1, 1, block_q), row_index)
+    # dk and dv: the k block's own accumulator, or with a group the whole
+    # K/V head's
+    kv_out, kv_rows = k_spec, block_k
+    if group > 1:
+        kv_out = pl.BlockSpec((1, Tk, D), lambda b, g, i, j: (b, 0, 0))
+        kv_rows = Tk
+    return _named_call(
+        "flash_bwd" if window is None else "flash_win_bwd",
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, num_q=q_steps,
+                          num_k=num_k, window=window, group=group,
+                          q_blocks=num_q),
+        grid=(bh // group, group, num_k, q_steps),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[pl.BlockSpec((1, Tq, D),
+                                lambda b, g, i, j: (b * group + g, 0, 0)),
+                   kv_out, kv_out],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32),
+                        pltpu.VMEM((kv_rows, D), jnp.float32),
+                        pltpu.VMEM((kv_rows, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+        interpret=interpret,
+    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+
+
+def _bwd_split(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
+               interpret, window, group):
+    """dq and dk/dv from a kernel each (``flash_bwd_dq``, ``flash_bwd_dkv``;
+    ``flash_win_*`` with a window): what runs where the fused call's whole-
+    sequence accumulators do not fit beside the tile.  Same operands as
+    ``_bwd_fused``."""
     bh, Tq, D = q.shape
     Tk = k.shape[1]
     num_q = Tq // block_q
@@ -455,17 +672,8 @@ def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
     if window is not None:
         k_steps, q_steps = _band_steps(num_q, num_k, block_q, block_k,
                                        window)
-
-    # Δ_i = Σ_d do·o − dlse: the lse cotangent enters exactly where the
-    # softmax normalizer does (∂lse/∂s_ij = p_ij), so it folds into delta.
-    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-             - dlse.astype(jnp.float32))                 # [bh, Tq]
     lse_b = jnp.broadcast_to(lse[:, :, None], (bh, Tq, _LANES))
     delta_b = jnp.broadcast_to(delta[:, :, None], (bh, Tq, _LANES))
-    # Same pre-scaled-q convention as the forward (see kernel docstrings:
-    # dq re-applies the factor at finalize; dk absorbs it via the q
-    # operand; dv never needs it).
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, D),
@@ -551,11 +759,17 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
     ``kv_heads`` (``KV``; ``None`` = ``H``) divides ``H``: query head ``j``
     reads K/V head ``j // (H // KV)`` through the kernels' index maps, and
     dk/dv come back ``[B,KV,Tk,D]``, summed over a group's query heads
-    inside the dkv kernel.  ``window`` (with ``causal``) keeps key ``s`` for
-    query ``t`` iff ``t - window < s <= t``; the three kernels then walk the
-    band's blocks alone and are named ``flash_win_*``.  Blocks as wide as
-    the window are the default there (block_k and the backward's blocks are
-    capped at 512): a wider block is mostly outside a 512-key band.
+    inside the backward kernel.  ``window`` (with ``causal``) keeps key
+    ``s`` for query ``t`` iff ``t - window < s <= t``; the kernels then walk
+    the band's blocks alone and are named ``flash_win_*``.  Blocks as wide
+    as the window are the default there (block_k and the backward's blocks
+    are capped at 512): a wider block is mostly outside a 512-key band.
+
+    The backward is one kernel (``flash_bwd``: dq, dk and dv from tiles
+    built once) wherever one query head's dq (and with a group the K/V
+    head's dk and dv) fits in VMEM beside the tile, and the dq and dkv
+    kernels otherwise; the shapes decide (``_fused_fits``), no argument
+    does.
 
     ``causal=True`` requires Tq == Tk (the standard aligned causal mask);
     cross-length blocks (ring attention's low/high steps) use
@@ -563,12 +777,15 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
     including through the lse output, so ring-step combinations
     backpropagate correctly.
 
-    Block defaults are measured on v5e at D=128 (dispatch-free in-jit
-    timing): 512×1024 runs the causal fwd+bwd ~2.6× faster than the
-    128×128 blocks of rounds 1-3 (fewer [Bq, Bk] tile passes per element;
-    the kernel sits at the VPU/exp roofline, so tile-pass count is the
-    scarce resource).  VMEM at 512×1024×f32 intermediates ≈ 10 MB — at
-    head dims well beyond 128, pass smaller blocks.
+    Block defaults are measured on v5e at D=128.  Forward 512×1024: ~2.6×
+    the 128×128 blocks of rounds 1-3 (a pre-round figure).  Backward 1024×
+    1024, swept again for the fused kernel in PR 35 (PERF.md section 6): a
+    1024-tile takes it 7.7 us (the split pair 12.7), 512-wide blocks 8.1-9.2
+    us for the same area; under a 512-key window 512×512 is the fastest
+    (narrower blocks lose 16%, wider ones 40%).  VMEM at 1024×1024: four
+    float32 intermediates of 4 MiB; the fused call asks for its own limit
+    (``_FUSED_VMEM_LIMIT``).  At head dims well beyond 128, pass smaller
+    blocks.
     """
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
@@ -598,8 +815,8 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
     if block_q < 8 or block_k < 8:
         raise ValueError(f"no usable block size (>=8) divides "
                          f"Tq={Tq}, Tk={Tk}")
-    # Backward blocks default to the measured 1024x1024 sweet spot,
-    # VMEM-scaled for large head dims like the forward caps.  When the
+    # Backward blocks default to 1024x1024 (the fused kernel's sweep, PR
+    # 35), VMEM-scaled for large head dims like the forward caps.  When the
     # pow2 default cannot divide an odd T, fall back to the (validated)
     # forward blocks rather than failing a call that may never be
     # differentiated; only EXPLICIT bad bwd blocks raise.

@@ -1,6 +1,9 @@
 """Pallas flash-attention kernel tests (interpret mode on the CPU mesh;
 the same kernel compiles for real on TPU — see ops/flash_attention.py)."""
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +11,26 @@ import pytest
 
 from conftest import dense_attention_ref
 
+from multiverso_tpu import metrics
 from multiverso_tpu.ops import flash_attention
+
+fa = importlib.import_module("multiverso_tpu.ops.flash_attention")
+
+
+def _bwd_traced(path):
+    return metrics.counter("attention.bwd_traced", {"path": path}).value
+
+
+@pytest.fixture(params=["fused", "split"])
+def bwd_path(request, monkeypatch):
+    """Both placements of the backward's accumulators: the fused call the
+    shapes below take by the rule, and the dq and dkv kernels, which a
+    budget that nothing fits sends every shape to."""
+    if request.param == "split":
+        monkeypatch.setattr(fa, "_FUSED_RESIDENT_BYTES", -1)
+    before = _bwd_traced(request.param)
+    yield request.param
+    assert _bwd_traced(request.param) > before
 
 
 @pytest.mark.parametrize("B,H,T,D,bq,bk", [
@@ -73,7 +95,7 @@ def _dense_loss(q, k, v, causal):
 
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("T,bq,bk", [(128, 64, 64), (256, 128, 128)])
-def test_flash_grad_matches_dense(causal, T, bq, bk):
+def test_flash_grad_matches_dense(causal, T, bq, bk, bwd_path):
     rng = np.random.RandomState(2)
     B, H, D = 1, 2, 32
     q = jnp.asarray(rng.randn(B, H, T, D).astype(np.float32)) * 0.3
@@ -107,7 +129,7 @@ def test_flash_lse_matches_dense():
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5)
 
 
-def test_flash_lse_combination_rule():
+def test_flash_lse_combination_rule(bwd_path):
     """Two normalized partials combined via lse == attention over the
     concatenated keys — the identity the ring schedule relies on — and
     its gradient flows through the lse output's custom_vjp path."""
@@ -142,7 +164,7 @@ def test_flash_lse_combination_rule():
     np.testing.assert_allclose(np.asarray(gv), np.asarray(wv), atol=3e-4)
 
 
-def test_flash_grad_bf16():
+def test_flash_grad_bf16(bwd_path):
     """bf16 inputs differentiate without error and track the f32 grads."""
     rng = np.random.RandomState(5)
     q = jnp.asarray(rng.randn(1, 2, 128, 64).astype(np.float32)) * 0.3
@@ -159,7 +181,7 @@ def test_flash_grad_bf16():
                                atol=0.15, rtol=0.1)
 
 
-def test_forced_flash_dispatch_under_value_and_grad(monkeypatch):
+def test_forced_flash_dispatch_under_value_and_grad(monkeypatch, bwd_path):
     """CI coverage of the exact line that killed round-1's bench: the
     dispatcher sends the transformer's attention to the Pallas kernel and
     value_and_grad must work through it."""
@@ -204,3 +226,152 @@ def test_forced_flash_transformer_train_step(monkeypatch):
     l1 = tr.train_step(toks)
     assert np.isfinite(l0) and np.isfinite(l1)
     assert l1 < l0
+
+
+# ---------------------------------------------------------------------------
+# The fused backward (PR 35): one call builds a tile's s, p, dp and ds once
+# and gives dq, dk and dv.  Each case against dense float32 attention and
+# against the dq and dkv kernels called directly on the same residuals.
+# ---------------------------------------------------------------------------
+
+def _dense_out_lse(q, k, v, causal, window):
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhtd,bhsd->bhts", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        t, u = jnp.arange(q.shape[2])[:, None], jnp.arange(k.shape[2])[None]
+        visible = t >= u
+        if window is not None:
+            visible = visible & (u > t - window)
+        s = jnp.where(visible, s, -jnp.inf)
+    return (jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(s, -1), v),
+            jax.nn.logsumexp(s, -1))
+
+
+# id: (H, KV, Tq, Tk, causal, window, bwd blocks (None: the defaults), dtype,
+#      a cotangent on lse)
+FUSED_CASES = {
+    "causal": (2, 2, 256, 256, True, None, (128, 128), jnp.float32, False),
+    "full": (2, 2, 256, 256, False, None, (128, 64), jnp.float32, False),
+    "cross-length": (2, 2, 128, 384, False, None, (128, 128), jnp.float32,
+                     False),
+    "lse-cotangent": (2, 2, 128, 384, False, None, (128, 128), jnp.float32,
+                      True),
+    "lse-cotangent-causal": (2, 1, 256, 256, True, None, (128, 128),
+                             jnp.float32, True),
+    "group-2": (4, 2, 256, 256, True, None, (128, 64), jnp.float32, False),
+    "group-6": (6, 1, 256, 256, True, None, (128, 128), jnp.float32, False),
+    "window-below": (2, 2, 256, 256, True, 128, (128, 128), jnp.float32,
+                     False),
+    "window-equal": (2, 2, 256, 256, True, 256, (128, 128), jnp.float32,
+                     False),
+    "window-above": (2, 2, 256, 256, True, 512, (256, 128), jnp.float32,
+                     False),
+    "window-ragged": (4, 2, 256, 256, True, 100, (128, 64), jnp.float32,
+                      False),
+    "window-group-6": (6, 1, 512, 512, True, 72, (128, 128), jnp.float32,
+                       False),
+    "odd-length": (2, 2, 384, 384, True, None, None, jnp.float32, False),
+    "bf16": (2, 2, 256, 256, True, None, (128, 128), jnp.bfloat16, False),
+    "bf16-window-group": (4, 2, 256, 256, True, 72, (128, 128),
+                          jnp.bfloat16, True),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _fused_case(name):
+    """``(fused, split, dense)`` gradients ``(dq, dk, dv)`` of one case,
+    float32 numpy; computed once for the three tests that read it."""
+    H, KV, Tq, Tk, causal, window, blocks, dtype, lse_ct = FUSED_CASES[name]
+    rng = np.random.RandomState(len(name))
+    B, D = 2, 32
+    q = jnp.asarray(rng.randn(B, H, Tq, D), dtype)
+    k = jnp.asarray(rng.randn(B, KV, Tk, D), dtype)
+    v = jnp.asarray(rng.randn(B, KV, Tk, D), dtype)
+    w = jnp.asarray(rng.randn(B, H, Tq, D), jnp.float32)
+    wl = jnp.asarray(rng.randn(B, H, Tq) * float(lse_ct), jnp.float32)
+    bq, bk = blocks or (None, None)
+
+    def flash(q, k, v):
+        o, lse = flash_attention(q, k, v, causal=causal, window=window,
+                                 block_q=64, block_k=64, block_q_bwd=bq,
+                                 block_k_bwd=bk, interpret=True,
+                                 return_lse=True)
+        return jnp.sum(o.astype(jnp.float32) * w) + jnp.sum(lse * wl)
+
+    def dense(q, k, v):
+        o, lse = _dense_out_lse(q, k, v, causal, window)
+        return jnp.sum(o * w) + jnp.sum(lse * wl)
+
+    before = _bwd_traced("fused"), _bwd_traced("split")
+    fused = jax.grad(flash, (0, 1, 2))(q, k, v)
+    assert (_bwd_traced("fused"), _bwd_traced("split")) == (
+        before[0] + 1, before[1])
+
+    # the dq and dkv kernels, called directly on the forward's residuals
+    scale, group = D ** -0.5, H // KV
+    bq, bk = blocks or (fa.fit_block(1024, Tq), fa.fit_block(1024, Tk))
+    q3, k3, v3 = (x.reshape(-1, x.shape[2], D) for x in (q, k, v))
+    o, lse = fa._fwd_impl(q3, k3, v3, scale, causal, 64, 64, True, window,
+                          group)
+    do = w.reshape(-1, Tq, D).astype(dtype)
+    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+             - wl.reshape(-1, Tq))
+    split = fa._bwd_split(
+        (q3.astype(jnp.float32) * scale).astype(dtype), k3, v3, do, lse,
+        delta, scale, causal, bq, bk, True, window, group)
+    split = [g.reshape(x.shape) for g, x in zip(split, (q, k, v))]
+    want = jax.grad(dense, (0, 1, 2))(q, k, v)
+    return tuple([np.asarray(g, np.float32) for g in gs]
+                 for gs in (fused, split, want))
+
+
+@pytest.mark.parametrize("grad", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("name", list(FUSED_CASES))
+def test_fused_backward_against_dense_and_split(name, grad):
+    fused, split, want = (gs[("dq", "dk", "dv").index(grad)]
+                          for gs in _fused_case(name))
+    bf16 = FUSED_CASES[name][7] == jnp.bfloat16
+    assert fused.shape == want.shape == split.shape
+    peak = np.max(np.abs(want))
+    # against the reference: float32 to rounding, bfloat16 to its 2^-8
+    assert np.max(np.abs(fused - want)) <= (3e-2 if bf16 else 2e-5) * peak
+    # against the two kernels: the same tiles, masks, dtypes and sums
+    assert np.max(np.abs(fused - split)) <= (1e-2 if bf16 else 2e-6) * peak
+
+
+def test_backward_over_the_budget_takes_the_split_kernels():
+    """A query head whose dq (float32 [Tq, D], and its output block) passes
+    ``_FUSED_RESIDENT_BYTES`` runs the dq and dkv kernels, by the one rule
+    and with no argument; the counter says which ran."""
+    Tq, Tk, D = 32768, 128, 128
+    assert not fa._fused_fits(Tq, Tk, D, 1, jnp.float32, 1024)
+    # every cell's shape fits: the dense ones, Laguna's grouped layers
+    assert fa._fused_fits(2048, 2048, D, 1, jnp.bfloat16, 1024)
+    assert fa._fused_fits(8192, 8192, D, 1, jnp.bfloat16, 1024)
+    assert fa._fused_fits(8192, 8192, D, 6, jnp.bfloat16, 1024)
+    assert fa._fused_fits(8192, 8192, D, 9, jnp.bfloat16, 512)
+    # a group holds dk and dv for the whole K/V head as well
+    assert fa._fused_fits(16384, 16384, D, 1, jnp.bfloat16, 1024)
+    assert not fa._fused_fits(16384, 16384, D, 2, jnp.bfloat16, 1024)
+    assert not fa._fused_fits(65536, 65536, D, 1, jnp.bfloat16, 1024)
+    assert not fa._fused_fits(192, 8192, D, 1, jnp.bfloat16, 64)  # half lanes
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(1, 1, Tq, D), jnp.float32) * 0.3
+    k = jnp.asarray(rng.randn(1, 1, Tk, D), jnp.float32) * 0.3
+    v = jnp.asarray(rng.randn(1, 1, Tk, D), jnp.float32) * 0.3
+
+    def flash(q, k, v):
+        return jnp.sum(jnp.square(flash_attention(
+            q, k, v, causal=False, interpret=True)))
+
+    before = _bwd_traced("fused"), _bwd_traced("split")
+    got = jax.grad(flash, (0, 1, 2))(q, k, v)
+    assert (_bwd_traced("fused"), _bwd_traced("split")) == (
+        before[0], before[1] + 1)
+    text = str(jax.make_jaxpr(jax.grad(flash, (0, 1, 2)))(q, k, v))
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    want = jax.grad(_dense_loss, (0, 1, 2))(q, k, v, False)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)

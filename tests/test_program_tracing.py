@@ -248,22 +248,41 @@ def test_fused_sgns_step_holds_scope(sgns_text, scope):
 
 
 @pytest.fixture(scope="module")
-def flash_text():
-    from multiverso_tpu.ops.flash_attention import flash_attention
+def flash_texts():
+    """The compiled gradient's ``op_name``s on each path of the backward:
+    the one fused call the shapes take, and the dq and dkv kernels that run
+    where a head's whole-sequence accumulators pass the fused form's
+    budget."""
+    import importlib
 
+    fa = importlib.import_module("multiverso_tpu.ops.flash_attention")
     q = jnp.ones((1, 1, 128, 32), jnp.float32)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, interpret=True).sum()
+        return fa.flash_attention(q, k, v, interpret=True).sum()
 
-    return _op_names(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, q, q).compile().as_text())
+    def text():
+        return _op_names(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).compile().as_text())
+
+    texts = {"fused": text()}
+    budget = fa._FUSED_RESIDENT_BYTES
+    fa._FUSED_RESIDENT_BYTES = -1
+    try:
+        texts["split"] = text()
+    finally:
+        fa._FUSED_RESIDENT_BYTES = budget
+    return texts
 
 
-@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv"])
-def test_flash_path_holds_kernel_name(flash_text, kernel):
-    assert _holds(flash_text, kernel)
+@pytest.mark.parametrize("path,kernel", [
+    ("fused", "flash_fwd"), ("fused", "flash_bwd"),
+    ("split", "flash_bwd_dq"), ("split", "flash_bwd_dkv")])
+def test_flash_path_holds_kernel_name(flash_texts, path, kernel):
+    assert _holds(flash_texts[path], kernel)
+    if path == "fused":
+        assert not _holds(flash_texts[path], "flash_bwd_dq")
+        assert not _holds(flash_texts[path], "flash_bwd_dkv")
 
 
 # ------------------------------------------- (d) scopes are metadata only

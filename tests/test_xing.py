@@ -300,6 +300,45 @@ def test_flash_mla_kernels_compile_for_v5e_at_the_cells_shape(one_v5e):
         assert name in text
 
 
+@pytest.mark.parametrize("batch,heads,kv,seq,window", [
+    (4, 16, 16, 2048, None),      # ouro-2.6b-l16-ut1.seq2k-b4, a dp4 chip
+    (1, 16, 16, 8192, None),      # ouro-2.6b-l16-ut1.seq8k-b1
+    (2, 16, 16, 4096, None),      # olmoe-1b-7b-e64.zipf-seq4k-b2
+    (1, 48, 8, 8192, None),       # Laguna's full layers
+    (1, 72, 8, 8192, 512),        # Laguna's sliding layers
+], ids=["seq2k-b4", "seq8k-b1", "olmoe", "laguna-full", "laguna-sliding"])
+def test_fused_flash_backward_compiles_for_v5e_at_each_cells_shape(
+        one_v5e, batch, heads, kv, seq, window):
+    """Mosaic takes the one backward call (``flash_bwd``; ``flash_win_bwd``
+    with a window) at the shape of every cell that runs it, at the blocks
+    the defaults give and inside the VMEM limit the call asks for; the dq
+    and dkv kernels are not in the program.  Nothing runs: no measurement."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from multiverso_tpu.ops import flash_attention
+
+    def shaped(h):
+        return jax.ShapeDtypeStruct((batch, h, seq, 128), jnp.bfloat16,
+                                    sharding=one_v5e)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, window=window).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(grads).lower(shaped(heads), shaped(kv),
+                                    shaped(kv)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    name = "flash_bwd" if window is None else "flash_win_bwd"
+    assert f"{name}." in text
+    assert "bwd_dq" not in text and "bwd_dkv" not in text
+
+
 def test_flash_mla_names_counter_and_refusals():
     def draw(*shape):
         return jnp.ones(shape, jnp.float32)
