@@ -75,12 +75,20 @@ top of score + ``router_bias``, the weights are the chosen scores
 (renormalised, scaled) without the bias.  The bias is a leaf of the layer
 that gets no gradient; ``TransformerTrainer`` moves it by rule from the
 step's own ``all_load`` (``models/transformer.py:_bias_rule``).
+
+**A choice limited by groups** (``groups=(n_group, topk_group)``): the
+experts lie in ``n_group`` groups of adjacent ones, a group's score is the sum
+of its two highest (score + bias), and a token chooses its k experts among
+those of its ``topk_group`` best groups only (``_kept_groups``).  A share then
+sees routes only from the tokens that kept its group: ``moe_ffn`` hands that
+count back beside ``load`` (``kept``), and ``moe.traced`` carries the labels
+``groups=,kept=``.  ``(1, 1)`` is no limit and traces what it always did.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -138,34 +146,65 @@ def moe_shardings(mesh: Mesh) -> Dict[str, Any]:
     return {k: NamedSharding(mesh, s) for k, s in moe_pspecs(mesh).items()}
 
 
+def _kept_groups(chosen, groups):
+    """``[..., n_group]`` bool: the ``topk_group`` groups a token may choose
+    its experts from, ``groups = (n_group, topk_group)``.  The ``E`` scores
+    ``chosen`` lie in ``n_group`` groups of adjacent experts; a group's score
+    is the sum of its two highest, and the best ``topk_group`` stay
+    (arXiv:2412.19437, section 2.1.2's node-limited routing, as the
+    ``noaux_tc`` routers of its descendants write it)."""
+    n_group, topk_group = groups
+    E = chosen.shape[-1]
+    if E % n_group or E // n_group < 2 or not 0 < topk_group <= n_group:
+        raise ValueError(f"{E} experts do not lie in {n_group} groups of at "
+                         f"least two, {topk_group} of them kept")
+    grouped = chosen.reshape(*chosen.shape[:-1], n_group, E // n_group)
+    score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(score, topk_group)               # [..., kept]
+    return jnp.any(best[..., None] == jnp.arange(n_group), axis=-2)
+
+
 def _routing(params, x, top_k: int, norm_topk_prob: bool,
-             routed_scale: float = 1.0, scoring: str = "softmax"):
-    """The shared router, in float32: ``(probs, logits, top_p, top_idx)``
-    with ``top_p`` renormalised to sum to 1 over the k routes if asked, then
-    scaled by ``routed_scale``.  ``scoring="sigmoid"``: ``probs`` are the
-    logits' sigmoids, the k experts are the top of ``probs + router_bias``
-    (the layer's per-expert correction bias, which a rule moves and no
-    gradient does: arXiv:2412.19437, section 2.1.2) and ``top_p`` are the
-    chosen experts' ``probs``, the bias left out."""
+             routed_scale: float = 1.0, scoring: str = "softmax",
+             groups=(1, 1)):
+    """The shared router, in float32: ``(probs, logits, top_p, top_idx,
+    kept)`` with ``top_p`` renormalised to sum to 1 over the k routes if
+    asked, then scaled by ``routed_scale``.  ``scoring="sigmoid"``: ``probs``
+    are the logits' sigmoids, the k experts are the top of ``probs +
+    router_bias`` (the layer's per-expert correction bias, which a rule moves
+    and no gradient does: arXiv:2412.19437, section 2.1.2) and ``top_p`` are
+    the chosen experts' ``probs``, the bias left out.  ``groups = (n_group,
+    topk_group)`` with more than one group limits a token's choice to the
+    experts of its best groups (``_kept_groups``; the others' scores are
+    masked to ``-inf`` before the top-k) and ``kept [B, T, n_group]`` says
+    which those are; ``(1, 1)`` traces what no limit traces and ``kept`` is
+    ``None``."""
     logits = (x.astype(jnp.float32)
               @ params["router"].astype(jnp.float32))        # [B,T,E]
-    if scoring == "sigmoid":
-        probs = jax.nn.sigmoid(logits)
-        bias = jax.lax.stop_gradient(
-            params["router_bias"].astype(jnp.float32))
-        _, top_idx = jax.lax.top_k(probs + bias, top_k)
-        top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
-    elif scoring == "softmax":
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_idx = jax.lax.top_k(probs, top_k)         # [B,T,k]
-    else:
+    if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"unknown router scoring '{scoring}' "
                          "(expected softmax|sigmoid)")
+    kept = None
+    if scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        chosen = probs + jax.lax.stop_gradient(
+            params["router_bias"].astype(jnp.float32))
+    else:
+        chosen = probs = jax.nn.softmax(logits, axis=-1)
+    if groups[0] > 1:
+        kept = _kept_groups(jax.lax.stop_gradient(chosen), groups)
+        chosen = jnp.where(jnp.repeat(kept, chosen.shape[-1] // groups[0],
+                                      axis=-1), chosen, -jnp.inf)
+    if chosen is probs:
+        top_p, top_idx = jax.lax.top_k(probs, top_k)         # [B,T,k]
+    else:
+        _, top_idx = jax.lax.top_k(chosen, top_k)
+        top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
     if norm_topk_prob:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     if routed_scale != 1.0:
         top_p = top_p * routed_scale
-    return probs, logits, top_p, top_idx
+    return probs, logits, top_p, top_idx, kept
 
 
 def _aux_losses(probs, logits, load):
@@ -205,9 +244,11 @@ def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
             compute_dtype=None, dispatch: str = "dense",
             norm_topk_prob: bool = True, held=None,
             routed_scale: float = 1.0, aux: bool = True,
-            scoring: str = "softmax", all_load: bool = False
-            ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """x [B, T, dim] → ``(out [B, T, dim], balance, z, load)``: the
+            scoring: str = "softmax", all_load: bool = False,
+            groups=(1, 1)
+            ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
+                       Optional[jax.Array]]:
+    """x [B, T, dim] → ``(out [B, T, dim], balance, z, load, kept)``: the
     layer's output, its two auxiliary loss terms (``_aux_losses``, scalars,
     unweighted; zeros and nothing computed with ``aux=False``) and the
     routes each expert was sent (int32 ``[E]``; with a share of the experts,
@@ -217,7 +258,12 @@ def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
     rule reads).  ``dispatch`` picks the schedule and ``scoring`` the
     router's scores (``_routing``); both are taken at trace time and counted
     in ``moe.traced`` (``scoring`` only where it is not softmax, so that the
-    counter softmax configurations read keeps its labels)."""
+    counter softmax configurations read keeps its labels).  ``groups =
+    (n_group, topk_group)`` limits a token's choice to its best groups
+    (``_routing``; labels ``groups=,kept=`` on the counter); ``kept`` is then
+    the tokens whose kept groups include the group of the first expert held
+    here (of expert 0 where all are held; an int32 scalar), which is what the
+    share's routes now hang on, and ``None`` without a limit."""
     schedules = {"grouped": _moe_grouped, "dense": _moe_dense}
     if dispatch not in schedules:
         raise ValueError(f"unknown moe dispatch '{dispatch}' "
@@ -229,10 +275,12 @@ def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
     labels = {"dispatch": dispatch}
     if scoring != "softmax":
         labels["scoring"] = scoring
+    if groups[0] > 1:
+        labels.update(groups=str(groups[0]), kept=str(groups[1]))
     metrics.counter("moe.traced", labels).inc()
     return schedules[dispatch](params, x, top_k, compute_dtype or x.dtype,
                                norm_topk_prob, _share(params, held),
-                               routed_scale, aux, scoring, all_load)
+                               routed_scale, aux, scoring, all_load, groups)
 
 
 def shared_expert(params: Dict[str, Any], h: jax.Array, dt) -> jax.Array:
@@ -257,6 +305,15 @@ def _count_load(top_idx, E):
 def _share_load(load, routes: int):
     """``[count + 1]`` from the held experts' routes ``load [count]``."""
     return jnp.concatenate([load, (routes - jnp.sum(load))[None]])
+
+
+def _kept_tokens(kept, share, E: int):
+    """The tokens whose kept groups (``kept [B, T, n_group]``) include the
+    group of the first expert held here; ``None`` without a group limit."""
+    if kept is None:
+        return None
+    group = (share[0] if share is not None else 0) // (E // kept.shape[-1])
+    return jnp.sum(kept[..., group], dtype=jnp.int32)
 
 
 def _sort_routes(key):
@@ -503,14 +560,13 @@ _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
 def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
-                 aux, scoring="softmax", all_load=False):
+                 aux, scoring="softmax", all_load=False, groups=(1, 1)):
     B, T, D = x.shape
     N = B * T
     E = params["router"].shape[1]
     with jax.named_scope("moe.route"):
-        probs, logits, top_p, top_idx = _routing(params, x, top_k,
-                                                 norm_topk_prob,
-                                                 routed_scale, scoring)
+        probs, logits, top_p, top_idx, kept = _routing(
+            params, x, top_k, norm_topk_prob, routed_scale, scoring, groups)
     with jax.named_scope("moe.dispatch"):
         # Route r = n*k + j is token n's j-th expert.  Sorted by expert, a
         # group's rows are contiguous and its size is the distance between
@@ -561,15 +617,15 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
             sizes = _count_load(top_idx, E)
     elif share is not None:
         sizes = _share_load(sizes, N * top_k)
-    return out.reshape(B, T, D), balance, z, sizes
+    return (out.reshape(B, T, D), balance, z, sizes,
+            _kept_tokens(kept, share, E))
 
 
 def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
-               aux, scoring="softmax", all_load=False):
+               aux, scoring="softmax", all_load=False, groups=(1, 1)):
     E = params["router"].shape[1]
-    probs, logits, top_p, top_idx = _routing(params, x, top_k,
-                                             norm_topk_prob, routed_scale,
-                                             scoring)
+    probs, logits, top_p, top_idx, kept = _routing(
+        params, x, top_k, norm_topk_prob, routed_scale, scoring, groups)
     load = _all_load(top_idx, E)
     balance = z = jnp.float32(0)
     if aux:
@@ -593,4 +649,5 @@ def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
                             params["w2"].astype(dt))          # [B,E,T,d]
     out = jnp.einsum("betd,bte->btd", expert_out,
                      combine.astype(dt))
-    return out.astype(x.dtype), balance, z, load
+    return (out.astype(x.dtype), balance, z, load,
+            _kept_tokens(kept, share, E))

@@ -9,12 +9,15 @@ Design (not in the reference — see models/__init__):
   over ``sp`` with ring attention (``parallel/ring_attention.py``);
 - layers of different kinds (``LayerKind``: full or sliding-window
   attention with its own query heads over grouped K/V heads and its own
-  ``Rope`` recipe, or latent attention, whose queries and keys/values pass
-  a low-rank bottleneck and whose scores add a rotated part shared by the
-  heads to an unrotated one; a per-head output gate, a dense or routed FFN
-  that may hold a share of the experts beside a shared one), held as
+  ``Rope`` recipe, or latent attention, whose keys/values (and queries,
+  where ``q_lora_rank`` > 0) pass a low-rank bottleneck and whose scores add
+  a rotated part shared by the heads to an unrotated one, or linear
+  attention, which carries a recurrent state a head through a chunked scan
+  (``ops/kda.py``) and no softmax; a per-head output gate, a dense or routed
+  FFN that may hold a share of the experts beside a shared one), held as
   ``Layout`` writes them: a leading group, a period whose slots are stacked
-  over its repetitions and scanned, a trailing part.  Every layer alike is
+  over its repetitions and scanned (a long run of consecutive slots alike as
+  one inner scan: ``Layout.runs``), a trailing part.  Every layer alike is
   the one-slot case;
 - a residual of ``hc_mult`` streams mixed by hyper-connections
   (``_hc_gates``, ``_hc_read``, ``_hc_write``), and a multi-token-prediction
@@ -48,7 +51,7 @@ __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
            "TransformerTrainer"]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
-LATENT = "latent_attention"
+LATENT, LINEAR = "latent_attention", "linear_attention"
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -72,9 +75,9 @@ class Rope:
 
 class LayerKind(NamedTuple):
     """What one layer is made of: its attention (``full_attention`` |
-    ``sliding_attention`` | ``latent_attention``), its query heads, its FFN
-    (``dense`` | ``sparse``).  Window, rotary recipe and widths follow from these and
-    the configuration."""
+    ``sliding_attention`` | ``latent_attention`` | ``linear_attention``), its
+    query heads, its FFN (``dense`` | ``sparse``).  Window, rotary recipe and
+    widths follow from these and the configuration."""
     attn: str
     heads: int
     ffn: str
@@ -83,7 +86,11 @@ class LayerKind(NamedTuple):
 class Layout(NamedTuple):
     """The layers as a leading group, a period repeated ``n_periods`` times
     and a trailing part of one more period: ``lead + period * n_periods +
-    period[:n_trail]``.  Every layer alike is one slot and no lead."""
+    period[:n_trail]``.  Every layer alike is one slot and no lead.
+    ``runs`` (``_alike_runs``) cuts the period's slots into ``((first slot,
+    count), ...)``: a run of several consecutive slots alike is held stacked
+    ``[n_periods, count, ...]`` and traced as one body (an inner scan), every
+    other slot is a run of one, stacked ``[n_periods, ...]``."""
     lead: Tuple[LayerKind, ...]
     period: Tuple[LayerKind, ...]
     n_periods: int
@@ -92,6 +99,10 @@ class Layout(NamedTuple):
     @property
     def uniform(self) -> bool:
         return not self.lead and len(self.period) == 1
+
+    @property
+    def runs(self) -> Tuple[Tuple[int, int], ...]:
+        return _alike_runs(self.period)
 
     @property
     def kinds(self) -> Tuple[LayerKind, ...]:
@@ -117,6 +128,31 @@ def _layout(kinds: Tuple[LayerKind, ...], period: int = 0) -> Layout:
     _, n_lead, p = min(found)
     return Layout(kinds[:n_lead], kinds[n_lead:n_lead + p],
                   (L - n_lead) // p, (L - n_lead) % p)
+
+
+# Consecutive slots alike are one stacked run from this many on.  Shorter runs
+# stay slots of their own, which is the tree a period of three sliding layers
+# and a full one always was: saved checkpoints hold it, and the benchmark's
+# accepted readers index it a slot at a time
+# (``benchmarks/runners/lm_train_kinds.py:_leaf``, ``benchmarks/reference/
+# laguna_lm.py:layer``: files only a ``benchmark`` PR may edit).
+STACKED_RUN = 4
+
+
+@functools.lru_cache(maxsize=None)
+def _alike_runs(period: Tuple[LayerKind, ...]):
+    """The period's slots as ``((first slot, count), ...)``: ``STACKED_RUN``
+    or more consecutive slots alike are one run, every other slot a run of
+    one."""
+    runs, s = [], 0
+    while s < len(period):
+        n = 1
+        while s + n < len(period) and period[s + n] == period[s]:
+            n += 1
+        runs += [(s, n)] if n >= STACKED_RUN else [(s + j, 1)
+                                                   for j in range(n)]
+        s += n
+    return tuple(runs)
 
 
 @dataclass(frozen=True)
@@ -204,7 +240,8 @@ class TransformerConfig:
     rope_full: Optional[Rope] = None
     rope_sliding: Optional[Rope] = None
     # "per_head": the attention output of every query head is multiplied by
-    # sigmoid(h wg), one scalar a head and position, before ``wo``.
+    # sigmoid(h wg), one scalar a head and position, before ``wo`` (on
+    # latent and linear layers too).
     attn_gate: str = ""
     # Width of the ``dense`` layers' SwiGLU where it differs from the
     # experts' ``hidden`` (0 = ``hidden``).
@@ -224,9 +261,17 @@ class TransformerConfig:
     # step's own counted routes, and no gradient does.
     router_scoring: str = "softmax"
     router_bias_rate: float = 0.001
+    # The experts in ``n_group`` groups of adjacent ones, a token choosing
+    # among the experts of its ``topk_group`` best groups only
+    # (models/moe.py:_kept_groups); 1 / 1 = no limit.  With a share held the
+    # step then hands back, beside its counted routes, the tokens that kept
+    # the group of the first expert held here (``TransformerTrainer.kept``).
+    n_group: int = 1
+    topk_group: int = 1
     # ---- ``latent_attention`` layers (arXiv:2405.04434, decompressed form):
     # ``c_q = norm(h wq_a)`` [q_lora_rank], ``q = c_q wq_b`` [heads,
-    # qk_nope_dim + qk_rope_dim]; ``h wkv_a`` = ``c_kv`` [kv_lora_rank] and
+    # qk_nope_dim + qk_rope_dim], or with ``q_lora_rank = 0`` no query latent:
+    # ``q = h wq``; ``h wkv_a`` = ``c_kv`` [kv_lora_rank] and
     # one rotated key head [qk_rope_dim]; ``norm(c_kv) wkv_b`` [heads,
     # qk_nope_dim + v_head_dim].  The rotated parts take ``rope_latent``;
     # the softmax scale is ``(qk_nope_dim + qk_rope_dim) ** -0.5 *
@@ -238,6 +283,16 @@ class TransformerConfig:
     v_head_dim: int = 0
     attn_mscale: float = 1.0
     rope_latent: Optional[Rope] = None
+    # ---- ``linear_attention`` layers (Kimi Delta Attention,
+    # arXiv:2510.26692; ops/kda.py), ``heads`` heads of ``head_dim`` keys and
+    # values: q, k, v = SiLU(causal depthwise conv of ``linear_conv_kernel``
+    # taps (h wq | wk | wv)); q, k L2-normalised a head, q times head_dim^-0.5;
+    # log-decay a head, channel and token ``kda_lower_bound * sigmoid(
+    # exp(A_log) * (h wf + dt_bias))`` (the bounded gate: the decay lies in
+    # (exp(kda_lower_bound), 1)); ``beta = sigmoid(h wb)`` a head; the scan; an
+    # RMSNorm a head (gain ``o_norm [head_dim]``), ``attn_gate``, ``wo``.
+    linear_conv_kernel: int = 4
+    kda_lower_bound: float = -5.0
     # ---- Hyper-connections (arXiv:2512.24880 over arXiv:2409.19606): 0 =
     # the residual ``x + f(x)``; n > 0 = n residual streams ``[B, T, dim]``
     # (a tuple: the scan's carry), every sub-layer reading ``u = sum_i
@@ -277,20 +332,35 @@ class TransformerConfig:
                 put(name, Rope(**getattr(self, name)))
         kv = self.n_kv_heads
         for k in self.layout.kinds:
-            if (k.attn not in (FULL, SLIDING, LATENT)
+            if (k.attn not in (FULL, SLIDING, LATENT, LINEAR)
                     or k.ffn not in (DENSE, SPARSE)):
                 raise ValueError(f"unknown layer kind {k}")
+            if k.ffn == SPARSE and not self.num_experts:
+                raise ValueError("sparse layers need num_experts > 0")
             if k.attn == LATENT:
-                widths = ("q_lora_rank", "kv_lora_rank", "qk_nope_dim",
-                          "qk_rope_dim", "v_head_dim")
-                if not all(getattr(self, w) > 0 for w in widths):
+                widths = ("kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
+                          "v_head_dim")
+                if (not all(getattr(self, w) > 0 for w in widths)
+                        or self.q_lora_rank < 0):
                     raise ValueError(
-                        f"latent_attention layers need {widths} > 0")
-                if kv or self.qk_norm or self.attn_gate:
+                        f"latent_attention layers need {widths} > 0 and "
+                        "q_lora_rank >= 0 (0 = no query latent: q = h wq)")
+                if kv or self.qk_norm:
                     raise ValueError(
-                        "latent_attention layers take no n_kv_heads, "
-                        "qk_norm or attn_gate: their K/V are per head and "
-                        "their norms are the two latent ones")
+                        "latent_attention layers take no n_kv_heads or "
+                        "qk_norm: their K/V are per head and their norms "
+                        "are the latent ones")
+                continue
+            if k.attn == LINEAR:
+                if kv or self.qk_norm:
+                    raise ValueError(
+                        "linear_attention layers take no n_kv_heads or "
+                        "qk_norm: every head has its own key and value, "
+                        "and q and k are L2-normalised a head")
+                if self.linear_conv_kernel < 1 or self.kda_lower_bound >= 0:
+                    raise ValueError(
+                        "linear_attention layers need linear_conv_kernel "
+                        ">= 1 and kda_lower_bound < 0")
                 continue
             if kv and k.heads % kv:
                 raise ValueError(f"{k.heads} query heads do not divide into "
@@ -298,8 +368,6 @@ class TransformerConfig:
             if k.attn == SLIDING and self.sliding_window < 1:
                 raise ValueError("sliding_attention layers need "
                                  "sliding_window >= 1")
-            if k.ffn == SPARSE and not self.num_experts:
-                raise ValueError("sparse layers need num_experts > 0")
         if self.attn_gate not in ("", "per_head"):
             raise ValueError(f"unknown attn_gate '{self.attn_gate}'")
         if self.router_scoring not in ("softmax", "sigmoid"):
@@ -311,6 +379,13 @@ class TransformerConfig:
                 "router_scoring='sigmoid' balances by its bias rule: set "
                 "aux_loss_coef and router_z_loss_coef to 0 (they are "
                 "softmax routing's)")
+        if self.n_group < 1 or not 0 < self.topk_group <= self.n_group or (
+                self.n_group > 1 and (self.num_experts % self.n_group
+                                      or self.num_experts < 2 * self.n_group)):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group}: "
+                f"{self.num_experts} experts must lie in n_group groups of "
+                "at least two, of which 1..n_group are kept")
         if self.hc_mult < 0 or self.hc_mult == 1:
             raise ValueError(f"hc_mult={self.hc_mult}: 0 (one stream, no "
                              "hyper-connections) or at least 2 streams")
@@ -375,15 +450,30 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
     q_width, kv_width = heads * cfg.head_dim, kv * cfg.head_dim
     if kind.attn == LATENT:
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-        lyr = {
-            "wq_a": w(cfg.dim, cfg.q_lora_rank),
-            "q_a_norm": np.ones(cfg.q_lora_rank, np.float32),
-            "wq_b": w(cfg.q_lora_rank, heads * (dn + dr)),
+        lyr = ({"wq_a": w(cfg.dim, cfg.q_lora_rank),
+                "q_a_norm": np.ones(cfg.q_lora_rank, np.float32),
+                "wq_b": w(cfg.q_lora_rank, heads * (dn + dr))}
+               if cfg.q_lora_rank else {"wq": w(cfg.dim, heads * (dn + dr))})
+        lyr.update({
             "wkv_a": w(cfg.dim, cfg.kv_lora_rank + dr),
             "kv_a_norm": np.ones(cfg.kv_lora_rank, np.float32),
             "wkv_b": w(cfg.kv_lora_rank, heads * (dn + dv)),
             "wo": w(heads * dv, cfg.dim),
-        }
+        })
+    elif kind.attn == LINEAR:
+        taps = cfg.linear_conv_kernel
+        lyr = {key: w(cfg.dim, q_width) for key in ("wq", "wk", "wv", "wf")}
+        lyr.update({key: w(taps, q_width, scale=taps ** -0.5)
+                    for key in ("conv_q", "conv_k", "conv_v")})
+        # The decay's time-scales as the flash-linear-attention library
+        # draws them: exp(A_log) uniform in (1, 16) a head, dt_bias the
+        # inverse softplus of a step log-uniform in (0.001, 0.1) a channel.
+        dt = np.exp(rng.uniform(math.log(0.001), math.log(0.1), q_width))
+        lyr.update(
+            wb=w(cfg.dim, heads), wo=w(q_width, cfg.dim),
+            A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
+            dt_bias=(dt + np.log(-np.expm1(-dt))).astype(np.float32),
+            o_norm=np.ones(cfg.head_dim, np.float32))
     else:
         lyr = {
             "wq": w(cfg.dim, q_width),
@@ -467,14 +557,24 @@ def group_layers(cfg: TransformerConfig, layers):
     (``layout.uniform``): one dict of leaves stacked ``[L, ...]``
     (``stack_layer_params``).  Else ``{"lead": [layer, ...], "period":
     [slot, ...], "trail": [layer, ...]}``, slot ``s`` holding the s-th layer
-    of every period stacked ``[n_periods, ...]``."""
+    of every period stacked ``[n_periods, ...]``; a run of several slots
+    alike (``Layout.runs``) is one entry, stacked ``[n_periods, count,
+    ...]``."""
     lay = cfg.layout
     if lay.uniform:
         return stack_layer_params(layers)
     n_lead, p = len(lay.lead), len(lay.period)
     body = layers[n_lead:n_lead + p * lay.n_periods]
+
+    def stacked(s, count):
+        if count == 1:
+            return stack_layer_params(body[s::p])
+        return stack_layer_params(
+            [stack_layer_params(body[at + s:at + s + count])
+             for at in range(0, len(body), p)])
+
     return {"lead": list(layers[:n_lead]),
-            "period": [stack_layer_params(body[s::p]) for s in range(p)],
+            "period": [stacked(s, count) for s, count in lay.runs],
             "trail": list(layers[n_lead + p * lay.n_periods:])}
 
 
@@ -510,10 +610,21 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
                 f"(tp={mesh.shape['tp']}): the heads leave wq_b/wkv_b "
                 "interleaved with the low-rank norms' inputs whole, and no "
                 "tp layout of the latent projections is written")
-        layer = {"wq_a": P(None, None), "q_a_norm": P(None),
-                 "wq_b": P(None, None), "wkv_a": P(None, None),
-                 "kv_a_norm": P(None), "wkv_b": P(None, None),
-                 "wo": P(None, None)}
+        layer = ({"wq_a": P(None, None), "q_a_norm": P(None),
+                  "wq_b": P(None, None)} if cfg.q_lora_rank
+                 else {"wq": P(None, None)})
+        layer.update({"wkv_a": P(None, None), "kv_a_norm": P(None),
+                      "wkv_b": P(None, None), "wo": P(None, None)})
+    elif kind.attn == LINEAR:
+        if tp and mesh.shape["tp"] > 1:
+            raise ValueError(
+                f"linear_attention does not shard over 'tp' "
+                f"(tp={mesh.shape['tp']}): no tp layout of the scan's heads "
+                "is written")
+        layer = {key: P(None, None)
+                 for key in ("wq", "wk", "wv", "wf", "wb", "wo", "conv_q",
+                             "conv_k", "conv_v")}
+        layer.update(A_log=P(None), dt_bias=P(None), o_norm=P(None))
     else:
         layer = {"wq": P(None, tp), "wk": P(None, tp), "wv": P(None, tp),
                  "wo": P(tp, None)}
@@ -521,7 +632,7 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
     if cfg.qk_norm:
         layer.update(q_norm=P(None), k_norm=P(None))
     if cfg.attn_gate:
-        layer["wg"] = P(None, tp)
+        layer["wg"] = P(None, None if kind.attn in (LATENT, LINEAR) else tp)
     if kind.ffn == SPARSE:
         layer.update(moe_pspecs(mesh))
         if cfg.rule_bias:
@@ -564,7 +675,9 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
             layers = sharded(lay.period[0], lead)
         else:
             layers = {"lead": [sharded(k) for k in lay.lead],
-                      "period": [sharded(k, None) for k in lay.period],
+                      "period": [sharded(lay.period[s],
+                                         *(None,) * (1 + (count > 1)))
+                                 for s, count in lay.runs],
                       "trail": [sharded(k)
                                 for k in lay.period[:lay.n_trail]]}
     else:
@@ -698,7 +811,7 @@ def transformer_forward(params, tokens, cfg: TransformerConfig,
     over the layers, weighted as ``lm_loss`` adds it: ``aux_loss_coef`` x
     the load-balancing term + ``router_z_loss_coef`` x the router z-loss
     (zero for dense configs)."""
-    logits, aux, _, _ = _forward(params, tokens, cfg, mesh)
+    logits, aux, *_ = _forward(params, tokens, cfg, mesh)
     return (logits, aux) if return_aux else logits
 
 
@@ -732,10 +845,25 @@ def _routes(cfg: TransformerConfig, load):
          - jnp.sum(mine, axis=1, keepdims=True)], axis=1)
 
 
+def _layer_order(parts, counts):
+    """What the period's scan stacked of its routed layers, ``[n_periods,
+    ...]`` a slot and ``[n_periods, count, ...]`` a run of ``count``
+    (``counts``, a part each), as ``[layers, ...]`` in layer order."""
+    if len(parts) == 1 and counts[0] == 1:
+        return parts[0]
+    slots = jnp.concatenate(
+        [part if count > 1 else jnp.expand_dims(part, 1)
+         for part, count in zip(parts, counts)], axis=1)
+    return slots.reshape(-1, *slots.shape[2:])
+
+
 def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
     """``(logits, weighted auxiliary loss, expert load [routed layers, E]
-    or None, the prediction module's logits or None)``.  With ``mtp_layers``
-    the module's layer is the load's last row."""
+    or None, the prediction module's logits or None, kept)``.  With
+    ``mtp_layers`` the module's layer is the load's last row.  ``kept``: where
+    groups limit the router's choice (``n_group`` > 1), the tokens of each
+    routed layer that kept the group of the first expert held (int32
+    ``[routed layers]``), else None."""
     from ..parallel.ring_attention import blockwise_attention_local, ring_attention
 
     lay = cfg.layout
@@ -786,6 +914,20 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             f"latent_attention runs on one device: on a mesh of {mesh.size} "
             "the Mosaic kernel sits inside ring_attention's shard_map, "
             "which carries one width for q, k and v")
+    if any(k.attn == LINEAR for k in lay.kinds) and mesh is not None \
+            and mesh.size > 1:
+        for axis, why in (("sp", "the scan's state is not handed from chip "
+                                 "to chip along an 'sp' ring"),
+                          ("tp", "no tp layout of the scan's heads is "
+                                 "written"),
+                          ("pp", "pipeline stages take every layer alike")):
+            if int(mesh.shape.get(axis, 1)) > 1:
+                raise ValueError(f"linear_attention does not run over "
+                                 f"{axis}={mesh.shape[axis]}: {why}")
+        raise ValueError(
+            f"linear_attention runs on one device: on a mesh of {mesh.size} "
+            "the scan's Mosaic kernel would need a shard_map of its own "
+            "(GSPMD cannot partition it)")
     if use_pp and (n_streams or cfg.mtp_layers):
         raise ValueError(
             "pipeline_microbatches does not compose with hc_mult or "
@@ -820,7 +962,8 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             if cfg.layer_types is None:
                 return contextlib.nullcontext()
             return jax.named_scope({SLIDING: "attn.sliding",
-                                    LATENT: "attn.latent"}.get(kind.attn,
+                                    LATENT: "attn.latent",
+                                    LINEAR: "attn.linear"}.get(kind.attn,
                                                                "attn.full"))
 
         def wc(w):
@@ -836,10 +979,13 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             [B, T, heads * v_head_dim]."""
             Bb, Tb, _ = h.shape
             dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-            c_q = _rms_norm(h @ wc(lyr["wq_a"]), lyr["q_a_norm"].astype(dt),
-                            cfg.norm_eps)
-            q = (c_q @ wc(lyr["wq_b"])).reshape(
-                Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
+            if cfg.q_lora_rank:
+                c_q = _rms_norm(h @ wc(lyr["wq_a"]),
+                                lyr["q_a_norm"].astype(dt), cfg.norm_eps)
+                q = c_q @ wc(lyr["wq_b"])
+            else:
+                q = h @ wc(lyr["wq"])
+            q = q.reshape(Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
             kv_a = h @ wc(lyr["wkv_a"])
             c_kv = _rms_norm(kv_a[..., :cfg.kv_lora_rank],
                              lyr["kv_a_norm"].astype(dt), cfg.norm_eps)
@@ -850,7 +996,54 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             o = blockwise_attention_local(
                 q[..., :dn], kv[..., :dn], kv[..., dn:], scale_l,
                 causal=True, q_rope=_rope(q[..., dn:], rope), k_rope=k_r)
-            return o.transpose(0, 2, 1, 3).reshape(Bb, Tb, local_heads * dv)
+            o = o.transpose(0, 2, 1, 3)                      # [B,T,H,dv]
+            if cfg.attn_gate:
+                o = gated(o, h, lyr)
+            return o.reshape(Bb, Tb, local_heads * dv)
+
+        def gated(o, h, lyr):
+            """``o [B, T, H, D]`` times sigmoid(h wg), a scalar a head."""
+            gate = jax.nn.sigmoid((h @ wc(lyr["wg"])).astype(jnp.float32))
+            return o * gate.astype(dt)[..., None]
+
+        def linear_heads(h, lyr):
+            """Linear attention's heads from the normed input ``h``:
+            [B, T, heads * head_dim], normed and gated (the configuration's
+            ``linear_attention`` comment has the equations)."""
+            from ..ops.kda import kda
+
+            Bb, Tb, _ = h.shape
+            D, f32 = cfg.head_dim, jnp.float32
+            taps = cfg.linear_conv_kernel
+
+            def conv(x, kernel):
+                # causal, depthwise: tap j reads the token taps - 1 - j back
+                kernel = kernel.astype(dt)
+                padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+                y = sum(padded[:, j:j + Tb] * kernel[j] for j in range(taps))
+                return jax.nn.silu(y).reshape(Bb, Tb, local_heads, D)
+
+            def unit(x):        # L2-normalised a head, in float32
+                x = x.astype(f32)
+                return x * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+            q = (unit(conv(h @ wc(lyr["wq"]), lyr["conv_q"]))
+                 * D ** -0.5).astype(dt)
+            k = unit(conv(h @ wc(lyr["wk"]), lyr["conv_k"])).astype(dt)
+            v = conv(h @ wc(lyr["wv"]), lyr["conv_v"])
+            f = jnp.dot(h, wc(lyr["wf"]), preferred_element_type=f32)
+            f = (f + lyr["dt_bias"].astype(f32)).reshape(Bb, Tb, local_heads,
+                                                         D)
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                jnp.exp(lyr["A_log"].astype(f32))[:, None] * f)
+            beta = jax.nn.sigmoid(
+                jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32))
+            o = _rms_norm(kda(q, k, v, g, beta), lyr["o_norm"].astype(dt),
+                          cfg.norm_eps)
+            if cfg.attn_gate:
+                o = gated(o, h, lyr)
+            return o.reshape(Bb, Tb, local_heads * D)
 
         def attn_sub(x, lyr, residual=True):
             """The attention sub-layer of ``x`` [B, T, dim]: with its
@@ -860,8 +1053,9 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             Bb, Tb, _ = x.shape
             with jax.named_scope("attn"), kind_scope():
                 h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
-                if latent:
-                    out = red(latent_heads(h, lyr) @ wc(lyr["wo"]))
+                if latent or kind.attn == LINEAR:
+                    heads = latent_heads if latent else linear_heads
+                    out = red(heads(h, lyr) @ wc(lyr["wo"]))
                     return x + out if residual else out
                 q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
                 if cfg.qk_norm:
@@ -891,22 +1085,24 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 return x + out if residual else out
 
         def mlp_sub(x, lyr, residual=True):
-            """``(the FFN sub-layer of x, its weighted auxiliary loss, its
-            load or None)``."""
+            """``(the FFN sub-layer of x, its weighted auxiliary loss, what
+            it counted)``: a routed layer's ``(load, kept)`` (``moe_ffn``'s;
+            ``kept`` is None without a group limit), a dense one's None."""
             with jax.named_scope("mlp"):
                 h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
                 if kind.ffn == SPARSE:
-                    out, balance, z, load = moe_ffn(
+                    out, balance, z, load, kept = moe_ffn(
                         lyr, h, top_k=cfg.top_k, compute_dtype=dt,
                         dispatch=cfg.moe_dispatch,
                         norm_topk_prob=cfg.norm_topk_prob, held=cfg.held,
                         routed_scale=cfg.routed_scale, aux=use_aux,
-                        scoring=cfg.router_scoring, all_load=cfg.rule_bias)
+                        scoring=cfg.router_scoring, all_load=cfg.rule_bias,
+                        groups=(cfg.n_group, cfg.topk_group))
                     if cfg.shared_expert_hidden:
                         out = out + shared_expert(lyr, h, dt)
                     aux = (cfg.aux_loss_coef * balance
                            + cfg.router_z_loss_coef * z)
-                    return (x + out if residual else out), aux, load
+                    return (x + out if residual else out), aux, (load, kept)
                 gated = (jax.nn.silu(h @ wc(lyr["w1"]))
                          * (h @ wc(lyr["w3"])))
                 out = red(gated @ wc(lyr["w2"]))
@@ -922,8 +1118,9 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             x = _hc_write(x, post, res,
                           attn_sub(_hc_read(x, pre), lyr, residual=False))
             pre, post, res = _hc_gates(x, lyr["hc_mlp"], cfg)
-            out, aux, load = mlp_sub(_hc_read(x, pre), lyr, residual=False)
-            return _hc_write(x, post, res, out), aux, load
+            out, aux, counted = mlp_sub(_hc_read(x, pre), lyr,
+                                        residual=False)
+            return _hc_write(x, post, res, out), aux, counted
 
         if cfg.remat:
             # Under scan the body already blocks CSE, so the anti-CSE
@@ -944,8 +1141,8 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                         jax.checkpoint_policies
                         .dots_with_no_batch_dims_saveable,
                         jax.checkpoint_policies.save_only_these_names(
-                            "flash_out", "flash_lse", "wcast",
-                            *GROUPED_SAVED)),
+                            "flash_out", "flash_lse", "wcast", "kda_out",
+                            "kda_state", *GROUPED_SAVED)),
                     prevent_cse=not cfg.scan_layers)
             elif cfg.remat_policy == "full":
                 block = jax.checkpoint(block,
@@ -1034,22 +1231,24 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                        param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
                                     else None))
         x = xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
-        aux_total, load, mtp_logits = jnp.float32(0), None, None
+        aux_total, counted, mtp_logits = jnp.float32(0), None, None
     else:
         # A leading group, a scan over the periods whose body runs one layer
         # of each slot, a trailing part of a period; every layer alike is
         # one slot and nothing around the scan.  Without ``scan_layers`` the
         # layers are a list and the loop below is all there is.
-        aux_total, loads = jnp.float32(0), []
+        # ``counted``: the routed layers' ``(load, kept)`` in layer order.
+        aux_total, counted = jnp.float32(0), []
+        tmap = jax.tree_util.tree_map
         if n_streams:       # the embedding row copied into the n streams
             x = (x,) * n_streams
 
         def run(x, aux, kinds, layers):
             for kind, lyr in zip(kinds, layers):
-                x, a, load = blocks[kind](x, lyr)
+                x, a, c = blocks[kind](x, lyr)
                 aux = aux + a
-                if load is not None:
-                    loads.append(load[None])
+                if c is not None:
+                    counted.append(tmap(lambda v: v[None], c))
             return x, aux
 
         with jax.named_scope("layers"):
@@ -1061,21 +1260,31 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
 
                 def scan_body(carry, slots):
                     x, aux = carry
-                    slot_loads = []
-                    for kind, lyr in zip(lay.period, slots):
-                        x, a, load = blocks[kind](x, lyr)
-                        aux = aux + a
-                        slot_loads.append(load)
-                    return (x, aux), tuple(slot_loads)
+                    run_counted = []
+                    for (s, count), lyr in zip(lay.runs, slots):
+                        block = blocks[lay.period[s]]
+                        if count == 1:
+                            x, a, c = block(x, lyr)
+                            aux = aux + a
+                        else:       # slots alike: one body, scanned
 
-                (x, aux_total), slot_loads = jax.lax.scan(
+                            def alike(carry, lyr, block=block):
+                                x, a, c = block(carry[0], lyr)
+                                return (x, carry[1] + a), c
+
+                            (x, aux), c = jax.lax.scan(alike, (x, aux), lyr)
+                        run_counted.append(c)
+                    return (x, aux), tuple(run_counted)
+
+                (x, aux_total), run_counted = jax.lax.scan(
                     scan_body, (x, aux_total), period)
-                routed = [l for l in slot_loads if l is not None]
-                if len(routed) == 1:
-                    loads.append(routed[0])
-                elif routed:        # [periods, slots, E] in layer order
-                    loads.append(jnp.stack(routed, axis=1).reshape(
-                        -1, routed[0].shape[-1]))
+                routed = [(c, count) for c, (_, count)
+                          in zip(run_counted, lay.runs) if c is not None]
+                if routed:
+                    counts = [count for _, count in routed]
+                    counted.append(tmap(
+                        lambda *parts: _layer_order(parts, counts),
+                        *[c for c, _ in routed]))
                 x, aux_total = run(x, aux_total, lay.period[:lay.n_trail],
                                    trail)
         if n_streams:       # the streams' mean goes on to the final norm
@@ -1098,22 +1307,23 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                     axis=-1) @ m["proj"].astype(dt)
                 if n_streams:
                     g = (g,) * n_streams
-                g, a, m_load = blocks[lay.kinds[-1]](g, m["layer"])
+                g, a, c = blocks[lay.kinds[-1]](g, m["layer"])
                 aux_total = aux_total + a
-                if m_load is not None:
-                    loads.append(m_load[None])
+                if c is not None:
+                    counted.append(tmap(lambda v: v[None], c))
                 if n_streams:
                     g = _hc_mean(g)
                 with jax.named_scope("head"):
                     g = _rms_norm(g, m["out_norm"].astype(dt), cfg.norm_eps)
                     mtp_logits = g @ params["head"].astype(dt)
-        load = (None if not loads else loads[0] if len(loads) == 1
-                else jnp.concatenate(loads))
+        counted = (None if not counted else counted[0] if len(counted) == 1
+                   else tmap(lambda *parts: jnp.concatenate(parts), *counted))
 
+    load, kept = counted or (None, None)
     with jax.named_scope("head"):
         x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
         logits = x @ params["head"].astype(dt)
-    return logits, aux_total, load, mtp_logits
+    return logits, aux_total, load, mtp_logits, kept
 
 
 def _ce_value(logits, targets):
@@ -1180,29 +1390,30 @@ def _loss_and_routes(params, tokens, cfg: TransformerConfig,
     """``(lm_loss, routes)``: ``routes`` is the routed layers' counted
     routes (``expert_load``'s array) where the configuration holds a share
     of the experts (``cfg.counts_routes``), else ``None``."""
-    loss, (routes, _) = _loss_routes_loads(params, tokens, cfg, mesh)
+    loss, (routes, _, _) = _loss_routes_loads(params, tokens, cfg, mesh)
     return loss, routes
 
 
 def _loss_routes_loads(params, tokens, cfg: TransformerConfig,
                        mesh: Optional[Mesh] = None):
-    """``(lm_loss, (routes, loads))``: ``_loss_and_routes`` and, where the
-    step moves a router bias by rule (``cfg.rule_bias``), every expert's
+    """``(lm_loss, (routes, loads, kept))``: ``_loss_and_routes`` and, where
+    the step moves a router bias by rule (``cfg.rule_bias``), every expert's
     counted routes ``[routed layers (+ the prediction module's), E]``, else
-    ``None``."""
-    ce, ce_mtp, aux, load = _ce_parts(params, tokens, cfg, mesh)
+    ``None``; ``kept`` is ``_forward``'s, where the step counts routes."""
+    ce, ce_mtp, aux, load, kept = _ce_parts(params, tokens, cfg, mesh)
     if ce_mtp is not None:
         ce = ce + cfg.mtp_loss_coef * ce_mtp
     return (ce + aux if cfg.num_experts else ce,
             (_routes(cfg, load) if cfg.counts_routes else None,
-             load if cfg.rule_bias else None))
+             load if cfg.rule_bias else None,
+             kept if cfg.counts_routes else None))
 
 
 def _ce_parts(params, tokens, cfg: TransformerConfig,
               mesh: Optional[Mesh] = None):
     """``(next-token cross-entropy, the prediction module's or None,
     weighted auxiliary loss, load)``."""
-    logits, aux, load, mtp_logits = _forward(params, tokens, cfg, mesh)
+    logits, aux, load, mtp_logits, kept = _forward(params, tokens, cfg, mesh)
     # Crossover measured between 8192 (big loss at dim 512) and 12,544 (a
     # small win at dim 3072).
     ce_fn = _ce if cfg.vocab_size >= 12288 else _ce_value
@@ -1212,7 +1423,7 @@ def _ce_parts(params, tokens, cfg: TransformerConfig,
     if mtp_logits is not None:       # position t predicts token t + 2
         with jax.named_scope("mtp"), jax.named_scope("loss"):
             ce_mtp = ce_fn(mtp_logits[:, :-2], tokens[:, 2:])
-    return ce, ce_mtp, aux, load
+    return ce, ce_mtp, aux, load, kept
 
 
 def _ruled(path) -> bool:
@@ -1245,11 +1456,19 @@ def _bias_rule(cfg: TransformerConfig, params, loads):
         else:
             lead, period, trail = _grouped(cfg, layers)
             n_lead, p = len(lay.lead), len(lay.period)
+
+            def slot_rows(s, count):
+                """The rows of a run's layers: [n_periods], or with a run
+                of several [n_periods, count], as its leaves are stacked."""
+                of = np.asarray([[row_of[n_lead + q * p + s + j]
+                                  for j in range(count)]
+                                 for q in range(lay.n_periods)])
+                return of if count > 1 else of[:, 0]
+
             period = [
                 slot if lay.period[s].ffn != SPARSE else moved(
-                    slot, np.asarray([row_of[n_lead + q * p + s]
-                                      for q in range(lay.n_periods)]))
-                for s, slot in enumerate(period)]
+                    slot, slot_rows(s, count))
+                for (s, count), slot in zip(lay.runs, period)]
             layers = (period[0] if lay.uniform else {
                 "lead": [at(i, lyr) for i, lyr in enumerate(lead)],
                 "period": period,
@@ -1296,8 +1515,11 @@ class TransformerTrainer:
         self._step = None
         # The last step's counted routes, on the device (``cfg.
         # counts_routes``: int32 [routed layers, experts_held + 1], the held
-        # experts' routes and the routes that went elsewhere), else None.
-        self.routes = None
+        # experts' routes and the routes that went elsewhere), else None;
+        # and, where groups limit the router's choice too, the tokens of each
+        # routed layer that kept the held experts' group (int32 [routed
+        # layers]), else None.
+        self.routes = self.kept = None
         self._steps_dispatched = 0     # the ``step`` of mv.trainer.dispatch
         self._eval = None
         self._eval_parts = None
@@ -1322,7 +1544,8 @@ class TransformerTrainer:
 
     def _raw_step(self, accum: int = 1):
         """Un-jitted (params, state, tokens) -> (params, state, loss,
-        routes); ``routes`` is ``None`` unless ``cfg.counts_routes``.
+        routes, kept); ``routes`` is ``None`` unless ``cfg.counts_routes``,
+        ``kept`` unless groups limit the router's choice as well.
 
         ``accum > 1`` splits the batch into that many microbatches,
         accumulates their gradients in float32 (a ``lax.scan`` so the
@@ -1347,9 +1570,9 @@ class TransformerTrainer:
                 "configs (batch-nonlinear aux loss); run MoE at full batch")
 
         def step(params, state, tokens):
-            routes = loads = None
+            routes = loads = kept = None
             if accum == 1:
-                (loss, (routes, loads)), grads = jax.value_and_grad(
+                (loss, (routes, loads, kept)), grads = jax.value_and_grad(
                     _loss_routes_loads, has_aux=True)(params, tokens, cfg,
                                                       mesh)
             else:
@@ -1380,7 +1603,7 @@ class TransformerTrainer:
             params, state = self._apply_updates(params, state, grads)
             if loads is not None:
                 params = _bias_rule(cfg, params, loads)
-            return params, state, loss, routes
+            return params, state, loss, routes, kept
 
         return step
 
@@ -1546,7 +1769,7 @@ class TransformerTrainer:
         self._steps_dispatched += 1
         if self._offload is None:
             with dispatch:
-                self.params, self.state, loss, self.routes = step(
+                self.params, self.state, loss, self.routes, self.kept = step(
                     self.params, self.state, placed)
             return loss
         # Offloaded state (docs/host_bridge.md): the vector prefetched
@@ -1557,7 +1780,7 @@ class TransformerTrainer:
         with dashboard.monitor("Transformer::offload_wait"):
             state = self._flat_to_state(self._offload.wait())
         with dispatch:
-            self.params, new_state, loss, self.routes = step(
+            self.params, new_state, loss, self.routes, self.kept = step(
                 self.params, state, placed)
         with dashboard.monitor("Transformer::offload_push"):
             self._offload.push(self._state_to_flat(new_state))

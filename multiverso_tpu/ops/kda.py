@@ -1,0 +1,425 @@
+"""Kimi Delta Attention (arXiv:2510.26692) as a chunked scan: a gated delta
+rule whose decay is a vector a head and token, its state ``[d_k, d_v]`` a
+head carried from chunk to chunk.
+
+The recurrence, a head (``alpha_t = exp(g_t)`` a channel of the key, ``g_t
+<= 0``; ``beta_t`` a scalar; the state zero before the first token)::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+**Chunks** of ``CHUNK`` = 64 tokens (the paper's section 3, the WY / UT
+form).  With ``G`` the cumulative log-decay inside a chunk and ``S`` the state
+that enters it, the pseudo-values ``u_t = beta_t (v_t - (Diag(alpha_t)
+S_{t-1})^T k_t)`` solve one unit lower-triangular system a chunk::
+
+    A[t, i] = beta_t sum_d k_t[d] k_i[d] exp(G_t[d] - G_i[d])      i <  t
+    Aqk[t, i] =      sum_d q_t[d] k_i[d] exp(G_t[d] - G_i[d])      i <= t
+    T = (I + A)^-1
+    W = T (beta k exp(G))        U0 = T (beta v)        U = U0 - W S
+    O = (q exp(G)) S + Aqk U
+    S' = Diag(exp(G_last)) S + (k exp(G_last - G))^T U
+
+Everything but ``U``, ``O`` and ``S'`` is a chunk's own (``_intra``); those
+three are the scan.  **Decays enter only as differences of cumulative
+log-decays, and no quotient of two exponentials spans more than ``SUB`` = 16
+tokens**: a row of sub-block ``I`` carries ``exp(G_t - G_ref(I))`` (at most
+1) and a column ``exp(G_ref(I) - G_i)``, ``ref(I)`` the sub-block's first
+token, which is at most 1 for the columns before the sub-block and at most
+``exp(15 |g|)`` inside it: with the bounded gate's ``g >= -5`` that is
+``e^75``, inside float32 and bfloat16.  The columns behind the diagonal are
+masked; their exponent is clamped so that what is masked is finite.  ``T``
+is built from the 16 x 16 diagonal blocks' inverses (a nilpotent series of
+four factors) and a block-level series of two factors, ten 64 x 64 products
+in float32, exact but for rounding.
+
+**Precision**: matmul operands in the inputs' dtype (bfloat16 on the chip),
+accumulation in float32; ``g``, the cumulative sums, every exponential, the
+solve and the carried state in float32.
+
+**Forward**: one Pallas kernel ``kda_fwd`` (scope and ``pallas_call(name=)``),
+grid ``(batch, head, chunk)`` with the chunk axis sequential and the state in
+VMEM scratch; it reads ``q``, ``k``, ``beta k``, ``beta v``, ``g`` in the
+model's ``[B, T, H * d]`` layout through its index maps (no transpose) and
+writes ``o`` and the state that ENTERED each chunk (float32 ``[B, H, chunks,
+d_v, d_k]``: 0.5 GiB a layer at 16,384 tokens and 32 heads of 128 x 128),
+which is all the backward keeps.  Off the TPU the same arithmetic runs as
+``jax.numpy`` (``_intra`` batched over the chunks, ``lax.scan`` over them), or
+the kernel in interpret mode under ``MVTPU_FORCE_FLASH`` (the flash kernels'
+switch; ``MVTPU_NO_FLASH`` keeps the kernel off a TPU too).
+
+**Backward** (scope ``kda_bwd``): XLA's chunked form, not yet a kernel
+(ROADMAP Reach 10).  It rebuilds a chunk's own part (``_intra``), rebuilds
+``U`` from the kept states, walks the chunks backwards carrying the state's
+cotangent (``lax.scan``: five products a chunk), and pulls the cotangents of
+``_intra``'s outputs back through it by ``jax.vjp``; the solve's transpose is
+``-T^T dT T^T``.  It does so ``_BWD_HEADS`` heads at a time, so that its
+float32 temporaries are a group's and not the layer's.
+
+``kda`` counts a trace in ``attention.linear_traced{heads=,chunk=,path=}``.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["kda", "CHUNK", "SUB"]
+
+CHUNK = 64
+SUB = 16
+# The exponent of a masked column's factor is cut here: exp(80) is finite in
+# float32 and bfloat16, and no kept column's exponent passes 15 |g|.
+_CLAMP = 80.0
+# The backward runs this many heads at a time (``_kda_bwd``): its float32
+# temporaries are a group's, 1 GiB at 8 heads of 16,384 tokens where all 32
+# at once took 3.8 and the v5e compiler refused the cell's step.
+_BWD_HEADS = 4
+# The forward kernel's grid step runs this many heads' chunks.
+_FWD_HEADS = 4
+_HIGHEST = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))          # x @ y^T
+_TN = (((0,), (0,)), ((), ()))          # x^T @ y
+
+
+# ------------------------------------------------------------ a chunk's own
+def _masks(n: int):
+    row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return row, col
+
+
+def _tri_inv_impl(a, mm):
+    """``(I + a)^-1`` of strictly lower triangular ``a [..., C, C]``
+    (float32), ``mm(x, y)`` the float32 product (module docstring)."""
+    n = a.shape[-1]
+    row, col = _masks(n)
+    eye = (row == col).astype(a.dtype)
+    same = (row // SUB) == (col // SUB)
+    p = -jnp.where(same, a, 0.0)             # the diagonal blocks, negated
+    d_inv = eye + p
+    for _ in range(SUB.bit_length() - 2):    # (I+p)(I+p^2)(I+p^4)(I+p^8)
+        p = mm(p, p)
+        d_inv = d_inv + mm(d_inv, p)
+    x = mm(d_inv, jnp.where(same, 0.0, a))   # block strictly lower
+    y, p = eye - x, mm(x, x)
+    for j in range((n // SUB).bit_length() - 2):
+        y = y + mm(y, p)
+        if j < (n // SUB).bit_length() - 3:
+            p = mm(p, p)
+    return mm(y, d_inv)
+
+
+def _mm32(x, y):
+    return jnp.matmul(x, y, precision=_HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def _tri_inv(a):
+    return _tri_inv_impl(a, _mm32)
+
+
+def _tri_inv_fwd(a):
+    t = _tri_inv(a)
+    return t, t
+
+
+def _tri_inv_bwd(t, d_t):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-_mm32(_mm32(tt, d_t), tt),)
+
+
+_tri_inv.defvjp(_tri_inv_fwd, _tri_inv_bwd)
+
+
+def _intra(q, k, v, g, beta):
+    """What a chunk computes without the state, every chunk at once: ``q``,
+    ``k``, ``g [..., C, d_k]``, ``v [..., C, d_v]``, ``beta [..., C]`` →
+    ``(W [..., C, d_k] f32, U0 [..., C, d_v] f32, Aqk [..., C, C] f32, q
+    exp(G), k exp(G_last - G) in the inputs' dtype, exp(G_last) [..., d_k]
+    f32)``."""
+    f32, dt = jnp.float32, q.dtype
+    C = q.shape[-2]
+    qf, kf = q.astype(f32), k.astype(f32)
+    G = jnp.cumsum(g.astype(f32), axis=-2)
+    firsts = G[..., ::SUB, :]                                # [..., C/SUB, d]
+    rowf = jnp.exp(G - jnp.repeat(firsts, SUB, axis=-2))
+    kb = beta.astype(f32)[..., None] * kf
+    # [..., I, C, d]: the columns as row block I reads them
+    colf = jnp.exp(jnp.minimum(firsts[..., :, None, :] - G[..., None, :, :],
+                               _CLAMP))
+    kc = (kf[..., None, :, :] * colf).astype(dt)
+    blocks = lambda x: (x * rowf).astype(dt).reshape(
+        *x.shape[:-2], C // SUB, SUB, x.shape[-1])
+    a = jnp.einsum("...ird,...icd->...irc", blocks(kb), kc,
+                   preferred_element_type=f32).reshape(*q.shape[:-2], C, C)
+    aqk = jnp.einsum("...ird,...icd->...irc", blocks(qf), kc,
+                     preferred_element_type=f32).reshape(*q.shape[:-2], C, C)
+    row, col = _masks(C)
+    aqk = jnp.where(row >= col, aqk, 0.0)
+    t = _tri_inv(jnp.where(row > col, a, 0.0)).astype(dt)
+    eg = jnp.exp(G)
+    w = jnp.matmul(t, (kb * eg).astype(dt), preferred_element_type=f32)
+    u0 = jnp.matmul(t, (beta.astype(f32)[..., None] * v.astype(f32)
+                        ).astype(dt), preferred_element_type=f32)
+    last = G[..., -1:, :]
+    return (w, u0, aqk, (qf * eg).astype(dt),
+            (kf * jnp.exp(last - G)).astype(dt), jnp.exp(last[..., 0, :]))
+
+
+# ------------------------------------------------------------------ the scan
+def _ein(spec, x, y):
+    return jnp.einsum(spec, x, y, preferred_element_type=jnp.float32)
+
+
+def _scan_fwd(w, u0, aqk, qg, kd, ec):
+    """The chunks in turn, leading axes ``[N, ...]`` with ``N`` the chunks:
+    ``(O [N, ..., C, d_v] f32, the state entering each chunk [N, ..., d_v,
+    d_k] f32)``."""
+    dt = qg.dtype
+
+    def step(s, xs):
+        w, u0, aqk, qg, kd, ec = xs
+        sb = s.astype(dt)
+        u = (u0 - _ein("...cd,...vd->...cv", w.astype(dt), sb)).astype(dt)
+        o = (_ein("...cd,...vd->...cv", qg, sb)
+             + _ein("...ct,...tv->...cv", aqk.astype(dt), u))
+        s_next = s * ec[..., None, :] + _ein("...cv,...cd->...vd", u, kd)
+        return s_next, (o, s)
+
+    s0 = jnp.zeros((*u0.shape[1:-2], u0.shape[-1], w.shape[-1]), jnp.float32)
+    _, (o, states) = jax.lax.scan(step, s0, (w, u0, aqk, qg, kd, ec))
+    return o, states
+
+
+def _scan_bwd(parts, states, d_o):
+    """The cotangents of ``_intra``'s outputs from ``d_o [N, ..., C, d_v]``
+    and the states the forward kept."""
+    w, u0, aqk, qg, kd, ec = parts
+    dt = qg.dtype
+    sb = states.astype(dt)
+    u = (u0 - _ein("n...cd,n...vd->n...cv", w.astype(dt), sb)).astype(dt)
+    d_o = d_o.astype(dt)
+    aqk_b, w_b = aqk.astype(dt), w.astype(dt)
+
+    def step(d_s, xs):
+        aqk, d_o, kd, qg, ec, w, u, s = xs
+        d_sb = d_s.astype(dt)
+        d_u = (_ein("...ct,...cv->...tv", aqk, d_o)
+               + _ein("...cd,...vd->...cv", kd, d_sb))
+        d_kd = _ein("...cv,...vd->...cd", u, d_sb)
+        d_ec = jnp.sum(s * d_s, axis=-2)
+        d_s = (d_s * ec[..., None, :] + _ein("...cv,...cd->...vd", d_o, qg)
+               - _ein("...cv,...cd->...vd", d_u.astype(dt), w))
+        return d_s, (d_u, d_kd, d_ec)
+
+    _, (d_u, d_kd, d_ec) = jax.lax.scan(
+        step, jnp.zeros(states.shape[1:], jnp.float32),
+        (aqk_b, d_o, kd, qg, ec, w_b, u, states), reverse=True)
+    d_w = -_ein("n...cv,n...vd->n...cd", d_u.astype(dt), sb)
+    d_qg = _ein("n...cv,n...vd->n...cd", d_o, sb)
+    d_aqk = _ein("n...cv,n...tv->n...ct", d_o, u)
+    return d_w, d_u, d_aqk, d_qg.astype(dt), d_kd.astype(dt), d_ec
+
+
+def _chunked(x, n):
+    """``[B, T, H, ...]`` → ``[n, B, H, C, ...]``."""
+    B, T, H = x.shape[:3]
+    x = x.reshape(B, n, T // n, H, *x.shape[3:])
+    return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+
+def _unchunked(x):
+    """``[n, B, H, C, d]`` → ``[B, n * C, H, d]``."""
+    n, B, H, C, d = x.shape
+    return jnp.moveaxis(jnp.moveaxis(x, 0, 1), 2, 3).reshape(B, n * C, H, d)
+
+
+def _fwd_jnp(q, k, v, g, beta):
+    n = q.shape[1] // CHUNK
+    o, states = _scan_fwd(*_intra(*(_chunked(x, n)
+                                    for x in (q, k, v, g, beta))))
+    return _unchunked(o).astype(v.dtype), states
+
+
+# ---------------------------------------------------------------- the kernel
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, s_ref, state,
+                *, heads, dk, dv):
+    """One chunk of ``heads`` heads (their columns side by side in the
+    blocks): the heads' chains of products are independent, so the
+    scheduler fills one's latencies with another's."""
+    f32, dt = jnp.float32, q_ref.dtype
+    C = q_ref.shape[1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state[:] = jnp.zeros_like(state)
+
+    def mm(x, y, dims=(((1,), (0,)), ((), ())), precision=None):
+        return jax.lax.dot_general(x, y, dims, precision=precision,
+                                   preferred_element_type=f32)
+
+    mm32 = functools.partial(mm, precision=_HIGHEST)
+    row, col = _masks(C)
+    lower = (row >= col).astype(f32)
+    for h in range(heads):
+        at_k, at_v = slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
+        q, k, kb = (r[0, :, at_k].astype(f32) for r in (q_ref, k_ref, kb_ref))
+        G = mm32(lower, g_ref[0, :, at_k])                    # cumulative
+        firsts = [G[i:i + 1] for i in range(0, C, SUB)]
+        rowf = jnp.exp(G - jnp.concatenate(
+            [jnp.broadcast_to(f, (SUB, dk)) for f in firsts], axis=0))
+        kr, qr = (kb * rowf).astype(dt), (q * rowf).astype(dt)
+        a, aqk = [], []
+        for i, first in enumerate(firsts):
+            kc = (k * jnp.exp(jnp.minimum(first - G, _CLAMP))).astype(dt)
+            rows = slice(i * SUB, (i + 1) * SUB)
+            a.append(mm(kr[rows], kc, _NT))
+            aqk.append(mm(qr[rows], kc, _NT))
+        aqk = jnp.where(row >= col, jnp.concatenate(aqk, axis=0), 0.0)
+        t = _tri_inv_impl(
+            jnp.where(row > col, jnp.concatenate(a, axis=0), 0.0),
+            mm32).astype(dt)
+        eg = jnp.exp(G)
+        w = mm(t, (kb * eg).astype(dt))
+        u0 = mm(t, vb_ref[0, :, at_v])
+        s = state[h]                                          # [d_v, d_k]
+        s_ref[0, h, 0] = s
+        sb = s.astype(dt)
+        u = (u0 - mm(w.astype(dt), sb, _NT)).astype(dt)
+        o_ref[0, :, at_v] = (mm((q * eg).astype(dt), sb, _NT)
+                             + mm(aqk.astype(dt), u)).astype(o_ref.dtype)
+        last = G[C - 1:C]
+        state[h] = s * jnp.exp(last) + mm(
+            u, (k * jnp.exp(last - G)).astype(dt), _TN)
+
+
+def _fwd_kernel_call(q, k, v, g, beta, interpret):
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    n = T // CHUNK
+    f32 = jnp.float32
+    hb = math.gcd(H, _FWD_HEADS)
+    bf = beta.astype(f32)[..., None]
+    kb = (bf * k.astype(f32)).astype(k.dtype)
+    vb = (bf * v.astype(f32)).astype(v.dtype)
+
+    def spec(width):
+        return pl.BlockSpec((1, CHUNK, hb * width), lambda b, h, c: (b, c, h))
+
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=hb, dk=dk, dv=dv),
+        name="kda_fwd", grid=(B, H // hb, n),
+        in_specs=[spec(dk), spec(dk), spec(dk), spec(dv), spec(dk)],
+        out_specs=[spec(dv),
+                   pl.BlockSpec((1, hb, 1, dv, dk),
+                                lambda b, h, c: (b, h, c, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * dv), v.dtype),
+                   jax.ShapeDtypeStruct((B, H, n, dv, dk), f32)],
+        scratch_shapes=[pltpu.VMEM((hb, dv, dk), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)
+    flat = lambda x: x.reshape(B, T, H * x.shape[-1])
+    o, states = call(flat(q), flat(k), flat(kb), flat(vb),
+                     flat(g.astype(f32)))
+    return o.reshape(B, T, H, dv), jnp.moveaxis(states, 2, 0)
+
+
+# ------------------------------------------------------------ the custom_vjp
+def _path() -> str:
+    """``mosaic`` | ``interpret`` | ``jnp``: taken at trace time, as the
+    flash kernels' dispatch takes it (``parallel/ring_attention.py``)."""
+    if os.environ.get("MVTPU_NO_FLASH"):
+        return "jnp"
+    if jax.default_backend() == "tpu":
+        return "mosaic"
+    return "interpret" if os.environ.get("MVTPU_FORCE_FLASH") else "jnp"
+
+
+def _forward(q, k, v, g, beta, path):
+    with jax.named_scope("kda_fwd"):
+        if path == "jnp":
+            return _fwd_jnp(q, k, v, g, beta)
+        return _fwd_kernel_call(q, k, v, g, beta, path == "interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda(q, k, v, g, beta, path):
+    return _forward(q, k, v, g, beta, path)[0]
+
+
+def _kda_fwd(q, k, v, g, beta, path):
+    # Named so that remat policy "dots" keeps them (``transformer.py``): with
+    # o and the states saved the backward does not run the forward again.
+    o, states = _forward(q, k, v, g, beta, path)
+    o, states = checkpoint_name(o, "kda_out"), checkpoint_name(states,
+                                                               "kda_state")
+    return o, (q, k, v, g, beta, states)
+
+
+def _kda_bwd(path, res, d_o):
+    q, k, v, g, beta, states = res
+    H, n = q.shape[2], states.shape[0]
+    hg = math.gcd(H, _BWD_HEADS)
+
+    def heads(i, x, axis=2):
+        return jax.lax.dynamic_slice_in_dim(x, i * hg, hg, axis)
+
+    def group(i, grads):
+        """The gradients of heads ``[i hg, (i + 1) hg)``, written into
+        their places: the backward's temporaries are one group's."""
+        with jax.named_scope("kda_bwd"):
+            parts, pull = jax.vjp(_intra, *(_chunked(heads(i, x), n)
+                                            for x in (q, k, v, g, beta)))
+            got = pull(_scan_bwd(parts, heads(i, states),
+                                 _chunked(heads(i, d_o), n)))
+            got = [_unchunked(x) for x in got[:4]] + [
+                _unchunked(got[4][..., None])[..., 0]]
+        return tuple(jax.lax.dynamic_update_slice_in_dim(
+            full, x.astype(full.dtype), i * hg, 2)
+            for full, x in zip(grads, got))
+
+    with jax.named_scope("kda_bwd"):
+        return jax.lax.fori_loop(
+            0, H // hg, group,
+            tuple(jnp.zeros(x.shape, x.dtype) for x in (q, k, v, g, beta)))
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
+def kda(q, k, v, g, beta):
+    """Kimi Delta Attention over a sequence from a zero state: ``q``, ``k``
+    ``[B, T, H, d_k]`` (as the layer made them: normalised, ``q`` scaled),
+    ``v [B, T, H, d_v]``, the log-decay ``g [B, T, H, d_k]`` (``<= 0``; kept
+    in float32) and ``beta [B, T, H]`` → ``o [B, T, H, d_v]`` in ``v``'s
+    dtype.  Differentiable in all five (``jax.custom_vjp``; the module
+    docstring has both passes).  A length that no chunk divides is padded
+    with tokens that leave the state as it is (``beta`` 0, ``g`` 0)."""
+    from .. import metrics
+
+    B, T, H, dk = q.shape
+    if (k.shape != q.shape or g.shape != q.shape or v.shape[:3] != (B, T, H)
+            or beta.shape != (B, T, H)):
+        raise ValueError(
+            "kda wants q/k/g [B,T,H,dk], v [B,T,H,dv], beta [B,T,H]; got "
+            f"{q.shape}, {k.shape}, {g.shape}, {v.shape}, {beta.shape}")
+    path = _path()
+    metrics.counter("attention.linear_traced",
+                    {"heads": str(H), "chunk": str(CHUNK), "path": path}).inc()
+    pad = -T % CHUNK
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    o = _kda(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32), path)
+    return o[:, :T] if pad else o

@@ -294,7 +294,7 @@ def test_the_shares_add_up_to_the_uncut_layer(dispatch):
         total = shared_expert(full, h, jnp.float32)
         loads = []
         for first in (0, 2, 4, 6):
-            out, _, _, load = moe_ffn(
+            out, _, _, load, _ = moe_ffn(
                 _share(full, first, 2), h, top_k=3, dispatch=dispatch,
                 norm_topk_prob=True, held=(first, 2), routed_scale=2.5,
                 aux=False)
@@ -303,7 +303,8 @@ def test_the_shares_add_up_to_the_uncut_layer(dispatch):
     assert _rel(total, want) < 1e-5
     loads = np.stack(loads)                       # [shares, 2 held + 1]
     assert (loads.sum(axis=1) == 2 * 24 * 3).all()
-    _, _, _, whole = moe_ffn(full, h, top_k=3, dispatch=dispatch, aux=False)
+    _, _, _, whole, _ = moe_ffn(full, h, top_k=3, dispatch=dispatch,
+                                aux=False)
     assert (loads[:, :2].reshape(-1) == np.asarray(whole)).all()
 
 

@@ -214,7 +214,7 @@ def test_a_shares_buffers_follow_its_held_routes_exactly(fill, scoring):
     sigmoid = scoring == "sigmoid"
 
     def run(params, x, dispatch):
-        out, _, _, load = moe_ffn(params, x, top_k=2, dispatch=dispatch,
+        out, _, _, load, _ = moe_ffn(params, x, top_k=2, dispatch=dispatch,
                                   held=_HELD, routed_scale=2.5, aux=False,
                                   scoring=scoring, all_load=sigmoid)
         return jnp.sum(w * out), (out, load)
@@ -239,7 +239,7 @@ def test_a_shares_buffers_follow_its_held_routes_exactly(fill, scoring):
 
     # The routed part alone, cut to its rung against all N*k rows.
     N, k = _ROUTES // 2, 2
-    _, _, top_p, top_idx = moe._routing(params, x, k, True, 2.5, scoring)
+    _, _, top_p, top_idx, _ = moe._routing(params, x, k, True, 2.5, scoring)
     key = top_idx.reshape(N, k)
     mine = (key >= 2) & (key < 4)
     sorted_key, order, inv = moe._sort_routes(jnp.where(mine, key - 2, 2))

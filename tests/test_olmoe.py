@@ -177,8 +177,8 @@ def test_grouped_equals_dense_values_and_gradients(norm_topk_prob):
 
     def run(dispatch):
         def f(p, x):
-            out, balance, z, _ = moe_ffn(p, x, top_k=3, dispatch=dispatch,
-                                         norm_topk_prob=norm_topk_prob)
+            out, balance, z, *_ = moe_ffn(p, x, top_k=3, dispatch=dispatch,
+                                          norm_topk_prob=norm_topk_prob)
             return (jnp.sum(jnp.sin(out)) + 0.3 * balance + 0.2 * z,
                     (out, balance, z))
 
@@ -226,7 +226,7 @@ def test_no_route_is_dropped_when_one_expert_takes_every_token():
     params = dict(params, router=jnp.asarray(router))
     x = jnp.abs(x)                                   # positive x => +logit
     dense, *_ = moe_ffn(params, x, top_k=1, dispatch="dense")
-    grouped, _, _, load = moe_ffn(params, x, top_k=1, dispatch="grouped")
+    grouped, _, _, load, _ = moe_ffn(params, x, top_k=1, dispatch="grouped")
     assert np.asarray(load).tolist() == [48, 0, 0, 0, 0, 0, 0, 0]
     np.testing.assert_allclose(grouped, dense, atol=2e-6)
     assert np.abs(np.asarray(dense)).min(axis=-1).max() > 0
@@ -264,8 +264,8 @@ def test_grouped_schedule_holds_no_route_by_expert_matrix():
 
 
 def _grouped_loss(p, x):
-    out, balance, z, _ = moe_ffn(p, x, top_k=3, dispatch="grouped",
-                                 norm_topk_prob=False)
+    out, balance, z, *_ = moe_ffn(p, x, top_k=3, dispatch="grouped",
+                                  norm_topk_prob=False)
     return jnp.sum(jnp.sin(out)) + 0.3 * balance + 0.2 * z
 
 
@@ -431,7 +431,8 @@ def test_auxiliary_terms_by_hand():
     params, x = _ffn_case(E=8, k=3)
     flat = dict(params, router=jnp.zeros((16, 8)))
     for dispatch in ("grouped", "dense"):
-        _, balance, z, load = moe_ffn(flat, x, top_k=3, dispatch=dispatch)
+        _, balance, z, load, _ = moe_ffn(flat, x, top_k=3,
+                                         dispatch=dispatch)
         assert float(balance) == pytest.approx(3.0, rel=1e-6)
         assert float(z) == pytest.approx(np.log(8.0) ** 2, rel=1e-6)
         assert np.asarray(load).tolist() == [48, 48, 48, 0, 0, 0, 0, 0]
