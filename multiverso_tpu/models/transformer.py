@@ -1142,7 +1142,7 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                         .dots_with_no_batch_dims_saveable,
                         jax.checkpoint_policies.save_only_these_names(
                             "flash_out", "flash_lse", "wcast", "kda_out",
-                            "kda_state", *GROUPED_SAVED)),
+                            "kda_state", "kda_solve", *GROUPED_SAVED)),
                     prevent_cse=not cfg.scan_layers)
             elif cfg.remat_policy == "full":
                 block = jax.checkpoint(block,

@@ -38,25 +38,36 @@ accumulation in float32; ``g``, the cumulative sums, every exponential, the
 solve and the carried state in float32.
 
 **Forward**: one Pallas kernel ``kda_fwd`` (scope and ``pallas_call(name=)``),
-grid ``(batch, head, chunk)`` with the chunk axis sequential and the state in
-VMEM scratch; it reads ``q``, ``k``, ``beta k``, ``beta v``, ``g`` in the
-model's ``[B, T, H * d]`` layout through its index maps (no transpose) and
-writes ``o`` and the state that ENTERED each chunk (float32 ``[B, H, chunks,
-d_v, d_k]``: 0.5 GiB a layer at 16,384 tokens and 32 heads of 128 x 128),
-which is all the backward keeps.  Off the TPU the same arithmetic runs as
-``jax.numpy`` (``_intra`` batched over the chunks, ``lax.scan`` over them), or
-the kernel in interpret mode under ``MVTPU_FORCE_FLASH`` (the flash kernels'
-switch; ``MVTPU_NO_FLASH`` keeps the kernel off a TPU too).
+grid ``(batch, head group, chunk)`` with the chunk axis sequential and the
+state in VMEM scratch; it reads ``q``, ``k``, ``beta k``, ``beta v``, ``g`` in
+the model's ``[B, T, H * d]`` layout through its index maps (no transpose) and
+writes ``o``, the state that ENTERED each chunk (float32 ``[B, H, chunks,
+d_v, d_k]``: 0.5 GiB a layer at 16,384 tokens and 32 heads of 128 x 128) and
+the chunk's solve ``T`` (float32 ``[B, H, chunks, C, C]``, 128 MiB there),
+which is all the backward keeps beside the inputs.  Off the TPU the same
+arithmetic runs as ``jax.numpy`` (``_intra`` batched over the chunks,
+``lax.scan`` over them), or the kernels in interpret mode under
+``MVTPU_FORCE_FLASH`` (the flash kernels' switch; ``MVTPU_NO_FLASH`` keeps
+the kernels off a TPU too).
 
-**Backward** (scope ``kda_bwd``): XLA's chunked form, not yet a kernel
-(ROADMAP Reach 10).  It rebuilds a chunk's own part (``_intra``), rebuilds
-``U`` from the kept states, walks the chunks backwards carrying the state's
-cotangent (``lax.scan``: five products a chunk), and pulls the cotangents of
-``_intra``'s outputs back through it by ``jax.vjp``; the solve's transpose is
-``-T^T dT T^T``.  It does so ``_BWD_HEADS`` heads at a time, so that its
-float32 temporaries are a group's and not the layer's.
+**Backward**: one Pallas kernel ``kda_bwd`` (scope and name) on the forward's
+grid and layout, the chunk axis REVERSED through the index maps.  A grid step
+rebuilds the chunk's own part around the kept ``T`` exactly as the forward
+built it (``_chunk_own``: the same roundings; the solve, half of a rebuild's
+products, is not run again), rebuilds ``U`` from the kept state, takes
+``_scan_bwd``'s step with the state's cotangent in float32 VMEM scratch
+(zeroed at the last chunk), and pulls the cotangents of ``W``, ``U0``,
+``Aqk`` and the decay factors back through the chunk's own part in place:
+the solve's transpose ``-T^T dT T^T`` in float32, the sub-blocks' products,
+every exponential's factor back to ``G``, and ``G``'s reverse cumulative sum
+back to ``g`` (one float32 product with the upper triangle).  ``dq``, ``dk``, ``dv`` leave in the inputs' dtype, ``dg`` and
+``dbeta`` (a lane reduction a token) in float32; nothing between two
+products touches HBM.  On the ``jnp`` path the same arithmetic is
+``_scan_bwd`` and ``jax.vjp(_intra)``, all heads at once: the tests' plain
+form.
 
-``kda`` counts a trace in ``attention.linear_traced{heads=,chunk=,path=}``.
+``kda`` counts a trace in ``attention.linear_traced{heads=,chunk=,path=}``,
+its backward one in ``attention.linear_bwd_traced`` under the same labels.
 """
 
 from __future__ import annotations
@@ -78,12 +89,8 @@ SUB = 16
 # The exponent of a masked column's factor is cut here: exp(80) is finite in
 # float32 and bfloat16, and no kept column's exponent passes 15 |g|.
 _CLAMP = 80.0
-# The backward runs this many heads at a time (``_kda_bwd``): its float32
-# temporaries are a group's, 1 GiB at 8 heads of 16,384 tokens where all 32
-# at once took 3.8 and the v5e compiler refused the cell's step.
-_BWD_HEADS = 4
-# The forward kernel's grid step runs this many heads' chunks.
-_FWD_HEADS = 4
+# A kernel's grid step runs this many heads' chunks.
+_HEADS = 4
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))          # x @ y^T
 _TN = (((0,), (0,)), ((), ()))          # x^T @ y
@@ -251,8 +258,46 @@ def _fwd_jnp(q, k, v, g, beta):
 
 
 # ---------------------------------------------------------------- the kernel
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, s_ref, state,
-                *, heads, dk, dv):
+def _mm(x, y, dims=(((1,), (0,)), ((), ())), precision=None):
+    return jax.lax.dot_general(x, y, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+_mm_f32 = functools.partial(_mm, precision=_HIGHEST)
+
+
+def _chunk_own(q, k, kb, vb, g, dt, t=None):
+    """What a chunk computes without the state, one head in a kernel (``q``,
+    ``k``, ``kb = beta k`` float32 ``[C, d_k]``, ``vb = beta v [C, d_v]`` in
+    ``dt``, ``g`` float32): ``_intra``'s arithmetic with the sub-blocks'
+    products unrolled.  Both kernels build it here, so the backward rebuilds
+    the forward's roundings exactly; the backward hands in the ``t`` the
+    forward kept, and neither ``A`` nor its inverse is built again."""
+    C, dk = q.shape
+    row, col = _masks(C)
+    G = _mm_f32((row >= col).astype(jnp.float32), g)          # cumulative
+    firsts = [G[i:i + 1] for i in range(0, C, SUB)]
+    rowf = jnp.exp(G - jnp.concatenate(
+        [jnp.broadcast_to(f, (SUB, dk)) for f in firsts], axis=0))
+    kr, qr = (kb * rowf).astype(dt), (q * rowf).astype(dt)
+    colf = [jnp.exp(jnp.minimum(first - G, _CLAMP)) for first in firsts]
+    kc = [(k * f).astype(dt) for f in colf]
+    blocks = [slice(i * SUB, (i + 1) * SUB) for i in range(C // SUB)]
+    aqk = jnp.where(row >= col, jnp.concatenate(
+        [_mm(qr[rows], c, _NT) for rows, c in zip(blocks, kc)], axis=0), 0.0)
+    if t is None:
+        t = _tri_inv_impl(jnp.where(row > col, jnp.concatenate(
+            [_mm(kr[rows], c, _NT) for rows, c in zip(blocks, kc)], axis=0),
+            0.0), _mm_f32)
+    eg = jnp.exp(G)
+    kbe = (kb * eg).astype(dt)
+    tb = t.astype(dt)
+    return dict(G=G, rowf=rowf, kr=kr, qr=qr, colf=colf, kc=kc, aqk=aqk,
+                t=t, tb=tb, eg=eg, kbe=kbe, w=_mm(tb, kbe), u0=_mm(tb, vb))
+
+
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, s_ref, t_ref,
+                state, *, heads, dk, dv):
     """One chunk of ``heads`` heads (their columns side by side in the
     blocks): the heads' chains of products are independent, so the
     scheduler fills one's latencies with another's."""
@@ -263,51 +308,39 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, s_ref, state,
     def _init():
         state[:] = jnp.zeros_like(state)
 
-    def mm(x, y, dims=(((1,), (0,)), ((), ())), precision=None):
-        return jax.lax.dot_general(x, y, dims, precision=precision,
-                                   preferred_element_type=f32)
-
-    mm32 = functools.partial(mm, precision=_HIGHEST)
-    row, col = _masks(C)
-    lower = (row >= col).astype(f32)
     for h in range(heads):
         at_k, at_v = slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
         q, k, kb = (r[0, :, at_k].astype(f32) for r in (q_ref, k_ref, kb_ref))
-        G = mm32(lower, g_ref[0, :, at_k])                    # cumulative
-        firsts = [G[i:i + 1] for i in range(0, C, SUB)]
-        rowf = jnp.exp(G - jnp.concatenate(
-            [jnp.broadcast_to(f, (SUB, dk)) for f in firsts], axis=0))
-        kr, qr = (kb * rowf).astype(dt), (q * rowf).astype(dt)
-        a, aqk = [], []
-        for i, first in enumerate(firsts):
-            kc = (k * jnp.exp(jnp.minimum(first - G, _CLAMP))).astype(dt)
-            rows = slice(i * SUB, (i + 1) * SUB)
-            a.append(mm(kr[rows], kc, _NT))
-            aqk.append(mm(qr[rows], kc, _NT))
-        aqk = jnp.where(row >= col, jnp.concatenate(aqk, axis=0), 0.0)
-        t = _tri_inv_impl(
-            jnp.where(row > col, jnp.concatenate(a, axis=0), 0.0),
-            mm32).astype(dt)
-        eg = jnp.exp(G)
-        w = mm(t, (kb * eg).astype(dt))
-        u0 = mm(t, vb_ref[0, :, at_v])
+        own = _chunk_own(q, k, kb, vb_ref[0, :, at_v], g_ref[0, :, at_k], dt)
+        G, eg = own["G"], own["eg"]
+        t_ref[0, h, 0] = own["t"]
         s = state[h]                                          # [d_v, d_k]
         s_ref[0, h, 0] = s
         sb = s.astype(dt)
-        u = (u0 - mm(w.astype(dt), sb, _NT)).astype(dt)
-        o_ref[0, :, at_v] = (mm((q * eg).astype(dt), sb, _NT)
-                             + mm(aqk.astype(dt), u)).astype(o_ref.dtype)
+        u = (own["u0"] - _mm(own["w"].astype(dt), sb, _NT)).astype(dt)
+        o_ref[0, :, at_v] = (_mm((q * eg).astype(dt), sb, _NT)
+                             + _mm(own["aqk"].astype(dt), u)
+                             ).astype(o_ref.dtype)
         last = G[C - 1:C]
-        state[h] = s * jnp.exp(last) + mm(
+        state[h] = s * jnp.exp(last) + _mm(
             u, (k * jnp.exp(last - G)).astype(dt), _TN)
 
 
+def _a_chunk(hb, rows, cols, at):
+    """The block of a ``[B, H, chunks, rows, cols]`` array that holds chunk
+    ``at(c)`` of a grid step's ``hb`` heads."""
+    return pl.BlockSpec((1, hb, 1, rows, cols),
+                        lambda b, h, c: (b, h, at(c), 0, 0))
+
+
 def _fwd_kernel_call(q, k, v, g, beta, interpret):
+    """``(o, (states, solves))``: what entered each chunk and each chunk's
+    ``T``, float32 ``[B, H, chunks, d_v, d_k]`` and ``[..., C, C]``."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     n = T // CHUNK
     f32 = jnp.float32
-    hb = math.gcd(H, _FWD_HEADS)
+    hb = math.gcd(H, _HEADS)
     bf = beta.astype(f32)[..., None]
     kb = (bf * k.astype(f32)).astype(k.dtype)
     vb = (bf * v.astype(f32)).astype(v.dtype)
@@ -319,19 +352,162 @@ def _fwd_kernel_call(q, k, v, g, beta, interpret):
         functools.partial(_fwd_kernel, heads=hb, dk=dk, dv=dv),
         name="kda_fwd", grid=(B, H // hb, n),
         in_specs=[spec(dk), spec(dk), spec(dk), spec(dv), spec(dk)],
-        out_specs=[spec(dv),
-                   pl.BlockSpec((1, hb, 1, dv, dk),
-                                lambda b, h, c: (b, h, c, 0, 0))],
+        out_specs=[spec(dv), _a_chunk(hb, dv, dk, lambda c: c),
+                   _a_chunk(hb, CHUNK, CHUNK, lambda c: c)],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * dv), v.dtype),
-                   jax.ShapeDtypeStruct((B, H, n, dv, dk), f32)],
+                   jax.ShapeDtypeStruct((B, H, n, dv, dk), f32),
+                   jax.ShapeDtypeStruct((B, H, n, CHUNK, CHUNK), f32)],
         scratch_shapes=[pltpu.VMEM((hb, dv, dk), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret)
     flat = lambda x: x.reshape(B, T, H * x.shape[-1])
-    o, states = call(flat(q), flat(k), flat(kb), flat(vb),
-                     flat(g.astype(f32)))
-    return o.reshape(B, T, H, dv), jnp.moveaxis(states, 2, 0)
+    o, states, solves = call(flat(q), flat(k), flat(kb), flat(vb),
+                             flat(g.astype(f32)))
+    return o.reshape(B, T, H, dv), (states, solves)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, s_ref, t_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, d_state,
+                *, heads, dk, dv):
+    """One chunk of ``heads`` heads, the chunks LAST TO FIRST (the index
+    maps reverse the axis): rebuild the chunk's own part as the forward
+    built it around the ``T`` it kept, ``_scan_bwd``'s step with the state's
+    cotangent in ``d_state`` (float32 VMEM), then the pull back through the
+    chunk's own part that ``jax.vjp(_intra)`` is on the ``jnp`` path.
+    Cotangents stay float32 between products; a product's operands are the
+    inputs' dtype, and two products that share an operand are one, their
+    other operands side by side."""
+    f32, dt = jnp.float32, q_ref.dtype
+    C = q_ref.shape[1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        d_state[:] = jnp.zeros_like(d_state)
+
+    row, col = _masks(C)
+    row_k = jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+    # G's cotangent and the sub-blocks' first rows' back to g in one product:
+    # [upper | upper at a column's sub-block's first row] [C, 2 C]
+    wide = jax.lax.broadcasted_iota(jnp.int32, (C, 2 * C), 1)
+    row2 = jax.lax.broadcasted_iota(jnp.int32, (C, 2 * C), 0)
+    back = (row2 <= jnp.where(wide < C, wide, (wide - C) // SUB * SUB)
+            ).astype(f32)
+    betas = beta_ref[0, 0]                                    # [C, heads]
+    lane = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+    d_betas = jnp.zeros(betas.shape, f32)
+    for h in range(heads):
+        at_k, at_v = slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
+        q, k = (r[0, :, at_k].astype(f32) for r in (q_ref, k_ref))
+        v = v_ref[0, :, at_v].astype(f32)
+        beta = betas[:, h:h + 1]                              # [C, 1]
+        kb = (beta * k).astype(dt).astype(f32)                # as the forward
+        vb = (beta * v).astype(dt)
+        own = _chunk_own(q, k, kb, vb, g_ref[0, :, at_k], dt, t_ref[0, h, 0])
+        G, rowf, eg, t = own["G"], own["rowf"], own["eg"], own["t"]
+        s = s_ref[0, h, 0]                                    # [d_v, d_k]
+        sb, wb = s.astype(dt), own["w"].astype(dt)
+        u = (own["u0"] - _mm(wb, sb, _NT)).astype(dt)
+        qe = (q * eg).astype(dt)
+        last = G[C - 1:C]
+        ec, x = jnp.exp(last), jnp.exp(last - G)
+        kd = (k * x).astype(dt)
+        d_o = do_ref[0, :, at_v].astype(dt)
+        # ---- the scan's step (``_scan_bwd``)
+        d_s = d_state[h]
+        d_sb = d_s.astype(dt)
+        d_u = (_mm(own["aqk"].astype(dt), d_o, _TN)
+               + _mm(kd, d_sb, _NT)).astype(dt)
+        d_kd = _mm(u, d_sb).astype(dt).astype(f32)
+        d_last = jnp.sum(s * d_s, axis=0, keepdims=True) * ec
+        d_state[h] = d_s * ec + _mm(jnp.concatenate([d_o, d_u], axis=0),
+                                    jnp.concatenate([qe, -wb], axis=0), _TN)
+        d_wq = _mm(jnp.concatenate([d_u, d_o], axis=0), sb)   # [2 C, d_k]
+        d_w, d_qe = (-d_wq[:C]).astype(dt), d_wq[C:].astype(dt).astype(f32)
+        d_aqk = jnp.where(row >= col, _mm(d_o, u, _NT), 0.0)
+        # ---- back through W = T (beta k exp G), U0 = T (beta v), the solve
+        d_wu = jnp.concatenate([d_w, d_u], axis=1)            # [C, d_k + d_v]
+        d_t = _mm(d_wu, jnp.concatenate([own["kbe"], vb], axis=1), _NT)
+        d_kv = _mm(own["tb"], d_wu, _TN)
+        d_kbe, d_vb = d_kv[:, :dk], d_kv[:, dk:]
+        d_a = jnp.where(row > col,
+                        -_mm_f32(_mm_f32(t, d_t, _TN), t, _NT), 0.0)
+        # ---- back through the sub-blocks' products
+        d_kqr, d_k, d_G = [], 0.0, 0.0
+        for i in range(C // SUB):
+            rows = slice(i * SUB, (i + 1) * SUB)
+            both = jnp.concatenate([d_a[rows], d_aqk[rows]], axis=0
+                                   ).astype(dt)               # [2 SUB, C]
+            d_kqr.append(_mm(both, own["kc"][i]))
+            d_kc = _mm(both, jnp.concatenate(
+                [own["kr"][rows], own["qr"][rows]], axis=0), _TN)
+            colf = own["colf"][i]
+            d_k = d_k + d_kc * colf
+            d_e = jnp.where(G[i * SUB:i * SUB + 1] - G < _CLAMP,
+                            d_kc * k * colf, 0.0)
+            d_G = d_G - d_e + jnp.where(
+                row_k == i * SUB, jnp.sum(d_e, axis=0, keepdims=True), 0.0)
+        d_kr, d_qr = (jnp.concatenate([x[part] for x in d_kqr], axis=0)
+                      for part in (slice(0, SUB), slice(SUB, 2 * SUB)))
+        # ---- every exponential's factor back to G, G back to g
+        d_kb = d_kr * rowf + d_kbe * eg
+        d_d = (d_kr * kb + d_qr * q) * rowf                   # of G - firsts
+        d_x = d_kd * k * x
+        d_G = d_G + d_d + (d_kbe * kb + d_qe * q) * eg - d_x + jnp.where(
+            row_k == C - 1, jnp.sum(d_x, axis=0, keepdims=True) + d_last,
+            0.0)
+        dg_ref[0, :, at_k] = _mm_f32(
+            back, jnp.concatenate([d_G, -d_d], axis=0))
+        dq_ref[0, :, at_k] = (d_qr * rowf + d_qe * eg).astype(dq_ref.dtype)
+        dk_ref[0, :, at_k] = (d_k + d_kd * x + beta * d_kb
+                              ).astype(dk_ref.dtype)
+        dv_ref[0, :, at_v] = (beta * d_vb).astype(dv_ref.dtype)
+        d_betas = jnp.where(
+            lane == h, jnp.sum(d_kb * k, axis=1, keepdims=True)
+            + jnp.sum(d_vb * v, axis=1, keepdims=True), d_betas)
+    dbeta_ref[0, 0] = d_betas
+
+
+def _bwd_kernel_call(q, k, v, g, beta, kept, d_o, interpret):
+    """``(dq, dk, dv, dg, dbeta)`` from the inputs, what the forward kernel
+    kept (``_fwd_kernel_call``) and ``d_o``."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    n = T // CHUNK
+    f32 = jnp.float32
+    hb = math.gcd(H, _HEADS)
+
+    def spec(width):
+        return pl.BlockSpec((1, CHUNK, hb * width),
+                            lambda b, h, c: (b, n - 1 - c, h))
+
+    # beta and its gradient a head group apart, [B, H / hb, T, hb]: a block's
+    # last dimension is the array's own
+    a_token = pl.BlockSpec((1, 1, CHUNK, hb),
+                           lambda b, h, c: (b, h, n - 1 - c, 0))
+    call = pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=hb, dk=dk, dv=dv),
+        name="kda_bwd", grid=(B, H // hb, n),
+        in_specs=[spec(dk), spec(dk), spec(dv), spec(dk), a_token, spec(dv),
+                  _a_chunk(hb, dv, dk, lambda c: n - 1 - c),
+                  _a_chunk(hb, CHUNK, CHUNK, lambda c: n - 1 - c)],
+        out_specs=[spec(dk), spec(dk), spec(dv), spec(dk), a_token],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * dk), q.dtype),
+                   jax.ShapeDtypeStruct((B, T, H * dk), k.dtype),
+                   jax.ShapeDtypeStruct((B, T, H * dv), v.dtype),
+                   jax.ShapeDtypeStruct((B, T, H * dk), f32),
+                   jax.ShapeDtypeStruct((B, H // hb, T, hb), f32)],
+        scratch_shapes=[pltpu.VMEM((hb, dv, dk), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret)
+    flat = lambda x: x.reshape(B, T, H * x.shape[-1])
+    grouped = lambda x: jnp.moveaxis(x.reshape(B, T, H // hb, hb), 2, 1)
+    dq, d_k, d_v, dg, dbeta = call(
+        flat(q), flat(k), flat(v), flat(g.astype(f32)),
+        grouped(beta.astype(f32)), flat(d_o), *kept)
+    return (dq.reshape(q.shape), d_k.reshape(k.shape), d_v.reshape(v.shape),
+            dg.reshape(g.shape), jnp.moveaxis(dbeta, 1, 2).reshape(B, T, H))
 
 
 # ------------------------------------------------------------ the custom_vjp
@@ -346,9 +522,12 @@ def _path() -> str:
 
 
 def _forward(q, k, v, g, beta, path):
+    """``(o, what the backward keeps)``: the states, and from a kernel the
+    solves beside them."""
     with jax.named_scope("kda_fwd"):
         if path == "jnp":
-            return _fwd_jnp(q, k, v, g, beta)
+            o, states = _fwd_jnp(q, k, v, g, beta)
+            return o, (states,)
         return _fwd_kernel_call(q, k, v, g, beta, path == "interpret")
 
 
@@ -359,39 +538,32 @@ def _kda(q, k, v, g, beta, path):
 
 def _kda_fwd(q, k, v, g, beta, path):
     # Named so that remat policy "dots" keeps them (``transformer.py``): with
-    # o and the states saved the backward does not run the forward again.
-    o, states = _forward(q, k, v, g, beta, path)
-    o, states = checkpoint_name(o, "kda_out"), checkpoint_name(states,
-                                                               "kda_state")
-    return o, (q, k, v, g, beta, states)
+    # o and these saved the backward does not run the forward again.
+    o, kept = _forward(q, k, v, g, beta, path)
+    kept = tuple(checkpoint_name(x, name)
+                 for x, name in zip(kept, ("kda_state", "kda_solve")))
+    return checkpoint_name(o, "kda_out"), (q, k, v, g, beta, kept)
 
 
 def _kda_bwd(path, res, d_o):
-    q, k, v, g, beta, states = res
-    H, n = q.shape[2], states.shape[0]
-    hg = math.gcd(H, _BWD_HEADS)
+    from .. import metrics
 
-    def heads(i, x, axis=2):
-        return jax.lax.dynamic_slice_in_dim(x, i * hg, hg, axis)
-
-    def group(i, grads):
-        """The gradients of heads ``[i hg, (i + 1) hg)``, written into
-        their places: the backward's temporaries are one group's."""
-        with jax.named_scope("kda_bwd"):
-            parts, pull = jax.vjp(_intra, *(_chunked(heads(i, x), n)
-                                            for x in (q, k, v, g, beta)))
-            got = pull(_scan_bwd(parts, heads(i, states),
-                                 _chunked(heads(i, d_o), n)))
-            got = [_unchunked(x) for x in got[:4]] + [
-                _unchunked(got[4][..., None])[..., 0]]
-        return tuple(jax.lax.dynamic_update_slice_in_dim(
-            full, x.astype(full.dtype), i * hg, 2)
-            for full, x in zip(grads, got))
-
+    q, k, v, g, beta, kept = res
+    metrics.counter("attention.linear_bwd_traced",
+                    {"heads": str(q.shape[2]), "chunk": str(CHUNK),
+                     "path": path}).inc()
     with jax.named_scope("kda_bwd"):
-        return jax.lax.fori_loop(
-            0, H // hg, group,
-            tuple(jnp.zeros(x.shape, x.dtype) for x in (q, k, v, g, beta)))
+        if path != "jnp":
+            return _bwd_kernel_call(q, k, v, g, beta, kept, d_o,
+                                    path == "interpret")
+        (states,), n = kept, kept[0].shape[0]
+        parts, pull = jax.vjp(_intra, *(_chunked(x, n)
+                                        for x in (q, k, v, g, beta)))
+        got = pull(_scan_bwd(parts, states, _chunked(d_o, n)))
+        return tuple(
+            _unchunked(x if x.ndim == 5 else x[..., None]).reshape(
+                like.shape).astype(like.dtype)
+            for x, like in zip(got, (q, k, v, g, beta)))
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)

@@ -117,15 +117,20 @@ def _scan_inputs(seed, B, T, H, dk, dv):
                  for x in (q, k, r.randn(B, T, H, dv), g, beta))
 
 
+def _take(monkeypatch, path):
+    """Make ``kda`` take ``path``: the kernels interpreted, or ``jax.numpy``."""
+    if path == "interpret":
+        monkeypatch.setenv("MVTPU_FORCE_FLASH", "1")
+    else:
+        monkeypatch.delenv("MVTPU_FORCE_FLASH", raising=False)
+
+
 @pytest.mark.parametrize("path", ["jnp", "interpret"])
 def test_kda_matches_the_recurrence_token_by_token(path, monkeypatch):
     """Outputs and every input's gradient, at a length that no sub-block
     divides (200 = 3 chunks + 8 tokens: padded), with decays from e^-5 to
     1 - 1e-6 in one chunk: the quotient trick's worst case."""
-    if path == "interpret":
-        monkeypatch.setenv("MVTPU_FORCE_FLASH", "1")
-    else:
-        monkeypatch.delenv("MVTPU_FORCE_FLASH", raising=False)
+    _take(monkeypatch, path)
     args = _scan_inputs(0, 2, 200, 2, 32, 16)
     assert float(args[3].min()) < -4.99 and float(args[3].max()) > -1e-3
     weight = jnp.asarray(np.random.RandomState(1).randn(2, 200, 2, 16),
@@ -157,13 +162,140 @@ def test_kda_carries_the_state_across_chunks_and_refuses_wrong_shapes():
         kda_ops.kda(q, k, v, g, beta[..., None])
 
 
-def test_kda_counts_its_trace():
-    counter = metrics.counter("attention.linear_traced",
-                              {"heads": "2", "chunk": str(kda_ops.CHUNK),
-                               "path": "jnp"})
-    before = counter.value
-    jax.eval_shape(kda_ops.kda, *_scan_inputs(0, 1, 64, 2, 16, 16))
-    assert counter.value == before + 1
+def _kda_grads(monkeypatch, path, args, d_o):
+    """The five gradients of ``sum(kda(...) * d_o)`` on ``path``."""
+    _take(monkeypatch, path)
+
+    def grads(*args):                  # traced anew a call: the path's own
+        o, pull = jax.vjp(kda_ops.kda, *args)
+        return pull(d_o.astype(o.dtype))
+
+    return jax.jit(grads)(*args)
+
+
+@pytest.mark.parametrize("dtype,shape", [
+    ("float32", (1, 64, 1, 16, 16)),             # one chunk, one head
+    ("float32", (2, 200, 1, 16, 16)),            # three chunks + a padded tail
+    ("float32", (1, 200, 2 * kda_ops._HEADS, 16, 16)),   # two grid groups
+    ("float32", (1, 200, 2, 32, 16)),            # d_k != d_v
+    ("bfloat16", (1, 64, 1, 16, 16)),
+    ("bfloat16", (1, 200, 2 * kda_ops._HEADS, 16, 16)),
+    ("bfloat16", (2, 200, 2, 32, 16)),
+])
+def test_the_kernel_backward_gives_the_jnp_paths_gradients(dtype, shape,
+                                                           monkeypatch):
+    """``kda_bwd`` (interpreted) against ``_scan_bwd`` + ``jax.vjp(_intra)``:
+    in float32 the same arithmetic in another order; in bfloat16 the kernel
+    also rounds a cotangent where it is a product's operand, as the TPU's
+    default precision does to XLA's form (``g``'s gradient hangs on the
+    most of them)."""
+    q, k, v, g, beta = _scan_inputs(7, *shape)
+    args = (*(x.astype(dtype) for x in (q, k, v)), g, beta)
+    d_o = jnp.asarray(np.random.RandomState(8).randn(*v.shape), jnp.float32)
+    got = _kda_grads(monkeypatch, "interpret", args, d_o)
+    want = _kda_grads(monkeypatch, "jnp", args, d_o)
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        rel = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        bound = 2e-5 if dtype == "float32" else (
+            3e-2 if name == "g" else 8e-3)
+        assert rel < bound, (name, rel)
+
+
+def test_the_kernel_backward_carries_the_states_cotangent(monkeypatch):
+    """A gradient at token 3 hangs on ``d_o`` at tokens 150-159, two chunks
+    on (the ``d_s`` in VMEM is what is tested), and nothing after them has
+    a gradient: the mirror of the forward's test above."""
+    q, k, v, g, beta = _scan_inputs(3, 1, 192, 1, 16, 16)
+    g = g * 0.01                                  # a long memory
+    d_o = jnp.zeros(v.shape).at[:, 150:160].set(1.0)
+    grads = _kda_grads(monkeypatch, "interpret", (q, k, v, g, beta), d_o)
+    for name, d in zip(("q", "k", "v", "g", "beta"), grads):
+        if name != "q":                           # q_t meets d_o_t alone
+            assert float(jnp.max(jnp.abs(d[:, 3]))) > 1e-6, name
+        assert float(jnp.max(jnp.abs(d[:, 160:]))) == 0.0, name
+    assert float(jnp.max(jnp.abs(grads[0][:, :150]))) == 0.0
+
+
+@pytest.mark.parametrize("path", ["jnp", "interpret"])
+def test_kda_counts_its_traces(path, monkeypatch):
+    """One ``attention.linear_traced`` a trace of ``kda`` and one
+    ``attention.linear_bwd_traced`` a trace of its backward, under the
+    labels the accepted runner reads."""
+    _take(monkeypatch, path)
+    labels = {"heads": "2", "chunk": str(kda_ops.CHUNK), "path": path}
+    fwd = metrics.counter("attention.linear_traced", labels)
+    bwd = metrics.counter("attention.linear_bwd_traced", labels)
+    before = fwd.value, bwd.value
+    args = _scan_inputs(0, 1, 64, 2, 16, 16)
+    jax.eval_shape(lambda *a: kda_ops.kda(*a), *args)    # no cached trace
+    assert (fwd.value, bwd.value) == (before[0] + 1, before[1])
+    jax.eval_shape(jax.grad(lambda *a: jnp.sum(kda_ops.kda(*a)),
+                            argnums=range(5)), *args)
+    assert (fwd.value, bwd.value) == (before[0] + 2, before[1] + 1)
+    for s in metrics.REGISTRY.series():
+        if s.name in ("attention.linear_traced",
+                      "attention.linear_bwd_traced"):
+            assert sorted(s.labels) == ["chunk", "heads", "path"], s.labels
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("name", ["kda_fwd", "kda_bwd"])
+def test_the_kernels_hold_the_stated_precision(name):
+    """Read off the kernel's own jaxpr on bfloat16 inputs: every exponential
+    is float32 in and out and meets nothing but a float32 product (a decay
+    rounded to bfloat16 would be a convert there, and no check of the
+    numbers tells that apart: ``PERF.md`` section 7), a float32 product is
+    at "highest" (the cumulative sums, the solve and its transpose), every
+    other product has the inputs' dtype, the carried state or its cotangent
+    is float32 VMEM, and ``dg`` and ``dbeta`` leave in float32."""
+    q, k, v, g, beta = _scan_inputs(0, 1, 128, 2, 16, 16)
+    args = (*(x.astype(jnp.bfloat16) for x in (q, k, v)), g, beta)
+    traced = jax.make_jaxpr(lambda *a: jax.vjp(
+        lambda *b: kda_ops._kda(*b, "interpret"), *a)[1](a[2]))(*args)
+    (call,) = [e for e in _eqns(traced.jaxpr)
+               if e.primitive.name == "pallas_call"
+               and e.params["name"] == name]
+    body = call.params["jaxpr"]
+    eqns = list(_eqns(body))
+    users = {}
+    for eqn in eqns:
+        for var in eqn.invars:
+            if not hasattr(var, "val"):             # not a literal
+                users.setdefault(var, []).append(eqn)
+    exps = [e for e in eqns if e.primitive.name == "exp"]
+    assert len(exps) >= 8
+    for eqn in exps:
+        assert eqn.invars[0].aval.dtype == jnp.float32
+        assert {u.primitive.name for u in users[eqn.outvars[0]]} == {"mul"}
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    for eqn in dots:
+        kinds = {var.aval.dtype for var in eqn.invars}
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+        if kinds == {jnp.dtype(jnp.float32)}:
+            assert "HIGHEST" in str(eqn.params["precision"])
+        else:
+            assert kinds == {jnp.dtype(jnp.bfloat16)}
+    assert any(var.aval.dtype == jnp.float32 for e in dots
+               for var in e.invars)
+    carried = body.invars[-1].aval
+    assert "vmem" in str(carried) and carried.dtype == jnp.float32
+    if name == "kda_bwd":
+        dq, dk, dv, dg, dbeta = call.outvars
+        assert [x.aval.dtype for x in (dq, dk, dv)] == [jnp.bfloat16] * 3
+        assert [x.aval.dtype for x in (dg, dbeta)] == [jnp.float32] * 2
 
 
 def test_tri_inv_is_the_inverse_at_its_worst_case():
@@ -623,8 +755,9 @@ def one_v5e():
 
 
 def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
-    """Mosaic takes ``kda_fwd`` at 1 x 16,384 x 32 heads of 128 x 128 in
-    bfloat16, and XLA the chunked backward beside it.  Nothing runs: no
+    """Mosaic takes ``kda_fwd`` and ``kda_bwd`` at 1 x 16,384 x 32 heads of
+    128 x 128 in bfloat16, and nothing of XLA's chunked backward is left
+    beside them (no loop under the ``kda_bwd`` scope).  Nothing runs: no
     measurement."""
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -648,8 +781,10 @@ def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    assert text.count("tpu_custom_call") >= 1 and "kda_fwd" in text
-    assert "kda_bwd" in text
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    for name in ("kda_fwd", "kda_bwd"):
+        assert any(f"/{name}" in line for line in calls), (name, calls)
+    assert " while(" not in text and "dynamic-update-slice" not in text
 
 
 # ------------------------------------------------------ the cell, rehearsed
