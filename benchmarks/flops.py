@@ -4,14 +4,13 @@ The benchmark's yardstick for utilization and roofline shares: what the
 forward and backward passes *require*, so recomputation (remat, the flash
 backward rebuilding its scores) costs time and counts nothing.  ``model`` is
 a configuration file's ``model`` group (``TransformerConfig`` field names).
-The dense count is ``bench.py:_transformer_train_flops``'s, copied; the
-original stays for the host gate and is listed in ``PERF.md`` for deletion.
 """
 
 from __future__ import annotations
 
 __all__ = ["matmul_params", "causal_attention_flops", "lm_train_flops",
-           "flash_kernel_bytes", "roofline_seconds", "sgns_step_bytes"]
+           "flash_backward_bytes", "flash_kernel_bytes", "roofline_seconds",
+           "sgns_step_bytes"]
 
 
 def matmul_params(model: dict) -> int:
@@ -47,14 +46,24 @@ def lm_train_flops(model: dict, batch: int, seq: int) -> float:
                 batch, model["n_heads"], seq, head_dim))
 
 
+def flash_backward_bytes(batch: int, heads: int, seq: int, head_dim: int,
+                         dtype_bytes: int = 2) -> float:
+    """Least HBM traffic of flash attention's backward, whatever calls it is
+    made of: it reads q, k, v, o, do and the f32 row statistics and writes
+    dq, dk, dv, each once."""
+    tensor = batch * heads * seq * head_dim * dtype_bytes
+    return 8 * tensor + batch * heads * seq * 4
+
+
 def flash_kernel_bytes(batch: int, heads: int, seq: int, head_dim: int,
                        dtype_bytes: int = 2) -> float:
     """Least HBM traffic of flash attention forward and backward: the
     forward reads q, k, v and writes o and the f32 row statistics; the
-    backward reads q, k, v, o, do and the statistics and writes dq, dk, dv."""
+    backward is ``flash_backward_bytes``."""
     tensor = batch * heads * seq * head_dim * dtype_bytes
     stats = batch * heads * seq * 4
-    return (4 * tensor + stats) + (8 * tensor + stats)
+    return (4 * tensor + stats) + flash_backward_bytes(
+        batch, heads, seq, head_dim, dtype_bytes)
 
 
 def roofline_seconds(flops: float, bytes_moved: float, peaks: dict):

@@ -95,10 +95,14 @@ def attention_flops(model: dict, batch: int, seq: int, attn: str,
 
 def flash_kernel_bytes(model: dict, batch: int, seq: int, attn: str,
                        dtype_bytes: int = 2) -> dict:
-    """Least HBM traffic of each of the three flash kernels, all layers of
-    kind ``attn``: ``{"fwd", "dq", "dkv"}``.  Tensors of the query heads (q,
-    o, do, dq) and of the K/V heads (k, v, dk, dv) each move once; the f32
-    row statistics are one number a query head and position."""
+    """Least HBM traffic of flash attention's passes, all layers of kind
+    ``attn``: ``{"fwd", "bwd"}`` are what a pass requires whatever calls it
+    is made of (``bwd``: q, k, v, o, do and the statistics in, dq, dk, dv
+    out); ``{"dq", "dkv"}`` are the split backward kernels' own (each reads
+    the operands again), kept for what still quotes them.  Tensors of the
+    query heads (q, o, do, dq) and of the K/V heads (k, v, dk, dv) each move
+    once; the f32 row statistics are one number a query head and
+    position."""
     hd = _head_dim(model)
     q = kv = stats = 0
     for k in kinds(model):
@@ -108,6 +112,7 @@ def flash_kernel_bytes(model: dict, batch: int, seq: int, attn: str,
                    * dtype_bytes)
             stats += batch * k.heads * seq * 4
     return {"fwd": 2 * q + 2 * kv + stats,          # q k v -> o, lse
+            "bwd": 4 * q + 4 * kv + stats,          # + o do -> dq dk dv
             "dq": 3 * q + 2 * kv + 2 * stats,       # q k v do lse delta -> dq
             "dkv": 2 * q + 4 * kv + 2 * stats}      # ... -> dk dv
 

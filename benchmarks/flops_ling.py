@@ -96,8 +96,8 @@ def token_matmul_params(model: dict) -> int:
 
 def _latent_only(model: dict) -> dict:
     """The latent layers alone, as ``flops_xing.py`` counts blocks: its
-    attention arithmetic (exact causal pairs, 640 / 1,024 / 1,280 FLOPs a
-    pair and head in the three kernels, their least bytes) holds for them."""
+    attention arithmetic (exact causal pairs, 640 FLOPs a pair and head
+    forward and 1,280 backward, their least bytes) holds for them."""
     n = _count(model, LATENT)
     return dict(model, n_layers=n, mlp_layer_types=["dense"] * n,
                 mtp_layers=0)
@@ -110,13 +110,13 @@ def attention_flops(model: dict, batch: int, seq: int) -> float:
 
 
 def mla_kernel_flops(model: dict, batch: int, seq: int) -> Dict[str, float]:
-    """What each of the three ``flash_mla_*`` kernels multiplies a step over
-    the latent layers (one, here), the rebuilt scores included."""
+    """What latent attention's passes require a step over the latent layers
+    (one, here): ``flops_xing.mla_kernel_flops``."""
     return flops_xing.mla_kernel_flops(_latent_only(model), batch, seq)
 
 
 def mla_kernel_bytes(model: dict, batch: int, seq: int) -> Dict[str, float]:
-    """Least HBM traffic of each of those kernels over the latent layers."""
+    """Least HBM traffic of those passes over the latent layers."""
     return flops_xing.mla_kernel_bytes(_latent_only(model), batch, seq)
 
 

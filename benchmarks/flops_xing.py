@@ -25,9 +25,11 @@ The prediction module is one more block of the last layer's kind (its
 attention over the same pairs: it runs on all T positions), a ``2d x d``
 projection and a second application of the head.  Recompute (remat, the
 scores a backward kernel rebuilds) costs time and counts nothing in
-``train_flops``; a kernel's own roofline (``mla_kernel_flops``) counts what
-that kernel has to multiply, the rebuilt scores included: forward 640, dq
-1,024, dkv 1,280 FLOPs a pair and head at 192 / 128.
+``train_flops``, and nothing in a pass's roofline either
+(``mla_kernel_flops``: forward 640, backward 1,280 FLOPs a pair and head at
+192 / 128: dP 256 + dQ 384 + dV 256 + dK 384).  What the split backward
+kernels themselves multiply, the scores each rebuilds included, is 1,024
+(dq) and 1,280 (dkv); no metric reads those since PR 39.
 """
 
 from __future__ import annotations
@@ -92,21 +94,24 @@ def attention_flops(model: dict, batch: int, seq: int) -> float:
 
 
 def mla_kernel_flops(model: dict, batch: int, seq: int) -> Dict[str, float]:
-    """What each of the three ``flash_mla_*`` kernels multiplies a step, every
-    block: ``{"fwd", "dq", "dkv"}``.  qk = 2 (d_n + d_r), v = 2 d_v a pair
-    and head: forward scores + values; dq scores again + dP + dQ; dkv scores
-    again + dV + dP + dK."""
+    """What latent attention's passes require a step, every block: ``{"fwd",
+    "bwd"}``; qk = 2 (d_n + d_r), v = 2 d_v a pair and head: forward scores +
+    values, backward dP + dV at ``d_v`` and dQ + dK at ``d_n + d_r``, whatever
+    calls the backward is made of.  ``{"dq", "dkv"}`` are what each split
+    backward kernel multiplies (dq: scores again + dP + dQ; dkv: scores again
+    + dV + dP + dK), kept for what still quotes them."""
     qk = 2 * (model["qk_nope_dim"] + model["qk_rope_dim"])
     v = 2 * model["v_head_dim"]
     pairs = (float(batch) * model["n_heads"] * causal_pairs(seq)
              * blocks(model))
-    return {"fwd": (qk + v) * pairs, "dq": (2 * qk + v) * pairs,
-            "dkv": (2 * qk + 2 * v) * pairs}
+    return {"fwd": (qk + v) * pairs, "bwd": (2 * qk + 2 * v) * pairs,
+            "dq": (2 * qk + v) * pairs, "dkv": (2 * qk + 2 * v) * pairs}
 
 
 def mla_kernel_bytes(model: dict, batch: int, seq: int,
                      dtype_bytes: int = 2) -> Dict[str, float]:
-    """Least HBM traffic of each kernel, every block: every head's q (d_n +
+    """Least HBM traffic of each pass (``fwd``, ``bwd``) and of each split
+    backward kernel (``dq``, ``dkv``), every block: every head's q (d_n +
     d_r), k_n, v, o / do, the one rotated key head, the float32 row
     statistics (one number a head and position) move once."""
     H = model["n_heads"]
@@ -117,6 +122,8 @@ def mla_kernel_bytes(model: dict, batch: int, seq: int,
     kr = rows * dr * dtype_bytes
     stats = rows * H * 4
     one = {"fwd": q + kn + kr + v + v + stats,               # -> o, lse
+           # q k_n k_r v o do lse -> dq dk_n dk_r dv
+           "bwd": 2 * (q + kn + kr + v) + v + v + stats,
            "dq": q + kn + kr + v + v + 2 * stats + q,        # + do -> dq
            "dkv": q + kn + kr + v + v + 2 * stats + kn + kr + v}
     return {k: float(b) * blocks(model) for k, b in one.items()}

@@ -1,9 +1,9 @@
 """Traffic for ``sgns_train``: a Zipf-distributed token stream folded
 modulo the vocabulary, cut into chunks of a fixed number of tokens.
 
-A copy of ``multiverso_tpu/apps/word2vec.py:synthetic_corpus`` (the app's
-own stand-in for text8), kept here so that a later change to the program
-cannot move the traffic; the original is listed in PERF.md for deletion."""
+Began as a copy of ``multiverso_tpu/apps/word2vec.py:synthetic_corpus``
+(which the app keeps as its own stand-in for text8) and is the benchmark's
+own since: a later change to the program cannot move the traffic."""
 
 from __future__ import annotations
 

@@ -33,7 +33,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 
 __all__ = ["Cell", "Runtime", "Measured", "Reading", "load_cell",
-           "load_module", "layer_readers", "run_cell", "main", "REPO", "HERE"]
+           "load_module", "layer_readers", "reader_applies", "compared",
+           "run_cell", "main", "REPO", "HERE"]
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -121,6 +122,24 @@ def layer_readers(search) -> Dict[str, Any]:
     return readers
 
 
+def reader_applies(applies: dict, config: dict, chips: int) -> bool:
+    """Whether a reader's ``APPLIES`` holds for a cell, by the cell's data
+    alone and never by its name: ``runner`` (the configuration's runner, one
+    name or several), ``min_chips``, and ``model`` (keys of the
+    configuration's ``model`` group that have to be set, ``True``, or left
+    out or falsy, ``False``).  Among the cells that report the end-to-end
+    metric the reader's metric moves, these are its ``workloads``
+    (``tests/test_contract.py`` holds every entry to that)."""
+    runners = applies.get("runner", config.get("runner"))
+    if isinstance(runners, str):
+        runners = (runners,)
+    model = config.get("model", {})
+    return (config.get("runner") in runners
+            and chips >= applies.get("min_chips", 1)
+            and all(bool(model.get(key)) == want
+                    for key, want in applies.get("model", {}).items()))
+
+
 # ----------------------------------------------------------------- runtime
 class CompileWatch:
     """Counts what JAX compiles or fetches from its cache, by listening to
@@ -194,6 +213,8 @@ class Measured:
     compiled_peak_bytes: int                     # the step's compiler account
     facts: Dict[str, Any] = field(default_factory=dict)
     hlo_texts: List[str] = field(default_factory=list)
+    # what the reference check compared: name -> (reading, its limit)
+    compared: Dict[str, Tuple[float, float]] = field(default_factory=dict)
 
 
 @dataclass
@@ -204,6 +225,21 @@ class Reading:
     trace: Any                       # benchmarks.trace.reduce.Summary | None
     peaks: Dict[str, Any]
     compiles_in_window: int
+
+
+def compared(check: dict, pairs) -> Dict[str, Tuple[float, float]]:
+    """The numbers a runner's reference check compared, each beside its
+    limit, for the result line: ``pairs`` are (key of the reading, key of its
+    limit) in ``check``, a dot for a nested group; a reading that is a dict
+    of readings stands by its largest."""
+    def at(path):
+        found = check
+        for key in path.split("."):
+            found = found[key]
+        return max(found.values()) if isinstance(found, dict) else found
+
+    return {value: (float(at(value)), float(at(limit)))
+            for value, limit in pairs}
 
 
 # ------------------------------------------------------------------ device
@@ -376,6 +412,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         result["device"]["busy_s"] = summary.busy_s
         result["device"]["window_s"] = summary.window_s
         result["breakdown"] = trace_reduce.breakdown(summary)
+    # last on the line: every number ``correct`` compared, beside its limit
+    result["compared"] = {k: {"value": v, "limit": limit}
+                          for k, (v, limit) in measured.compared.items()}
+    result["compared"]["compiles_in_window"] = {
+        "value": rt.compiles_in_window, "limit": 0}
     return result
 
 
@@ -392,4 +433,8 @@ def main(argv, t_start: float) -> int:
     result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
                       t_start)
     print(json.dumps(result), flush=True)
+    for name, pair in result["compared"].items():      # stderr's last lines
+        print(f"compared {name}: {pair['value']!r} (limit {pair['limit']!r})",
+              file=sys.stderr)
+    print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     return 0

@@ -2,8 +2,8 @@
 program's span ``mv.input.next`` (``util/prefetch.py``: one pull from
 ``SkipGram.batches``) per batch delivered (``mv.input.place``).  The pull
 that finds the iterator dry has a span and delivers no batch, so this is the
-batcher's whole time a step, as ``apps.batcher_ms_per_step`` times it from
-outside."""
+batcher's whole time a step, inside the window (PR 39 retired the metric
+that ran the same loop again after the window and timed it from outside)."""
 
 from benchmarks.trace import program
 

@@ -11,7 +11,7 @@ BETTER = "higher"
 SOURCE = "device_trace"
 LAYER = "kernels"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {"runner": ("lm_train", "lm_train_kinds")}
 
 
 def read(reading):

@@ -13,7 +13,7 @@ BETTER = "higher"
 SOURCE = "device_trace"
 LAYER = "kernels"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train_latent"}
+APPLIES = {"model": {"kv_lora_rank": True}}
 
 
 def read(reading):

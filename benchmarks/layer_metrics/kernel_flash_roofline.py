@@ -2,7 +2,7 @@
 in percent: the least time the chip could take for the causal attention a
 step requires, forward and backward (``benchmarks/flops.py``: the larger of
 FLOPs over the bf16 peak and bytes over the HBM peak), over the time spent
-in the Mosaic kernels.  The backward kernels rebuild the scores: that costs
+in the Mosaic kernels.  The backward kernel rebuilds the scores: that costs
 time and counts no FLOPs.  At head size 128 and these lengths the bound is
 compute (about 600 FLOPs a byte at 2048 against the chip's 240)."""
 
@@ -14,7 +14,7 @@ BETTER = "higher"
 SOURCE = "device_trace"
 LAYER = "kernels"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {"runner": "lm_train", "model": {"num_experts": False}}
 
 
 def read(reading):

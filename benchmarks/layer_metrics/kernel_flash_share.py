@@ -1,7 +1,8 @@
-"""``kernel.flash_share``: share of device busy time in Mosaic custom
-calls, in percent.  The flash forward, dq and dkv ``pallas_call``s carry no
-``name=``, and the trace names them after the jaxpr, so they are counted
-together; the split is the ``tracing`` issue's."""
+"""``kernel.flash_share``: share of device busy time in the program's own
+Mosaic kernels, in percent: every ``tpu_custom_call`` but XLA's grouped
+matmul and the row update, which ``reduce.classify`` books as ``matmul``
+and ``scatter_gather``.  In the dense cells these are ``flash_fwd`` and
+``flash_bwd``; each has a roofline of its own beside this."""
 
 NAME = "kernel.flash_share"
 UNIT = "%"
@@ -9,7 +10,7 @@ BETTER = "lower"
 SOURCE = "device_trace"
 LAYER = "kernels"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {"runner": "lm_train", "model": {"num_experts": False}}
 
 
 def read(reading):

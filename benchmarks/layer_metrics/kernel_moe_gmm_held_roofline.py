@@ -12,7 +12,7 @@ BETTER = "higher"
 SOURCE = "device_trace"
 LAYER = "kernels"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train_kinds"}
+APPLIES = {"model": {"experts_held": True}}
 
 
 def read(reading):

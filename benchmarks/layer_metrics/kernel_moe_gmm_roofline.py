@@ -10,7 +10,7 @@ BETTER = "higher"
 SOURCE = "device_trace"
 LAYER = "kernels"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {"runner": "lm_train", "model": {"num_experts": True}}
 
 
 def read(reading):

@@ -10,7 +10,7 @@ BETTER = "lower"
 SOURCE = "device_trace"
 LAYER = "model"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {"model": {"num_experts": True}}
 
 
 def read(reading):

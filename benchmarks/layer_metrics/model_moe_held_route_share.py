@@ -11,7 +11,7 @@ BETTER = "higher"
 SOURCE = "program_counter"
 LAYER = "model"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train_kinds"}
+APPLIES = {"model": {"experts_held": True}}
 
 
 def read(reading):

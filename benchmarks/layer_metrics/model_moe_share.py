@@ -11,7 +11,7 @@ BETTER = "higher"
 SOURCE = "device_trace"
 LAYER = "model"
 MOVES = "tokens_per_chip_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {"model": {"num_experts": True}}
 
 
 def read(reading):

@@ -10,7 +10,7 @@ BETTER = "lower"
 SOURCE = "program_span"
 LAYER = "compiler"
 MOVES = "setup_s"
-APPLIES = {"runner": "lm_train"}
+APPLIES = {}       # every cell that reports the metric it moves
 
 
 def read(reading):

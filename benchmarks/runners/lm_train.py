@@ -29,7 +29,8 @@ import time
 import numpy as np
 
 from benchmarks import flops
-from benchmarks.harness import Measured, compiled_peak_bytes, load_module
+from benchmarks.harness import (Measured, compared, compiled_peak_bytes,
+                                load_module)
 
 # Published config keys the program has a field for.
 PUBLISHED = {"hidden_size": "dim", "num_attention_heads": "n_heads",
@@ -38,6 +39,8 @@ PUBLISHED = {"hidden_size": "dim", "num_attention_heads": "n_heads",
              "rms_norm_eps": "norm_eps", "max_position_embeddings": "max_seq"}
 LOSSES_LOGGED = 20
 SAMPLE_ROWS = 256          # embedding rows and weight-tile edge sampled
+# (reading, its limit) of the reference check, for the result line
+COMPARED = (("loss_abs_err", "loss_atol"), ("grad_rel_err", "grad_rtol"))
 
 
 def _check_published(config: dict) -> None:
@@ -263,8 +266,13 @@ class Session:
                 "attention_bytes_per_step":
                     self.model["n_layers"] * flops.flash_kernel_bytes(
                         self.batch, self.model["n_heads"], self.seq,
+                        head_dim),
+                "attention_bwd_bytes_per_step":
+                    self.model["n_layers"] * flops.flash_backward_bytes(
+                        self.batch, self.model["n_heads"], self.seq,
                         head_dim)},
-            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes)
+            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes,
+            compared=compared(self.check, COMPARED))
 
 
 def setup(cell, rt) -> Session:

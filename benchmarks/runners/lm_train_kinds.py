@@ -28,7 +28,17 @@ own:
   ``GRAD_RTOL_ROUTED`` for the routers and the held experts' ``w2``
   (``routed``), whose gradients move with every route the system's
   bfloat16 hidden state sends elsewhere than the reference's float32 one
-  (``laguna_lm``'s docstring has the readings).
+  (``laguna_lm``'s docstring has the readings);
+- **settling** (``trainer.settle_steps`` of the configuration's file, a fixed
+  number, the same for every seed; counted as set-up): after the two warm-up
+  steps, that many un-timed train steps of the cell's own compiled step on
+  fresh batches of the cell's stream.  How many routes reach the 16 experts
+  held here is the seed's luck at first (3.6-5.5% of them where even routing
+  gives 6.25%) and drifts as the router learns away from experts whose
+  absent peers add nothing; the step's time follows it (0.28 ms per 1,000
+  held routes), so unsettled the rate spread by 0.32-0.55% between seeds
+  where half the bound is 0.5% (``PERF.md`` section 6, PR 39, has the curve
+  the number was chosen from).
 """
 
 from __future__ import annotations
@@ -38,7 +48,8 @@ import time
 import numpy as np
 
 from benchmarks import flops_laguna
-from benchmarks.harness import Measured, compiled_peak_bytes, load_module
+from benchmarks.harness import (Measured, compared, compiled_peak_bytes,
+                                load_module)
 from benchmarks.runners.lm_train import (LOSSES_LOGGED, SAMPLE_ROWS,
                                          step_seconds)
 
@@ -65,6 +76,9 @@ ROPE = {"rope_theta": "theta", "partial_rotary_factor": "rotary_factor",
         "beta_fast": "beta_fast", "beta_slow": "beta_slow",
         "attention_factor": "attention_factor"}
 SAMPLED_LAYERS = (0, 2, 4)
+# (reading, its limit) of the reference check, for the result line
+COMPARED = (("loss_abs_err", "loss_atol"), ("worst", "grad_rtol"),
+            ("worst_routed", "grad_rtol_routed"))
 
 
 def _check_published(config: dict) -> None:
@@ -210,6 +224,7 @@ class Session:
         self.mesh = Mesh(np.asarray(rt.devices).reshape(shape), tuple(axes))
         self.chips = cell.chips
         lr = float(config["trainer"]["learning_rate"])
+        self.settle_steps = int(config["trainer"].get("settle_steps", 0))
         # Traces of the attention body by path and of its windows, counted
         # by the program at trace time (parallel/ring_attention.py:
         # _flash_dispatch); read as the change since this session began.
@@ -255,10 +270,21 @@ class Session:
         self.repeated = [float(self.trainer.train_step_async(first))
                          for _ in range(2)]
         warm_s = time.perf_counter() - t0
+
+        # Settling (module docstring): the window's own step, un-timed, on
+        # fresh batches.  The counted routes stay on the device.
+        t0 = time.perf_counter()
+        self.settling = []
+        for _ in range(self.settle_steps):
+            loss = self.trainer.train_step_async(
+                jax.device_put(next(self.stream), self.place_on))
+            self.settling.append((loss, self.trainer.routes))
+        jax.block_until_ready(self.trainer.params)
+        settle_s = time.perf_counter() - t0
         rt.log(setup_parts_s={"trainer_init": init_s,
                               "reference_check": check_s,
                               "compile_or_load": compile_s,
-                              "warm_up": warm_s},
+                              "warm_up": warm_s, "settling": settle_s},
                step_peak_bytes=self.peak_bytes,
                repeated_batch_losses=self.repeated)
 
@@ -309,6 +335,13 @@ class Session:
         per_expert = held.mean(axis=0)               # [layers, held]
         rt.log(steps_in_window=len(done_at), step_s=step_s,
                last_loss=losses[-1], attention_traced=traced,
+               settling={"steps": self.settle_steps,
+                         "losses_every_8th":
+                             [float(l) for l, _ in self.settling[::8]],
+                         "held_routes_every_8th":
+                             [int(np.asarray(r)[:, :-1].sum())
+                              for _, r in self.settling[::8]]},
+               held_routes_in_window=held.sum(axis=(1, 2)).tolist(),
                held_routes={"per_step": held_per_step,
                             "of": routes_per_step,
                             "per_layer": held.sum(axis=2).mean(axis=0).tolist(),
@@ -317,6 +350,8 @@ class Session:
                                  / per_expert.mean(axis=1)).tolist()})
         model = self.model
         full, sliding = "full_attention", "sliding_attention"
+        full_bytes = flops_laguna.flash_kernel_bytes(model, self.batch,
+                                                     self.seq, full)
         return Measured(
             attempted=len(window_losses),
             failed=sum(1 for v in window_losses if not np.isfinite(v)),
@@ -344,13 +379,13 @@ class Session:
                 "routes_per_step": routes_per_step,
                 "flops_per_step": flops_laguna.train_flops(
                     model, self.batch, self.seq, held_per_step),
-                # what the flash_fwd / flash_bwd_* readers divide by: the
+                # what the flash_fwd / flash_bwd readers divide by: the
                 # calls of those names are the full-attention layers'
                 "attention_flops_per_step": flops_laguna.attention_flops(
                     model, self.batch, self.seq, full),
-                "attention_bytes_per_step": sum(
-                    flops_laguna.flash_kernel_bytes(
-                        model, self.batch, self.seq, full).values()),
+                "attention_bytes_per_step":
+                    full_bytes["fwd"] + full_bytes["bwd"],
+                "attention_bwd_bytes_per_step": full_bytes["bwd"],
                 "sliding_attention_flops_per_step":
                     flops_laguna.attention_flops(model, self.batch, self.seq,
                                                  sliding),
@@ -361,7 +396,8 @@ class Session:
                     flops_laguna.routed_flops(model, held_per_step),
                 "gmm_held_bytes_per_step":
                     flops_laguna.grouped_matmul_bytes(model, held_per_step)},
-            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes)
+            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes,
+            compared=compared(self.check, COMPARED))
 
 
 def setup(cell, rt) -> Session:

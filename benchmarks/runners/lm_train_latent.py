@@ -62,7 +62,8 @@ import time
 import numpy as np
 
 from benchmarks import flops_xing
-from benchmarks.harness import Measured, compiled_peak_bytes, load_module
+from benchmarks.harness import (Measured, compared, compiled_peak_bytes,
+                                load_module)
 from benchmarks.runners.lm_train import (LOSSES_LOGGED, SAMPLE_ROWS,
                                          step_seconds)
 
@@ -89,6 +90,10 @@ PUBLISHED = {"hidden_size": "dim", "num_attention_heads": "n_heads",
 ROPE = {"factor": "yarn_factor",
         "original_max_position_embeddings": "original_max_seq",
         "beta_fast": "beta_fast", "beta_slow": "beta_slow"}
+# (reading, its limit) of the reference check, for the result line
+COMPARED = (("loss_abs_err", "loss_atol"), ("worst", "grad_rtol"),
+            ("worst_routed", "grad_rtol_routed"),
+            ("bias_mismatch", "bias_mismatch_max"))
 
 
 def _check_published(config: dict) -> None:
@@ -507,7 +512,8 @@ class Session:
                     flops_xing.routed_flops(model, held_per_step),
                 "gmm_held_bytes_per_step":
                     flops_xing.grouped_matmul_bytes(model, held_per_step)},
-            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes)
+            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes,
+            compared=compared(self.check, COMPARED))
 
 
 def setup(cell, rt) -> Session:

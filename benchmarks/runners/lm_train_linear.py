@@ -71,7 +71,8 @@ import time
 import numpy as np
 
 from benchmarks import flops_ling
-from benchmarks.harness import Measured, compiled_peak_bytes, load_module
+from benchmarks.harness import (Measured, compared, compiled_peak_bytes,
+                                load_module)
 from benchmarks.runners.lm_train import (LOSSES_LOGGED, SAMPLE_ROWS,
                                          step_seconds)
 
@@ -105,6 +106,14 @@ CONTROLS = {"state_bf16": {"state_dtype": "bfloat16"},
             "decay_bf16": {"decay_dtype": "bfloat16"},
             "no_decay": {"decay": False},
             "no_group_limit": {"group_limit": False}}
+# (reading, its limit) of the reference check, for the result line
+COMPARED = (("loss_abs_err", "loss_atol"), ("median_logits", "logits_rtol"),
+            ("worst", "grad_rtol"), ("worst_routed", "grad_rtol_routed"),
+            ("worst_decay", "grad_rtol_decay"),
+            ("bias_mismatch", "bias_mismatch_max"),
+            ("kept_mismatch", "kept_mismatch_max"),
+            ("scan.out_rel_err", "scan.out_rtol"),
+            ("scan.worst_grad", "scan.grad_rtol"))
 
 
 def _check_published(config: dict) -> None:
@@ -636,7 +645,8 @@ class Session:
                     flops_ling.routed_flops(model, held_per_step),
                 "gmm_held_bytes_per_step":
                     flops_ling.grouped_matmul_bytes(model, held_per_step)},
-            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes)
+            hlo_texts=self.hlo_texts, compiled_peak_bytes=self.peak_bytes,
+            compared=compared(self.check, COMPARED))
 
 
 def setup(cell, rt) -> Session:

@@ -18,9 +18,13 @@ import time
 import numpy as np
 
 from benchmarks import flops
-from benchmarks.harness import Measured, compiled_peak_bytes, load_module
+from benchmarks.harness import (Measured, compared, compiled_peak_bytes,
+                                load_module)
 
 LOSSES_LOGGED = 20
+# (reading, its limit) of the reference check, for the result line
+COMPARED = (("loss_rel_err", "loss_rtol"), ("row_err_in", "row_rtol"),
+            ("row_err_out", "row_rtol"))
 
 
 def reference_check(skipgram, reference, tokens: np.ndarray, batch: int,
@@ -123,7 +127,7 @@ class Session:
 
     def measure(self, rt) -> Measured:
         span = rt.span
-        chunks_run, records = [], []          # (steps, seconds, loss)
+        records = []                          # (steps, seconds, loss)
         t_open = rt.open_window()
         with span("bench.window"):
             k = 0
@@ -134,24 +138,12 @@ class Session:
                     steps, loss = self.skipgram.train_epoch_fused(
                         tokens, self.batch, seed=rt.seed + 1 + k)
                 records.append((steps, time.perf_counter() - t0, loss))
-                chunks_run.append(tokens)
                 k += 1
         rt.close_window()
 
         facts = {"runner": "sgns_train", "chips": self.chips,
                  "steps": sum(s for s, _, _ in records),
                  "window_s": sum(t for _, t, _ in records)}
-        if rt.trace:
-            # The batcher alone over the same slices, from outside: the
-            # program has no span around it yet.
-            t0 = time.perf_counter()
-            n = 0
-            for k, tokens in enumerate(chunks_run):
-                for _ in self.skipgram.batches(tokens, self.batch,
-                                               seed=rt.seed + 1 + k):
-                    n += 1
-            facts["batcher_ms_per_step"] = (
-                1e3 * (time.perf_counter() - t0) / max(n, 1))
         self.mv.shutdown()
 
         losses = [loss for _, _, loss in records]
@@ -170,7 +162,8 @@ class Session:
                     "losses finite": bool(np.all(np.isfinite(losses))),
                     "loss fell": bool(losses[-1] < losses[0])},
             facts=facts, hlo_texts=self.hlo_texts,
-            compiled_peak_bytes=self.peak_bytes)
+            compiled_peak_bytes=self.peak_bytes,
+            compared=compared(self.check, COMPARED))
 
 
 def setup(cell, rt) -> Session:

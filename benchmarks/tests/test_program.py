@@ -1,6 +1,6 @@
 """``benchmarks/trace/program.py`` on hand-written ``op_name``s, hand-made
-events and the recorded fixture ``toy_1chip_scoped``; the fourteen readers
-that go through it, and their entries in ``BENCHMARK.json``."""
+events and the recorded fixture ``toy_1chip_scoped``; the readers that go through
+it, and their entries in ``BENCHMARK.json``."""
 
 import gzip
 import json
@@ -15,19 +15,14 @@ from benchmarks.trace import reduce as R
 FIXTURES = os.path.join(harness.HERE, "trace", "fixtures")
 STEM = os.path.join(FIXTURES, "toy_1chip_scoped")
 
-NEW = {  # metric -> runner it applies to
-    "model.fwd_ms_per_step": "lm_train", "model.bwd_ms_per_step": "lm_train",
-    "model.remat_ms_per_step": "lm_train", "updater.ms_per_step": "lm_train",
-    "model.head_loss_ms_per_step": "lm_train",
-    "kernel.flash_fwd_roofline": "lm_train",
-    "kernel.flash_dq_roofline": "lm_train",
-    "kernel.flash_dkv_roofline": "lm_train",
-    "device.unscoped_share": "lm_train",
-    "device.unscoped_share.sgns": "sgns_train",
-    "tables.gather_ms_per_step": "sgns_train",
-    "tables.scatter_apply_ms_per_step": "sgns_train",
-    "apps.batcher_span_ms_per_step": "sgns_train",
-    "host.dispatch_ms_per_step": "sgns_train"}
+NEW = (  # the readers that go through ``program.py`` and read the fixture
+    "model.fwd_ms_per_step", "model.bwd_ms_per_step",
+    "model.remat_ms_per_step", "updater.ms_per_step",
+    "model.head_loss_ms_per_step", "kernel.flash_fwd_roofline",
+    "kernel.flash_bwd_roofline", "device.unscoped_share",
+    "device.unscoped_share.sgns", "tables.gather_ms_per_step",
+    "tables.scatter_apply_ms_per_step", "apps.batcher_span_ms_per_step",
+    "host.dispatch_ms_per_step")
 
 # (op_name, phase, scope).  The first rows are the old fixture's (a program
 # without scopes), the next the v5e compile's of the dense step at full
@@ -45,8 +40,20 @@ OP_NAMES = [
      "flash_fwd/pallas_call", "fwd", "flash_fwd"),
     ("jit(step)/jvp(layers)/while/body/closed_call/mlp/jit(silu)/logistic",
      "fwd", "mlp"),
+    # a split backward kernel is its family's (``KERNELS`` are beginnings)
     ("jit(step)/transpose(jvp(layers))/while/body/closed_call/checkpoint/"
-     "attn/flash_bwd_dq/flash_bwd_dq/pallas_call", "bwd", "flash_bwd_dq"),
+     "attn/flash_bwd_dq/flash_bwd_dq/pallas_call", "bwd", "flash_bwd"),
+    ("jit(step)/transpose(jvp(layers))/while/body/closed_call/checkpoint/"
+     "attn/flash_bwd/flash_bwd/pallas_call", "bwd", "flash_bwd"),
+    ("jit(step)/transpose(jvp(layers))/while/body/attn/attn.sliding/"
+     "flash_win_bwd_dkv/flash_win_bwd_dkv/pallas_call", "bwd",
+     "flash_win_bwd"),
+    ("jit(step)/jvp(layers)/while/body/attn/attn.latent/flash_mla_fwd/"
+     "flash_mla_fwd/pallas_call", "fwd", "flash_mla_fwd"),
+    ("kda_bwd/transpose(jvp())/reduce_sum", "bwd", "kda_bwd"),
+    # the row update's time stays its scope's
+    ("jit(step)/tables.scatter_apply/row_update/pallas_call", "other",
+     "tables.scatter_apply"),
     ("jit(step)/transpose(jvp(layers))/while/body/closed_call/checkpoint/"
      "rematted_computation/attn/mul", "remat", "attn"),
     ("jit(step)/transpose(jvp(layers))/while/body/dynamic_update_slice",
@@ -86,15 +93,30 @@ MOSAIC = ('%{} = (bf16[4,8]{{1,0}}, f32[4]{{0}}) custom-call(bf16[4,8]{{1,0}} '
           '%q), custom_call_target="tpu_custom_call"')
 
 
-def test_kernel_is_a_mosaic_call_under_a_kernels_scope():
-    path = "jit(step)/jvp(layers)/while/body/attn/flash_fwd/flash_fwd/pallas_call"
-    assert P.kernel(MOSAIC.format("closed_call.6"), path) == "flash_fwd"
-    assert P.kernel(MOSAIC.format("flash_fwd.6"), path) == "flash_fwd"
-    assert P.kernel(MOSAIC.format("flash_fwd.6"), None) is None
-    assert P.kernel(MOSAIC.format("closed_call.6"),
-                    "jit(step)/jvp(layers)/attn/pallas_call") is None
-    assert P.kernel("%fusion.3 = f32[8]{0} fusion(f32[8]{0} %a), kind=kLoop, "
-                    "calls=%f", path) is None
+def test_a_kernel_is_what_its_calls_names_begin_with():
+    body = "jit(step)/jvp(layers)/while/body/attn/"
+    assert P.kernel(body + "flash_fwd/flash_fwd/pallas_call") == "flash_fwd"
+    for name, family in (("flash_bwd", "flash_bwd"),
+                         ("flash_bwd_dq", "flash_bwd"),
+                         ("flash_bwd_dkv", "flash_bwd"),
+                         ("flash_win_bwd_dq", "flash_win_bwd"),
+                         ("flash_mla_bwd_dkv", "flash_mla_bwd"),
+                         ("flash_mla_bwd", "flash_mla_bwd"),
+                         ("kda_bwd_dq", "kda_bwd"), ("kda_fwd", "kda_fwd")):
+        assert P.kernel(f"{body}{name}/{name}/pallas_call") == family, name
+    assert P.kernel("jit(step)/tables.scatter_apply/row_update/"
+                    "pallas_call") == "row_update"
+    assert P.kernel(None) is None
+    assert P.kernel("jit(step)/jvp(layers)/attn/pallas_call") is None
+    assert P.kernel("jit(step)/jvp(layers)/attn/my_flash_bwd/mul") is None
+    # no kernel's name begins another's
+    assert not [(a, b) for a in P.KERNELS for b in P.KERNELS
+                if a != b and a.startswith(b)]
+    # XLA's grouped matmul carries neither scope nor phase: known by name
+    assert P.unscoped("ragged-dot-none") and R.is_grouped_matmul(
+        MOSAIC.format("ragged-dot-none.8"))
+    assert not R.is_grouped_matmul(MOSAIC.format("flash_fwd.6"))
+    assert not R.is_grouped_matmul("%ragged-dot-none.8 = f32[8]{0} add(%a)")
 
 
 HLO = """HloModule jit_step
@@ -234,7 +256,8 @@ def test_fixture_phases_add_up_to_the_reductions_busy_time(recorded):
     assert sum(prog.by_phase_s.values()) == pytest.approx(summary.busy_s)
     assert all(prog.by_phase_s[p] > 0 for p in ("fwd", "bwd", "remat",
                                                 "update"))
-    assert set(prog.by_kernel_s) == set(P.KERNELS)
+    # the fixture dates from PR 23: its backward is the split pair
+    assert set(prog.by_kernel_s) == {"flash_fwd", "flash_bwd"}
     assert sum(prog.by_kernel_s.values()) == pytest.approx(
         summary.by_category_s["mosaic"])
     assert {"mv.input.next", "mv.input.place", "mv.sgns.dispatch",
@@ -253,18 +276,20 @@ def test_the_trace_and_the_text_agree_on_op_names(recorded):
     same = sum(1 for a, b in both if a == b)
     assert len(both) > 100 and same >= 0.95 * len(both)
     kernels = [e for e in events if R.classify(e) == "mosaic"]
-    assert sorted(P.kernel(e, from_text.op_name(e)) for e in kernels) == \
-        sorted(P.kernel(e, from_trace.op_name(e)) for e in kernels) == \
-        sorted(P.KERNELS)
+    assert sorted(P.kernel(from_text.op_name(e)) for e in kernels) == \
+        sorted(P.kernel(from_trace.op_name(e)) for e in kernels) == \
+        ["flash_bwd", "flash_bwd", "flash_fwd"]
     # The compiled program names the custom call after the kernel.
     assert sorted(R.instruction_name(e).split(".")[0] for e in kernels) == \
-        sorted(P.KERNELS)
+        ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
 def _reading(trace):
     return harness.Reading(
-        facts={"chips": 1, "attention_flops_per_step": 3.0e6}, trace=trace,
-        peaks={"bf16_flops_per_s": 1.97e14}, compiles_in_window=0)
+        facts={"chips": 1, "attention_flops_per_step": 3.0e6,
+               "attention_bwd_bytes_per_step": 1.0e3}, trace=trace,
+        peaks={"bf16_flops_per_s": 1.97e14, "hbm_bytes_per_s": 8.19e11},
+        compiles_in_window=0)
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
@@ -306,31 +331,18 @@ def test_a_program_without_scopes_reads_as_absent(tmp_path, monkeypatch):
 
 # ------------------------------------------------------------- the entries
 def test_new_entries_match_their_readers():
-    """``test_contract.py``'s rule for the fourteen, as far as it can hold:
-    the nine dense metrics apply by their data to the pending four-chip cell
-    too, whose ``join`` list in ``benchmarks/pending/`` only a ``benchmark``
-    PR may extend (PERF.md section 7)."""
-    from benchmarks.tests.tiny import real_bench
+    """``test_contract.py``'s rule for the readers that go through
+    ``program.py``: each one's constants are its entry's, and its
+    ``workloads`` are the cells its ``APPLIES`` holds for."""
+    from benchmarks.tests.tiny import real_bench, workloads_by_applies
 
-    with open(os.path.join(harness.REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = real_bench()
     declared = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == [
-        m["name"] for m in bench["per_layer"] if m["name"] in NEW]
     readers = harness.layer_readers((harness.HERE,))
-    runner_of = {}
-    for w in bench["workloads"]:
-        config = {c["name"]: c for c in bench["configs"]}[w["config"]]
-        with open(os.path.join(harness.REPO, config["file"])) as f:
-            runner_of[w["name"]] = json.load(f)["runner"]
-    pending = ({w["name"] for w in real_bench()["workloads"]}
-               - set(runner_of))
-    for name, runner in NEW.items():
+    applies = workloads_by_applies(bench, readers)
+    for name in NEW:
         r, m = readers[name], declared[name]
         assert (r.NAME, r.UNIT, r.BETTER, r.SOURCE, r.LAYER, r.MOVES) == (
             m["name"], m["unit"], m["better"], m["source"], m["layer"],
             m["moves"])
-        assert r.APPLIES == {"runner": runner}
-        assert set(m["workloads"]) == {c for c, rn in runner_of.items()
-                                       if rn == runner}
-        assert not set(m["workloads"]) & pending
+        assert set(m["workloads"]) == applies[name], name

@@ -17,7 +17,7 @@ from benchmarks.tests.tiny import make_tiny_root
 
 CELLS = ["ouro-2.6b-l16-ut1.seq2k-b4", "ouro-2.6b-l16-ut1.seq8k-b1",
          "ouro-2.6b-l16-ut1.dp4-seq2k-b16", "w2v-gn3m300.zipf-b8k"]
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 @pytest.fixture(scope="module")
@@ -47,6 +47,11 @@ def rehearse(root, name, trace, capsys):
 def test_cell_rehearses(tiny_root, name, trace, capsys):
     cell, result, lines = rehearse(tiny_root, name, trace, capsys)
     assert set(result) == KEYS                     # no breakdown off-chip
+    # last on the line: every number compared, beside its limit
+    assert list(result)[-1] == "compared" and len(result["compared"]) >= 3
+    assert all(set(pair) == {"value", "limit"}
+               and pair["value"] <= pair["limit"]
+               for pair in result["compared"].values())
     assert result["correct"] is True, lines
     assert result["attempted"] > 0 and result["failed"] == 0
     assert result["device"]["platform"] == "cpu"
@@ -90,6 +95,8 @@ def test_a_wrong_reference_answer_is_not_correct(tiny_root, capsys,
     monkeypatch.setattr(reference, "ROW_RTOL", 1e-12)
     _, result, lines = rehearse(tiny_root, CELLS[3], False, capsys)
     assert result["correct"] is False
+    assert [k for k, pair in result["compared"].items()
+            if pair["value"] > pair["limit"]] == ["row_err_in", "row_err_out"]
     assert [x for x in lines if "failed_checks" in x][0]["failed_checks"] == \
         ["reference agrees"]
 

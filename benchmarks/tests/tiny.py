@@ -1,8 +1,8 @@
 """A throwaway copy of the benchmark at a toy size, made of data files only.
 
 ``make_tiny_root`` writes ``BENCHMARK.json`` with the real cells, metrics
-and names (the cells waiting under ``benchmarks/pending/`` switched on too),
-but every configuration and traffic file shrunk, into a temporary directory.
+and names, but every configuration and traffic file shrunk, into a temporary
+directory.
 The rehearsal runs the real runners, generators, references and readers on
 it: proof that a configuration, a traffic mix and a cell are data."""
 
@@ -36,37 +36,28 @@ def _dump(path: str, obj) -> None:
         json.dump(obj, f, indent=1)
 
 
-def with_pending(bench: dict) -> dict:
-    """``bench`` with every cell under ``benchmarks/pending/`` switched on,
-    exactly as the file says a later PR does it: entries only."""
-    pending_dir = os.path.join(REPO, "benchmarks", "pending")
-    for name in sorted(os.listdir(pending_dir)):
-        with open(os.path.join(pending_dir, name)) as f:
-            pending = json.load(f)
-        cell = pending["workload"]["name"]
-        bench["workloads"].append(pending["workload"])
-        bench["per_layer"].extend(pending["per_layer"])
-        for m in bench["end_to_end"] + bench["per_layer"]:
-            if m["name"] in pending["join"]:
-                m["workloads"].append(cell)
-    return bench
-
-
-def real_bench(pending: bool = True) -> dict:
+def real_bench() -> dict:
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    return with_pending(bench) if pending else bench
+        return json.load(f)
 
 
-def make_full_root(root: str) -> dict:
-    """The real benchmark with the pending cells switched on, its
-    configuration files copied: for compiling a pending cell at full size."""
-    bench = real_bench()
-    for declared in bench["configs"]:
-        with open(os.path.join(REPO, declared["file"])) as f:
-            _dump(os.path.join(root, declared["file"]), json.load(f))
-    _dump(os.path.join(root, "BENCHMARK.json"), bench)
-    return bench
+def workloads_by_applies(bench: dict, readers: dict) -> dict:
+    """metric -> the cells its reader's ``APPLIES`` holds for among those
+    that report the end-to-end metric it moves: what its ``workloads`` in
+    ``BENCHMARK.json`` has to be (``harness.reader_applies``)."""
+    from benchmarks import harness
+
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    cells = {}
+    for w in bench["workloads"]:
+        with open(os.path.join(REPO, files[w["config"]])) as f:
+            cells[w["name"]] = (json.load(f), w["chips"])
+    moved = {m["name"]: set(m.get("workloads", cells))
+             for m in bench["end_to_end"]}
+    return {name: {cell for cell, (config, chips) in cells.items()
+                   if cell in moved[r.MOVES]
+                   and harness.reader_applies(r.APPLIES, config, chips)}
+            for name, r in readers.items()}
 
 
 def make_tiny_root(root: str, directory: str = "tinybench") -> dict:

@@ -27,10 +27,10 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-from benchmarks import flops
-from benchmarks.trace import moe, program
+from benchmarks.trace import program
 from benchmarks.trace.reduce import (WINDOW_SPAN, _clip, classify,
-                                     load_xplane, self_times)
+                                     is_grouped_matmul, load_xplane,
+                                     self_times)
 
 __all__ = ["SCOPES", "KERNELS", "Kinds", "summarize", "of_reading",
            "scope_ms_per_step", "kernel_roofline", "gmm_held_roofline",
@@ -39,8 +39,7 @@ __all__ = ["SCOPES", "KERNELS", "Kinds", "summarize", "of_reading",
 SCOPES = ("attn.full", "attn.sliding", "moe.shared")
 KERNELS = ("flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv")
 # the runner's fact that holds a kernel's least bytes
-_KERNEL_PART = {"flash_win_fwd": "fwd", "flash_win_bwd_dq": "dq",
-                "flash_win_bwd_dkv": "dkv"}
+_KERNEL_PART = {"flash_win_fwd": "fwd"}
 
 
 @dataclass
@@ -71,12 +70,12 @@ def summarize(trace, index) -> Optional[Kinds]:
             where = program.scope(op_name, among=SCOPES)
             if where is not None:
                 scopes[where] += self_ns
-            if classify(e.name) == "mosaic":
+            if is_grouped_matmul(e.name):
+                grouped += self_ns
+            elif classify(e.name) == "mosaic":
                 which = program.scope(op_name, among=KERNELS)
                 if which is not None:
                     kernels[which] += self_ns
-                elif moe.is_grouped_matmul(e.name):
-                    grouped += self_ns
         programs += sum(1 for e in _clip(dev.modules, t0, t1)
                         if e.name.startswith("jit_step"))
     if not any(scopes.values()) and not any(kernels.values()):
@@ -117,29 +116,19 @@ def scope_ms_per_step(reading, name: str) -> Optional[float]:
     return 1e3 * k.by_scope_s[name] / k.step_programs
 
 
-def _roofline(reading, spent_s: float, flops_per_step, bytes_per_step,
-              step_programs: int) -> Optional[float]:
-    if (spent_s <= 0 or not reading.peaks or flops_per_step is None
-            or bytes_per_step is None):
-        return None
-    per_chip = step_programs / reading.facts["chips"]
-    least_s, _bound = flops.roofline_seconds(
-        flops_per_step * per_chip, bytes_per_step * per_chip, reading.peaks)
-    return 100.0 * least_s / spent_s
-
-
 def kernel_roofline(reading, name: str) -> Optional[float]:
-    """Windowed kernel ``name``'s share of its roofline, percent: a third of
-    the banded attention a step requires (``flops_laguna.attention_flops``:
-    forward 1, backward 2; the scores a backward kernel rebuilds are
-    recompute) at the bf16 peak, or the kernel's least bytes at the HBM
-    peak, the larger, over the kernel's time."""
+    """The windowed forward kernel's share of its roofline, percent: a third
+    of the banded attention a step requires (``flops_laguna.attention_flops``:
+    forward 1, backward 2) at the bf16 peak, or the pass's least bytes at the
+    HBM peak, the larger, over the kernel's time.  The backward is read by
+    family (``program.family_roofline``, PR 39); this walk still books the
+    split kernels' time under their own names."""
     k = of_reading(reading)
     if k is None:
         return None
     f = reading.facts
     third = f.get("sliding_attention_flops_per_step")
-    return _roofline(
+    return program.roofline_pct(
         reading, k.by_kernel_s[name], None if third is None else third / 3,
         f.get("sliding_kernel_bytes_per_step", {}).get(_KERNEL_PART[name]),
         k.step_programs)
@@ -155,9 +144,10 @@ def gmm_held_roofline(reading) -> Optional[float]:
     if k is None:
         return None
     f = reading.facts
-    return _roofline(reading, k.grouped_matmul_s,
-                     f.get("gmm_held_flops_per_step"),
-                     f.get("gmm_held_bytes_per_step"), k.step_programs)
+    return program.roofline_pct(reading, k.grouped_matmul_s,
+                                f.get("gmm_held_flops_per_step"),
+                                f.get("gmm_held_bytes_per_step"),
+                                k.step_programs)
 
 
 def held_route_share(reading) -> Optional[float]:

@@ -30,7 +30,6 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-from benchmarks import flops
 from benchmarks.trace import program
 from benchmarks.trace.reduce import (WINDOW_SPAN, _clip, classify,
                                      load_xplane, self_times)
@@ -43,8 +42,7 @@ SCOPES = ("attn.latent", "hc.gates", "hc.mix")
 KERNELS = ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv")
 MODULE = "mtp"
 # the runner's facts hold a kernel's FLOPs and least bytes under these
-_KERNEL_PART = {"flash_mla_fwd": "fwd", "flash_mla_bwd_dq": "dq",
-                "flash_mla_bwd_dkv": "dkv"}
+_KERNEL_PART = {"flash_mla_fwd": "fwd"}
 
 
 @dataclass
@@ -136,11 +134,12 @@ def module_ms_per_step(reading) -> Optional[float]:
 
 
 def kernel_roofline(reading, name: str) -> Optional[float]:
-    """Kernel ``name``'s share of its roofline, percent: what the kernel
-    multiplies a step (``flops_xing.mla_kernel_flops``: the scores at 192,
-    the values at 128, a backward kernel's rebuilt scores included) at the
-    bf16 peak, or its least bytes at the HBM peak, the larger, over the time
-    in the Mosaic call of that name."""
+    """The forward kernel's share of its roofline, percent: what the pass
+    requires a step (``flops_xing.mla_kernel_flops``: the scores at 192, the
+    values at 128) at the bf16 peak, or its least bytes at the HBM peak, the
+    larger, over the time in the Mosaic call of that name.  The backward is
+    read by family (``program.family_roofline``, PR 39); this walk still
+    books the split kernels' time under their own names."""
     found = of_reading(reading)
     if found is None or not reading.peaks:
         return None
@@ -148,10 +147,5 @@ def kernel_roofline(reading, name: str) -> Optional[float]:
     f = reading.facts
     work = f.get("mla_kernel_flops_per_step", {}).get(part)
     moved = f.get("mla_kernel_bytes_per_step", {}).get(part)
-    spent = found.by_kernel_s[name]
-    if work is None or moved is None or spent <= 0:
-        return None
-    per_chip = found.step_programs / f["chips"]
-    least_s, _bound = flops.roofline_seconds(work * per_chip,
-                                             moved * per_chip, reading.peaks)
-    return 100.0 * least_s / spent
+    return program.roofline_pct(reading, found.by_kernel_s[name], work,
+                                moved, found.step_programs)

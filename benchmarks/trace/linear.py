@@ -29,7 +29,6 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-from benchmarks import flops
 from benchmarks.trace import program
 from benchmarks.trace.reduce import WINDOW_SPAN, _clip, load_xplane, self_times
 
@@ -130,13 +129,8 @@ def pass_roofline(reading, name: str) -> Optional[float]:
     f = reading.facts
     work = f.get("kda_flops_per_step", {}).get(part)
     moved = f.get("kda_bytes_per_step", {}).get(part)
-    spent = found.by_pass_s[name]
-    if work is None or moved is None or spent <= 0:
-        return None
-    per_chip = found.step_programs / f["chips"]
-    least_s, _bound = flops.roofline_seconds(work * per_chip,
-                                             moved * per_chip, reading.peaks)
-    return 100.0 * least_s / spent
+    return program.roofline_pct(reading, found.by_pass_s[name], work, moved,
+                                found.step_programs)
 
 
 def group_kept_share(reading) -> Optional[float]:

@@ -1,10 +1,10 @@
 """A traced window by the routed FFN's own names: the four ``moe.*`` scopes
 of ``multiverso_tpu/models/moe.py`` and XLA's grouped-matmul calls.
 
-``program.SCOPES`` is a constant that does not hold these names, so the four
-MoE readers under ``layer_metrics/`` share this walk of the run's trace (a
-third one; to be folded into ``program.py`` by a ``benchmark`` PR, ``PERF.md``
-section 7).  Two rules book an instruction to a scope:
+``program.SCOPES`` does not hold these names, so the four MoE readers under
+``layer_metrics/`` share this walk of the run's trace (a third one; to be
+folded into ``program.py`` by a later ``benchmark`` PR, ``PERF.md`` section
+7).  Two rules book an instruction to a scope:
 
 - its ``op_name`` holds one of ``SCOPES`` (the innermost counts), whatever
   the phase: ``.../mlp/moe.dispatch/sort``;
@@ -13,7 +13,8 @@ section 7).  Two rules book an instruction to a scope:
   (instruction and ``op_name`` alike) ``ragged-dot-none.N``, with a small
   ``ragged-dot-metadata.N`` call before each group of them; the rewrite
   drops the program's scope and phase (seen in the v5e compile, PR 26), so
-  they are known by that name and booked to ``moe.experts``.
+  they are known by that name (``reduce.is_grouped_matmul``) and booked to
+  ``moe.experts``.
 
 A program without any of this (the parent of the PR that added it, a dense
 model) gives ``None`` and the readers leave their metric out.
@@ -29,21 +30,15 @@ from typing import Dict, Optional
 
 from benchmarks import flops, flops_moe
 from benchmarks.trace import program
-from benchmarks.trace.reduce import (WINDOW_SPAN, _clip, classify,
-                                     instruction_name, load_xplane,
+from benchmarks.trace.reduce import (GROUPED_MATMUL, WINDOW_SPAN, _clip,
+                                     is_grouped_matmul, load_xplane,
                                      self_times)
 
 __all__ = ["SCOPES", "GROUPED_MATMUL", "MoE", "book", "summarize",
            "of_reading", "share", "scope_ms_per_step", "gmm_roofline"]
 
 SCOPES = ("moe.route", "moe.dispatch", "moe.experts", "moe.combine")
-GROUPED_MATMUL = "ragged-dot"          # prefix of the compiler's own names
 EXPERTS = "moe.experts"
-
-
-def is_grouped_matmul(event_name: str) -> bool:
-    return (classify(event_name) == "mosaic"
-            and instruction_name(event_name).startswith(GROUPED_MATMUL))
 
 
 def book(event_name: str, op_name: Optional[str]) -> Optional[str]:
@@ -80,7 +75,7 @@ def summarize(trace, index, cell: str = "") -> Optional[MoE]:
             where = book(e.name, index.op_name(e.name))
             if where is not None:
                 scopes[where] += self_ns
-                if where == EXPERTS and classify(e.name) == "mosaic":
+                if is_grouped_matmul(e.name):
                     calls += self_ns
         programs += sum(1 for e in _clip(dev.modules, t0, t1)
                         if e.name.startswith("jit_step"))

@@ -1,6 +1,7 @@
 """A traced window through the program's own names: the scopes in the
 compiled steps, the names of the flash kernels and the ``mv.*`` host spans
-(``PERF.md`` section 3 lists them with their files).
+(``PERF.md`` section 3 lists them with their files), and XLA's grouped
+matmul by the compiler's own name for it.
 
 What the compiled program says about an instruction is its ``op_name``, a
 path that JAX writes from the name stack at trace time::
@@ -36,19 +37,33 @@ import re
 from dataclasses import dataclass
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from benchmarks.trace.reduce import (_INSTRUCTION, WINDOW_SPAN, Event, Trace,
-                                     _clip, classify, instruction_name,
-                                     load_xplane, self_times)
+from benchmarks import flops
+from benchmarks.trace.reduce import (_INSTRUCTION, GROUPED_MATMUL,
+                                     WINDOW_SPAN, Event, Trace, _clip,
+                                     classify, instruction_name,
+                                     is_grouped_matmul, load_xplane,
+                                     self_times)
 
 __all__ = ["SCOPES", "KERNELS", "PHASES", "SPAN_PREFIX", "ScopeIndex",
            "components", "phase", "scope", "unscoped", "kernel",
            "span_totals", "Program", "summarize", "of_reading",
            "phase_ms_per_step", "scope_ms_per_step", "kernel_roofline",
-           "unscoped_share", "span_ms"]
+           "family_roofline", "roofline_pct", "unscoped_share", "span_ms"]
 
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+# A kernel is known by what its calls' names BEGIN with: ``flash_bwd`` is the
+# fused call where there is one and ``flash_bwd_dq`` + ``flash_bwd_dkv`` where
+# the program splits it (``ops/flash_attention.py:_fused_fits``), so a metric
+# reads the same work whatever implements it.  No name here begins another.
+KERNELS = ("flash_fwd", "flash_bwd", "flash_win_fwd", "flash_win_bwd",
+           "flash_mla_fwd", "flash_mla_bwd", "kda_fwd", "kda_bwd",
+           "row_update")
+# The scopes device time is booked to (the innermost counts).  The attention
+# and scan kernels are scopes of their own inside ``attn``; ``row_update`` is
+# not: ``tables.scatter_apply`` is all a step pays to apply its rows, the
+# kernel included (``tables.scatter_apply_ms_per_step``).
 SCOPES = ("embed", "layers", "attn", "mlp", "head", "loss", "update",
-          "tables.gather", "sgns.grad", "tables.scatter_apply") + KERNELS
+          "tables.gather", "sgns.grad", "tables.scatter_apply") + tuple(
+              k for k in KERNELS if k != "row_update")
 PHASES = ("fwd", "bwd", "remat", "update", "other")
 SPAN_PREFIX = "mv."
 REMAT = "rematted_computation"
@@ -86,28 +101,39 @@ def phase(op_name: Optional[str]) -> str:
     return "other"
 
 
+def _kernel_of(component: str) -> Optional[str]:
+    return next((k for k in KERNELS if component.startswith(k)), None)
+
+
+@functools.lru_cache(maxsize=None)       # a trace repeats a few hundred paths
 def scope(op_name: Optional[str], among: Sequence[str] = SCOPES
           ) -> Optional[str]:
-    """The innermost of the program's scope names in the path, or none."""
+    """The innermost of the program's scope names in the path, or none.  A
+    component that begins with a kernel's name counts as that kernel's scope
+    where ``among`` (a tuple) holds it (``flash_bwd_dq`` is ``flash_bwd``'s)."""
     for name, _ in reversed(components(op_name or "")):
         if name in among:
             return name
+        family = _kernel_of(name)
+        if family in among:
+            return family
     return None
+
+
+def kernel(op_name: Optional[str]) -> Optional[str]:
+    """Which of ``KERNELS`` a device event runs under: the innermost
+    component of its ``op_name`` that begins with a kernel's name, the
+    Mosaic call and any fusion the scope holds alike (all device time under
+    the name, as ``trace/linear.py`` reads the scan's passes).  The v5e
+    program also names the call's instruction after it, ``flash_bwd.10``;
+    the ``op_name`` is what this goes by."""
+    return scope(op_name, KERNELS)
 
 
 def unscoped(op_name: Optional[str]) -> bool:
     """No ``op_name``, or one that holds neither a phase nor a scope."""
     return not op_name or (phase(op_name) == "other"
                            and scope(op_name) is None)
-
-
-def kernel(event_name: str, op_name: Optional[str]) -> Optional[str]:
-    """Which flash kernel a device event is: a Mosaic custom call under a
-    kernel's scope.  (The v5e program also names the instruction after it,
-    ``flash_fwd.6``; the ``op_name`` is what this goes by.)"""
-    if classify(event_name) != "mosaic":
-        return None
-    return scope(op_name, KERNELS)
 
 
 # ------------------------------------------------------- where op_names are
@@ -228,7 +254,10 @@ class Program:
     busy_s: float
     by_phase_s: Dict[str, float]
     by_scope_s: Dict[str, float]              # innermost scope, any phase
-    by_kernel_s: Dict[str, float]
+    by_kernel_s: Dict[str, float]             # all time under ``KERNELS``'
+                                              # names; XLA's grouped matmul
+                                              # under ``ragged-dot``
+    by_call_s: Dict[str, float]               # ... in the Mosaic calls alone
     unscoped_s: List[Tuple[str, float]]       # by instruction, most first
     spans: Dict[str, Tuple[float, int]]       # mv.* -> (seconds, count)
 
@@ -246,17 +275,23 @@ def summarize(trace: Trace, index: ScopeIndex) -> Optional[Program]:
     phases = {p: 0.0 for p in PHASES}
     scopes: Dict[str, float] = {}
     kernels: Dict[str, float] = {}
+    calls: Dict[str, float] = {}
     loose: Dict[str, float] = {}
     programs = 0
     for dev in trace.devices.values():
         for e, self_ns in self_times(_clip(dev.ops, t0, t1)):
             op_name = index.op_name(e.name)
             phases[phase(op_name)] += self_ns
-            for key, into in ((scope(op_name), scopes),
-                              (kernel(e.name, op_name), kernels)):
+            # XLA's grouped matmul drops the program's scope and phase: it
+            # is known by its name, and is not what the names cannot see
+            grouped = is_grouped_matmul(e.name)
+            family = GROUPED_MATMUL if grouped else kernel(op_name)
+            for key, into in ((scope(op_name), scopes), (family, kernels),
+                              (family if classify(e.name) == "mosaic"
+                               else None, calls)):
                 if key is not None:
                     into[key] = into.get(key, 0.0) + self_ns
-            if unscoped(op_name):
+            if unscoped(op_name) and not grouped:
                 name = instruction_name(e.name)
                 loose[name] = loose.get(name, 0.0) + self_ns
         programs += sum(1 for e in _clip(dev.modules, t0, t1)
@@ -269,7 +304,7 @@ def summarize(trace: Trace, index: ScopeIndex) -> Optional[Program]:
         step_programs=programs // chips,
         busy_s=sum(phases.values()) / chips / 1e9,
         by_phase_s=seconds(phases), by_scope_s=seconds(scopes),
-        by_kernel_s=seconds(kernels),
+        by_kernel_s=seconds(kernels), by_call_s=seconds(calls),
         unscoped_s=sorted(seconds(loose).items(), key=lambda kv: -kv[1]),
         spans=span_totals(trace.host, t0, t1))
 
@@ -325,16 +360,49 @@ def kernel_roofline(reading, name: str) -> Optional[float]:
     """Kernel ``name``'s share of its compute roofline, percent: a third of
     the causal attention a step requires (``flops.causal_attention_flops``:
     forward 1, backward 2 = dP + dQ and dV + dK; the scores a backward
-    kernel rebuilds are recompute) at the bf16 peak, over the kernel's
-    time."""
+    kernel rebuilds are recompute) at the bf16 peak, over the time in the
+    Mosaic calls of that name alone (what XLA books to the kernel's name
+    beside them, a layout copy of its result, is not counted here: the
+    forward readers of PR 23 stand as they read)."""
     prog = of_reading(reading)
-    spent = prog.by_kernel_s.get(name, 0.0) if prog else 0.0
-    if spent <= 0 or not reading.peaks:
+    work = reading.facts.get("attention_flops_per_step")
+    if prog is None:
         return None
-    f = reading.facts
-    least_s = (f["attention_flops_per_step"] / 3 * prog.step_programs
-               / f["chips"] / reading.peaks["bf16_flops_per_s"])
-    return 100.0 * least_s / spent
+    return roofline_pct(reading, prog.by_call_s.get(name, 0.0),
+                        None if work is None else work / 3, 0.0,
+                        prog.step_programs)
+
+
+def family_roofline(reading, name: str, work: Optional[float],
+                    moved: Optional[float]) -> Optional[float]:
+    """The share of its roofline, percent, of everything the device runs
+    under names that begin ``name`` (one of ``KERNELS``): ``work`` FLOPs a
+    step at the bf16 peak or ``moved`` bytes a step at the HBM peak, the
+    larger, over ALL that device time.  ``work`` and ``moved`` are what the
+    algorithm requires, so a program that fuses two calls into one, or
+    splits one, is read by the same number, and one that issues less than it
+    requires cannot read over 100%.  Nothing where the trace holds no such
+    call, or the runner gave no count."""
+    prog = of_reading(reading)
+    if prog is None:
+        return None
+    return roofline_pct(reading, prog.by_kernel_s.get(name, 0.0), work, moved,
+                        prog.step_programs)
+
+
+def roofline_pct(reading, spent_s: float, work: Optional[float],
+                 moved: Optional[float], step_programs: int
+                 ) -> Optional[float]:
+    """``work`` FLOPs and ``moved`` bytes a step, over ``step_programs``
+    steps on the cell's chips, at the peaks (the larger of the two times)
+    over ``spent_s`` seconds of device time, percent; nothing where there is
+    no time, no peak or no count."""
+    if spent_s <= 0 or not reading.peaks or work is None or moved is None:
+        return None
+    per_chip = step_programs / reading.facts["chips"]
+    least_s, _bound = flops.roofline_seconds(work * per_chip,
+                                             moved * per_chip, reading.peaks)
+    return 100.0 * least_s / spent_s
 
 
 def unscoped_share(reading) -> Optional[float]:

@@ -14,9 +14,12 @@ What a trace of this system on a TPU v5e looks like (read by hand, PR 22,
   the instruction's opcode and, for a fusion, from the opcodes of the
   computation it calls, looked up in the compiled program's text;
 - a Mosaic kernel is a ``custom-call`` with
-  ``custom_call_target="tpu_custom_call"``; its instruction name follows the
-  jaxpr (``closed_call.6``, ``checkpoint.20``), not the kernel, so the three
-  flash kernels cannot be told apart by a stable name and are counted together;
+  ``custom_call_target="tpu_custom_call"``.  Since PR 23 the program names
+  its ``pallas_call``s and the v5e compiler names the instruction after the
+  kernel (``flash_bwd.10``, ``kda_fwd.26``, ``row_update.3``); XLA's own
+  grouped matmul is such a call too (``ragged-dot-none.N``).  ``classify``
+  books the two that are not attention kernels where their work belongs
+  (``_CALLS_BY_NAME``); an unnamed call (``closed_call.6``) stays ``mosaic``;
 - ``jax.profiler.TraceAnnotation`` spans land on the ``python`` line of the
   plane ``/host:CPU``, on the same clock as the device events (nanoseconds
   since the profile began), beside JAX's own host events
@@ -35,7 +38,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 __all__ = ["Event", "DeviceLines", "Trace", "Summary", "load_xplane",
            "HloIndex", "classify", "instruction_name", "union", "covered_ns",
            "subtract", "self_times", "summarize", "breakdown",
-           "WINDOW_SPAN", "CATEGORIES"]
+           "is_grouped_matmul", "WINDOW_SPAN", "CATEGORIES", "GROUPED_MATMUL"]
 
 # The runner wraps its measuring loop in this span; device events are
 # clipped to it, so profiler start-up and shut-down are not read as idle.
@@ -52,6 +55,12 @@ _MOVES = {"copy", "transpose", "bitcast", "reshape", "slice", "pad",
           "slice-done", "dynamic-slice", "broadcast", "parameter", "tuple",
           "get-tuple-element", "constant", "convert", "iota"}
 _CONTROL = {"while", "conditional", "call"}
+# ``tpu_custom_call``s that are no attention or scan kernel, by what the
+# instruction's name begins with: XLA's grouped matmul (the rewrite of
+# ``jax.lax.ragged_dot``; ``ragged-dot-metadata.N`` is its small set-up call)
+# and the sorted row update of ``ops/row_update.py``.
+GROUPED_MATMUL = "ragged-dot"
+_CALLS_BY_NAME = ((GROUPED_MATMUL, "matmul"), ("row_update", "scatter_gather"))
 
 
 @dataclass(frozen=True)
@@ -174,8 +183,10 @@ def classify(event_name: str, index: Optional[HloIndex] = None) -> str:
         # the instruction's name.
         op = re.sub(r"-(start|update|done)(\.\d+)?$", "", name)
     if op == "custom-call":
-        return ("mosaic" if 'custom_call_target="tpu_custom_call"' in rhs
-                else "other")
+        if 'custom_call_target="tpu_custom_call"' not in rhs:
+            return "other"
+        return next((cat for stem, cat in _CALLS_BY_NAME
+                     if name.startswith(stem)), "mosaic")
     if op.startswith(_COLLECTIVES):
         return "collective"
     if op in _CONTROL:
@@ -204,6 +215,14 @@ def classify(event_name: str, index: Optional[HloIndex] = None) -> str:
     if inner and inner <= _MOVES:
         return "copy"
     return "elementwise" if inner else "other"
+
+
+def is_grouped_matmul(event_name: str) -> bool:
+    """XLA's grouped-matmul call, which carries no scope and no phase: its
+    instruction and its ``op_name`` alike are ``ragged-dot-none.N``."""
+    m = _INSTRUCTION.match(event_name)
+    return bool(m and m.group(1).startswith(GROUPED_MATMUL)
+                and 'custom_call_target="tpu_custom_call"' in m.group(2))
 
 
 # ---------------------------------------------------------------- intervals
