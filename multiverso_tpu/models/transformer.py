@@ -13,7 +13,9 @@ Design (not in the reference — see models/__init__):
   where ``q_lora_rank`` > 0) pass a low-rank bottleneck and whose scores add
   a rotated part shared by the heads to an unrotated one, or linear
   attention, which carries a recurrent state a head through a chunked scan
-  (``ops/kda.py``) and no softmax; a per-head output gate, a dense or routed
+  (``ops/kda.py``) and no softmax, or EVA attention, one softmax over a
+  query's own window and chunk summaries of every earlier window
+  (``ops/flash_eva.py``); a per-head output gate, a dense or routed
   FFN that may hold a share of the experts beside a shared one), held as
   ``Layout`` writes them: a leading group, a period whose slots are stacked
   over its repetitions and scanned (a long run of consecutive slots alike as
@@ -21,7 +23,10 @@ Design (not in the reference — see models/__init__):
   the one-slot case;
 - a residual of ``hc_mult`` streams mixed by hyper-connections
   (``_hc_gates``, ``_hc_read``, ``_hc_write``), and a multi-token-prediction
-  module (``mtp_layers``), both off by default;
+  module (``mtp_layers``), both off by default; a residual stream wider in
+  precision than the sub-layers' compute (``residual_dtype``), norm gains
+  stored as offsets from one (``norm_unit_offset``) and ``n_pred_heads``
+  parallel heads predicting the next tokens, all off by default too;
 - updater integration: the train step applies the framework's server-side
   updaters (SURVEY.md §2.16) per parameter leaf, so a Multiverso user's
   ``-updater_type`` flag means the same thing here.
@@ -52,6 +57,7 @@ __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 LATENT, LINEAR = "latent_attention", "linear_attention"
+EVA = "eva_attention"
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -75,9 +81,10 @@ class Rope:
 
 class LayerKind(NamedTuple):
     """What one layer is made of: its attention (``full_attention`` |
-    ``sliding_attention`` | ``latent_attention`` | ``linear_attention``), its
-    query heads, its FFN (``dense`` | ``sparse``).  Window, rotary recipe and
-    widths follow from these and the configuration."""
+    ``sliding_attention`` | ``latent_attention`` | ``linear_attention`` |
+    ``eva_attention``), its query heads, its FFN (``dense`` | ``sparse``).
+    Window, rotary recipe and widths follow from these and the
+    configuration."""
     attn: str
     heads: int
     ffn: str
@@ -293,6 +300,16 @@ class TransformerConfig:
     # RMSNorm a head (gain ``o_norm [head_dim]``), ``attn_gate``, ``wo``.
     linear_conv_kernel: int = 4
     kda_lower_bound: float = -5.0
+    # ---- ``eva_attention`` layers (EVA, arXiv:2302.04542, as EvaByte runs
+    # it; ops/flash_eva.py), ``heads`` heads of ``head_dim``: q, k rotated
+    # (``rope_full``), v plain; chunk ``m`` of ``eva_chunk`` positions is one
+    # key and one value, ``a_j = softmax_{j in m}(scale k_j . phi)``, ``kbar_m
+    # = sum_j a_j k_j + mu``, ``vbar_m = sum_j a_j v_j`` (leaves ``phi``,
+    # ``mu`` [heads, head_dim]); query t of window ``w = t // eva_window``
+    # sees the keys ``eva_window * w <= j <= t`` and the summaries ``m <
+    # (eva_window // eva_chunk) * w`` in ONE softmax.
+    eva_window: int = 0
+    eva_chunk: int = 0
     # ---- Hyper-connections (arXiv:2512.24880 over arXiv:2409.19606): 0 =
     # the residual ``x + f(x)``; n > 0 = n residual streams ``[B, T, dim]``
     # (a tuple: the scan's carry), every sub-layer reading ``u = sum_i
@@ -313,6 +330,24 @@ class TransformerConfig:
     # shared embedding and head; ``loss += mtp_loss_coef * CE(token t+2)``.
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.3
+    # ---- The residual stream's dtype where it is not the compute dtype
+    # (None): "float32" keeps ``x + f(norm(x))`` summed in float32 while the
+    # norms and sub-layers compute in ``compute_dtype``.
+    residual_dtype: Any = None
+    # RMSNorm gains stored as offsets from one: ``x / rms(x) * (1 + w)``, the
+    # leaves drawn as zeros.
+    norm_unit_offset: bool = False
+    # n > 1: the head holds ``n * vocab_size`` columns, head i predicting
+    # token t + 1 + i from position t's state; the loss is the mean over the
+    # heads of each one's mean cross-entropy over the positions that have its
+    # target.
+    n_pred_heads: int = 1
+    # The logits' dtype where it is not the compute dtype (None): "float32"
+    # has the head's product accumulate and leave in float32.
+    logits_dtype: Any = None
+    # > 0: every matrix (embedding and head too) is drawn N(0, init_std^2);
+    # 0 = fan-in scaling, the embedding 0.02.
+    init_std: float = 0.0
 
     def __post_init__(self):
         def put(name, value):
@@ -330,9 +365,18 @@ class TransformerConfig:
         for name in ("rope_full", "rope_sliding", "rope_latent"):
             if isinstance(getattr(self, name), dict):
                 put(name, Rope(**getattr(self, name)))
+        for name in ("residual_dtype", "logits_dtype"):
+            if getattr(self, name) is not None:
+                put(name, jnp.dtype(getattr(self, name)))
+        if self.n_pred_heads < 1 or (self.n_pred_heads > 1
+                                     and self.mtp_layers):
+            raise ValueError(
+                f"n_pred_heads={self.n_pred_heads}: at least one head, and "
+                "parallel heads or a prediction module (mtp_layers), not "
+                "both")
         kv = self.n_kv_heads
         for k in self.layout.kinds:
-            if (k.attn not in (FULL, SLIDING, LATENT, LINEAR)
+            if (k.attn not in (FULL, SLIDING, LATENT, LINEAR, EVA)
                     or k.ffn not in (DENSE, SPARSE)):
                 raise ValueError(f"unknown layer kind {k}")
             if k.ffn == SPARSE and not self.num_experts:
@@ -361,6 +405,18 @@ class TransformerConfig:
                     raise ValueError(
                         "linear_attention layers need linear_conv_kernel "
                         ">= 1 and kda_lower_bound < 0")
+                continue
+            if k.attn == EVA:
+                if kv or self.qk_norm:
+                    raise ValueError(
+                        "eva_attention layers take no n_kv_heads or qk_norm: "
+                        "every head pools its own keys and values")
+                if (self.eva_chunk < 1 or self.eva_window < self.eva_chunk
+                        or self.eva_window % self.eva_chunk):
+                    raise ValueError(
+                        "eva_attention layers need eva_window a multiple of "
+                        f"eva_chunk >= 1, got {self.eva_window} / "
+                        f"{self.eva_chunk}")
                 continue
             if kv and k.heads % kv:
                 raise ValueError(f"{k.heads} query heads do not divide into "
@@ -445,18 +501,24 @@ def _hc_init(cfg: TransformerConfig, w):
                                  8.0 * np.eye(n).ravel()]).astype(np.float32)}
 
 
+def _unit_gain(cfg: TransformerConfig, n: int):
+    """A norm's gain of one as its leaf holds it: ones, or with
+    ``norm_unit_offset`` (the gain is ``1 + w``) zeros."""
+    return (np.zeros if cfg.norm_unit_offset else np.ones)(n, np.float32)
+
+
 def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
     heads, kv = kind.heads, cfg.n_kv_heads or kind.heads
     q_width, kv_width = heads * cfg.head_dim, kv * cfg.head_dim
     if kind.attn == LATENT:
         dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
         lyr = ({"wq_a": w(cfg.dim, cfg.q_lora_rank),
-                "q_a_norm": np.ones(cfg.q_lora_rank, np.float32),
+                "q_a_norm": _unit_gain(cfg, cfg.q_lora_rank),
                 "wq_b": w(cfg.q_lora_rank, heads * (dn + dr))}
                if cfg.q_lora_rank else {"wq": w(cfg.dim, heads * (dn + dr))})
         lyr.update({
             "wkv_a": w(cfg.dim, cfg.kv_lora_rank + dr),
-            "kv_a_norm": np.ones(cfg.kv_lora_rank, np.float32),
+            "kv_a_norm": _unit_gain(cfg, cfg.kv_lora_rank),
             "wkv_b": w(cfg.kv_lora_rank, heads * (dn + dv)),
             "wo": w(heads * dv, cfg.dim),
         })
@@ -473,7 +535,7 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
             wb=w(cfg.dim, heads), wo=w(q_width, cfg.dim),
             A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
             dt_bias=(dt + np.log(-np.expm1(-dt))).astype(np.float32),
-            o_norm=np.ones(cfg.head_dim, np.float32))
+            o_norm=_unit_gain(cfg, cfg.head_dim))
     else:
         lyr = {
             "wq": w(cfg.dim, q_width),
@@ -481,11 +543,18 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
             "wv": w(cfg.dim, kv_width),
             "wo": w(q_width, cfg.dim),
         }
-    lyr.update(attn_norm=np.ones(cfg.dim, np.float32),
-               mlp_norm=np.ones(cfg.dim, np.float32))
+        if kind.attn == EVA:
+            # The pooling's query and the summaries' key offset, a head:
+            # N(0, 1 / head_dim), cut at three deviations.
+            std = cfg.head_dim ** -0.5
+            lyr.update({key: np.clip(
+                std * rng.randn(heads, cfg.head_dim), -3 * std, 3 * std
+            ).astype(np.float32) for key in ("phi", "mu")})
+    lyr.update(attn_norm=_unit_gain(cfg, cfg.dim),
+               mlp_norm=_unit_gain(cfg, cfg.dim))
     if cfg.qk_norm:
-        lyr.update(q_norm=np.ones(q_width, np.float32),
-                   k_norm=np.ones(kv_width, np.float32))
+        lyr.update(q_norm=_unit_gain(cfg, q_width),
+                   k_norm=_unit_gain(cfg, kv_width))
     if kind.ffn == SPARSE:
         # router, w1, w3, w2 at the layer's top level, expert-indexed
         lyr.update(init_moe_params(cfg.dim, cfg.hidden, cfg.num_experts,
@@ -517,7 +586,7 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
     rng = np.random.RandomState(seed)
 
     def w(*shape, scale=None):
-        scale = scale or (shape[0] ** -0.5)
+        scale = cfg.init_std or scale or (shape[0] ** -0.5)
         return (scale * rng.randn(*shape)).astype(np.float32)
 
     layers = [_init_layer(cfg, kind, rng, w) for kind in cfg.layout.kinds]
@@ -525,16 +594,16 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
         layers = group_layers(cfg, layers)
     params = {
         "embed": w(cfg.vocab_size, cfg.dim, scale=0.02),
-        "out_norm": np.ones(cfg.dim, np.float32),
-        "head": w(cfg.dim, cfg.vocab_size),
+        "out_norm": _unit_gain(cfg, cfg.dim),
+        "head": w(cfg.dim, cfg.n_pred_heads * cfg.vocab_size),
         "layers": layers,
     }
     if cfg.mtp_layers:
         params["mtp"] = {
             "proj": w(2 * cfg.dim, cfg.dim),
-            "h_norm": np.ones(cfg.dim, np.float32),
-            "e_norm": np.ones(cfg.dim, np.float32),
-            "out_norm": np.ones(cfg.dim, np.float32),
+            "h_norm": _unit_gain(cfg, cfg.dim),
+            "e_norm": _unit_gain(cfg, cfg.dim),
+            "out_norm": _unit_gain(cfg, cfg.dim),
             "layer": _init_layer(cfg, cfg.layout.kinds[-1], rng, w)}
     return params
 
@@ -625,6 +694,9 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
                  for key in ("wq", "wk", "wv", "wf", "wb", "wo", "conv_q",
                              "conv_k", "conv_v")}
         layer.update(A_log=P(None), dt_bias=P(None), o_norm=P(None))
+    elif kind.attn == EVA:             # one device (``_forward`` refuses more)
+        layer = {key: P(None, None)
+                 for key in ("wq", "wk", "wv", "wo", "phi", "mu")}
     else:
         layer = {"wq": P(None, tp), "wk": P(None, tp), "wv": P(None, tp),
                  "wo": P(tp, None)}
@@ -632,7 +704,8 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
     if cfg.qk_norm:
         layer.update(q_norm=P(None), k_norm=P(None))
     if cfg.attn_gate:
-        layer["wg"] = P(None, None if kind.attn in (LATENT, LINEAR) else tp)
+        layer["wg"] = P(None, None if kind.attn in (LATENT, LINEAR, EVA)
+                        else tp)
     if kind.ffn == SPARSE:
         layer.update(moe_pspecs(mesh))
         if cfg.rule_bias:
@@ -805,7 +878,9 @@ def _hc_mean(X):
 def transformer_forward(params, tokens, cfg: TransformerConfig,
                         mesh: Optional[Mesh] = None,
                         return_aux: bool = False):
-    """tokens [B, T] int32 → logits [B, T, vocab] (compute dtype).
+    """tokens [B, T] int32 → logits [B, T, vocab] (compute dtype, or
+    ``logits_dtype``; with ``n_pred_heads`` n > 1 ``[B, T, n * vocab]``, head
+    i's columns ``[i * vocab, (i + 1) * vocab)`` predicting token t + 1 + i).
 
     With ``return_aux=True`` also returns the MoE auxiliary loss summed
     over the layers, weighted as ``lm_loss`` adds it: ``aux_loss_coef`` x
@@ -873,8 +948,26 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             f"sequence length {tokens.shape[1]} exceeds max_seq "
             f"{cfg.max_seq}")
     dt = cfg.compute_dtype
+    # The residual stream's dtype; the norms and sub-layers read it in ``dt``.
+    res_dt = cfg.residual_dtype or dt
+    wide = jnp.dtype(res_dt) != jnp.dtype(dt)
+
+    def gain(leaf):
+        """A norm's gain in the compute dtype: the leaf, or ``1 + leaf``."""
+        if cfg.norm_unit_offset:
+            return (leaf.astype(jnp.float32) + 1.0).astype(dt)
+        return leaf.astype(dt)
+
+    def add(x, out):
+        """The residual sum ``x + out`` in the stream's dtype."""
+        return x + (out.astype(res_dt) if wide else out)
+
+    def read(x):
+        """The stream as a norm reads it: in the compute dtype."""
+        return x.astype(dt) if wide else x
+
     with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(dt)            # [B,T,dim]
+        x = params["embed"][tokens].astype(res_dt)        # [B,T,dim]
     B, T, _ = x.shape
     scale = cfg.head_dim ** -0.5
     use_pp = (mesh is not None and cfg.pipeline_microbatches > 0
@@ -928,6 +1021,19 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             f"linear_attention runs on one device: on a mesh of {mesh.size} "
             "the scan's Mosaic kernel would need a shard_map of its own "
             "(GSPMD cannot partition it)")
+    if any(k.attn == EVA for k in lay.kinds) and mesh is not None \
+            and mesh.size > 1:
+        raise ValueError(
+            f"eva_attention runs on one device: on a mesh of {mesh.size} "
+            f"({dict(mesh.shape)}) its Mosaic kernels would need a shard_map "
+            "of their own (GSPMD cannot partition them), and no layout of "
+            "its heads and their summaries over 'tp', or of a window's "
+            "summaries along an 'sp' ring, is written")
+    if wide and (n_streams or use_pp or cfg.mtp_layers):
+        raise ValueError(
+            "residual_dtype does not compose with hc_mult, mtp_layers or "
+            "pipeline_microbatches: the streams' mixes, the module's "
+            "projection and the stages pass the compute dtype")
     if use_pp and (n_streams or cfg.mtp_layers):
         raise ValueError(
             "pipeline_microbatches does not compose with hc_mult or "
@@ -963,8 +1069,9 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 return contextlib.nullcontext()
             return jax.named_scope({SLIDING: "attn.sliding",
                                     LATENT: "attn.latent",
-                                    LINEAR: "attn.linear"}.get(kind.attn,
-                                                               "attn.full"))
+                                    LINEAR: "attn.linear",
+                                    EVA: "attn.eva"}.get(kind.attn,
+                                                         "attn.full"))
 
         def wc(w):
             # Named so the "dots" policy SAVES the bf16 weight cast:
@@ -981,14 +1088,14 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
             if cfg.q_lora_rank:
                 c_q = _rms_norm(h @ wc(lyr["wq_a"]),
-                                lyr["q_a_norm"].astype(dt), cfg.norm_eps)
+                                gain(lyr["q_a_norm"]), cfg.norm_eps)
                 q = c_q @ wc(lyr["wq_b"])
             else:
                 q = h @ wc(lyr["wq"])
             q = q.reshape(Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
             kv_a = h @ wc(lyr["wkv_a"])
             c_kv = _rms_norm(kv_a[..., :cfg.kv_lora_rank],
-                             lyr["kv_a_norm"].astype(dt), cfg.norm_eps)
+                             gain(lyr["kv_a_norm"]), cfg.norm_eps)
             kv = (c_kv @ wc(lyr["wkv_b"])).reshape(
                 Bb, Tb, local_heads, dn + dv).transpose(0, 2, 1, 3)
             # the rotated key part is one head, whatever the query heads
@@ -1039,8 +1146,38 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 jnp.exp(lyr["A_log"].astype(f32))[:, None] * f)
             beta = jax.nn.sigmoid(
                 jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32))
-            o = _rms_norm(kda(q, k, v, g, beta), lyr["o_norm"].astype(dt),
+            o = _rms_norm(kda(q, k, v, g, beta), gain(lyr["o_norm"]),
                           cfg.norm_eps)
+            if cfg.attn_gate:
+                o = gated(o, h, lyr)
+            return o.reshape(Bb, Tb, local_heads * D)
+
+        def eva_heads(h, lyr):
+            """EVA attention's heads from the normed input ``h``: [B, T,
+            heads * head_dim] (the configuration's ``eva_attention`` comment
+            has the equations)."""
+            from ..ops.flash_eva import eva_attention, summarise
+
+            Bb, Tb, _ = h.shape
+            D = cfg.head_dim
+
+            def heads_of(y):
+                return y.reshape(Bb, Tb, local_heads, D).transpose(0, 2, 1, 3)
+
+            q = _rope(heads_of(h @ wc(lyr["wq"])), rope)
+            k = _rope(heads_of(h @ wc(lyr["wk"])), rope)
+            v = heads_of(h @ wc(lyr["wv"]))
+            chunk = cfg.eva_chunk
+            # whole chunks, and past one window whole windows: the padding
+            # lies after every query, so none sees it or its summaries
+            pad = -Tb % (cfg.eva_window if Tb > cfg.eva_window else chunk)
+            if pad:
+                q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                           for y in (q, k, v))
+            kbar, vbar = summarise(k, v, lyr["phi"], lyr["mu"], scale, chunk)
+            o = eva_attention(q, k, v, kbar, vbar, cfg.eva_window, chunk,
+                              scale=scale)[:, :, :Tb]
+            o = o.transpose(0, 2, 1, 3)                      # [B,T,H,D]
             if cfg.attn_gate:
                 o = gated(o, h, lyr)
             return o.reshape(Bb, Tb, local_heads * D)
@@ -1052,15 +1189,16 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             block sees microbatches, not the full batch."""
             Bb, Tb, _ = x.shape
             with jax.named_scope("attn"), kind_scope():
-                h = _rms_norm(x, lyr["attn_norm"].astype(dt), cfg.norm_eps)
-                if latent or kind.attn == LINEAR:
-                    heads = latent_heads if latent else linear_heads
+                h = _rms_norm(read(x), gain(lyr["attn_norm"]), cfg.norm_eps)
+                if kind.attn in (LATENT, LINEAR, EVA):
+                    heads = {LATENT: latent_heads, LINEAR: linear_heads,
+                             EVA: eva_heads}[kind.attn]
                     out = red(heads(h, lyr) @ wc(lyr["wo"]))
-                    return x + out if residual else out
+                    return add(x, out) if residual else out
                 q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
                 if cfg.qk_norm:
-                    q = _rms_norm(q, lyr["q_norm"].astype(dt), cfg.norm_eps)
-                    k = _rms_norm(k, lyr["k_norm"].astype(dt), cfg.norm_eps)
+                    q = _rms_norm(q, gain(lyr["q_norm"]), cfg.norm_eps)
+                    k = _rms_norm(k, gain(lyr["k_norm"]), cfg.norm_eps)
                 q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
                 k = k.reshape(Bb, Tb, local_kv, cfg.head_dim)
                 v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_kv,
@@ -1082,14 +1220,14 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                     o = o * gate.astype(dt)[..., None]
                 o = o.reshape(Bb, Tb, local_heads * cfg.head_dim)
                 out = red(o @ wc(lyr["wo"]))
-                return x + out if residual else out
+                return add(x, out) if residual else out
 
         def mlp_sub(x, lyr, residual=True):
             """``(the FFN sub-layer of x, its weighted auxiliary loss, what
             it counted)``: a routed layer's ``(load, kept)`` (``moe_ffn``'s;
             ``kept`` is None without a group limit), a dense one's None."""
             with jax.named_scope("mlp"):
-                h = _rms_norm(x, lyr["mlp_norm"].astype(dt), cfg.norm_eps)
+                h = _rms_norm(read(x), gain(lyr["mlp_norm"]), cfg.norm_eps)
                 if kind.ffn == SPARSE:
                     out, balance, z, load, kept = moe_ffn(
                         lyr, h, top_k=cfg.top_k, compute_dtype=dt,
@@ -1102,11 +1240,13 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                         out = out + shared_expert(lyr, h, dt)
                     aux = (cfg.aux_loss_coef * balance
                            + cfg.router_z_loss_coef * z)
-                    return (x + out if residual else out), aux, (load, kept)
+                    return ((add(x, out) if residual else out), aux,
+                            (load, kept))
                 gated = (jax.nn.silu(h @ wc(lyr["w1"]))
                          * (h @ wc(lyr["w3"])))
                 out = red(gated @ wc(lyr["w2"]))
-                return (x + out if residual else out), jnp.float32(0), None
+                return ((add(x, out) if residual else out),
+                        jnp.float32(0), None)
 
         def block(x, lyr):
             """One decoder layer: attn + residual, MLP/MoE + residual; with
@@ -1302,8 +1442,8 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 nxt = nxt.astype(dt) * (jnp.arange(T) < T - 1
                                         ).astype(dt)[None, :, None]
                 g = jnp.concatenate(
-                    [_rms_norm(x, m["h_norm"].astype(dt), cfg.norm_eps),
-                     _rms_norm(nxt, m["e_norm"].astype(dt), cfg.norm_eps)],
+                    [_rms_norm(x, gain(m["h_norm"]), cfg.norm_eps),
+                     _rms_norm(nxt, gain(m["e_norm"]), cfg.norm_eps)],
                     axis=-1) @ m["proj"].astype(dt)
                 if n_streams:
                     g = (g,) * n_streams
@@ -1314,15 +1454,19 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 if n_streams:
                     g = _hc_mean(g)
                 with jax.named_scope("head"):
-                    g = _rms_norm(g, m["out_norm"].astype(dt), cfg.norm_eps)
+                    g = _rms_norm(g, gain(m["out_norm"]), cfg.norm_eps)
                     mtp_logits = g @ params["head"].astype(dt)
         counted = (None if not counted else counted[0] if len(counted) == 1
                    else tmap(lambda *parts: jnp.concatenate(parts), *counted))
 
     load, kept = counted or (None, None)
     with jax.named_scope("head"):
-        x = _rms_norm(x, params["out_norm"].astype(dt), cfg.norm_eps)
-        logits = x @ params["head"].astype(dt)
+        x = _rms_norm(read(x), gain(params["out_norm"]), cfg.norm_eps)
+        if cfg.logits_dtype is not None:
+            logits = jnp.dot(x, params["head"].astype(dt),
+                             preferred_element_type=cfg.logits_dtype)
+        else:
+            logits = x @ params["head"].astype(dt)
     return logits, aux_total, load, mtp_logits, kept
 
 
@@ -1418,7 +1562,15 @@ def _ce_parts(params, tokens, cfg: TransformerConfig,
     # small win at dim 3072).
     ce_fn = _ce if cfg.vocab_size >= 12288 else _ce_value
     with jax.named_scope("loss"):
-        ce = ce_fn(logits[:, :-1], tokens[:, 1:])
+        if cfg.n_pred_heads > 1:
+            # head i's mean over the positions that have token t + 1 + i,
+            # the heads weighted alike
+            V, T = cfg.vocab_size, tokens.shape[1]
+            ce = sum(ce_fn(logits[:, :T - 1 - i, i * V:(i + 1) * V],
+                           tokens[:, 1 + i:])
+                     for i in range(cfg.n_pred_heads)) / cfg.n_pred_heads
+        else:
+            ce = ce_fn(logits[:, :-1], tokens[:, 1:])
     ce_mtp = None
     if mtp_logits is not None:       # position t predicts token t + 2
         with jax.named_scope("mtp"), jax.named_scope("loss"):

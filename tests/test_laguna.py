@@ -617,12 +617,17 @@ def test_benchmark_lists_the_cell_where_its_readers_are_right():
     listed = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
               if cell in m.get("workloads", ())}
     assert {"tokens_per_chip_s", "kernel.flash_fwd_roofline",
-            "kernel.flash_win_fwd_roofline", "kernel.flash_win_dq_roofline",
-            "kernel.flash_win_dkv_roofline", "kernel.moe_gmm_held_roofline",
+            "kernel.flash_win_fwd_roofline", "kernel.flash_win_bwd_roofline",
+            "kernel.flash_bwd_roofline", "kernel.moe_gmm_held_roofline",
             "model.attn_sliding_ms_per_step", "model.attn_full_ms_per_step",
             "model.moe_held_route_share", "model.moe_share"} <= listed
+    # the split calls' entries were retired with the calls (PR 39)
     assert not listed & {"kernel.moe_gmm_roofline", "kernel.flash_share",
-                         "kernel.flash_roofline"}
+                         "kernel.flash_roofline",
+                         "kernel.flash_win_dq_roofline",
+                         "kernel.flash_win_dkv_roofline"}
+    assert bench["workloads"][5] is row          # later PRs' cells follow
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     from benchmarks import harness
     readers = harness.layer_readers((os.path.join(REPO, "benchmarks"),))
     loaded = harness.load_cell(cell)

@@ -989,19 +989,21 @@ def test_the_benchmark_declares_the_cell_and_its_metrics():
     names = {m["name"] for m in cell.per_layer}
     assert {"model.attn_linear_ms_per_step", "kernel.kda_fwd_roofline",
             "kernel.kda_bwd_roofline", "model.moe_group_kept_share",
-            "kernel.flash_mla_fwd_roofline", "kernel.flash_mla_dq_roofline",
-            "kernel.flash_mla_dkv_roofline", "model.attn_latent_ms_per_step",
+            "kernel.flash_mla_fwd_roofline", "kernel.flash_mla_bwd_roofline",
+            "model.attn_latent_ms_per_step",
             "model.moe_held_route_share", "kernel.moe_gmm_held_roofline",
             "model.mfu_pct", "device.idle_share"} <= names
     assert not names & {"kernel.flash_fwd_roofline", "kernel.flash_share",
                         "kernel.flash_roofline", "kernel.moe_gmm_roofline",
                         "model.hc_ms_per_step", "model.mtp_ms_per_step",
-                        "kernel.flash_win_fwd_roofline"}
+                        "kernel.flash_win_fwd_roofline",
+                        "kernel.flash_mla_dq_roofline",
+                        "kernel.flash_mla_dkv_roofline"}
     assert {m["name"] for m in cell.end_to_end} == {
         "setup_s", "tokens_per_chip_s", "peak_hbm_gib"}
     readers = harness.layer_readers(cell.search)
     assert names <= set(readers)
-    assert len(bench["workloads"]) == 8
+    assert bench["workloads"][7]["name"] == CELL   # later PRs' cells follow
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     for w in bench["workloads"] + bench["configs"]:
         assert len(w["why"]) <= 200

@@ -848,7 +848,7 @@ def test_benchmark_lists_the_cell_where_its_readers_are_right():
     listed = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
               if CELL in m.get("workloads", ())}
     assert {"tokens_per_chip_s", "kernel.flash_mla_fwd_roofline",
-            "kernel.flash_mla_dq_roofline", "kernel.flash_mla_dkv_roofline",
+            "kernel.flash_mla_bwd_roofline",
             "model.attn_latent_ms_per_step", "model.hc_ms_per_step",
             "model.mtp_ms_per_step", "kernel.moe_gmm_held_roofline",
             "model.moe_held_route_share", "model.moe_share",
@@ -856,14 +856,18 @@ def test_benchmark_lists_the_cell_where_its_readers_are_right():
     assert not listed & {"kernel.moe_gmm_roofline", "kernel.flash_share",
                          "kernel.flash_roofline", "kernel.flash_fwd_roofline",
                          "kernel.flash_dq_roofline",
-                         "kernel.flash_dkv_roofline"}
-    # appended in this order; later PRs' entries follow them
+                         "kernel.flash_dkv_roofline",
+                         "kernel.flash_mla_dq_roofline",
+                         "kernel.flash_mla_dkv_roofline"}
+    # appended in this order; later PRs' entries follow them (the backward's
+    # one roofline a family came in PR 39, where its two by call went)
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index("kernel.flash_mla_fwd_roofline")
-    assert names[first:first + 6] == [
-        "kernel.flash_mla_fwd_roofline", "kernel.flash_mla_dq_roofline",
-        "kernel.flash_mla_dkv_roofline", "model.attn_latent_ms_per_step",
+    assert names[first:first + 4] == [
+        "kernel.flash_mla_fwd_roofline", "model.attn_latent_ms_per_step",
         "model.hc_ms_per_step", "model.mtp_ms_per_step"]
+    assert names.index("kernel.flash_mla_bwd_roofline") > first + 3
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     from benchmarks import harness
     readers = harness.layer_readers((os.path.join(REPO, "benchmarks"),))
     loaded = harness.load_cell(CELL)
