@@ -358,8 +358,8 @@ def phase_flash_reference(batch: int = 2, heads: int = 4, seq: int = 1024,
 def phase_flash_latent(heads: int = 32, seq: int = 8192, nope: int = 128,
                        rope: int = 64, v_dim: int = 128,
                        tol: float = 3e-2) -> dict:
-    """The three two-width kernels (``flash_mla_fwd``, ``flash_mla_bwd_dq``,
-    ``flash_mla_bwd_dkv``) at the shape of the cell
+    """The two-width kernels (``flash_mla_fwd`` and the one backward call
+    ``flash_mla_bwd``, which this shape takes) at the shape of the cell
     ``xing4.0-29b-a4b-e8.zipf-seq8k-b1`` (1 x 32 x 8192, scores 128 + 64
     wide, values 128, the rotated key one head for all 32) against a dense
     float32 reference computed a head at a time.  Errors as in

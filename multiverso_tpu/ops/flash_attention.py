@@ -12,15 +12,15 @@ partial diagonals, and are named ``flash_win_fwd`` and ``flash_win_bwd``
 (``flash_win_bwd_dq``, ``flash_win_bwd_dkv`` where the backward is split).
 
 **Two widths** (``flash_attention_latent``, latent attention's decompressed
-form): a score is the sum of two products, ``q_n . k_n`` over the unrotated
-dims of a head and ``q_r . k_r`` over the rotated ones, and the values have
-a width of their own.  The rotated key part is ONE head that every query
-head reads through the index maps (nothing is repeated in HBM), and its
-gradient is summed over the query heads inside the dkv kernel, whose grid
-has an axis over them as the grouped one has.  The two products stay two
-(128 + 64 wide: no operand is padded to 256); causal only.  Kernels
-``flash_mla_fwd``, ``flash_mla_bwd_dq``, ``flash_mla_bwd_dkv``; a trace is
-counted in ``attention.latent_traced{qk=,v=}``.
+form): a score is ``q_n . k_n`` over a head's unrotated dims plus ``q_r .
+k_r`` over the rotated ones (two products, 128 + 64 wide: no operand is
+padded to 256), values of a width of their own; causal only.  The rotated
+key is ONE head that every query head reads through the index maps, its
+gradient summed over them inside the kernel.  Kernels ``flash_mla_fwd`` and
+ONE backward, ``flash_mla_bwd`` (PR 41: the tile built once, eight MXU
+passes for the pair's eleven; ``flash_mla_bwd_dq`` + ``flash_mla_bwd_dkv``
+where ``_mla_fused_fits`` turns the shape away); counters ``attention.
+latent_traced{qk=,v=}``, ``attention.latent_bwd_traced{path=fused|split}``.
 
 Causal/full attention with O(T) memory: the forward grid walks (batch·head,
 q-block, k-block) with the k dimension innermost; per q-block the kernel
@@ -1046,20 +1046,191 @@ def _flash_mla_fwd(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
     return (o, lse), (qn, qr, kn, kr, v, o, lse)
 
 
+def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                    dqn_acc, dqr_acc, dkn_acc, dkr_acc, dv_acc, *, scale,
+                    block_q, block_k, num_q, num_k, heads):
+    # ``_bwd_kernel`` at two widths: the two-part scores, the mask, p, dp and
+    # ds of a tile are built ONCE and feed all five gradients (eight MXU
+    # passes a tile where the dq and dkv kernels issue eleven, one pass of
+    # exp for two).  Grid (batch, query head, k block, q step).  dq_n and
+    # dq_r are held for the WHOLE sequence of the query head, float32, a
+    # tile adding into its q block's rows; dk_n and dv are the block the q
+    # steps stay on; dk_r, one head shared by all, is held whole while every
+    # head of the batch element passes (``_bwd_kernel``'s group, the group
+    # being all the heads).  The tile is built TRANSPOSED, [Bk, Bq]: dv, dk_n
+    # and dk_r are plain matmuls, only the two dq contract a leading axis,
+    # and lse and delta arrive as rows [1, Bq].  q_n, q_r PRE-SCALED.
+    head = pl.program_id(1)
+    ki = pl.program_id(2)
+    qi = pl.program_id(3)
+    first_of_head = (ki == 0) & (qi == 0)
+    last_of_head = (ki == num_k - 1) & (qi == num_q - 1)
+
+    @pl.when(first_of_head)
+    def _init_q():
+        dqn_acc[:] = jnp.zeros_like(dqn_acc)
+        dqr_acc[:] = jnp.zeros_like(dqr_acc)
+
+    @pl.when(first_of_head & (head == 0))
+    def _init_shared():
+        dkr_acc[:] = jnp.zeros_like(dkr_acc)
+
+    @pl.when(qi == 0)
+    def _init_kv():
+        dkn_acc[:] = jnp.zeros_like(dkn_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _compute(masked):
+        qn, qr, do = qn_ref[0], qr_ref[0], do_ref[0]     # [Bq, *]
+        kn, kr = kn_ref[0], kr_ref[0]                    # [Bk, *]
+        st = _mla_scores(kn, kr, qn, qr)                 # [Bk, Bq]
+        if masked:
+            st = _causal_mask(st, qi, ki, block_q, block_k, q_axis=1)
+        pt = jnp.exp(st - lse_ref[0])
+        dpt = jax.lax.dot_general(v_ref[0], do, (((1,), (1,)), ((), ())),
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0])).astype(qn.dtype)
+        plain = (((1,), (0,)), ((), ()))
+        dv_acc[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, plain,
+            preferred_element_type=jnp.float32)          # [Bk, Dv]
+        dkn_acc[:] += jax.lax.dot_general(
+            dst, qn, plain, preferred_element_type=jnp.float32)
+        k_rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        dkr_acc[k_rows, :] += jax.lax.dot_general(
+            dst, qr, plain, preferred_element_type=jnp.float32)
+        q_rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        leading = (((0,), (0,)), ((), ()))
+        dqn_acc[q_rows, :] += jax.lax.dot_general(
+            dst, kn, leading, preferred_element_type=jnp.float32)
+        dqr_acc[q_rows, :] += jax.lax.dot_general(
+            dst, kr, leading, preferred_element_type=jnp.float32)
+
+    computed = qi * block_q + block_q - 1 >= ki * block_k
+    full = qi * block_q >= ki * block_k + block_k - 1
+    pl.when(computed & full)(lambda: _compute(False))
+    pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+
+    @pl.when(qi == num_q - 1)
+    def _finalize_kv():
+        dkn_ref[0] = dkn_acc[:].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(last_of_head)
+    def _finalize_q():
+        dqn_ref[0] = (dqn_acc[:] * scale).astype(dqn_ref.dtype)
+        dqr_ref[0] = (dqr_acc[:] * scale).astype(dqr_ref.dtype)
+
+    @pl.when(last_of_head & (head == heads - 1))
+    def _finalize_shared():
+        dkr_ref[0] = dkr_acc[:].astype(dkr_ref.dtype)
+
+
+def _mla_fused_fits(T, Dn, Dr, dtype, block_q):
+    """``_fused_fits`` for the two-width backward, from the shapes alone: one
+    call (``flash_mla_bwd``) when what it holds while a head (dq_n, dq_r) or
+    a batch element (dk_r) passes, the float32 accumulators and the
+    double-buffered output blocks they are cast into, fits
+    ``_FUSED_RESIDENT_BYTES`` (16 MiB at Xing's 8,192 tokens in bfloat16, 32
+    at Ling's 16,384, the longest that does; the tile's own intermediates,
+    some 36 MiB at 1024 x 1024 with the two dq results, stand beside them
+    inside ``_FUSED_VMEM_LIMIT``) and a q block's row statistics make whole
+    lanes; the dq and dkv kernels otherwise."""
+    resident = T * (Dn + 2 * Dr) * (4 + 2 * jnp.dtype(dtype).itemsize)
+    return (resident <= _FUSED_RESIDENT_BYTES
+            and (block_q % _LANES == 0 or block_q == T))
+
+
 def _flash_mla_bwd(scale, heads, block_q, block_k, block_q_bwd, block_k_bwd,
                    interpret, res, cts):
+    from .. import metrics
+
     block_q, block_k = block_q_bwd, block_k_bwd
     qn, qr, kn, kr, v, o, lse = res
     do, dlse = cts
+    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+             - dlse.astype(jnp.float32))
+    qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
+    qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
+    fused = _mla_fused_fits(qn.shape[1], qn.shape[2], qr.shape[2], qn.dtype,
+                            block_q)
+    metrics.counter("attention.latent_bwd_traced",
+                    {"path": "fused" if fused else "split"}).inc()
+    return (_mla_bwd_fused if fused else _mla_bwd_split)(
+        qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q, block_k,
+        interpret)
+
+
+def _mla_bwd_fused(qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q,
+                   block_k, interpret):
+    """dq_n, dq_r, dk_n, dk_r, dv from ONE call (``flash_mla_bwd``); q_n and
+    q_r pre-scaled, lse and delta [bh, T] float32."""
     bh, T, Dn = qn.shape
     Dr, Dv = qr.shape[-1], v.shape[-1]
     num_q, num_k = T // block_q, T // block_k
-    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-             - dlse.astype(jnp.float32))
+
+    # grid (batch, query head, k block, q step); a step above the column's
+    # computed blocks keeps the first of them: it is skipped, and nothing is
+    # fetched for it
+    def q_block(i, j):
+        return jnp.maximum(j, _first_q(i, block_q, block_k))
+
+    def of_q(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda b, h, i, j: (b * heads + h, q_block(i, j),
+                                                0))
+
+    def of_k(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda b, h, i, j: (b * heads + h, i, 0))
+
+    def of_head(width):
+        return pl.BlockSpec((1, T, width),
+                            lambda b, h, i, j: (b * heads + h, 0, 0))
+
+    row_spec = pl.BlockSpec(
+        (1, 1, block_q), lambda b, h, i, j: (b * heads + h, 0, q_block(i, j)))
+    return _named_call(
+        "flash_mla_bwd",
+        functools.partial(_mla_bwd_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, num_q=num_q, num_k=num_k,
+                          heads=heads),
+        grid=(bh // heads, heads, num_k, num_q),
+        in_specs=[of_q(Dn), of_q(Dr), of_k(Dn),
+                  pl.BlockSpec((1, block_k, Dr),
+                               lambda b, h, i, j: (b, i, 0)),
+                  of_k(Dv), of_q(Dv), row_spec, row_spec],
+        out_specs=[of_head(Dn), of_head(Dr), of_k(Dn),
+                   pl.BlockSpec((1, T, Dr), lambda b, h, i, j: (b, 0, 0)),
+                   of_k(Dv)],
+        out_shape=[jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+                   jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+                   jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((T, Dn), jnp.float32),
+                        pltpu.VMEM((T, Dr), jnp.float32),
+                        pltpu.VMEM((block_k, Dn), jnp.float32),
+                        pltpu.VMEM((T, Dr), jnp.float32),
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_FUSED_VMEM_LIMIT),
+        interpret=interpret,
+    )(qn, qr, kn, kr, v, do, lse[:, None, :], delta[:, None, :])
+
+
+def _mla_bwd_split(qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q,
+                   block_k, interpret):
+    """dq_n, dq_r and dk_n, dk_r, dv from a kernel each (``flash_mla_bwd_dq``,
+    ``flash_mla_bwd_dkv``): what runs where the fused call's whole-sequence
+    accumulators do not fit beside the tile.  Same operands as
+    ``_mla_bwd_fused``."""
+    bh, T, Dn = qn.shape
+    Dr, Dv = qr.shape[-1], v.shape[-1]
+    num_q, num_k = T // block_q, T // block_k
     lse_b = jnp.broadcast_to(lse[:, :, None], (bh, T, _LANES))
     delta_b = jnp.broadcast_to(delta[:, :, None], (bh, T, _LANES))
-    qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
-    qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
     operands = (qn, qr, kn, kr, v, do, lse_b, delta_b)
 
     def q_spec(width):
@@ -1127,8 +1298,10 @@ def flash_attention_latent(q_nope, q_rope, k_nope, k_rope, v,
     (one head for all H), v [B,H,T,Dv] → [B,H,T,Dv].  ``scale`` defaults to
     ``(Dn + Dr) ** -0.5``.  Differentiable in all five (``jax.custom_vjp``);
     ``k_rope``'s gradient comes back ``[B,1,T,Dr]``, summed over the heads
-    inside the dkv kernel.  Blocks shrink to divide ``T`` as
-    ``flash_attention``'s do."""
+    inside the backward kernel (ONE call, ``flash_mla_bwd``, where the
+    shapes fit: ``_mla_fused_fits``).  Blocks shrink to divide ``T`` as
+    ``flash_attention``'s do; 1024 x 1024 is the backward's fastest at 8,192
+    and 16,384 tokens (swept on a v5e: PERF.md section 6, PR 41)."""
     from .. import metrics
 
     B, H, T, Dn = q_nope.shape

@@ -159,7 +159,7 @@ def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,
     if q_offset == 0 and k_offset == 0 and T == k.shape[2]:
         # Latent attention keeps the D=128 blocks: no operand's tile is
         # wider than 128 (the rotated parts are 64), and the v5e compiler
-        # takes the three kernels at 512x1024 / 1024x1024.
+        # takes both kernels at 512x1024 / 1024x1024.
         flash = _flash_dispatch(T, T, max(D, v.shape[-1]) if latent else D,
                                 window)
     if flash is not None and latent:

@@ -5,6 +5,7 @@ prediction module, held to the plain reference
 ``benchmarks/reference/xing_lm.py``, small, on the CPU."""
 
 import hashlib
+import importlib
 import json
 import os
 import sys
@@ -33,9 +34,11 @@ from multiverso_tpu.models.transformer import (_bias_rule,  # noqa: E402
                                                param_shardings,
                                                transformer_forward)
 from multiverso_tpu.ops.flash_attention import (  # noqa: E402
-    flash_attention_latent)
+    fit_block, flash_attention_latent)
 from multiverso_tpu.parallel.ring_attention import (  # noqa: E402
     blockwise_attention_local)
+
+fa = importlib.import_module("multiverso_tpu.ops.flash_attention")
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CONFIG = os.path.join(REPO, "benchmarks", "configs",
@@ -207,12 +210,18 @@ def test_leaving_a_part_out_fails_the_comparison(switch, least):
 
 
 # ------------------------------------------------------ the two-width kernel
-def _dense_latent(qn, qr, kn, kr, v, scale):
+def _dense_latent_lse(qn, qr, kn, kr, v, scale):
+    """Dense two-width causal attention and the rows' logsumexp beside it."""
     T = qn.shape[2]
     s = (jnp.einsum("bhtd,bhsd->bhts", qn, kn)
          + jnp.einsum("bhtd,bsd->bhts", qr, kr[:, 0])) * scale
     s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
-    return jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(s, axis=-1), v)
+    return (jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(s, axis=-1), v),
+            jax.scipy.special.logsumexp(s, axis=-1))
+
+
+def _dense_latent(qn, qr, kn, kr, v, scale):
+    return _dense_latent_lse(qn, qr, kn, kr, v, scale)[0]
 
 
 @pytest.mark.parametrize("T,heads,dn,dr,dv,bq,bk,bqb,bkb", [
@@ -272,14 +281,17 @@ def one_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_flash_mla_kernels_compile_for_v5e_at_the_cells_shape(one_v5e):
-    """Mosaic takes the three kernels at 1 x 32 x 8192, scores 128 + 64,
-    values 128, at the blocks the dispatcher gives (512 x 1024 forward, 1024
-    x 1024 backward).  Nothing runs: no measurement."""
+@pytest.mark.parametrize("seq", [8192, 16384], ids=["xing-8k", "ling-16k"])
+def test_flash_mla_kernels_compile_for_v5e_at_the_cells_shape(one_v5e, seq):
+    """Mosaic takes the forward and the ONE backward call at 1 x 32 x 8192
+    (Xing's cell) and 1 x 32 x 16384 (Ling's), scores 128 + 64, values 128,
+    at the blocks the dispatcher gives (512 x 1024 forward, 1024 x 1024
+    backward) and inside the VMEM limit the backward asks for; the dq and dkv
+    kernels are not in the program.  Nothing runs: no measurement."""
     from jax.experimental.compilation_cache import compilation_cache
 
     def shaped(heads, width):
-        return jax.ShapeDtypeStruct((1, heads, 8192, width), jnp.bfloat16,
+        return jax.ShapeDtypeStruct((1, heads, seq, width), jnp.bfloat16,
                                     sharding=one_v5e)
 
     def grads(*a):
@@ -296,8 +308,8 @@ def test_flash_mla_kernels_compile_for_v5e_at_the_cells_shape(one_v5e):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
-    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
-        assert name in text
+    assert "flash_mla_fwd." in text and "flash_mla_bwd." in text
+    assert "bwd_dq" not in text and "bwd_dkv" not in text
 
 
 @pytest.mark.parametrize("batch,heads,kv,seq,window", [
@@ -347,18 +359,122 @@ def test_flash_mla_names_counter_and_refusals():
             draw(1, 1, 64, 8), draw(1, 2, 64, 16))
     counter = metrics.counter("attention.latent_traced",
                               {"qk": "24", "v": "16"})
-    before = counter.value
+    fused = metrics.counter("attention.latent_bwd_traced", {"path": "fused"})
+    before, fused_before = counter.value, fused.value
     text = str(jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(flash_attention_latent(*a, interpret=True))))(
             *args))
-    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
-        assert name in text
+    # one block of 64 = T: the backward is the one call
+    assert "flash_mla_fwd" in text and "flash_mla_bwd" in text
+    assert "bwd_dq" not in text and "bwd_dkv" not in text
     assert "flash_fwd" not in text and counter.value == before + 1
+    assert fused.value == fused_before + 1
     with pytest.raises(ValueError, match="k_rope \\[B,1,T,Dr\\]"):
         flash_attention_latent(args[0], args[1], args[2],
                                draw(1, 2, 64, 8), args[4])
     with pytest.raises(ValueError, match="no usable block"):
         flash_attention_latent(*(a[:, :, :7] for a in args))
+
+
+def _flash_latent_lse(qn, qr, kn, kr, v, scale, block_q_bwd, block_k_bwd):
+    """``flash_attention_latent`` below its wrapper, which drops lse: (o,
+    lse) of the interpreted kernels, so that a test can send a cotangent
+    down both."""
+    B, H, T, _ = qn.shape
+    blocks = [fit_block(b, T) for b in (512, 1024, block_q_bwd, block_k_bwd)]
+    o, lse = fa._flash_mla(
+        *(a.reshape(B * a.shape[1], T, a.shape[-1])
+          for a in (qn, qr, kn, kr, v)), float(scale), H, *blocks, True)
+    return o.reshape(B, H, T, -1), lse.reshape(B, H, T)
+
+
+@pytest.mark.parametrize("dtype,close", [(jnp.float32, 2e-5),
+                                         (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("T,heads,bqb,bkb", [
+    (128, 2, 128, 128),           # one block
+    (512, 4, 128, 256),           # 4 q steps x 2 k blocks, dk_r over 4 heads
+    (256, 2, 256, 64),            # one q step, 4 k blocks
+    (1536, 2, 1024, 1024)],       # the blocks asked for fit to 512
+    ids=["one-block", "T512-h4", "T256-k4", "T1536-fit512"])
+def test_the_fused_latent_backward_against_dense_and_against_the_pair(
+        monkeypatch, T, heads, bqb, bkb, dtype, close):
+    """All five gradients of ``flash_mla_bwd`` in interpret mode, with a
+    cotangent on lse as well as on the output (``dlse`` folds into delta):
+    against dense attention in float32 on the same (rounded) inputs, and
+    against the dq and dkv kernels, which the same call takes when the rule
+    turns the shape away.  dk_r sums over the heads and over every k block's
+    q steps inside the one call."""
+    rng = np.random.RandomState(T + heads)
+    B, dn, dr, dv = 2 if T <= 512 else 1, 16, 8, 16
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(
+            dtype)
+
+    args = (draw(B, heads, T, dn), draw(B, heads, T, dr),
+            draw(B, heads, T, dn), draw(B, 1, T, dr), draw(B, heads, T, dv))
+    w_o = draw(B, heads, T, dv).astype(jnp.float32)
+    w_lse = draw(B, heads, T).astype(jnp.float32)
+    scale = 0.3
+
+    def loss(pair):
+        o, lse = pair
+        return jnp.sum(o.astype(jnp.float32) * w_o) + jnp.sum(lse * w_lse)
+
+    def flash(*a):
+        return loss(_flash_latent_lse(*a, scale, bqb, bkb))
+
+    def dense(*a):
+        return loss(_dense_latent_lse(*a, scale))
+
+    counters = {path: metrics.counter("attention.latent_bwd_traced",
+                                      {"path": path})
+                for path in ("fused", "split")}
+    before = {path: c.value for path, c in counters.items()}
+    assert fit_block(bqb, T) != 1024
+    with jax.default_matmul_precision("highest"):
+        fused = jax.grad(flash, argnums=range(5))(*args)
+        assert (counters["fused"].value, counters["split"].value) == (
+            before["fused"] + 1, before["split"])
+        monkeypatch.setattr(fa, "_mla_fused_fits", lambda *a: False)
+        pair = jax.grad(flash, argnums=range(5))(*args)
+        assert (counters["fused"].value, counters["split"].value) == (
+            before["fused"] + 1, before["split"] + 1)
+        want = jax.grad(dense, argnums=range(5))(
+            *(a.astype(jnp.float32) for a in args))
+    for got, other, ref, a in zip(fused, pair, want, args):
+        assert got.shape == a.shape and got.dtype == a.dtype
+        assert _rel(got, ref) < close and _rel(got, other) < close
+
+
+def test_the_rule_turns_a_shape_away_and_the_pair_runs():
+    """The choice is the shapes' alone: both cells' shapes take the one call;
+    float32 at Ling's length, twice its length, or a q block that makes no
+    whole lanes take the dq and dkv kernels, and the counter says which."""
+    fits = fa._mla_fused_fits
+    assert fits(8192, 128, 64, jnp.bfloat16, 1024)        # Xing's cell
+    assert fits(16384, 128, 64, jnp.bfloat16, 1024)       # Ling's cell
+    assert fits(8192, 128, 64, jnp.float32, 1024)
+    assert not fits(16384, 128, 64, jnp.float32, 1024)
+    assert not fits(32768, 128, 64, jnp.bfloat16, 1024)
+    assert fits(96, 16, 8, jnp.float32, 96)
+    assert not fits(96, 16, 8, jnp.float32, 32)
+
+    def draw(*shape):
+        return jnp.ones(shape, jnp.float32)
+
+    # T = 96 under blocks of 64: they fit to 32, no whole lanes and not T
+    args = (draw(1, 2, 96, 16), draw(1, 2, 96, 8), draw(1, 2, 96, 16),
+            draw(1, 1, 96, 8), draw(1, 2, 96, 16))
+    split = metrics.counter("attention.latent_bwd_traced", {"path": "split"})
+    fused = metrics.counter("attention.latent_bwd_traced", {"path": "fused"})
+    before = split.value, fused.value
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        flash_attention_latent(*a, block_q=64, block_k=64, block_q_bwd=64,
+                               block_k_bwd=64, interpret=True))))(*args))
+    assert "flash_mla_bwd_dq" in text and "flash_mla_bwd_dkv" in text
+    assert (split.value, fused.value) == (before[0] + 1, before[1])
 
 
 def test_jnp_fallback_and_dispatch_carry_the_two_widths(monkeypatch):
@@ -724,10 +840,11 @@ def test_scopes_and_counters_are_in_the_step():
     # (a differentiated top-level scope is written ``jvp(mtp)``)
     for scope in ("attn/attn.latent/", "hc.gates/", "hc.mix/", "jvp(mtp)/",
                   "mtp)/head/", "mtp)/loss/", "mtp)/checkpoint/",
-                  "flash_mla_fwd",
-                  "flash_mla_bwd_dq", "flash_mla_bwd_dkv", "moe.route/",
+                  "flash_mla_fwd", "flash_mla_bwd", "moe.route/",
                   "update/"):
         assert scope in text, scope
+    # one block of 64 = T a head: the backward is the one call
+    assert "flash_mla_bwd_dq" not in text and "flash_mla_bwd_dkv" not in text
     assert all(c.value > before[k] for k, c in counters.items())
     # softmax routing keeps the label set it had
     plain = metrics.counter("moe.traced", {"dispatch": "dense"})
