@@ -30,8 +30,20 @@ token, which is at most 1 for the columns before the sub-block and at most
 ``e^75``, inside float32 and bfloat16.  The columns behind the diagonal are
 masked; their exponent is clamped so that what is masked is finite.  ``T``
 is built from the 16 x 16 diagonal blocks' inverses (a nilpotent series of
-four factors) and a block-level series of two factors, ten 64 x 64 products
-in float32, exact but for rounding.
+four factors) and a block-level series of two factors, ten products in
+float32 at "highest", exact but for rounding (``_tri_inv_impl``: the ``jnp``
+path's and the tests' plain form, ten 64 x 64 products a chunk and head).
+**The forward kernel issues them two heads a 128-wide MXU tile**
+(``_tri_inv_packed``): the pair's matrices side by side as the left operand
+``[X1 | X2]`` against the block diagonal of ``[Y1 | Y2]`` give ``[X1 Y1 | X2
+Y2]``, and the series of the diagonal blocks, which is block-diagonal in 16s,
+runs in 16 rows (``pc[r, 16 b + c] = p[16 b + r, 16 b + c]``): six 16-row
+and four 64-row products a pair of heads where the plain form has twenty of
+64 rows.  Every term the packing adds is an exact zero, so each entry of
+``T`` is the sum it is in the plain form.  A grid step with an odd number of
+heads solves its lone head through the same helper in a 64-wide tile;
+the call counts which in ``attention.linear_solve_traced{heads=,pairs=,
+lone=}``, one a trace of the forward kernel.
 
 **Precision**: matmul operands in the inputs' dtype (bfloat16 on the chip),
 accumulation in float32; ``g``, the cumulative sums, every exponential, the
@@ -53,8 +65,8 @@ the kernels off a TPU too).
 **Backward**: one Pallas kernel ``kda_bwd`` (scope and name) on the forward's
 grid and layout, the chunk axis REVERSED through the index maps.  A grid step
 rebuilds the chunk's own part around the kept ``T`` exactly as the forward
-built it (``_chunk_own``: the same roundings; the solve, half of a rebuild's
-products, is not run again), rebuilds ``U`` from the kept state, takes
+built it (``_chunk_own`` and ``_chunk_solved``: the same roundings; the
+solve is not run again), rebuilds ``U`` from the kept state, takes
 ``_scan_bwd``'s step with the state's cotangent in float32 VMEM scratch
 (zeroed at the last chunk), and pulls the cotangents of ``W``, ``U0``,
 ``Aqk`` and the decay factors back through the chunk's own part in place:
@@ -67,7 +79,8 @@ products touches HBM.  On the ``jnp`` path the same arithmetic is
 form.
 
 ``kda`` counts a trace in ``attention.linear_traced{heads=,chunk=,path=}``,
-its backward one in ``attention.linear_bwd_traced`` under the same labels.
+its backward one in ``attention.linear_bwd_traced`` under the same labels,
+the forward kernel's solves in ``attention.linear_solve_traced`` (above).
 """
 
 from __future__ import annotations
@@ -266,13 +279,70 @@ def _mm(x, y, dims=(((1,), (0,)), ((), ())), precision=None):
 _mm_f32 = functools.partial(_mm, precision=_HIGHEST)
 
 
-def _chunk_own(q, k, kb, vb, g, dt, t=None):
-    """What a chunk computes without the state, one head in a kernel (``q``,
-    ``k``, ``kb = beta k`` float32 ``[C, d_k]``, ``vb = beta v [C, d_v]`` in
-    ``dt``, ``g`` float32): ``_intra``'s arithmetic with the sub-blocks'
-    products unrolled.  Both kernels build it here, so the backward rebuilds
-    the forward's roundings exactly; the backward hands in the ``t`` the
-    forward kept, and neither ``A`` nor its inverse is built again."""
+def _block_diag(x, size):
+    """``x [r, W]`` tiled down the rows to ``[W, W]`` and cut to its diagonal
+    blocks of ``size``: the right operand under which a packed left operand
+    ``[X1 | X2 | ...]`` gives ``[X1 Y1 | X2 Y2 | ...]``, the zeros exact in
+    every partial sum."""
+    W = x.shape[1]
+    if x.shape[0] == size == W:
+        return x
+    row, col = _masks(W)
+    return jnp.where(row // size == col // size,
+                     jnp.concatenate([x] * (W // x.shape[0]), axis=0), 0.0)
+
+
+def _tri_inv_tile(mats):
+    """``_tri_inv_impl``'s products for one or two matrices side by side in
+    one MXU tile, ``[C, C]`` or ``[C, 2 C]`` from ``A`` to ``T``: the same
+    sums entry by entry (module docstring)."""
+    C = mats[0].shape[0]
+    a = mats[0] if len(mats) == 1 else jnp.concatenate(mats, axis=1)
+    W = a.shape[1]
+    f32 = a.dtype
+
+    def places(n):              # a row, and a column's place in its own matrix
+        return (jax.lax.broadcasted_iota(jnp.int32, (n, W), 0),
+                jax.lax.broadcasted_iota(jnp.int32, (n, W), 1) % C)
+
+    (row, own), (row_c, own_c) = places(C), places(SUB)
+    same = (row // SUB) == (own // SUB)
+    # the diagonal blocks, negated, in SUB rows: pc[r, SUB b + c] =
+    # p[SUB b + r, SUB b + c], a column's one block picked out of the rows'
+    pc = -a[:SUB]
+    for b in range(1, C // SUB):
+        pc = jnp.where(own_c // SUB == b, -a[b * SUB:(b + 1) * SUB], pc)
+    d = (row_c == own_c % SUB).astype(f32) + pc
+    pb = _block_diag(pc, SUB)
+    for _ in range(SUB.bit_length() - 2):    # (I+p)(I+p^2)(I+p^4)(I+p^8)
+        pc = _mm_f32(pc, pb)
+        pb = _block_diag(pc, SUB)
+        d = d + _mm_f32(d, pb)
+    d_inv = jnp.where(same, jnp.concatenate([d] * (C // SUB), axis=0), 0.0)
+    x = _mm_f32(d_inv, _block_diag(jnp.where(same, 0.0, a), C))
+    y, p = (row == own).astype(f32) - x, _mm_f32(x, _block_diag(x, C))
+    for j in range((C // SUB).bit_length() - 2):
+        y = y + _mm_f32(y, _block_diag(p, C))
+        if j < (C // SUB).bit_length() - 3:
+            p = _mm_f32(p, _block_diag(p, C))
+    t = _mm_f32(y, _block_diag(d_inv, C))
+    return [t[:, i * C:(i + 1) * C] for i in range(len(mats))]
+
+
+def _tri_inv_packed(mats):
+    """``(I + a)^-1`` of every strictly lower triangular ``a [C, C]``
+    (float32) of ``mats``, two a tile and an odd one out alone."""
+    return [t for i in range(0, len(mats), 2)
+            for t in _tri_inv_tile(mats[i:i + 2])]
+
+
+def _chunk_own(q, k, kb, g, dt, solve):
+    """What a chunk computes without the state up to its solve, one head in a
+    kernel (``q``, ``k``, ``kb = beta k`` float32 ``[C, d_k]``, ``g``
+    float32): ``_intra``'s arithmetic with the sub-blocks' products unrolled.
+    Both kernels build it here and finish it in ``_chunk_solved``, so the
+    backward rebuilds the forward's roundings exactly; only the forward asks
+    for the masked ``A`` that it will ``solve``."""
     C, dk = q.shape
     row, col = _masks(C)
     G = _mm_f32((row >= col).astype(jnp.float32), g)          # cumulative
@@ -283,24 +353,33 @@ def _chunk_own(q, k, kb, vb, g, dt, t=None):
     colf = [jnp.exp(jnp.minimum(first - G, _CLAMP)) for first in firsts]
     kc = [(k * f).astype(dt) for f in colf]
     blocks = [slice(i * SUB, (i + 1) * SUB) for i in range(C // SUB)]
-    aqk = jnp.where(row >= col, jnp.concatenate(
+    own = dict(G=G, rowf=rowf, kr=kr, qr=qr, colf=colf, kc=kc)
+    own["aqk"] = jnp.where(row >= col, jnp.concatenate(
         [_mm(qr[rows], c, _NT) for rows, c in zip(blocks, kc)], axis=0), 0.0)
-    if t is None:
-        t = _tri_inv_impl(jnp.where(row > col, jnp.concatenate(
+    if solve:
+        own["a"] = jnp.where(row > col, jnp.concatenate(
             [_mm(kr[rows], c, _NT) for rows, c in zip(blocks, kc)], axis=0),
-            0.0), _mm_f32)
-    eg = jnp.exp(G)
-    kbe = (kb * eg).astype(dt)
+            0.0)
+    own["eg"] = jnp.exp(G)
+    own["kbe"] = (kb * own["eg"]).astype(dt)
+    return own
+
+
+def _chunk_solved(own, t, vb, dt):
+    """``own`` with what follows the solve ``t`` (the forward's own, or the
+    one it kept for the backward): ``T`` in ``dt``, ``W`` and ``U0``
+    (``vb = beta v [C, d_v]`` in ``dt``)."""
     tb = t.astype(dt)
-    return dict(G=G, rowf=rowf, kr=kr, qr=qr, colf=colf, kc=kc, aqk=aqk,
-                t=t, tb=tb, eg=eg, kbe=kbe, w=_mm(tb, kbe), u0=_mm(tb, vb))
+    return dict(own, t=t, tb=tb, w=_mm(tb, own["kbe"]), u0=_mm(tb, vb))
 
 
 def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, s_ref, t_ref,
                 state, *, heads, dk, dv):
     """One chunk of ``heads`` heads (their columns side by side in the
-    blocks): the heads' chains of products are independent, so the
-    scheduler fills one's latencies with another's."""
+    blocks): every head's ``A`` first, the solves two heads a tile
+    (``_tri_inv_packed``), then each head's step of the scan; the heads'
+    chains of products are independent, so the scheduler fills one's
+    latencies with another's."""
     f32, dt = jnp.float32, q_ref.dtype
     C = q_ref.shape[1]
 
@@ -308,19 +387,24 @@ def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, s_ref, t_ref,
     def _init():
         state[:] = jnp.zeros_like(state)
 
-    for h in range(heads):
-        at_k, at_v = slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
-        q, k, kb = (r[0, :, at_k].astype(f32) for r in (q_ref, k_ref, kb_ref))
-        own = _chunk_own(q, k, kb, vb_ref[0, :, at_v], g_ref[0, :, at_k], dt)
+    at_k = [slice(h * dk, (h + 1) * dk) for h in range(heads)]
+    at_v = [slice(h * dv, (h + 1) * dv) for h in range(heads)]
+    qs, ks = ([r[0, :, at].astype(f32) for at in at_k]
+              for r in (q_ref, k_ref))
+    owns = [_chunk_own(q, k, kb_ref[0, :, at].astype(f32), g_ref[0, :, at],
+                       dt, solve=True) for q, k, at in zip(qs, ks, at_k)]
+    solves = _tri_inv_packed([own["a"] for own in owns])
+    for h, (q, k, own, t) in enumerate(zip(qs, ks, owns, solves)):
+        own = _chunk_solved(own, t, vb_ref[0, :, at_v[h]], dt)
         G, eg = own["G"], own["eg"]
-        t_ref[0, h, 0] = own["t"]
+        t_ref[0, h, 0] = t
         s = state[h]                                          # [d_v, d_k]
         s_ref[0, h, 0] = s
         sb = s.astype(dt)
         u = (own["u0"] - _mm(own["w"].astype(dt), sb, _NT)).astype(dt)
-        o_ref[0, :, at_v] = (_mm((q * eg).astype(dt), sb, _NT)
-                             + _mm(own["aqk"].astype(dt), u)
-                             ).astype(o_ref.dtype)
+        o_ref[0, :, at_v[h]] = (_mm((q * eg).astype(dt), sb, _NT)
+                                + _mm(own["aqk"].astype(dt), u)
+                                ).astype(o_ref.dtype)
         last = G[C - 1:C]
         state[h] = s * jnp.exp(last) + _mm(
             u, (k * jnp.exp(last - G)).astype(dt), _TN)
@@ -336,11 +420,16 @@ def _a_chunk(hb, rows, cols, at):
 def _fwd_kernel_call(q, k, v, g, beta, interpret):
     """``(o, (states, solves))``: what entered each chunk and each chunk's
     ``T``, float32 ``[B, H, chunks, d_v, d_k]`` and ``[..., C, C]``."""
+    from .. import metrics
+
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     n = T // CHUNK
     f32 = jnp.float32
     hb = math.gcd(H, _HEADS)
+    metrics.counter("attention.linear_solve_traced",
+                    {"heads": str(H), "pairs": str(hb // 2),
+                     "lone": str(hb % 2)}).inc()
     bf = beta.astype(f32)[..., None]
     kb = (bf * k.astype(f32)).astype(k.dtype)
     vb = (bf * v.astype(f32)).astype(v.dtype)
@@ -403,7 +492,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, s_ref, t_ref,
         beta = betas[:, h:h + 1]                              # [C, 1]
         kb = (beta * k).astype(dt).astype(f32)                # as the forward
         vb = (beta * v).astype(dt)
-        own = _chunk_own(q, k, kb, vb, g_ref[0, :, at_k], dt, t_ref[0, h, 0])
+        own = _chunk_solved(
+            _chunk_own(q, k, kb, g_ref[0, :, at_k], dt, solve=False),
+            t_ref[0, h, 0], vb, dt)
         G, rowf, eg, t = own["G"], own["rowf"], own["eg"], own["t"]
         s = s_ref[0, h, 0]                                    # [d_v, d_k]
         sb, wb = s.astype(dt), own["w"].astype(dt)

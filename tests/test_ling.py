@@ -222,12 +222,16 @@ def test_the_kernel_backward_carries_the_states_cotangent(monkeypatch):
 def test_kda_counts_its_traces(path, monkeypatch):
     """One ``attention.linear_traced`` a trace of ``kda`` and one
     ``attention.linear_bwd_traced`` a trace of its backward, under the
-    labels the accepted runner reads."""
+    labels the accepted runner reads, and one
+    ``attention.linear_solve_traced{heads=,pairs=,lone=}`` a trace of the
+    forward kernel."""
     _take(monkeypatch, path)
     labels = {"heads": "2", "chunk": str(kda_ops.CHUNK), "path": path}
     fwd = metrics.counter("attention.linear_traced", labels)
     bwd = metrics.counter("attention.linear_bwd_traced", labels)
-    before = fwd.value, bwd.value
+    solve = metrics.counter("attention.linear_solve_traced",
+                            {"heads": "2", "pairs": "1", "lone": "0"})
+    before, solve_before = (fwd.value, bwd.value), solve.value
     args = _scan_inputs(0, 1, 64, 2, 16, 16)
     jax.eval_shape(lambda *a: kda_ops.kda(*a), *args)    # no cached trace
     assert (fwd.value, bwd.value) == (before[0] + 1, before[1])
@@ -238,6 +242,17 @@ def test_kda_counts_its_traces(path, monkeypatch):
         if s.name in ("attention.linear_traced",
                       "attention.linear_bwd_traced"):
             assert sorted(s.labels) == ["chunk", "heads", "path"], s.labels
+        if s.name == "attention.linear_solve_traced":
+            assert sorted(s.labels) == ["heads", "lone", "pairs"], s.labels
+    # the forward kernel's solves, one count a trace of the kernel (none on
+    # the ``jnp`` path): two heads are one pair, three heads three lone ones
+    assert solve.value == solve_before + (2 if path == "interpret" else 0)
+    lone = metrics.counter("attention.linear_solve_traced",
+                           {"heads": "3", "pairs": "0", "lone": "1"})
+    lone_before = lone.value
+    jax.eval_shape(lambda *a: kda_ops.kda(*a),
+                   *_scan_inputs(0, 1, 64, 3, 16, 16))
+    assert lone.value == lone_before + (path == "interpret")
 
 
 def _eqns(jaxpr):
@@ -292,25 +307,105 @@ def test_the_kernels_hold_the_stated_precision(name):
                for var in e.invars)
     carried = body.invars[-1].aval
     assert "vmem" in str(carried) and carried.dtype == jnp.float32
+    if name == "kda_fwd":
+        # two heads: a cumulative sum each and the ten products of ONE solve,
+        # six of them SUB rows against the pair's 2 C x 2 C block diagonal
+        wide = [e for e in dots if e.invars[0].aval.dtype == jnp.float32]
+        C, S = kda_ops.CHUNK, kda_ops.SUB
+        assert sorted(tuple(v.aval.shape for v in e.invars) for e in wide) \
+            == sorted([((C, C), (C, 16))] * 2
+                      + [((S, 2 * C), (2 * C, 2 * C))] * 6
+                      + [((C, 2 * C), (2 * C, 2 * C))] * 4)
     if name == "kda_bwd":
         dq, dk, dv, dg, dbeta = call.outvars
         assert [x.aval.dtype for x in (dq, dk, dv)] == [jnp.bfloat16] * 3
         assert [x.aval.dtype for x in (dg, dbeta)] == [jnp.float32] * 2
 
 
+def _solve_cases():
+    """The all-ones lower triangle (all keys alike and beta 1) and three
+    seeded random ``A``."""
+    n = kda_ops.CHUNK
+    rng = np.random.RandomState(0)
+    return [jnp.tril(jnp.ones((n, n), jnp.float32), -1)] + [
+        jnp.tril(jnp.asarray(rng.randn(n, n) * 0.3, jnp.float32), -1)
+        for _ in range(3)]
+
+
 def test_tri_inv_is_the_inverse_at_its_worst_case():
     """All keys alike and beta 1: ``I + A`` is the all-ones lower triangle,
     whose inverse a plain Neumann series loses to cancellation."""
     n = kda_ops.CHUNK
-    a = jnp.tril(jnp.ones((n, n), jnp.float32), -1)
+    a, *random = _solve_cases()
     t = kda_ops._tri_inv(a)
     assert float(jnp.max(jnp.abs(t @ (jnp.eye(n) + a) - jnp.eye(n)))) < 1e-5
-    rng = np.random.RandomState(0)
-    a = jnp.tril(jnp.asarray(rng.randn(3, n, n) * 0.3, jnp.float32), -1)
+    a = jnp.stack(random)
     t = kda_ops._tri_inv(a)
     want = np.linalg.inv(np.eye(n) + np.asarray(a, np.float64))
     assert float(np.max(np.abs(np.asarray(t) - want))) < 1e-3 * np.max(
         np.abs(want))
+
+
+@pytest.mark.parametrize("first", range(4))
+@pytest.mark.parametrize("count", [1, 2, 3, 4])
+def test_the_packed_solve_is_the_plain_one(count, first):
+    """``_tri_inv_packed`` over 1 to 4 matrices (a lone one, a pair, a pair
+    and a lone one, two pairs), each case once at every place in its tile,
+    against ``_tri_inv_impl`` with ``_mm32``: the packing adds exact zeros
+    to the same sums, so two float32 ulps of the largest entry is room."""
+    cases = _solve_cases()
+    mats = [cases[(first + i) % len(cases)] for i in range(count)]
+    got = kda_ops._tri_inv_packed(mats)
+    assert len(got) == count
+    for a, t in zip(mats, got):
+        want = kda_ops._tri_inv_impl(a, kda_ops._mm32)
+        assert t.shape == want.shape and t.dtype == jnp.float32
+        top = float(jnp.max(jnp.abs(want)))
+        assert float(jnp.max(jnp.abs(t - want))) <= 2 * np.spacing(
+            np.float32(top))
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 128, 1, 16, 16),                         # a lone head
+    (1, 128, 2, 16, 16),                         # one pair
+    (1, 128, 3, 16, 16),                         # three lone groups
+    (1, 200, 2 * kda_ops._HEADS, 16, 16),        # two grid groups of pairs
+    (2, 128, 2, 32, 16),                         # d_k != d_v
+])
+def test_the_forward_kernel_keeps_the_jnp_paths_solve_and_output(shape):
+    """``kda_fwd`` interpreted against the ``jnp`` path in float32: its
+    third output ``T`` against ``_tri_inv`` of the ``A`` that ``_intra``
+    builds, its first against ``_fwd_jnp``'s ``o`` and its second against
+    the states, whatever the heads a grid step pairs (the kernel builds
+    ``A`` a sub-block at a time, so the two differ by roundings, not by
+    heads)."""
+    q, k, v, g, beta = _scan_inputs(11, *shape)
+    pad = -shape[1] % kda_ops.CHUNK
+    q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (
+        x.ndim - 2)) for x in (q, k, v, g, beta))
+    o, (states, solves) = kda_ops._fwd_kernel_call(q, k, v, g, beta, True)
+    want_o, want_states = kda_ops._fwd_jnp(q, k, v, g, beta)
+    assert float(jnp.max(jnp.abs(o - want_o))) < 1e-5
+    # [n, B, H, ...] on the jnp path, [B, H, n, ...] from the kernel
+    assert float(jnp.max(jnp.abs(
+        jnp.moveaxis(states, 2, 0) - want_states))) < 5e-5 * max(
+            1.0, float(jnp.max(jnp.abs(want_states))))
+    n = q.shape[1] // kda_ops.CHUNK
+    taken = []
+
+    def spy(a):
+        taken.append(kda_ops._tri_inv_impl(a, kda_ops._mm32))
+        return taken[-1]
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kda_ops, "_tri_inv", spy)
+        kda_ops._intra(*(kda_ops._chunked(x, n) for x in (q, k, v, g, beta)))
+    (want_t,) = taken                             # [n, B, H, C, C]
+    assert solves.shape == (shape[0], shape[2], n, kda_ops.CHUNK,
+                            kda_ops.CHUNK)
+    got_t = jnp.moveaxis(solves, 2, 0)
+    assert float(jnp.max(jnp.abs(got_t - want_t))) < 1e-5 * max(
+        1.0, float(jnp.max(jnp.abs(want_t))))
 
 
 # ----------------------------------------- (b) the whole small model, (e), (f)
@@ -756,7 +851,8 @@ def one_v5e():
 
 def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
     """Mosaic takes ``kda_fwd`` and ``kda_bwd`` at 1 x 16,384 x 32 heads of
-    128 x 128 in bfloat16, and nothing of XLA's chunked backward is left
+    128 x 128 in bfloat16 (the packed solve's 16-row cuts and lane
+    concatenations among them), and nothing of XLA's chunked backward is left
     beside them (no loop under the ``kda_bwd`` scope).  Nothing runs: no
     measurement."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -773,6 +869,9 @@ def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     B, T, H, D = 1, 16384, 32, 128
+    solved = metrics.counter("attention.linear_solve_traced",
+                             {"heads": str(H), "pairs": "2", "lone": "0"})
+    before = solved.value
     try:
         text = jax.jit(grads).lower(
             shaped(B, T, H, D), shaped(B, T, H, D), shaped(B, T, H, D),
@@ -784,6 +883,7 @@ def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     for name in ("kda_fwd", "kda_bwd"):
         assert any(f"/{name}" in line for line in calls), (name, calls)
+    assert solved.value == before + 1            # the cell's: two pairs a step
     assert " while(" not in text and "dynamic-update-slice" not in text
 
 
