@@ -1,0 +1,127 @@
+"""``linear_attention`` (Kimi Delta Attention, arXiv:2510.26692): a recurrent
+state a head through the chunked scan of ``ops/kda.py``, no softmax.
+``heads`` heads of ``head_dim`` keys and values: q, k, v = SiLU(causal
+depthwise conv of ``linear_conv_kernel`` taps (h wq | wk | wv)); q, k
+L2-normalised a head, q times head_dim^-0.5; log-decay a head, channel and
+token ``kda_lower_bound * sigmoid(exp(A_log) * (h wf + dt_bias))`` (the
+bounded gate: the decay lies in (exp(kda_lower_bound), 1)); ``beta =
+sigmoid(h wb)`` a head; the scan; an RMSNorm a head (gain ``o_norm
+[head_dim]``), then the caller's ``attn_gate`` and ``wo``.  One device: the
+scan's state is not handed along an ``sp`` ring and no layout of its heads
+over ``tp`` is written."""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+from ..common import AttnKind, rms_norm, unit_gain
+
+__all__ = ["LINEAR"]
+
+
+def _check(cfg, kind):
+    if cfg.n_kv_heads or cfg.qk_norm:
+        raise ValueError(
+            "linear_attention layers take no n_kv_heads or "
+            "qk_norm: every head has its own key and value, "
+            "and q and k are L2-normalised a head")
+    if cfg.linear_conv_kernel < 1 or cfg.kda_lower_bound >= 0:
+        raise ValueError(
+            "linear_attention layers need linear_conv_kernel "
+            ">= 1 and kda_lower_bound < 0")
+
+
+def _init(cfg, kind, rng, w):
+    heads, q_width = kind.heads, kind.heads * cfg.head_dim
+    taps = cfg.linear_conv_kernel
+    lyr = {key: w(cfg.dim, q_width) for key in ("wq", "wk", "wv", "wf")}
+    lyr.update({key: w(taps, q_width, scale=taps ** -0.5)
+                for key in ("conv_q", "conv_k", "conv_v")})
+    # The decay's time-scales as the flash-linear-attention library
+    # draws them: exp(A_log) uniform in (1, 16) a head, dt_bias the
+    # inverse softplus of a step log-uniform in (0.001, 0.1) a channel.
+    dt = np.exp(rng.uniform(math.log(0.001), math.log(0.1), q_width))
+    lyr.update(
+        wb=w(cfg.dim, heads), wo=w(q_width, cfg.dim),
+        A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
+        dt_bias=(dt + np.log(-np.expm1(-dt))).astype(np.float32),
+        o_norm=unit_gain(cfg, cfg.head_dim))
+    return lyr
+
+
+def _pspecs(cfg, kind, tp, tp_size):
+    if tp_size > 1:
+        raise ValueError(
+            f"linear_attention does not shard over 'tp' "
+            f"(tp={tp_size}): no tp layout of the scan's heads "
+            "is written")
+    layer = {key: P(None, None)
+             for key in ("wq", "wk", "wv", "wf", "wb", "wo", "conv_q",
+                         "conv_k", "conv_v")}
+    layer.update(A_log=P(None), dt_bias=P(None), o_norm=P(None))
+    return layer
+
+
+def _refuse(cfg, mesh):
+    if mesh is None or mesh.size <= 1:
+        return
+    for axis, why in (("sp", "the scan's state is not handed from chip "
+                             "to chip along an 'sp' ring"),
+                      ("tp", "no tp layout of the scan's heads is "
+                             "written"),
+                      ("pp", "pipeline stages take every layer alike")):
+        if int(mesh.shape.get(axis, 1)) > 1:
+            raise ValueError(f"linear_attention does not run over "
+                             f"{axis}={mesh.shape[axis]}: {why}")
+    raise ValueError(
+        f"linear_attention runs on one device: on a mesh of {mesh.size} "
+        "the scan's Mosaic kernel would need a shard_map of its own "
+        "(GSPMD cannot partition it)")
+
+
+def _heads(ctx, kind, h, lyr):
+    """Linear attention's heads from the normed input ``h``:
+    [B, T, heads, head_dim], normed a head."""
+    from ...ops.kda import kda       # pallas: imported where first traced
+
+    cfg, wc, dt = ctx.cfg, ctx.wc, ctx.dt
+    Bb, Tb, _ = h.shape
+    local_heads = kind.heads // ctx.tp
+    D, f32 = cfg.head_dim, jnp.float32
+    taps = cfg.linear_conv_kernel
+
+    def conv(x, kernel):
+        # causal, depthwise: tap j reads the token taps - 1 - j back
+        kernel = kernel.astype(dt)
+        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+        y = sum(padded[:, j:j + Tb] * kernel[j] for j in range(taps))
+        return jax.nn.silu(y).reshape(Bb, Tb, local_heads, D)
+
+    def unit(x):        # L2-normalised a head, in float32
+        x = x.astype(f32)
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    q = (unit(conv(h @ wc(lyr["wq"]), lyr["conv_q"]))
+         * D ** -0.5).astype(dt)
+    k = unit(conv(h @ wc(lyr["wk"]), lyr["conv_k"])).astype(dt)
+    v = conv(h @ wc(lyr["wv"]), lyr["conv_v"])
+    f = jnp.dot(h, wc(lyr["wf"]), preferred_element_type=f32)
+    f = (f + lyr["dt_bias"].astype(f32)).reshape(Bb, Tb, local_heads, D)
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(lyr["A_log"].astype(f32))[:, None] * f)
+    beta = jax.nn.sigmoid(
+        jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32))
+    return rms_norm(kda(q, k, v, g, beta), ctx.gain(lyr["o_norm"]),
+                    cfg.norm_eps)
+
+
+LINEAR = AttnKind(
+    scope="attn.linear", saved=("kda_out", "kda_state", "kda_solve"),
+    gate_tp=False, check=_check, init=_init, pspecs=_pspecs, refuse=_refuse,
+    rope=lambda cfg: cfg.rope_full, heads=_heads)

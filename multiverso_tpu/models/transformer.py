@@ -7,20 +7,23 @@ Design (not in the reference — see models/__init__):
 - mesh-aware: batch shards over ``dp``, attention heads + MLP hidden +
   vocab shard over ``tp`` (GSPMD inserts the collectives), sequence shards
   over ``sp`` with ring attention (``parallel/ring_attention.py``);
-- layers of different kinds (``LayerKind``: full or sliding-window
-  attention with its own query heads over grouped K/V heads and its own
-  ``Rope`` recipe, or latent attention, whose keys/values (and queries,
-  where ``q_lora_rank`` > 0) pass a low-rank bottleneck and whose scores add
-  a rotated part shared by the heads to an unrotated one, or linear
-  attention, which carries a recurrent state a head through a chunked scan
-  (``ops/kda.py``) and no softmax, or EVA attention, one softmax over a
-  query's own window and chunk summaries of every earlier window
-  (``ops/flash_eva.py``); a per-head output gate, a dense or routed
-  FFN that may hold a share of the experts beside a shared one), held as
-  ``Layout`` writes them: a leading group, a period whose slots are stacked
-  over its repetitions and scanned (a long run of consecutive slots alike as
-  one inner scan: ``Layout.runs``), a trailing part.  Every layer alike is
-  the one-slot case;
+- layers of different kinds (``LayerKind``: an attention kind with its own
+  query heads, a dense or routed FFN that may hold a share of the experts
+  beside a shared one; a per-head output gate), held as ``Layout`` writes
+  them: a leading group, a period whose slots are stacked over its
+  repetitions and scanned (a long run of consecutive slots alike as one inner
+  scan: ``Layout.runs``), a trailing part.  Every layer alike is the one-slot
+  case.  **An attention kind is one module under ``attention/``**
+  (``attention.KINDS``: full and sliding softmax attention, latent, linear,
+  EVA) that owns its checks, leaves, specs, mesh refusals, scope, rotary
+  recipe and heads; this file looks a kind up there and names none.  A new
+  kind is added in four steps: a file under ``attention/`` ending in its
+  ``AttnKind``, a line in ``KINDS``, its sizes as fields of
+  ``TransformerConfig``, its kernel under ``ops/``;
+- ``_forward`` is embed -> layers (``_run_stack``, or ``_run_pipeline`` over
+  ``pp``) -> (``_mtp``) -> head over module-level parts that take a ``Ctx``
+  (``common.py``: dtypes, mesh, the norm gain, the residual sum); a layer is
+  ``_make_block``'s ``block`` = ``_attn_sub`` + ``_mlp_sub``;
 - a residual of ``hc_mult`` streams mixed by hyper-connections
   (``_hc_gates``, ``_hc_read``, ``_hc_write``), and a multi-token-prediction
   module (``mtp_layers``), both off by default; a residual stream wider in
@@ -37,17 +40,18 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..updaters import AddOption, get_updater
 from .. import dashboard, metrics, tracing
+from .attention import KINDS
+from .common import Ctx, Rope, rms_norm, unit_gain
 from .moe import (GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs,
                   route_rungs, shared_expert)
 
@@ -55,28 +59,7 @@ __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
            "stack_layer_params", "transformer_forward", "expert_load",
            "TransformerTrainer"]
 
-FULL, SLIDING = "full_attention", "sliding_attention"
-LATENT, LINEAR = "latent_attention", "linear_attention"
-EVA = "eva_attention"
 DENSE, SPARSE = "dense", "sparse"
-
-
-@dataclass(frozen=True)
-class Rope:
-    """One rotary recipe.  The first ``rotary_factor`` of every head's dims
-    are rotated (split in halves, as ``_rope`` always has), the rest pass.
-    ``yarn_factor`` > 0 blends interpolated and extrapolated inverse
-    frequencies as HF's ``_compute_yarn_parameters`` does (ramp between the
-    dims that turn ``beta_fast`` and ``beta_slow`` times over
-    ``original_max_seq`` positions, truncated); cos and sin are multiplied
-    by ``attention_factor``."""
-    theta: float = 10000.0
-    rotary_factor: float = 1.0
-    yarn_factor: float = 0.0
-    original_max_seq: int = 0
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: float = 1.0
 
 
 class LayerKind(NamedTuple):
@@ -275,14 +258,9 @@ class TransformerConfig:
     # the group of the first expert held here (``TransformerTrainer.kept``).
     n_group: int = 1
     topk_group: int = 1
-    # ---- ``latent_attention`` layers (arXiv:2405.04434, decompressed form):
-    # ``c_q = norm(h wq_a)`` [q_lora_rank], ``q = c_q wq_b`` [heads,
-    # qk_nope_dim + qk_rope_dim], or with ``q_lora_rank = 0`` no query latent:
-    # ``q = h wq``; ``h wkv_a`` = ``c_kv`` [kv_lora_rank] and
-    # one rotated key head [qk_rope_dim]; ``norm(c_kv) wkv_b`` [heads,
-    # qk_nope_dim + v_head_dim].  The rotated parts take ``rope_latent``;
-    # the softmax scale is ``(qk_nope_dim + qk_rope_dim) ** -0.5 *
-    # attn_mscale ** 2`` (YaRN's mscale on q and k both).
+    # ---- ``latent_attention`` layers: the latents' and the heads' widths
+    # (``q_lora_rank`` 0 = no query latent), YaRN's mscale, the rotated
+    # parts' recipe.  ``attention/latent.py`` has the equations.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -290,24 +268,12 @@ class TransformerConfig:
     v_head_dim: int = 0
     attn_mscale: float = 1.0
     rope_latent: Optional[Rope] = None
-    # ---- ``linear_attention`` layers (Kimi Delta Attention,
-    # arXiv:2510.26692; ops/kda.py), ``heads`` heads of ``head_dim`` keys and
-    # values: q, k, v = SiLU(causal depthwise conv of ``linear_conv_kernel``
-    # taps (h wq | wk | wv)); q, k L2-normalised a head, q times head_dim^-0.5;
-    # log-decay a head, channel and token ``kda_lower_bound * sigmoid(
-    # exp(A_log) * (h wf + dt_bias))`` (the bounded gate: the decay lies in
-    # (exp(kda_lower_bound), 1)); ``beta = sigmoid(h wb)`` a head; the scan; an
-    # RMSNorm a head (gain ``o_norm [head_dim]``), ``attn_gate``, ``wo``.
+    # ---- ``linear_attention`` layers: the causal convolution's taps and the
+    # decay's lower bound.  ``attention/linear.py`` has the equations.
     linear_conv_kernel: int = 4
     kda_lower_bound: float = -5.0
-    # ---- ``eva_attention`` layers (EVA, arXiv:2302.04542, as EvaByte runs
-    # it; ops/flash_eva.py), ``heads`` heads of ``head_dim``: q, k rotated
-    # (``rope_full``), v plain; chunk ``m`` of ``eva_chunk`` positions is one
-    # key and one value, ``a_j = softmax_{j in m}(scale k_j . phi)``, ``kbar_m
-    # = sum_j a_j k_j + mu``, ``vbar_m = sum_j a_j v_j`` (leaves ``phi``,
-    # ``mu`` [heads, head_dim]); query t of window ``w = t // eva_window``
-    # sees the keys ``eva_window * w <= j <= t`` and the summaries ``m <
-    # (eva_window // eva_chunk) * w`` in ONE softmax.
+    # ---- ``eva_attention`` layers: a query's own window and the positions
+    # a summary pools.  ``attention/eva.py`` has the equations.
     eva_window: int = 0
     eva_chunk: int = 0
     # ---- Hyper-connections (arXiv:2512.24880 over arXiv:2409.19606): 0 =
@@ -374,56 +340,12 @@ class TransformerConfig:
                 f"n_pred_heads={self.n_pred_heads}: at least one head, and "
                 "parallel heads or a prediction module (mtp_layers), not "
                 "both")
-        kv = self.n_kv_heads
         for k in self.layout.kinds:
-            if (k.attn not in (FULL, SLIDING, LATENT, LINEAR, EVA)
-                    or k.ffn not in (DENSE, SPARSE)):
+            if k.attn not in KINDS or k.ffn not in (DENSE, SPARSE):
                 raise ValueError(f"unknown layer kind {k}")
             if k.ffn == SPARSE and not self.num_experts:
                 raise ValueError("sparse layers need num_experts > 0")
-            if k.attn == LATENT:
-                widths = ("kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
-                          "v_head_dim")
-                if (not all(getattr(self, w) > 0 for w in widths)
-                        or self.q_lora_rank < 0):
-                    raise ValueError(
-                        f"latent_attention layers need {widths} > 0 and "
-                        "q_lora_rank >= 0 (0 = no query latent: q = h wq)")
-                if kv or self.qk_norm:
-                    raise ValueError(
-                        "latent_attention layers take no n_kv_heads or "
-                        "qk_norm: their K/V are per head and their norms "
-                        "are the latent ones")
-                continue
-            if k.attn == LINEAR:
-                if kv or self.qk_norm:
-                    raise ValueError(
-                        "linear_attention layers take no n_kv_heads or "
-                        "qk_norm: every head has its own key and value, "
-                        "and q and k are L2-normalised a head")
-                if self.linear_conv_kernel < 1 or self.kda_lower_bound >= 0:
-                    raise ValueError(
-                        "linear_attention layers need linear_conv_kernel "
-                        ">= 1 and kda_lower_bound < 0")
-                continue
-            if k.attn == EVA:
-                if kv or self.qk_norm:
-                    raise ValueError(
-                        "eva_attention layers take no n_kv_heads or qk_norm: "
-                        "every head pools its own keys and values")
-                if (self.eva_chunk < 1 or self.eva_window < self.eva_chunk
-                        or self.eva_window % self.eva_chunk):
-                    raise ValueError(
-                        "eva_attention layers need eva_window a multiple of "
-                        f"eva_chunk >= 1, got {self.eva_window} / "
-                        f"{self.eva_chunk}")
-                continue
-            if kv and k.heads % kv:
-                raise ValueError(f"{k.heads} query heads do not divide into "
-                                 f"{kv} K/V heads")
-            if k.attn == SLIDING and self.sliding_window < 1:
-                raise ValueError("sliding_attention layers need "
-                                 "sliding_window >= 1")
+            KINDS[k.attn].check(self, k)
         if self.attn_gate not in ("", "per_head"):
             raise ValueError(f"unknown attn_gate '{self.attn_gate}'")
         if self.router_scoring not in ("softmax", "sigmoid"):
@@ -452,9 +374,10 @@ class TransformerConfig:
 
     @functools.cached_property
     def layout(self) -> Layout:
-        ffn = SPARSE if self.num_experts else DENSE
+        # None = every layer ``KINDS``' first kind, full attention
+        plain, ffn = next(iter(KINDS)), SPARSE if self.num_experts else DENSE
         return _layout(tuple(
-            LayerKind((self.layer_types or (FULL,) * self.n_layers)[i],
+            LayerKind((self.layer_types or (plain,) * self.n_layers)[i],
                       (self.heads_per_layer
                        or (self.n_heads,) * self.n_layers)[i],
                       (self.mlp_layer_types or (ffn,) * self.n_layers)[i])
@@ -481,9 +404,7 @@ class TransformerConfig:
         return bool(self.num_experts) and self.router_scoring == "sigmoid"
 
     def rope(self, attn: str) -> Rope:
-        given = {SLIDING: self.rope_sliding,
-                 LATENT: self.rope_latent}.get(attn, self.rope_full)
-        return given or Rope(theta=self.rope_theta)
+        return KINDS[attn].rope(self) or Rope(theta=self.rope_theta)
 
 
 def _hc_init(cfg: TransformerConfig, w):
@@ -501,60 +422,17 @@ def _hc_init(cfg: TransformerConfig, w):
                                  8.0 * np.eye(n).ravel()]).astype(np.float32)}
 
 
-def _unit_gain(cfg: TransformerConfig, n: int):
-    """A norm's gain of one as its leaf holds it: ones, or with
-    ``norm_unit_offset`` (the gain is ``1 + w``) zeros."""
-    return (np.zeros if cfg.norm_unit_offset else np.ones)(n, np.float32)
-
-
 def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
-    heads, kv = kind.heads, cfg.n_kv_heads or kind.heads
-    q_width, kv_width = heads * cfg.head_dim, kv * cfg.head_dim
-    if kind.attn == LATENT:
-        dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-        lyr = ({"wq_a": w(cfg.dim, cfg.q_lora_rank),
-                "q_a_norm": _unit_gain(cfg, cfg.q_lora_rank),
-                "wq_b": w(cfg.q_lora_rank, heads * (dn + dr))}
-               if cfg.q_lora_rank else {"wq": w(cfg.dim, heads * (dn + dr))})
-        lyr.update({
-            "wkv_a": w(cfg.dim, cfg.kv_lora_rank + dr),
-            "kv_a_norm": _unit_gain(cfg, cfg.kv_lora_rank),
-            "wkv_b": w(cfg.kv_lora_rank, heads * (dn + dv)),
-            "wo": w(heads * dv, cfg.dim),
-        })
-    elif kind.attn == LINEAR:
-        taps = cfg.linear_conv_kernel
-        lyr = {key: w(cfg.dim, q_width) for key in ("wq", "wk", "wv", "wf")}
-        lyr.update({key: w(taps, q_width, scale=taps ** -0.5)
-                    for key in ("conv_q", "conv_k", "conv_v")})
-        # The decay's time-scales as the flash-linear-attention library
-        # draws them: exp(A_log) uniform in (1, 16) a head, dt_bias the
-        # inverse softplus of a step log-uniform in (0.001, 0.1) a channel.
-        dt = np.exp(rng.uniform(math.log(0.001), math.log(0.1), q_width))
-        lyr.update(
-            wb=w(cfg.dim, heads), wo=w(q_width, cfg.dim),
-            A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
-            dt_bias=(dt + np.log(-np.expm1(-dt))).astype(np.float32),
-            o_norm=_unit_gain(cfg, cfg.head_dim))
-    else:
-        lyr = {
-            "wq": w(cfg.dim, q_width),
-            "wk": w(cfg.dim, kv_width),
-            "wv": w(cfg.dim, kv_width),
-            "wo": w(q_width, cfg.dim),
-        }
-        if kind.attn == EVA:
-            # The pooling's query and the summaries' key offset, a head:
-            # N(0, 1 / head_dim), cut at three deviations.
-            std = cfg.head_dim ** -0.5
-            lyr.update({key: np.clip(
-                std * rng.randn(heads, cfg.head_dim), -3 * std, 3 * std
-            ).astype(np.float32) for key in ("phi", "mu")})
-    lyr.update(attn_norm=_unit_gain(cfg, cfg.dim),
-               mlp_norm=_unit_gain(cfg, cfg.dim))
+    """One layer's leaves.  The order of the draws (the attention's, the
+    norms, QK-norm, FFN, gate, shared expert, streams) is part of every seeded
+    result: a routed cell's rates follow the drawn router."""
+    lyr = KINDS[kind.attn].init(cfg, kind, rng, w)
+    lyr.update(attn_norm=unit_gain(cfg, cfg.dim),
+               mlp_norm=unit_gain(cfg, cfg.dim))
     if cfg.qk_norm:
-        lyr.update(q_norm=_unit_gain(cfg, q_width),
-                   k_norm=_unit_gain(cfg, kv_width))
+        kv = cfg.n_kv_heads or kind.heads
+        lyr.update(q_norm=unit_gain(cfg, kind.heads * cfg.head_dim),
+                   k_norm=unit_gain(cfg, kv * cfg.head_dim))
     if kind.ffn == SPARSE:
         # router, w1, w3, w2 at the layer's top level, expert-indexed
         lyr.update(init_moe_params(cfg.dim, cfg.hidden, cfg.num_experts,
@@ -569,7 +447,7 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
             "w2": w(hidden, cfg.dim),   # down
         })
     if cfg.attn_gate:
-        lyr["wg"] = w(cfg.dim, heads)
+        lyr["wg"] = w(cfg.dim, kind.heads)
     if kind.ffn == SPARSE and cfg.shared_expert_hidden:
         shared = cfg.shared_expert_hidden
         lyr.update(shared_w1=w(cfg.dim, shared), shared_w3=w(cfg.dim, shared),
@@ -594,16 +472,16 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
         layers = group_layers(cfg, layers)
     params = {
         "embed": w(cfg.vocab_size, cfg.dim, scale=0.02),
-        "out_norm": _unit_gain(cfg, cfg.dim),
+        "out_norm": unit_gain(cfg, cfg.dim),
         "head": w(cfg.dim, cfg.n_pred_heads * cfg.vocab_size),
         "layers": layers,
     }
     if cfg.mtp_layers:
         params["mtp"] = {
             "proj": w(2 * cfg.dim, cfg.dim),
-            "h_norm": _unit_gain(cfg, cfg.dim),
-            "e_norm": _unit_gain(cfg, cfg.dim),
-            "out_norm": _unit_gain(cfg, cfg.dim),
+            "h_norm": unit_gain(cfg, cfg.dim),
+            "e_norm": unit_gain(cfg, cfg.dim),
+            "out_norm": unit_gain(cfg, cfg.dim),
             "layer": _init_layer(cfg, cfg.layout.kinds[-1], rng, w)}
     return params
 
@@ -672,40 +550,13 @@ def _layer_pspecs(cfg: TransformerConfig, mesh: Mesh,
             f"{cfg.n_kv_heads or kind.heads} K/V heads do not divide over "
             f"the 'tp' axis ({mesh.shape['tp']}): wk/wv shard by head")
 
-    if kind.attn == LATENT:
-        if tp and mesh.shape["tp"] > 1:
-            raise ValueError(
-                f"latent_attention does not shard over 'tp' "
-                f"(tp={mesh.shape['tp']}): the heads leave wq_b/wkv_b "
-                "interleaved with the low-rank norms' inputs whole, and no "
-                "tp layout of the latent projections is written")
-        layer = ({"wq_a": P(None, None), "q_a_norm": P(None),
-                  "wq_b": P(None, None)} if cfg.q_lora_rank
-                 else {"wq": P(None, None)})
-        layer.update({"wkv_a": P(None, None), "kv_a_norm": P(None),
-                      "wkv_b": P(None, None), "wo": P(None, None)})
-    elif kind.attn == LINEAR:
-        if tp and mesh.shape["tp"] > 1:
-            raise ValueError(
-                f"linear_attention does not shard over 'tp' "
-                f"(tp={mesh.shape['tp']}): no tp layout of the scan's heads "
-                "is written")
-        layer = {key: P(None, None)
-                 for key in ("wq", "wk", "wv", "wf", "wb", "wo", "conv_q",
-                             "conv_k", "conv_v")}
-        layer.update(A_log=P(None), dt_bias=P(None), o_norm=P(None))
-    elif kind.attn == EVA:             # one device (``_forward`` refuses more)
-        layer = {key: P(None, None)
-                 for key in ("wq", "wk", "wv", "wo", "phi", "mu")}
-    else:
-        layer = {"wq": P(None, tp), "wk": P(None, tp), "wv": P(None, tp),
-                 "wo": P(tp, None)}
+    attn = KINDS[kind.attn]
+    layer = attn.pspecs(cfg, kind, tp, mesh.shape["tp"] if tp else 1)
     layer.update(attn_norm=P(None), mlp_norm=P(None))
     if cfg.qk_norm:
         layer.update(q_norm=P(None), k_norm=P(None))
     if cfg.attn_gate:
-        layer["wg"] = P(None, None if kind.attn in (LATENT, LINEAR, EVA)
-                        else tp)
+        layer["wg"] = P(None, tp if attn.gate_tp else None)
     if kind.ffn == SPARSE:
         layer.update(moe_pspecs(mesh))
         if cfg.rule_bias:
@@ -770,50 +621,6 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, Any]:
                       "e_norm": s(None), "out_norm": s(None),
                       "layer": sharded(lay.kinds[-1])}
     return out
-
-
-def _rms_norm(x, gain, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * gain
-
-
-def _rope_freqs(rope: Rope, half: int):
-    """Inverse frequencies ``[half]`` of a recipe that rotates ``2 * half``
-    dims."""
-    if not rope.yarn_factor:
-        return rope.theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    # YaRN, as HF's _compute_yarn_parameters: constants of the trace.
-    rot = 2 * half
-    plain = rope.theta ** (-np.arange(half, dtype=np.float64) / half)
-
-    def turns_at(turns):      # the dim that turns ``turns`` times
-        return (rot * math.log(rope.original_max_seq / (turns * 2 * math.pi))
-                / (2 * math.log(rope.theta)))
-
-    low = max(math.floor(turns_at(rope.beta_fast)), 0)
-    high = min(math.ceil(turns_at(rope.beta_slow)), rot - 1)
-    ramp = np.clip((np.arange(half) - low) / (max(high - low, 0.001)), 0, 1)
-    extrapolated = 1.0 - ramp
-    freqs = (plain / rope.yarn_factor * (1 - extrapolated)
-             + plain * extrapolated)
-    return jnp.asarray(freqs, jnp.float32)
-
-
-def _rope(x, rope: Rope):
-    """Rotary embedding over global positions; x [B, H, T, D]."""
-    B, H, T, D = x.shape
-    rot = int(D * rope.rotary_factor)
-    half = rot // 2
-    freqs = _rope_freqs(rope, half)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * freqs[None, :]  # [T,half]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if rope.attention_factor != 1.0:
-        cos, sin = cos * rope.attention_factor, sin * rope.attention_factor
-    x1, x2 = x[..., :half], x[..., half:rot]
-    parts = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
-    if rot < D:
-        parts.append(x[..., rot:].astype(cos.dtype))
-    return jnp.concatenate(parts, -1).astype(x.dtype)
 
 
 # ---- hyper-connections.  The streams are a tuple of n ``[B, T, dim]``
@@ -939,37 +746,11 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
     groups limit the router's choice (``n_group`` > 1), the tokens of each
     routed layer that kept the group of the first expert held (int32
     ``[routed layers]``), else None."""
-    from ..parallel.ring_attention import blockwise_attention_local, ring_attention
-
     lay = cfg.layout
-
     if tokens.shape[1] > cfg.max_seq:
         raise ValueError(
             f"sequence length {tokens.shape[1]} exceeds max_seq "
             f"{cfg.max_seq}")
-    dt = cfg.compute_dtype
-    # The residual stream's dtype; the norms and sub-layers read it in ``dt``.
-    res_dt = cfg.residual_dtype or dt
-    wide = jnp.dtype(res_dt) != jnp.dtype(dt)
-
-    def gain(leaf):
-        """A norm's gain in the compute dtype: the leaf, or ``1 + leaf``."""
-        if cfg.norm_unit_offset:
-            return (leaf.astype(jnp.float32) + 1.0).astype(dt)
-        return leaf.astype(dt)
-
-    def add(x, out):
-        """The residual sum ``x + out`` in the stream's dtype."""
-        return x + (out.astype(res_dt) if wide else out)
-
-    def read(x):
-        """The stream as a norm reads it: in the compute dtype."""
-        return x.astype(dt) if wide else x
-
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(res_dt)        # [B,T,dim]
-    B, T, _ = x.shape
-    scale = cfg.head_dim ** -0.5
     use_pp = (mesh is not None and cfg.pipeline_microbatches > 0
               and int(mesh.shape.get("pp", 1)) > 1)
     # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
@@ -977,8 +758,41 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
     # runs inside ring_attention's shard_map — sp == 1 is its no-ring
     # degenerate case.  Pipeline stages already sit inside gpipe's
     # shard_map and call the local kernel directly.
-    attn_in_shard_map = (mesh is not None and mesh.size > 1
-                         and not use_pp)
+    ctx = Ctx(cfg, mesh,
+              ring=mesh is not None and mesh.size > 1 and not use_pp)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(ctx.res_dt)    # [B,T,dim]
+    _refuse(ctx, use_pp)
+    blocks = {kind: _make_block(ctx, kind) for kind in set(lay.kinds)}
+    mtp_logits = None
+    if use_pp:
+        x = _run_pipeline(ctx, blocks, params["layers"], x)
+        aux_total, counted = jnp.float32(0), None
+    else:
+        # ``counted``: the routed layers' ``(load, kept)`` in layer order.
+        x, aux_total, counted = _run_stack(ctx, blocks, params["layers"], x)
+        if cfg.mtp_layers:
+            mtp_logits, aux_total = _mtp(ctx, blocks[lay.kinds[-1]], params,
+                                         tokens, x, aux_total, counted)
+        counted = (None if not counted else counted[0] if len(counted) == 1
+                   else jax.tree_util.tree_map(
+                       lambda *parts: jnp.concatenate(parts), *counted))
+    load, kept = counted or (None, None)
+    with jax.named_scope("head"):
+        x = rms_norm(ctx.read(x), ctx.gain(params["out_norm"]), cfg.norm_eps)
+        if cfg.logits_dtype is not None:
+            logits = jnp.dot(x, params["head"].astype(ctx.dt),
+                             preferred_element_type=cfg.logits_dtype)
+        else:
+            logits = x @ params["head"].astype(ctx.dt)
+    return logits, aux_total, load, mtp_logits, kept
+
+
+def _refuse(ctx: Ctx, use_pp: bool) -> None:
+    """Raise on a mesh or a composition this model does not run: the routed
+    schedule's, each attention kind's own (``AttnKind.refuse``, in
+    ``KINDS``' order), the residual dtype's and the pipeline's."""
+    cfg, mesh = ctx.cfg, ctx.mesh
     if (cfg.num_experts and cfg.moe_dispatch == "grouped"
             and mesh is not None and int(mesh.shape.get("ep", 1)) > 1):
         raise ValueError(
@@ -986,488 +800,303 @@ def _forward(params, tokens, cfg: TransformerConfig, mesh: Optional[Mesh]):
             f"(ep={mesh.shape['ep']}): its grouped matmul wants every "
             "expert's weights on the chip that holds the rows; use "
             "moe_dispatch='dense', which GSPMD partitions over 'ep'")
-
-    if cfg.sliding_window and any(k.attn == SLIDING for k in lay.kinds):
-        if mesh is not None and int(mesh.shape.get("sp", 1)) > 1:
-            raise ValueError(
-                f"sliding_attention layers (window {cfg.sliding_window}) do "
-                f"not run over an 'sp' ring (sp={mesh.shape['sp']})")
-    use_aux = bool(cfg.aux_loss_coef or cfg.router_z_loss_coef)
-    n_streams = cfg.hc_mult
-    if any(k.attn == LATENT for k in lay.kinds) and mesh is not None \
-            and mesh.size > 1:
-        for axis, why in (("sp", "its two-part scores do not ride the 'sp' "
-                                 "ring"),
-                          ("tp", "no tp layout of the latent projections "
-                                 "is written")):
-            if int(mesh.shape.get(axis, 1)) > 1:
-                raise ValueError(f"latent_attention does not run over "
-                                 f"{axis}={mesh.shape[axis]}: {why}")
-        raise ValueError(
-            f"latent_attention runs on one device: on a mesh of {mesh.size} "
-            "the Mosaic kernel sits inside ring_attention's shard_map, "
-            "which carries one width for q, k and v")
-    if any(k.attn == LINEAR for k in lay.kinds) and mesh is not None \
-            and mesh.size > 1:
-        for axis, why in (("sp", "the scan's state is not handed from chip "
-                                 "to chip along an 'sp' ring"),
-                          ("tp", "no tp layout of the scan's heads is "
-                                 "written"),
-                          ("pp", "pipeline stages take every layer alike")):
-            if int(mesh.shape.get(axis, 1)) > 1:
-                raise ValueError(f"linear_attention does not run over "
-                                 f"{axis}={mesh.shape[axis]}: {why}")
-        raise ValueError(
-            f"linear_attention runs on one device: on a mesh of {mesh.size} "
-            "the scan's Mosaic kernel would need a shard_map of its own "
-            "(GSPMD cannot partition it)")
-    if any(k.attn == EVA for k in lay.kinds) and mesh is not None \
-            and mesh.size > 1:
-        raise ValueError(
-            f"eva_attention runs on one device: on a mesh of {mesh.size} "
-            f"({dict(mesh.shape)}) its Mosaic kernels would need a shard_map "
-            "of their own (GSPMD cannot partition them), and no layout of "
-            "its heads and their summaries over 'tp', or of a window's "
-            "summaries along an 'sp' ring, is written")
-    if wide and (n_streams or use_pp or cfg.mtp_layers):
+    present = {k.attn for k in cfg.layout.kinds}
+    for name, attn in KINDS.items():
+        if name in present:
+            attn.refuse(cfg, mesh)
+    if ctx.wide and (cfg.hc_mult or use_pp or cfg.mtp_layers):
         raise ValueError(
             "residual_dtype does not compose with hc_mult, mtp_layers or "
             "pipeline_microbatches: the streams' mixes, the module's "
             "projection and the stages pass the compute dtype")
-    if use_pp and (n_streams or cfg.mtp_layers):
+    if use_pp and (cfg.hc_mult or cfg.mtp_layers):
         raise ValueError(
             "pipeline_microbatches does not compose with hc_mult or "
             "mtp_layers: stages pass one [B, T, dim] stream and the "
             "prediction module reads the last stage's hidden state")
 
-    def make_block(kind: LayerKind, tp: int = 1, reduce=None):
-        """Build one decoder-layer fn of ``kind`` (with the remat wrapper
-        applied).
 
-        ``tp``/``reduce`` specialize it for manual tensor
-        parallelism inside a pipeline stage: the block then sees
-        tp-local column shards of wq/wk/wv/w1/w3 (so it has ``heads/tp``
-        heads and the io width is ``dim/tp``) and ``reduce`` —
-        a ``psum`` over the tp axis — completes the row-parallel
-        wo/w2 matmuls (the Megatron two-all-reduce-per-layer pattern).
-        Default (GSPMD paths): full heads, no explicit collective.
-        """
-        red = reduce if reduce is not None else (lambda t: t)
-        local_heads = kind.heads // tp
-        local_kv = (cfg.n_kv_heads or kind.heads) // tp
-        window = cfg.sliding_window if kind.attn == SLIDING else None
-        rope = cfg.rope(kind.attn)
+def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
+    """The attention sub-layer of ``x`` [B, T, dim]: with its residual, or
+    (hyper-connections) its output alone.  The kind makes the heads
+    (``AttnKind.heads``); the gate, ``wo`` and the residual are every kind's.
+    Shapes derive from ``x`` itself — under pipeline parallelism the block
+    sees microbatches, not the full batch."""
+    cfg, attn = ctx.cfg, KINDS[kind.attn]
+    # The kind's own scope inside ``attn`` where the layers differ.
+    kind_scope = (contextlib.nullcontext() if cfg.layer_types is None
+                  else jax.named_scope(attn.scope))
+    with jax.named_scope("attn"), kind_scope:
+        h = rms_norm(ctx.read(x), ctx.gain(lyr["attn_norm"]), cfg.norm_eps)
+        o = attn.heads(ctx, kind, h, lyr)                    # [B,T,H,width]
+        if cfg.attn_gate:       # times sigmoid(h wg), a scalar a head
+            gate = jax.nn.sigmoid(
+                (h @ ctx.wc(lyr["wg"])).astype(jnp.float32))
+            o = o * gate.astype(ctx.dt)[..., None]
+        Bb, Tb, heads, width = o.shape
+        o = o.reshape(Bb, Tb, heads * width)
+        out = ctx.red(o @ ctx.wc(lyr["wo"]))
+        return ctx.add(x, out) if residual else out
 
-        latent = kind.attn == LATENT
-        if latent:
-            scale_l = ((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
-                       * cfg.attn_mscale ** 2)
 
-        def kind_scope():
-            # The kind's own scope inside ``attn`` where the layers differ.
-            if cfg.layer_types is None:
-                return contextlib.nullcontext()
-            return jax.named_scope({SLIDING: "attn.sliding",
-                                    LATENT: "attn.latent",
-                                    LINEAR: "attn.linear",
-                                    EVA: "attn.eva"}.get(kind.attn,
-                                                         "attn.full"))
+def _mlp_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
+    """``(the FFN sub-layer of x, its weighted auxiliary loss, what it
+    counted)``: a routed layer's ``(load, kept)`` (``moe_ffn``'s; ``kept`` is
+    None without a group limit), a dense one's None."""
+    cfg, dt, wc = ctx.cfg, ctx.dt, ctx.wc
+    with jax.named_scope("mlp"):
+        h = rms_norm(ctx.read(x), ctx.gain(lyr["mlp_norm"]), cfg.norm_eps)
+        if kind.ffn == SPARSE:
+            out, balance, z, load, kept = moe_ffn(
+                lyr, h, top_k=cfg.top_k, compute_dtype=dt,
+                dispatch=cfg.moe_dispatch,
+                norm_topk_prob=cfg.norm_topk_prob, held=cfg.held,
+                routed_scale=cfg.routed_scale,
+                aux=bool(cfg.aux_loss_coef or cfg.router_z_loss_coef),
+                scoring=cfg.router_scoring, all_load=cfg.rule_bias,
+                groups=(cfg.n_group, cfg.topk_group))
+            if cfg.shared_expert_hidden:
+                out = out + shared_expert(lyr, h, dt)
+            aux = (cfg.aux_loss_coef * balance
+                   + cfg.router_z_loss_coef * z)
+            return ((ctx.add(x, out) if residual else out), aux,
+                    (load, kept))
+        gated = (jax.nn.silu(h @ wc(lyr["w1"]))
+                 * (h @ wc(lyr["w3"])))
+        out = ctx.red(gated @ wc(lyr["w2"]))
+        return ((ctx.add(x, out) if residual else out),
+                jnp.float32(0), None)
 
-        def wc(w):
-            # Named so the "dots" policy SAVES the bf16 weight cast:
-            # the cast is not a dot, so without the name the
-            # backward re-reads the f32 masters and recasts every
-            # big weight per layer — avoidable HBM traffic for one
-            # bf16 copy of the layer weights of residency.
-            return checkpoint_name(w.astype(dt), "wcast")
 
-        def latent_heads(h, lyr):
-            """Latent attention's heads from the normed input ``h``:
-            [B, T, heads * v_head_dim]."""
-            Bb, Tb, _ = h.shape
-            dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-            if cfg.q_lora_rank:
-                c_q = _rms_norm(h @ wc(lyr["wq_a"]),
-                                gain(lyr["q_a_norm"]), cfg.norm_eps)
-                q = c_q @ wc(lyr["wq_b"])
-            else:
-                q = h @ wc(lyr["wq"])
-            q = q.reshape(Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
-            kv_a = h @ wc(lyr["wkv_a"])
-            c_kv = _rms_norm(kv_a[..., :cfg.kv_lora_rank],
-                             gain(lyr["kv_a_norm"]), cfg.norm_eps)
-            kv = (c_kv @ wc(lyr["wkv_b"])).reshape(
-                Bb, Tb, local_heads, dn + dv).transpose(0, 2, 1, 3)
-            # the rotated key part is one head, whatever the query heads
-            k_r = _rope(kv_a[..., cfg.kv_lora_rank:][:, None], rope)
-            o = blockwise_attention_local(
-                q[..., :dn], kv[..., :dn], kv[..., dn:], scale_l,
-                causal=True, q_rope=_rope(q[..., dn:], rope), k_rope=k_r)
-            o = o.transpose(0, 2, 1, 3)                      # [B,T,H,dv]
-            if cfg.attn_gate:
-                o = gated(o, h, lyr)
-            return o.reshape(Bb, Tb, local_heads * dv)
+def _make_block(ctx: Ctx, kind: LayerKind, tp: int = 1, reduce=None):
+    """Build one decoder-layer fn of ``kind`` (with the remat wrapper
+    applied).
 
-        def gated(o, h, lyr):
-            """``o [B, T, H, D]`` times sigmoid(h wg), a scalar a head."""
-            gate = jax.nn.sigmoid((h @ wc(lyr["wg"])).astype(jnp.float32))
-            return o * gate.astype(dt)[..., None]
+    ``tp``/``reduce`` specialize it for manual tensor
+    parallelism inside a pipeline stage: the block then sees
+    tp-local column shards of wq/wk/wv/w1/w3 (so it has ``heads/tp``
+    heads and the io width is ``dim/tp``) and ``reduce`` —
+    a ``psum`` over the tp axis — completes the row-parallel
+    wo/w2 matmuls (the Megatron two-all-reduce-per-layer pattern).
+    Default (GSPMD paths): full heads, no explicit collective.
+    """
+    cfg = ctx.cfg
+    if reduce is not None:
+        ctx = replace(ctx, tp=tp, red=reduce)
 
-        def linear_heads(h, lyr):
-            """Linear attention's heads from the normed input ``h``:
-            [B, T, heads * head_dim], normed and gated (the configuration's
-            ``linear_attention`` comment has the equations)."""
-            from ..ops.kda import kda
+    def block(x, lyr):
+        """One decoder layer: attn + residual, MLP/MoE + residual; with
+        hyper-connections ``x`` is the n streams (a tuple) and
+        each sub-layer reads and writes them through its own gates."""
+        if not cfg.hc_mult:
+            return _mlp_sub(ctx, kind, _attn_sub(ctx, kind, x, lyr), lyr)
+        pre, post, res = _hc_gates(x, lyr["hc_attn"], cfg)
+        x = _hc_write(x, post, res, _attn_sub(ctx, kind, _hc_read(x, pre),
+                                              lyr, residual=False))
+        pre, post, res = _hc_gates(x, lyr["hc_mlp"], cfg)
+        out, aux, counted = _mlp_sub(ctx, kind, _hc_read(x, pre), lyr,
+                                     residual=False)
+        return _hc_write(x, post, res, out), aux, counted
 
-            Bb, Tb, _ = h.shape
-            D, f32 = cfg.head_dim, jnp.float32
-            taps = cfg.linear_conv_kernel
-
-            def conv(x, kernel):
-                # causal, depthwise: tap j reads the token taps - 1 - j back
-                kernel = kernel.astype(dt)
-                padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-                y = sum(padded[:, j:j + Tb] * kernel[j] for j in range(taps))
-                return jax.nn.silu(y).reshape(Bb, Tb, local_heads, D)
-
-            def unit(x):        # L2-normalised a head, in float32
-                x = x.astype(f32)
-                return x * jax.lax.rsqrt(
-                    jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-
-            q = (unit(conv(h @ wc(lyr["wq"]), lyr["conv_q"]))
-                 * D ** -0.5).astype(dt)
-            k = unit(conv(h @ wc(lyr["wk"]), lyr["conv_k"])).astype(dt)
-            v = conv(h @ wc(lyr["wv"]), lyr["conv_v"])
-            f = jnp.dot(h, wc(lyr["wf"]), preferred_element_type=f32)
-            f = (f + lyr["dt_bias"].astype(f32)).reshape(Bb, Tb, local_heads,
-                                                         D)
-            g = cfg.kda_lower_bound * jax.nn.sigmoid(
-                jnp.exp(lyr["A_log"].astype(f32))[:, None] * f)
-            beta = jax.nn.sigmoid(
-                jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32))
-            o = _rms_norm(kda(q, k, v, g, beta), gain(lyr["o_norm"]),
-                          cfg.norm_eps)
-            if cfg.attn_gate:
-                o = gated(o, h, lyr)
-            return o.reshape(Bb, Tb, local_heads * D)
-
-        def eva_heads(h, lyr):
-            """EVA attention's heads from the normed input ``h``: [B, T,
-            heads * head_dim] (the configuration's ``eva_attention`` comment
-            has the equations)."""
-            from ..ops.flash_eva import eva_attention, summarise
-
-            Bb, Tb, _ = h.shape
-            D = cfg.head_dim
-
-            def heads_of(y):
-                return y.reshape(Bb, Tb, local_heads, D).transpose(0, 2, 1, 3)
-
-            q = _rope(heads_of(h @ wc(lyr["wq"])), rope)
-            k = _rope(heads_of(h @ wc(lyr["wk"])), rope)
-            v = heads_of(h @ wc(lyr["wv"]))
-            chunk = cfg.eva_chunk
-            # whole chunks, and past one window whole windows: the padding
-            # lies after every query, so none sees it or its summaries
-            pad = -Tb % (cfg.eva_window if Tb > cfg.eva_window else chunk)
-            if pad:
-                q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                           for y in (q, k, v))
-            kbar, vbar = summarise(k, v, lyr["phi"], lyr["mu"], scale, chunk)
-            o = eva_attention(q, k, v, kbar, vbar, cfg.eva_window, chunk,
-                              scale=scale)[:, :, :Tb]
-            o = o.transpose(0, 2, 1, 3)                      # [B,T,H,D]
-            if cfg.attn_gate:
-                o = gated(o, h, lyr)
-            return o.reshape(Bb, Tb, local_heads * D)
-
-        def attn_sub(x, lyr, residual=True):
-            """The attention sub-layer of ``x`` [B, T, dim]: with its
-            residual, or (hyper-connections) its output alone.  Shapes
-            derive from ``x`` itself — under pipeline parallelism the
-            block sees microbatches, not the full batch."""
-            Bb, Tb, _ = x.shape
-            with jax.named_scope("attn"), kind_scope():
-                h = _rms_norm(read(x), gain(lyr["attn_norm"]), cfg.norm_eps)
-                if kind.attn in (LATENT, LINEAR, EVA):
-                    heads = {LATENT: latent_heads, LINEAR: linear_heads,
-                             EVA: eva_heads}[kind.attn]
-                    out = red(heads(h, lyr) @ wc(lyr["wo"]))
-                    return add(x, out) if residual else out
-                q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
-                if cfg.qk_norm:
-                    q = _rms_norm(q, gain(lyr["q_norm"]), cfg.norm_eps)
-                    k = _rms_norm(k, gain(lyr["k_norm"]), cfg.norm_eps)
-                q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
-                k = k.reshape(Bb, Tb, local_kv, cfg.head_dim)
-                v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_kv,
-                                                cfg.head_dim)
-                q = _rope(q.transpose(0, 2, 1, 3), rope)
-                k = _rope(k.transpose(0, 2, 1, 3), rope)
-                v = v.transpose(0, 2, 1, 3)
-                if attn_in_shard_map:
-                    o = ring_attention(q, k, v, mesh, axis_name="sp",
-                                       causal=True, scale=scale,
-                                       window=window)
-                else:
-                    o = blockwise_attention_local(q, k, v, scale,
-                                                  causal=True, window=window)
-                o = o.transpose(0, 2, 1, 3)                  # [B,T,H,D]
-                if cfg.attn_gate:
-                    gate = jax.nn.sigmoid(
-                        (h @ wc(lyr["wg"])).astype(jnp.float32))
-                    o = o * gate.astype(dt)[..., None]
-                o = o.reshape(Bb, Tb, local_heads * cfg.head_dim)
-                out = red(o @ wc(lyr["wo"]))
-                return add(x, out) if residual else out
-
-        def mlp_sub(x, lyr, residual=True):
-            """``(the FFN sub-layer of x, its weighted auxiliary loss, what
-            it counted)``: a routed layer's ``(load, kept)`` (``moe_ffn``'s;
-            ``kept`` is None without a group limit), a dense one's None."""
-            with jax.named_scope("mlp"):
-                h = _rms_norm(read(x), gain(lyr["mlp_norm"]), cfg.norm_eps)
-                if kind.ffn == SPARSE:
-                    out, balance, z, load, kept = moe_ffn(
-                        lyr, h, top_k=cfg.top_k, compute_dtype=dt,
-                        dispatch=cfg.moe_dispatch,
-                        norm_topk_prob=cfg.norm_topk_prob, held=cfg.held,
-                        routed_scale=cfg.routed_scale, aux=use_aux,
-                        scoring=cfg.router_scoring, all_load=cfg.rule_bias,
-                        groups=(cfg.n_group, cfg.topk_group))
-                    if cfg.shared_expert_hidden:
-                        out = out + shared_expert(lyr, h, dt)
-                    aux = (cfg.aux_loss_coef * balance
-                           + cfg.router_z_loss_coef * z)
-                    return ((add(x, out) if residual else out), aux,
-                            (load, kept))
-                gated = (jax.nn.silu(h @ wc(lyr["w1"]))
-                         * (h @ wc(lyr["w3"])))
-                out = red(gated @ wc(lyr["w2"]))
-                return ((add(x, out) if residual else out),
-                        jnp.float32(0), None)
-
-        def block(x, lyr):
-            """One decoder layer: attn + residual, MLP/MoE + residual; with
-            hyper-connections ``x`` is the n streams (a tuple) and
-            each sub-layer reads and writes them through its own gates."""
-            if not n_streams:
-                return mlp_sub(attn_sub(x, lyr), lyr)
-            pre, post, res = _hc_gates(x, lyr["hc_attn"], cfg)
-            x = _hc_write(x, post, res,
-                          attn_sub(_hc_read(x, pre), lyr, residual=False))
-            pre, post, res = _hc_gates(x, lyr["hc_mlp"], cfg)
-            out, aux, counted = mlp_sub(_hc_read(x, pre), lyr,
-                                        residual=False)
-            return _hc_write(x, post, res, out), aux, counted
-
-        if cfg.remat:
-            # Under scan the body already blocks CSE, so the anti-CSE
-            # barriers are pure overhead there.  The flash kernel's
-            # custom_vjp composes with checkpoint under both policies.
-            if cfg.remat_policy == "dots":
-                # Dot outputs PLUS the flash kernel's named (o, lse)
-                # residuals (ops/flash_attention.py `_flash_fwd`): with
-                # them saved, the backward calls its own flash kernel
-                # directly instead of replaying the forward kernel —
-                # the recompute tax drops to the cheap tensor ops
-                # (norms, rope) for ~one extra o-sized buffer per layer.
-                # The grouped schedule's three matmuls are no dot_general
-                # either and are saved by name (moe.GROUPED_SAVED).
-                block = jax.checkpoint(
-                    block,
-                    policy=jax.checkpoint_policies.save_from_both_policies(
-                        jax.checkpoint_policies
-                        .dots_with_no_batch_dims_saveable,
-                        jax.checkpoint_policies.save_only_these_names(
-                            "flash_out", "flash_lse", "wcast", "kda_out",
-                            "kda_state", "kda_solve", *GROUPED_SAVED)),
-                    prevent_cse=not cfg.scan_layers)
-            elif cfg.remat_policy == "full":
-                block = jax.checkpoint(block,
-                                       prevent_cse=not cfg.scan_layers)
-            else:
-                raise ValueError(
-                    f"unknown remat_policy '{cfg.remat_policy}' "
-                    "(expected 'full' or 'dots')")
+    if not cfg.remat:
         return block
+    # Under scan the body already blocks CSE, so the anti-CSE
+    # barriers are pure overhead there.  The flash kernel's
+    # custom_vjp composes with checkpoint under both policies.
+    if cfg.remat_policy == "dots":
+        # Dot outputs PLUS the flash kernel's named (o, lse)
+        # residuals (ops/flash_attention.py `_flash_fwd`): with
+        # them saved, the backward calls its own flash kernel
+        # directly instead of replaying the forward kernel —
+        # the recompute tax drops to the cheap tensor ops
+        # (norms, rope) for ~one extra o-sized buffer per layer.
+        # The grouped schedule's three matmuls are no dot_general
+        # either and are saved by name (moe.GROUPED_SAVED); the kind's
+        # kernels name theirs (``AttnKind.saved``).
+        return jax.checkpoint(
+            block,
+            policy=jax.checkpoint_policies.save_from_both_policies(
+                jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+                jax.checkpoint_policies.save_only_these_names(
+                    "wcast", *KINDS[kind.attn].saved, *GROUPED_SAVED)),
+            prevent_cse=not cfg.scan_layers)
+    if cfg.remat_policy == "full":
+        return jax.checkpoint(block, prevent_cse=not cfg.scan_layers)
+    raise ValueError(f"unknown remat_policy '{cfg.remat_policy}' "
+                     "(expected 'full' or 'dots')")
 
-    blocks = {kind: make_block(kind) for kind in set(lay.kinds)}
 
-    if use_pp:
-        # GPipe over the layer stack: embed/head stay replicated, the
-        # [L, ...] params reshape to [pp, L/pp, ...] stages, microbatches
-        # ride the schedule in parallel/pipeline.py.
-        from ..parallel.pipeline import gpipe
+def _run_pipeline(ctx: Ctx, blocks, layers, x):
+    """The layers of ``x`` [B, T, dim] as GPipe runs them over the mesh's
+    ``pp`` axis: embed/head stay replicated, the [L, ...] params reshape to
+    [pp, L/pp, ...] stages, microbatches ride the schedule in
+    parallel/pipeline.py."""
+    from ..parallel.pipeline import gpipe
 
-        if not cfg.scan_layers or cfg.num_experts:
-            raise ValueError(
-                "pipeline_microbatches requires scan_layers=True and a "
-                "dense MLP (num_experts=0)")
-        if not lay.uniform:
-            raise ValueError(
-                "pipeline_microbatches requires every layer alike: stages "
-                f"slice one stacked tree, and the layers are {lay.lead} + "
-                f"{lay.n_periods} x {lay.period}")
-        kind = lay.period[0]
-        if int(mesh.shape.get("sp", 1)) > 1:
-            # Ring attention's own shard_map cannot nest inside gpipe's.
-            raise ValueError(
-                "pipeline parallelism composes with dp and tp, not sp "
-                "(ring attention inside pipeline stages is unsupported)")
-        pp = int(mesh.shape["pp"])
-        dp = int(mesh.shape.get("dp", 1))
-        tp = int(mesh.shape.get("tp", 1))
-        M = cfg.pipeline_microbatches
-        if cfg.n_layers % pp or B % (M * dp):
-            raise ValueError(
-                f"n_layers ({cfg.n_layers}) must divide into pp ({pp}) "
-                f"stages and batch ({B}) into {M} microbatches x dp "
-                f"({dp}) shards")
-        if tp > 1 and cfg.qk_norm:
-            raise ValueError(
-                "qk_norm inside a pipeline stage with tp > 1 is unsupported: "
-                "the stage shards wq/wk by hand and the norm's mean of "
-                "squares would need a psum over 'tp'")
-        if (kind.heads % tp or (cfg.n_kv_heads or kind.heads) % tp
-                or cfg.hidden % tp or cfg.dim % tp):
-            raise ValueError(
-                f"pp x tp needs n_heads ({kind.heads}), K/V heads "
-                f"({cfg.n_kv_heads or kind.heads}), hidden "
-                f"({cfg.hidden}) and dim ({cfg.dim}) divisible by tp "
-                f"({tp}) — the stage body shards them manually")
-        stages = jax.tree_util.tree_map(
-            lambda l: l.reshape(pp, cfg.n_layers // pp, *l.shape[1:]),
-            params["layers"])
+    cfg, mesh, lay = ctx.cfg, ctx.mesh, ctx.cfg.layout
+    B, T, _ = x.shape
+    if not cfg.scan_layers or cfg.num_experts:
+        raise ValueError(
+            "pipeline_microbatches requires scan_layers=True and a "
+            "dense MLP (num_experts=0)")
+    if not lay.uniform:
+        raise ValueError(
+            "pipeline_microbatches requires every layer alike: stages "
+            f"slice one stacked tree, and the layers are {lay.lead} + "
+            f"{lay.n_periods} x {lay.period}")
+    kind = lay.period[0]
+    if int(mesh.shape.get("sp", 1)) > 1:
+        # Ring attention's own shard_map cannot nest inside gpipe's.
+        raise ValueError(
+            "pipeline parallelism composes with dp and tp, not sp "
+            "(ring attention inside pipeline stages is unsupported)")
+    pp = int(mesh.shape["pp"])
+    dp = int(mesh.shape.get("dp", 1))
+    tp = int(mesh.shape.get("tp", 1))
+    M = cfg.pipeline_microbatches
+    if cfg.n_layers % pp or B % (M * dp):
+        raise ValueError(
+            f"n_layers ({cfg.n_layers}) must divide into pp ({pp}) "
+            f"stages and batch ({B}) into {M} microbatches x dp "
+            f"({dp}) shards")
+    if tp > 1 and cfg.qk_norm:
+        raise ValueError(
+            "qk_norm inside a pipeline stage with tp > 1 is unsupported: "
+            "the stage shards wq/wk by hand and the norm's mean of "
+            "squares would need a psum over 'tp'")
+    if (kind.heads % tp or (cfg.n_kv_heads or kind.heads) % tp
+            or cfg.hidden % tp or cfg.dim % tp):
+        raise ValueError(
+            f"pp x tp needs n_heads ({kind.heads}), K/V heads "
+            f"({cfg.n_kv_heads or kind.heads}), hidden "
+            f"({cfg.hidden}) and dim ({cfg.dim}) divisible by tp "
+            f"({tp}) — the stage body shards them manually")
+    stages = jax.tree_util.tree_map(
+        lambda l: l.reshape(pp, cfg.n_layers // pp, *l.shape[1:]), layers)
 
-        if tp > 1:
-            # Manual tensor parallelism inside the stage: gpipe's
-            # shard_map makes every named axis manual, so the tp layout
-            # becomes explicit — column-parallel wq/wk/wv/w1/w3 shards
-            # arrive via param_specs, and the block psums the
-            # row-parallel wo/w2 outputs over "tp".
-            stage_block = make_block(
-                kind, tp, reduce=lambda t: jax.lax.psum(t, "tp"))
-        else:
-            stage_block = blocks[kind]
-
-        def stage_fn(stage_params, h):
-            def body(h, lyr):
-                h, _, _ = stage_block(h, lyr)
-                return h, None
-
-            h, _ = jax.lax.scan(body, h, stage_params)
-            return h
-
-        # INTERLEAVED microbatch assignment (row r -> microbatch r % M):
-        # each microbatch's rows stay evenly spread over the contiguous
-        # dp batch shards, so no cross-device reshard per step — a
-        # contiguous split would all-to-all the whole activation tensor.
-        xm = x.reshape(B // M, M, T, cfg.dim).swapaxes(0, 1)
-        with jax.named_scope("layers"):
-            xm = gpipe(stage_fn, stages, xm, mesh, axis_name="pp",
-                       batch_axis="dp",
-                       param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
-                                    else None))
-        x = xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
-        aux_total, counted, mtp_logits = jnp.float32(0), None, None
+    if tp > 1:
+        # Manual tensor parallelism inside the stage: gpipe's
+        # shard_map makes every named axis manual, so the tp layout
+        # becomes explicit — column-parallel wq/wk/wv/w1/w3 shards
+        # arrive via param_specs, and the block psums the
+        # row-parallel wo/w2 outputs over "tp".
+        stage_block = _make_block(
+            ctx, kind, tp, reduce=lambda t: jax.lax.psum(t, "tp"))
     else:
-        # A leading group, a scan over the periods whose body runs one layer
-        # of each slot, a trailing part of a period; every layer alike is
-        # one slot and nothing around the scan.  Without ``scan_layers`` the
-        # layers are a list and the loop below is all there is.
-        # ``counted``: the routed layers' ``(load, kept)`` in layer order.
-        aux_total, counted = jnp.float32(0), []
-        tmap = jax.tree_util.tree_map
-        if n_streams:       # the embedding row copied into the n streams
-            x = (x,) * n_streams
+        stage_block = blocks[kind]
 
-        def run(x, aux, kinds, layers):
-            for kind, lyr in zip(kinds, layers):
-                x, a, c = blocks[kind](x, lyr)
+    def stage_fn(stage_params, h):
+        def body(h, lyr):
+            h, _, _ = stage_block(h, lyr)
+            return h, None
+
+        h, _ = jax.lax.scan(body, h, stage_params)
+        return h
+
+    # INTERLEAVED microbatch assignment (row r -> microbatch r % M):
+    # each microbatch's rows stay evenly spread over the contiguous
+    # dp batch shards, so no cross-device reshard per step — a
+    # contiguous split would all-to-all the whole activation tensor.
+    xm = x.reshape(B // M, M, T, cfg.dim).swapaxes(0, 1)
+    with jax.named_scope("layers"):
+        xm = gpipe(stage_fn, stages, xm, mesh, axis_name="pp",
+                   batch_axis="dp",
+                   param_specs=(_layer_pspecs(cfg, mesh) if tp > 1
+                                else None))
+    return xm.swapaxes(0, 1).reshape(B, T, cfg.dim)
+
+
+def _run_stack(ctx: Ctx, blocks, layers, x):
+    """``(x, weighted auxiliary loss, counted)`` after the layers: a leading
+    group, a scan over the periods whose body runs one layer of each slot, a
+    trailing part of a period; every layer alike is one slot and nothing
+    around the scan.  Without ``scan_layers`` the layers are a list and one
+    loop is all there is.  ``counted``: a list of the routed layers' ``(load,
+    kept)``, ``[layers, ...]`` an entry, in layer order."""
+    cfg, lay = ctx.cfg, ctx.cfg.layout
+    aux_total, counted = jnp.float32(0), []
+    tmap = jax.tree_util.tree_map
+    if cfg.hc_mult:         # the embedding row copied into the n streams
+        x = (x,) * cfg.hc_mult
+
+    def run(x, aux, kinds, layers):
+        for kind, lyr in zip(kinds, layers):
+            x, a, c = blocks[kind](x, lyr)
+            aux = aux + a
+            if c is not None:
+                counted.append(tmap(lambda v: v[None], c))
+        return x, aux
+
+    def scan_body(carry, slots):
+        x, aux = carry
+        run_counted = []
+        for (s, count), lyr in zip(lay.runs, slots):
+            block = blocks[lay.period[s]]
+            if count == 1:
+                x, a, c = block(x, lyr)
                 aux = aux + a
-                if c is not None:
-                    counted.append(tmap(lambda v: v[None], c))
-            return x, aux
+            else:       # slots alike: one body, scanned
 
-        with jax.named_scope("layers"):
-            if not cfg.scan_layers:
-                x, aux_total = run(x, aux_total, lay.kinds, params["layers"])
-            else:
-                lead, period, trail = _grouped(cfg, params["layers"])
-                x, aux_total = run(x, aux_total, lay.lead, lead)
+                def alike(carry, lyr, block=block):
+                    x, a, c = block(carry[0], lyr)
+                    return (x, carry[1] + a), c
 
-                def scan_body(carry, slots):
-                    x, aux = carry
-                    run_counted = []
-                    for (s, count), lyr in zip(lay.runs, slots):
-                        block = blocks[lay.period[s]]
-                        if count == 1:
-                            x, a, c = block(x, lyr)
-                            aux = aux + a
-                        else:       # slots alike: one body, scanned
+                (x, aux), c = jax.lax.scan(alike, (x, aux), lyr)
+            run_counted.append(c)
+        return (x, aux), tuple(run_counted)
 
-                            def alike(carry, lyr, block=block):
-                                x, a, c = block(carry[0], lyr)
-                                return (x, carry[1] + a), c
-
-                            (x, aux), c = jax.lax.scan(alike, (x, aux), lyr)
-                        run_counted.append(c)
-                    return (x, aux), tuple(run_counted)
-
-                (x, aux_total), run_counted = jax.lax.scan(
-                    scan_body, (x, aux_total), period)
-                routed = [(c, count) for c, (_, count)
-                          in zip(run_counted, lay.runs) if c is not None]
-                if routed:
-                    counts = [count for _, count in routed]
-                    counted.append(tmap(
-                        lambda *parts: _layer_order(parts, counts),
-                        *[c for c, _ in routed]))
-                x, aux_total = run(x, aux_total, lay.period[:lay.n_trail],
-                                   trail)
-        if n_streams:       # the streams' mean goes on to the final norm
-            x = _hc_mean(x)
-        mtp_logits = None
-        if cfg.mtp_layers:
-            # One more depth: position t pairs its hidden state with the
-            # embedding of token t+1 and predicts token t+2.  It runs over
-            # all T positions so that the kernel's blocks divide; the last
-            # has no next token (a zero row), takes no loss and, being last
-            # under a causal mask, moves no other position.
-            with jax.named_scope("mtp"):
-                m = params["mtp"]
-                nxt = params["embed"][jnp.roll(tokens, -1, axis=1)]
-                nxt = nxt.astype(dt) * (jnp.arange(T) < T - 1
-                                        ).astype(dt)[None, :, None]
-                g = jnp.concatenate(
-                    [_rms_norm(x, gain(m["h_norm"]), cfg.norm_eps),
-                     _rms_norm(nxt, gain(m["e_norm"]), cfg.norm_eps)],
-                    axis=-1) @ m["proj"].astype(dt)
-                if n_streams:
-                    g = (g,) * n_streams
-                g, a, c = blocks[lay.kinds[-1]](g, m["layer"])
-                aux_total = aux_total + a
-                if c is not None:
-                    counted.append(tmap(lambda v: v[None], c))
-                if n_streams:
-                    g = _hc_mean(g)
-                with jax.named_scope("head"):
-                    g = _rms_norm(g, gain(m["out_norm"]), cfg.norm_eps)
-                    mtp_logits = g @ params["head"].astype(dt)
-        counted = (None if not counted else counted[0] if len(counted) == 1
-                   else tmap(lambda *parts: jnp.concatenate(parts), *counted))
-
-    load, kept = counted or (None, None)
-    with jax.named_scope("head"):
-        x = _rms_norm(read(x), gain(params["out_norm"]), cfg.norm_eps)
-        if cfg.logits_dtype is not None:
-            logits = jnp.dot(x, params["head"].astype(dt),
-                             preferred_element_type=cfg.logits_dtype)
+    with jax.named_scope("layers"):
+        if not cfg.scan_layers:
+            x, aux_total = run(x, aux_total, lay.kinds, layers)
         else:
-            logits = x @ params["head"].astype(dt)
-    return logits, aux_total, load, mtp_logits, kept
+            lead, period, trail = _grouped(cfg, layers)
+            x, aux_total = run(x, aux_total, lay.lead, lead)
+            (x, aux_total), run_counted = jax.lax.scan(
+                scan_body, (x, aux_total), period)
+            routed = [(c, count) for c, (_, count)
+                      in zip(run_counted, lay.runs) if c is not None]
+            if routed:
+                counts = [count for _, count in routed]
+                counted.append(tmap(
+                    lambda *parts: _layer_order(parts, counts),
+                    *[c for c, _ in routed]))
+            x, aux_total = run(x, aux_total, lay.period[:lay.n_trail],
+                               trail)
+    if cfg.hc_mult:         # the streams' mean goes on to the final norm
+        x = _hc_mean(x)
+    return x, aux_total, counted
+
+
+def _mtp(ctx: Ctx, block, params, tokens, x, aux_total, counted):
+    """``(the prediction module's logits, the auxiliary loss with its
+    layer's added)``; what that layer counted is appended to ``counted``.
+    One more depth: position t pairs its hidden state ``x`` with the
+    embedding of token t+1 and predicts token t+2.  It runs over all T
+    positions so that the kernel's blocks divide; the last has no next token
+    (a zero row), takes no loss and, being last under a causal mask, moves no
+    other position."""
+    cfg, dt, gain = ctx.cfg, ctx.dt, ctx.gain
+    T = tokens.shape[1]
+    with jax.named_scope("mtp"):
+        m = params["mtp"]
+        nxt = params["embed"][jnp.roll(tokens, -1, axis=1)]
+        nxt = nxt.astype(dt) * (jnp.arange(T) < T - 1
+                                ).astype(dt)[None, :, None]
+        g = jnp.concatenate(
+            [rms_norm(x, gain(m["h_norm"]), cfg.norm_eps),
+             rms_norm(nxt, gain(m["e_norm"]), cfg.norm_eps)],
+            axis=-1) @ m["proj"].astype(dt)
+        if cfg.hc_mult:
+            g = (g,) * cfg.hc_mult
+        g, a, c = block(g, m["layer"])
+        aux_total = aux_total + a
+        if c is not None:
+            counted.append(jax.tree_util.tree_map(lambda v: v[None], c))
+        if cfg.hc_mult:
+            g = _hc_mean(g)
+        with jax.named_scope("head"):
+            g = rms_norm(g, gain(m["out_norm"]), cfg.norm_eps)
+            return g @ params["head"].astype(dt), aux_total
 
 
 def _ce_value(logits, targets):

@@ -42,8 +42,8 @@ tiles' ``dq`` adds into the window's.
 
 Off the TPU the same arithmetic is a dense masked softmax over ``[own window
 | summaries]`` a window (``jnp`` path; its backward by ``jax.vjp``), or the
-kernels in interpret mode under ``MVTPU_FORCE_FLASH`` (the scan's dispatch,
-``ops/kda.py:_path``).  A trace is counted in
+kernels in interpret mode under ``MVTPU_FORCE_FLASH``
+(``ops/kernel_path.py``).  A trace is counted in
 ``attention.eva_traced{window=,chunk=,path=mosaic|interpret|jnp}``, the
 backward's in ``attention.eva_bwd_traced`` under the same labels.
 """
@@ -61,7 +61,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import (_FUSED_VMEM_LIMIT, _LANES, _NEG, _causal_mask,
                               _named_call, fit_block)
-from .kda import _path          # mosaic | interpret | jnp, at trace time
+from .kernel_path import kernel_path
 
 __all__ = ["eva_attention", "summarise"]
 
@@ -435,7 +435,7 @@ def eva_attention(q, k, v, kbar, vbar, window: int, chunk: int,
                                                      block_q, block_k)
     blocks = (block_q, block_k, fit_block(block_q_bwd, window),
               fit_block(block_k_bwd, window))
-    path = _path()
+    path = kernel_path()
     metrics.counter("attention.eva_traced",
                     _labels(window, chunk, path)).inc()
     flat = lambda x: x.reshape(B * H, x.shape[2], D)

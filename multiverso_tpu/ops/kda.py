@@ -87,13 +87,14 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .kernel_path import kernel_path
 
 __all__ = ["kda", "CHUNK", "SUB"]
 
@@ -602,16 +603,6 @@ def _bwd_kernel_call(q, k, v, g, beta, kept, d_o, interpret):
 
 
 # ------------------------------------------------------------ the custom_vjp
-def _path() -> str:
-    """``mosaic`` | ``interpret`` | ``jnp``: taken at trace time, as the
-    flash kernels' dispatch takes it (``parallel/ring_attention.py``)."""
-    if os.environ.get("MVTPU_NO_FLASH"):
-        return "jnp"
-    if jax.default_backend() == "tpu":
-        return "mosaic"
-    return "interpret" if os.environ.get("MVTPU_FORCE_FLASH") else "jnp"
-
-
 def _forward(q, k, v, g, beta, path):
     """``(o, what the backward keeps)``: the states, and from a kernel the
     solves beside them."""
@@ -676,7 +667,7 @@ def kda(q, k, v, g, beta):
         raise ValueError(
             "kda wants q/k/g [B,T,H,dk], v [B,T,H,dv], beta [B,T,H]; got "
             f"{q.shape}, {k.shape}, {g.shape}, {v.shape}, {beta.shape}")
-    path = _path()
+    path = kernel_path()
     metrics.counter("attention.linear_traced",
                     {"heads": str(H), "chunk": str(CHUNK), "path": path}).inc()
     pad = -T % CHUNK

@@ -98,7 +98,9 @@ def _flash_dispatch(tq: int, tk: int, head_dim: int,
     """``(block_q, block_k, interpret)`` for the Pallas flash kernel, or
     ``None`` for the jnp streaming path.
 
-    The ONE place the kernel-or-reference decision is taken.  It is taken
+    Where the flash kernels' body is decided: ``ops.kernel_path()`` (the
+    backend and the two switches, shared with the other kernels) and whether
+    blocks fit.  It is taken
     at trace time, so a compiled step holds whichever body this returned
     and never switches; every decision is therefore counted in
     ``attention.traced{path=mosaic|interpret|jnp}`` (``metrics``), and a
@@ -111,25 +113,21 @@ def _flash_dispatch(tq: int, tk: int, head_dim: int,
       kernel in interpret mode, so CI covers this exact dispatch.
     - ``MVTPU_NO_FLASH``, or no block ≥64 divides the sequence: jnp.
     """
+    from ..ops.kernel_path import kernel_path
+
     bq = _flash_block(tq, cap=512, head_dim=head_dim)
     bk = _flash_block(tk, cap=1024, head_dim=head_dim)
-    on_tpu = jax.default_backend() == "tpu"
-    wanted = ((on_tpu or os.environ.get("MVTPU_FORCE_FLASH"))
-              and not os.environ.get("MVTPU_NO_FLASH"))
-    if bq and bk and wanted:
-        path = "mosaic" if on_tpu else "interpret"
-    else:
-        path = "jnp"
-        if on_tpu:
-            Log.info("attention Tq=%d Tk=%d D=%d traced on the O(T^2) jnp "
-                     "path (flash blocks %d/%d, MVTPU_NO_FLASH=%r)",
-                     tq, tk, head_dim, bq, bk,
-                     os.environ.get("MVTPU_NO_FLASH", ""))
+    path = kernel_path() if bq and bk else "jnp"
+    if path == "jnp" and jax.default_backend() == "tpu":
+        Log.info("attention Tq=%d Tk=%d D=%d traced on the O(T^2) jnp "
+                 "path (flash blocks %d/%d, MVTPU_NO_FLASH=%r)",
+                 tq, tk, head_dim, bq, bk,
+                 os.environ.get("MVTPU_NO_FLASH", ""))
     metrics.counter("attention.traced", {"path": path}).inc()
     if window is not None:
         metrics.counter("attention.window_traced",
                         {"window": str(window)}).inc()
-    return None if path == "jnp" else (bq, bk, not on_tpu)
+    return None if path == "jnp" else (bq, bk, path == "interpret")
 
 
 def blockwise_attention_local(q, k, v, scale: float, causal: bool = True,

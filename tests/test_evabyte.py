@@ -96,7 +96,7 @@ def _attend(path, window=32, chunk=4, **blocks):
     the call is traced)."""
     def attend(q, k, v, phi, mu):
         scale = q.shape[-1] ** -0.5
-        with mock.patch.object(flash_eva, "_path", lambda: path):
+        with mock.patch.object(flash_eva, "kernel_path", lambda: path):
             kbar, vbar = flash_eva.summarise(k, v, phi, mu, scale, chunk)
             return flash_eva.eva_attention(q, k, v, kbar, vbar, window,
                                            chunk, scale=scale, **blocks)
@@ -160,7 +160,7 @@ def test_the_staircase_sees_the_past_through_summaries_alone(path):
     assert float(jnp.abs(moved[:, :, 70:96] - base[:, :, 70:96]).max()) > 1e-4
     early = k.at[:, :, 5].add(1.0)              # window 0, chunk 1
     kbar, vbar = flash_eva.summarise(k, v, phi, mu, scale, c)
-    with mock.patch.object(flash_eva, "_path", lambda: path):
+    with mock.patch.object(flash_eva, "kernel_path", lambda: path):
         held = flash_eva.eva_attention(q, early, v, kbar, vbar, W, c,
                                        scale=scale)
     np.testing.assert_array_equal(np.asarray(held[:, :, 32:]),

@@ -659,11 +659,11 @@ def test_configuration_file_agrees_with_itself():
         with pytest.raises(ValueError, match="model group runs"):
             runner._check_published({**config, **wrong})
     # the reference's inverse frequencies are the program's
-    from multiverso_tpu.models.transformer import Rope, _rope_freqs
+    from multiverso_tpu.models.common import Rope, rope_freqs
     for key in ("rope_full", "rope_sliding"):
         rotated = int(128 * model[key]["rotary_factor"])
         want = laguna_lm.inverse_frequencies(model[key], rotated)
-        got = np.asarray(_rope_freqs(Rope(**model[key]), rotated // 2))
+        got = np.asarray(rope_freqs(Rope(**model[key]), rotated // 2))
         assert np.allclose(got, want, rtol=1e-6, atol=0)
     # YaRN moved the slow dims and left the fast ones: by hand, dim 64
     yarn = laguna_lm.inverse_frequencies(model["rope_full"], 64)

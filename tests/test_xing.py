@@ -1024,9 +1024,9 @@ def test_configuration_file_agrees_with_itself():
     assert model["attn_mscale"] == pytest.approx(1.41589, abs=1e-5)
     # the reference's inverse frequencies are the program's; YaRN moved the
     # slow dims (/ 64) and left the fast ones
-    from multiverso_tpu.models.transformer import Rope, _rope_freqs
+    from multiverso_tpu.models.common import Rope, rope_freqs
     want = xing_lm.inverse_frequencies(model["rope_latent"], 64)
-    got = np.asarray(_rope_freqs(Rope(**model["rope_latent"]), 32))
+    got = np.asarray(rope_freqs(Rope(**model["rope_latent"]), 32))
     assert np.allclose(got, want, rtol=1e-6, atol=0)
     plain = 10000.0 ** (-np.arange(32) / 32)
     assert np.allclose(want[:8], plain[:8]) and np.allclose(
