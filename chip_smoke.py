@@ -541,9 +541,6 @@ def phase_moe(cfg, batch: int, seq: int, mesh, steps: int = 3,
     unevenly the routes of this batch fall on the experts."""
     import dataclasses
 
-    import jax
-    import jax.numpy as jnp
-
     from multiverso_tpu.models import init_params
     from multiverso_tpu.models.transformer import expert_load
 
@@ -559,7 +556,7 @@ def phase_moe(cfg, batch: int, seq: int, mesh, steps: int = 3,
             f"grouped step-0 loss {first} vs dense dispatch {oracle}")
     toks = np.random.RandomState(0).randint(
         cfg.vocab_size, size=(batch, seq)).astype(np.int32)
-    params = jax.tree_util.tree_map(jnp.asarray, init_params(cfg, seed=0))
+    params = init_params(cfg, seed=0)
     load = np.asarray(expert_load(params, toks, cfg))
     require(load.shape == (cfg.n_layers, cfg.num_experts)
             and (load.sum(axis=1) == batch * seq * cfg.top_k).all(),

@@ -10,7 +10,6 @@ eva_chunk) * w`` in ONE softmax.  One device."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..common import AttnKind, apply_rope
@@ -31,20 +30,20 @@ def _check(cfg, kind):
             f"{cfg.eva_chunk}")
 
 
-def _init(cfg, kind, rng, w):
+def _init(cfg, kind, w):
     heads, width = kind.heads, kind.heads * cfg.head_dim
     lyr = {
-        "wq": w(cfg.dim, width),
-        "wk": w(cfg.dim, width),
-        "wv": w(cfg.dim, width),
-        "wo": w(width, cfg.dim),
+        "wq": w("wq", cfg.dim, width),
+        "wk": w("wk", cfg.dim, width),
+        "wv": w("wv", cfg.dim, width),
+        "wo": w("wo", width, cfg.dim),
     }
     # The pooling's query and the summaries' key offset, a head:
     # N(0, 1 / head_dim), cut at three deviations.
     std = cfg.head_dim ** -0.5
-    lyr.update({key: np.clip(
-        std * rng.randn(heads, cfg.head_dim), -3 * std, 3 * std
-    ).astype(np.float32) for key in ("phi", "mu")})
+    lyr.update({name: jnp.clip(
+        w.normal(name, (heads, cfg.head_dim), std), -3 * std, 3 * std)
+        for name in ("phi", "mu")})
     return lyr
 
 

@@ -33,18 +33,19 @@ def _check(cfg, kind):
             "are the latent ones")
 
 
-def _init(cfg, kind, rng, w):
+def _init(cfg, kind, w):
     heads = kind.heads
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    lyr = ({"wq_a": w(cfg.dim, cfg.q_lora_rank),
+    lyr = ({"wq_a": w("wq_a", cfg.dim, cfg.q_lora_rank),
             "q_a_norm": unit_gain(cfg, cfg.q_lora_rank),
-            "wq_b": w(cfg.q_lora_rank, heads * (dn + dr))}
-           if cfg.q_lora_rank else {"wq": w(cfg.dim, heads * (dn + dr))})
+            "wq_b": w("wq_b", cfg.q_lora_rank, heads * (dn + dr))}
+           if cfg.q_lora_rank
+           else {"wq": w("wq", cfg.dim, heads * (dn + dr))})
     lyr.update({
-        "wkv_a": w(cfg.dim, cfg.kv_lora_rank + dr),
+        "wkv_a": w("wkv_a", cfg.dim, cfg.kv_lora_rank + dr),
         "kv_a_norm": unit_gain(cfg, cfg.kv_lora_rank),
-        "wkv_b": w(cfg.kv_lora_rank, heads * (dn + dv)),
-        "wo": w(heads * dv, cfg.dim),
+        "wkv_b": w("wkv_b", cfg.kv_lora_rank, heads * (dn + dv)),
+        "wo": w("wo", heads * dv, cfg.dim),
     })
     return lyr
 

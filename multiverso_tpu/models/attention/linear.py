@@ -16,7 +16,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..common import AttnKind, rms_norm, unit_gain
@@ -36,20 +35,24 @@ def _check(cfg, kind):
             ">= 1 and kda_lower_bound < 0")
 
 
-def _init(cfg, kind, rng, w):
+def _init(cfg, kind, w):
     heads, q_width = kind.heads, kind.heads * cfg.head_dim
     taps = cfg.linear_conv_kernel
-    lyr = {key: w(cfg.dim, q_width) for key in ("wq", "wk", "wv", "wf")}
-    lyr.update({key: w(taps, q_width, scale=taps ** -0.5)
-                for key in ("conv_q", "conv_k", "conv_v")})
+    lyr = {name: w(name, cfg.dim, q_width)
+           for name in ("wq", "wk", "wv", "wf")}
+    lyr.update({name: w(name, taps, q_width, scale=taps ** -0.5)
+                for name in ("conv_q", "conv_k", "conv_v")})
     # The decay's time-scales as the flash-linear-attention library
     # draws them: exp(A_log) uniform in (1, 16) a head, dt_bias the
     # inverse softplus of a step log-uniform in (0.001, 0.1) a channel.
-    dt = np.exp(rng.uniform(math.log(0.001), math.log(0.1), q_width))
+    dt = jnp.exp(jax.random.uniform(
+        w.key("dt_bias"), (q_width,), jnp.float32,
+        math.log(0.001), math.log(0.1)))
     lyr.update(
-        wb=w(cfg.dim, heads), wo=w(q_width, cfg.dim),
-        A_log=np.log(rng.uniform(1.0, 16.0, heads)).astype(np.float32),
-        dt_bias=(dt + np.log(-np.expm1(-dt))).astype(np.float32),
+        wb=w("wb", cfg.dim, heads), wo=w("wo", q_width, cfg.dim),
+        A_log=jnp.log(jax.random.uniform(w.key("A_log"), (heads,),
+                                         jnp.float32, 1.0, 16.0)),
+        dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
         o_norm=unit_gain(cfg, cfg.head_dim))
     return lyr
 

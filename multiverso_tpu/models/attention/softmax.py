@@ -29,14 +29,14 @@ def _check_sliding(cfg, kind):
                          "sliding_window >= 1")
 
 
-def _init(cfg, kind, rng, w):
+def _init(cfg, kind, w):
     q_width = kind.heads * cfg.head_dim
     kv_width = (cfg.n_kv_heads or kind.heads) * cfg.head_dim
     return {
-        "wq": w(cfg.dim, q_width),
-        "wk": w(cfg.dim, kv_width),
-        "wv": w(cfg.dim, kv_width),
-        "wo": w(q_width, cfg.dim),
+        "wq": w("wq", cfg.dim, q_width),
+        "wk": w("wk", cfg.dim, kv_width),
+        "wv": w("wv", cfg.dim, kv_width),
+        "wo": w("wo", q_width, cfg.dim),
     }
 
 

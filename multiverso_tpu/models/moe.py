@@ -97,9 +97,10 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import metrics
+from .common import Draw
 
-__all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_pspecs",
-           "moe_shardings", "route_rungs", "shared_expert"]
+__all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_leaves",
+           "moe_pspecs", "moe_shardings", "route_rungs", "shared_expert"]
 
 # ``checkpoint_name``s of the three grouped-matmul outputs.  A grouped matmul
 # is not a ``dot_general``, so remat policy "dots" saves them by name
@@ -107,27 +108,31 @@ __all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_pspecs",
 GROUPED_SAVED = ("moe_gate", "moe_up", "moe_down")
 
 
-def init_moe_params(dim: int, hidden: int, num_experts: int,
-                    seed: int = 0, held: int = 0,
-                    scoring: str = "softmax") -> Dict[str, Any]:
-    """``held`` experts' weights (0 = all) under a router of ``num_experts``
-    columns; with ``scoring="sigmoid"`` the correction bias ``router_bias
-    [num_experts]`` too, zeros."""
-    rng = np.random.RandomState(seed)
+def moe_leaves(w: Draw, dim: int, hidden: int, num_experts: int,
+               held: int = 0, scoring: str = "softmax") -> Dict[str, Any]:
+    """A routed layer's leaves from its ``Draw``: ``held`` experts' weights
+    (0 = all) under a router of ``num_experts`` columns; with
+    ``scoring="sigmoid"`` the correction bias ``router_bias [num_experts]``
+    too, zeros.  Their deviations are their own (the router 0.02, the experts
+    fan-in), whatever the model's ``init_std``."""
     held = held or num_experts
-
-    def w(*shape, scale):
-        return (scale * rng.randn(*shape)).astype(np.float32)
-
     params = {
-        "router": w(dim, num_experts, scale=0.02),
-        "w1": w(held, dim, hidden, scale=dim ** -0.5),   # gate
-        "w3": w(held, dim, hidden, scale=dim ** -0.5),   # up
-        "w2": w(held, hidden, dim, scale=hidden ** -0.5),
+        "router": w.normal("router", (dim, num_experts), 0.02),
+        "w1": w.normal("w1", (held, dim, hidden), dim ** -0.5),   # gate
+        "w3": w.normal("w3", (held, dim, hidden), dim ** -0.5),   # up
+        "w2": w.normal("w2", (held, hidden, dim), hidden ** -0.5),
     }
     if scoring == "sigmoid":
         params["router_bias"] = np.zeros(num_experts, np.float32)
     return params
+
+
+def init_moe_params(dim: int, hidden: int, num_experts: int,
+                    seed: int = 0, held: int = 0,
+                    scoring: str = "softmax") -> Dict[str, Any]:
+    """``moe_leaves`` of a layer of its own, drawn from ``seed``."""
+    return moe_leaves(Draw(jax.random.key(seed)), dim, hidden, num_experts,
+                      held, scoring)
 
 
 def moe_pspecs(mesh: Mesh) -> Dict[str, Any]:

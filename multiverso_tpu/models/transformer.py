@@ -51,8 +51,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..updaters import AddOption, get_updater
 from .. import dashboard, metrics, tracing
 from .attention import KINDS
-from .common import Ctx, Rope, rms_norm, unit_gain
-from .moe import (GROUPED_SAVED, init_moe_params, moe_ffn, moe_pspecs,
+from .common import Ctx, Draw, Rope, rms_norm, unit_gain
+from .moe import (GROUPED_SAVED, moe_ffn, moe_leaves, moe_pspecs,
                   route_rungs, shared_expert)
 
 __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
@@ -98,6 +98,14 @@ class Layout(NamedTuple):
     def kinds(self) -> Tuple[LayerKind, ...]:
         return (self.lead + self.period * self.n_periods
                 + self.period[:self.n_trail])
+
+    def run_layers(self, s: int, count: int) -> np.ndarray:
+        """Where in ``kinds`` the layers of the run ``(s, count)`` stand:
+        [n_periods], or for a run of several [n_periods, count], as its
+        leaves are stacked."""
+        at = (len(self.lead) + len(self.period)
+              * np.arange(self.n_periods)[:, None] + s + np.arange(count))
+        return at if count > 1 else at[:, 0]
 
 
 def _layout(kinds: Tuple[LayerKind, ...], period: int = 0) -> Layout:
@@ -407,14 +415,14 @@ class TransformerConfig:
         return KINDS[attn].rope(self) or Rope(theta=self.rope_theta)
 
 
-def _hc_init(cfg: TransformerConfig, w):
-    """One sub-layer's hyper-connection leaves: ``phi [n*dim, 2n + n*n]``
+def _hc_init(cfg: TransformerConfig, w: Draw, sub: str):
+    """Sub-layer ``sub``'s hyper-connection leaves: ``phi [n*dim, 2n + n*n]``
     (columns: pre, post, res row-major), ``alpha [3]``, ``b [2n + n*n]``.
     With ``alpha`` 0 these are ``H_pre = 1/n``, ``H_post = 1``, ``H_res``
     the identity to 1e-3: the one-stream pre-norm model on n equal
     streams."""
     n = cfg.hc_mult
-    return {"phi": w(n * cfg.dim, 2 * n + n * n,
+    return {"phi": w(f"{sub}.phi", n * cfg.dim, 2 * n + n * n,
                      scale=0.02 * (n * cfg.dim) ** -0.5),
             "alpha": np.full(3, 0.01, np.float32),
             "b": np.concatenate([np.full(n, math.log(1 / (n - 1))),
@@ -422,11 +430,12 @@ def _hc_init(cfg: TransformerConfig, w):
                                  8.0 * np.eye(n).ravel()]).astype(np.float32)}
 
 
-def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
-    """One layer's leaves.  The order of the draws (the attention's, the
-    norms, QK-norm, FFN, gate, shared expert, streams) is part of every seeded
-    result: a routed cell's rates follow the drawn router."""
-    lyr = KINDS[kind.attn].init(cfg, kind, rng, w)
+def _init_layer(cfg: TransformerConfig, kind: LayerKind, w: Draw):
+    """One layer's leaves from its ``Draw``: a leaf's values follow from the
+    seed, the layer's index and the leaf's name, so a leaf added here or in a
+    kind's ``init`` moves no other.  (A routed cell's rates follow the drawn
+    router.)"""
+    lyr = KINDS[kind.attn].init(cfg, kind, w)
     lyr.update(attn_norm=unit_gain(cfg, cfg.dim),
                mlp_norm=unit_gain(cfg, cfg.dim))
     if cfg.qk_norm:
@@ -435,55 +444,105 @@ def _init_layer(cfg: TransformerConfig, kind: LayerKind, rng, w):
                    k_norm=unit_gain(cfg, kv * cfg.head_dim))
     if kind.ffn == SPARSE:
         # router, w1, w3, w2 at the layer's top level, expert-indexed
-        lyr.update(init_moe_params(cfg.dim, cfg.hidden, cfg.num_experts,
-                                   seed=rng.randint(2 ** 31),
-                                   held=cfg.experts_held,
-                                   scoring=cfg.router_scoring))
+        lyr.update(moe_leaves(w, cfg.dim, cfg.hidden, cfg.num_experts,
+                              cfg.experts_held, cfg.router_scoring))
     else:
         hidden = cfg.dense_hidden or cfg.hidden
         lyr.update({
-            "w1": w(cfg.dim, hidden),   # gate
-            "w3": w(cfg.dim, hidden),   # up
-            "w2": w(hidden, cfg.dim),   # down
+            "w1": w("w1", cfg.dim, hidden),   # gate
+            "w3": w("w3", cfg.dim, hidden),   # up
+            "w2": w("w2", hidden, cfg.dim),   # down
         })
     if cfg.attn_gate:
-        lyr["wg"] = w(cfg.dim, kind.heads)
+        lyr["wg"] = w("wg", cfg.dim, kind.heads)
     if kind.ffn == SPARSE and cfg.shared_expert_hidden:
         shared = cfg.shared_expert_hidden
-        lyr.update(shared_w1=w(cfg.dim, shared), shared_w3=w(cfg.dim, shared),
-                   shared_w2=w(shared, cfg.dim))
+        lyr.update(shared_w1=w("shared_w1", cfg.dim, shared),
+                   shared_w3=w("shared_w3", cfg.dim, shared),
+                   shared_w2=w("shared_w2", shared, cfg.dim))
     if cfg.hc_mult:
-        lyr.update(hc_attn=_hc_init(cfg, w), hc_mlp=_hc_init(cfg, w))
+        lyr.update(hc_attn=_hc_init(cfg, w, "hc_attn"),
+                   hc_mlp=_hc_init(cfg, w, "hc_mlp"))
     return lyr
 
 
-def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
-    """Float32 master weights, truncated-normal-ish init.  ``layers`` is a
-    list of per-layer dicts, or under ``scan_layers`` the layers as
-    ``group_layers`` holds them."""
-    rng = np.random.RandomState(seed)
+def _draw_tree(cfg: TransformerConfig, root) -> Dict[str, Any]:
+    """``init_params``' tree from the seed's key ``root``, traced: one
+    program draws every leaf in its final shape."""
+    lay = cfg.layout
 
-    def w(*shape, scale=None):
-        scale = cfg.init_std or scale or (shape[0] ** -0.5)
-        return (scale * rng.randn(*shape)).astype(np.float32)
+    def layers_at(kind, index):
+        """The leaves of the layer of ``kind`` at ``index`` of ``lay.kinds``;
+        with an array of indices, of those layers, stacked as the array is
+        shaped: a loop of the one-layer draw that writes each layer where it
+        rests, so no stack of whole leaves runs, and one body to compile
+        however many layers there are."""
+        draw = lambda i: _init_layer(
+            cfg, kind, Draw(jax.random.fold_in(root, i), cfg.init_std))
+        for _ in range(np.ndim(index)):
+            draw = functools.partial(jax.lax.map, draw)
+        return draw(jnp.asarray(index, jnp.uint32))
 
-    layers = [_init_layer(cfg, kind, rng, w) for kind in cfg.layout.kinds]
-    if cfg.scan_layers:
-        layers = group_layers(cfg, layers)
+    if not cfg.scan_layers:
+        # alike layers by one loop, one body to compile, then taken apart
+        where = {kind: [i for i, k in enumerate(lay.kinds) if k == kind]
+                 for kind in set(lay.kinds)}
+        drawn = {kind: layers_at(kind, np.asarray(at))
+                 for kind, at in where.items()}
+        layers = [jax.tree_util.tree_map(
+            lambda leaf: leaf[where[kind].index(i)], drawn[kind])
+            for i, kind in enumerate(lay.kinds)]
+    elif lay.uniform:
+        layers = layers_at(lay.period[0], np.arange(cfg.n_layers))
+    else:
+        n_lead = len(lay.lead)
+        n_body = n_lead + len(lay.period) * lay.n_periods
+        layers = {"lead": [layers_at(kind, i)
+                           for i, kind in enumerate(lay.lead)],
+                  "period": [layers_at(lay.period[s], lay.run_layers(s, count))
+                             for s, count in lay.runs],
+                  "trail": [layers_at(kind, n_body + i) for i, kind
+                            in enumerate(lay.period[:lay.n_trail])]}
+    w = Draw(root, cfg.init_std)
     params = {
-        "embed": w(cfg.vocab_size, cfg.dim, scale=0.02),
+        "embed": w("embed", cfg.vocab_size, cfg.dim, scale=0.02),
         "out_norm": unit_gain(cfg, cfg.dim),
-        "head": w(cfg.dim, cfg.n_pred_heads * cfg.vocab_size),
+        "head": w("head", cfg.dim, cfg.n_pred_heads * cfg.vocab_size),
         "layers": layers,
     }
-    if cfg.mtp_layers:
+    if cfg.mtp_layers:               # its layer: one past the model's last
         params["mtp"] = {
-            "proj": w(2 * cfg.dim, cfg.dim),
+            "proj": w("mtp.proj", 2 * cfg.dim, cfg.dim),
             "h_norm": unit_gain(cfg, cfg.dim),
             "e_norm": unit_gain(cfg, cfg.dim),
             "out_norm": unit_gain(cfg, cfg.dim),
-            "layer": _init_layer(cfg, cfg.layout.kinds[-1], rng, w)}
+            "layer": layers_at(lay.kinds[-1], cfg.n_layers)}
     return params
+
+
+@functools.lru_cache(maxsize=64)
+def _draw_program(cfg: TransformerConfig, placed, tree):
+    """``_draw_tree`` jitted, its leaves drawn to the shardings ``placed``
+    (the leaves of ``tree``) or, with no ``tree``, on the default device.
+    Kept a configuration and placement, so a second draw compiles nothing."""
+    return jax.jit(
+        functools.partial(_draw_tree, cfg),
+        out_shardings=None if tree is None else tree.unflatten(placed))
+
+
+def init_params(cfg: TransformerConfig, seed: int = 0,
+                shardings=None) -> Dict[str, Any]:
+    """Float32 master weights, normal draws (``common.Draw``; the gains ones,
+    the rest as the kinds say), made on the device from ``jax.random.key(
+    seed)``: nothing the size of a matrix is made on, or crosses, the host.
+    Every leaf comes to be in its final shape, on ``shardings``' sharding
+    (``param_shardings``' tree; None = the default device), a function of
+    (seed, layer index, leaf name) alone.  ``layers`` is a list of per-layer
+    dicts, or under ``scan_layers`` the layers as ``group_layers`` holds
+    them, the same values either way."""
+    placed, tree = ((), None) if shardings is None else (
+        jax.tree_util.tree_flatten(shardings))
+    return _draw_program(cfg, tuple(placed), tree)(jax.random.key(seed))
 
 
 def stack_layer_params(layers):
@@ -491,8 +550,8 @@ def stack_layer_params(layers):
 
     The scan-format params: leaf k holds ``stack([lyr[k] for lyr in
     layers])``.  Works on numpy or jax leaves (nested dicts included);
-    used by ``init_params(scan_layers=True)`` and by tests converting
-    loop-format params for parity checks.
+    for tests converting loop-format params for parity checks
+    (``init_params`` draws a stacked slot stacked).
     """
     return jax.tree_util.tree_map(
         lambda *xs: (np.stack(xs) if isinstance(xs[0], np.ndarray)
@@ -1237,18 +1296,11 @@ def _bias_rule(cfg: TransformerConfig, params, loads):
         else:
             lead, period, trail = _grouped(cfg, layers)
             n_lead, p = len(lay.lead), len(lay.period)
-
-            def slot_rows(s, count):
-                """The rows of a run's layers: [n_periods], or with a run
-                of several [n_periods, count], as its leaves are stacked."""
-                of = np.asarray([[row_of[n_lead + q * p + s + j]
-                                  for j in range(count)]
-                                 for q in range(lay.n_periods)])
-                return of if count > 1 else of[:, 0]
-
+            # a run's rows, shaped as its leaves are stacked
+            rows_at = np.asarray([-1 if r is None else r for r in row_of])
             period = [
                 slot if lay.period[s].ffn != SPARSE else moved(
-                    slot, slot_rows(s, count))
+                    slot, rows_at[lay.run_layers(s, count)])
                 for (s, count), slot in zip(lay.runs, period)]
             layers = (period[0] if lay.uniform else {
                 "lead": [at(i, lyr) for i, lyr in enumerate(lead)],
@@ -1278,21 +1330,19 @@ class TransformerTrainer:
         self.mesh = mesh
         self.updater = get_updater(updater_type)
         self.option = option or AddOption(learning_rate=0.1)
-        shardings = param_shardings(cfg, mesh)
-        # Start-up sections (docs/observability.md, "Start-up"): the numpy
-        # draw on the host, then the leaves' transfer and the state's zeros
-        # to their completion.
+        # Start-up sections (docs/observability.md, "Start-up"): the draw of
+        # every leaf on its own sharding (each device of a replicated leaf
+        # draws its own copy), then the state's zeros, each to its
+        # completion.
         with dashboard.monitor("Transformer::init_draw"):
-            host = init_params(cfg, seed)
+            self.params = jax.block_until_ready(
+                init_params(cfg, seed, param_shardings(cfg, mesh)))
         with dashboard.monitor("Transformer::init_place"):
-            self.params = jax.tree_util.tree_map(
-                lambda a, s: jax.device_put(a, s), host, shardings,
-                is_leaf=lambda x: isinstance(x, np.ndarray))
             self.state = jax.tree_util.tree_map(
                 lambda p: tuple(jnp.zeros_like(p)
                                 for _ in range(self.updater.num_slots)),
                 self.params)
-            jax.block_until_ready((self.params, self.state))
+            jax.block_until_ready(self.state)
         self._step = None
         # The last step's counted routes, on the device (``cfg.
         # counts_routes``: int32 [routed layers, experts_held + 1], the held

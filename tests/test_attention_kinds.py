@@ -18,6 +18,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from multiverso_tpu.models import TransformerConfig, init_params
 from multiverso_tpu.models import transformer
 from multiverso_tpu.models.attention import KINDS, AttnKind
+from multiverso_tpu.models.common import Draw
 from multiverso_tpu.models.transformer import (LayerKind, _init_layer,
                                                _layer_pspecs)
 from multiverso_tpu.ops import flash_eva, kda
@@ -75,17 +76,13 @@ def test_a_kinds_leaves_are_the_leaves_it_has_specs_for(name, attn_gate,
                                                         q_lora_rank):
     cfg = TransformerConfig(**_model(name, attn_gate=attn_gate,
                                      q_lora_rank=q_lora_rank))
-    rng = np.random.RandomState(0)
-
-    def w(*shape, scale=None):
-        return np.zeros(shape, np.float32)
-
+    w = Draw(jax.random.key(0))
     mesh = _mesh(("dp",), (1,))
     for kind in cfg.layout.kinds:                 # a dense and a routed layer
-        own = KINDS[name].init(cfg, kind, rng, w)
+        own = jax.eval_shape(lambda: KINDS[name].init(cfg, kind, w))
         assert set(own) == set(KINDS[name].pspecs(cfg, kind, None, 1))
         assert "wo" in own
-        leaves = _init_layer(cfg, kind, rng, w)
+        leaves = jax.eval_shape(lambda: _init_layer(cfg, kind, w))
         specs = _layer_pspecs(cfg, mesh, kind)
         assert set(leaves) == set(specs)
         assert ("wg" in leaves) == bool(attn_gate)
@@ -248,7 +245,8 @@ def test_a_sixth_kind_is_one_entry_of_kinds(monkeypatch):
 def test_a_seeded_draw_of_every_kind_is_pinned():
     """One layer of every kind, dense and routed FFNs, a gate, a shared
     expert, two streams and the module: a PR that moves a draw fails here and
-    not in a cell's rate."""
+    not in a cell's rate.  (PR 46, which drew the weights on the device from
+    a seeded key, took the digest again; before it was 1efde8ac...8097.)"""
     cfg = TransformerConfig(**_model(
         "full_attention", n_layers=5, layer_types=list(NAMES),
         heads_per_layer=[2, 4, 2, 2, 2], n_kv_heads=0,
@@ -264,7 +262,7 @@ def test_a_seeded_draw_of_every_kind_is_pinned():
         digest.update(np.ascontiguousarray(leaf).tobytes())
     assert len(leaves) == 138
     assert digest.hexdigest() == (
-        "1efde8ac3ec86ba3c9ddf4a85f739900400bbca98a4c1604ddb1de62e9db8097")
+        "8d09b733fb5f9fc6fa81165eb601132d03a60f189598bc971cd7d0a7546a141d")
 
 
 # ------------------------------------------------- one kernel-path decision

@@ -17,7 +17,13 @@ of its own text without locations); the sorted multiset of the text's name
 stacks, which masking drops and the benchmark's per-layer readers parse; the
 compiled step's peak bytes.  ``--compare`` reads two directories of
 ``<mode>_<cell>.json`` and exits 1 unless every one of them is equal.  PR 43
-ran it over the eight transformer cells (CHANGES.md)."""
+ran it over the eight transformer cells (CHANGES.md).
+
+``init_sha256`` follows the draw, so across PR 46 (the weights drawn on the
+device from a seeded key, where a numpy generator made them) it differs by
+design, once, and with it the toy losses, which are made of the weights'
+values; every other key (the lowered text, its names, the compiled peak) says
+as before whether the step is the same program."""
 import collections
 import glob
 import hashlib
@@ -174,7 +180,7 @@ def sha(b) -> str:
 def tree_hash(params) -> dict:
     """sha256 over the leaves in order: path, shape, dtype, bytes."""
     h = hashlib.sha256()
-    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
+    leaves, _ = jax.tree_util.tree_flatten_with_path(jax.device_get(params))
     names = []
     for path, leaf in leaves:
         a = np.asarray(leaf)
