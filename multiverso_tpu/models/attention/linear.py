@@ -8,7 +8,27 @@ bounded gate: the decay lies in (exp(kda_lower_bound), 1)); ``beta =
 sigmoid(h wb)`` a head; the scan; an RMSNorm a head (gain ``o_norm
 [head_dim]``), then the caller's ``attn_gate`` and ``wo``.  One device: the
 scan's state is not handed along an ``sp`` ring and no layout of its heads
-over ``tp`` is written."""
+over ``tp`` is written.
+
+**Layout** (ISSUE 47).  Every array of the sub-layer that is ``heads *
+head_dim`` wide stays ``[B, T, heads * head_dim]`` from its projection to the
+scan and from the scan to ``wo``: that is the layout the projections write,
+the kernels of ``ops/kda.py`` read through their index maps, and ``wo``
+multiplies.  On a TPU ``[T, heads * head_dim]`` and ``[T, heads, head_dim]``
+are different tilings of memory (the minor two dimensions are tiled (8, 128):
+rows of ``T`` in one, rows of ``heads`` in the other), so a reshape between
+them is a copy of the whole array, 256 MiB in float32 at 16,384 tokens, which
+autodiff mirrors in the backward and remat "full" runs again.  So a head's
+reduction (the L2 norms, the output norm's mean of squares) is
+``common.head_sum`` and a head's broadcast (the ``rsqrt`` back to its
+channels, the caller's gate) ``common.head_spread``: products with a constant
+0/1 matrix, the same float32 sums in another order.  The decay's
+``exp(A_log)`` and the output norm's gain are repeated to ``heads *
+head_dim`` once, as vectors.  ``kda`` keeps its ``[B, T, H, d]`` signature:
+the reshape before a call that flattens again is a pair of bitcasts, which
+XLA removes.  ``_heads`` counts a trace in
+``attention.linear_flat_traced{heads=}``; ``tests/test_ling.py`` holds the
+sub-layer to the 4-D form and the v5e program to no relayout copy."""
 
 from __future__ import annotations
 
@@ -18,7 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..common import AttnKind, rms_norm, unit_gain
+from ... import metrics
+from ..common import AttnKind, head_spread, head_sum, unit_gain
 
 __all__ = ["LINEAR"]
 
@@ -88,8 +109,9 @@ def _refuse(cfg, mesh):
 
 
 def _heads(ctx, kind, h, lyr):
-    """Linear attention's heads from the normed input ``h``:
-    [B, T, heads, head_dim], normed a head."""
+    """Linear attention's heads from the normed input ``h``: flat,
+    [B, T, heads * head_dim], normed a head (the module docstring has the
+    layout)."""
     from ...ops.kda import kda       # pallas: imported where first traced
 
     cfg, wc, dt = ctx.cfg, ctx.wc, ctx.dt
@@ -97,31 +119,41 @@ def _heads(ctx, kind, h, lyr):
     local_heads = kind.heads // ctx.tp
     D, f32 = cfg.head_dim, jnp.float32
     taps = cfg.linear_conv_kernel
+    metrics.counter("attention.linear_flat_traced",
+                    {"heads": str(local_heads)}).inc()
 
     def conv(x, kernel):
         # causal, depthwise: tap j reads the token taps - 1 - j back
         kernel = kernel.astype(dt)
         padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
         y = sum(padded[:, j:j + Tb] * kernel[j] for j in range(taps))
-        return jax.nn.silu(y).reshape(Bb, Tb, local_heads, D)
+        return jax.nn.silu(y)
 
     def unit(x):        # L2-normalised a head, in float32
         x = x.astype(f32)
-        return x * jax.lax.rsqrt(
-            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+        return x * head_spread(jax.lax.rsqrt(
+            head_sum(jnp.square(x), local_heads) + 1e-6), D)
+
+    def split(x):       # a bitcast pair with kda's own flatten
+        return x.reshape(Bb, Tb, local_heads, D)
 
     q = (unit(conv(h @ wc(lyr["wq"]), lyr["conv_q"]))
          * D ** -0.5).astype(dt)
     k = unit(conv(h @ wc(lyr["wk"]), lyr["conv_k"])).astype(dt)
     v = conv(h @ wc(lyr["wv"]), lyr["conv_v"])
     f = jnp.dot(h, wc(lyr["wf"]), preferred_element_type=f32)
-    f = (f + lyr["dt_bias"].astype(f32)).reshape(Bb, Tb, local_heads, D)
     g = cfg.kda_lower_bound * jax.nn.sigmoid(
-        jnp.exp(lyr["A_log"].astype(f32))[:, None] * f)
+        jnp.repeat(jnp.exp(lyr["A_log"].astype(f32)), D)
+        * (f + lyr["dt_bias"].astype(f32)))
     beta = jax.nn.sigmoid(
         jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32))
-    return rms_norm(kda(q, k, v, g, beta), ctx.gain(lyr["o_norm"]),
-                    cfg.norm_eps)
+    o = kda(split(q), split(k), split(v), split(g), beta)
+    o = o.reshape(Bb, Tb, local_heads * D)
+    # rms_norm a head, its mean of squares taken flat
+    var = head_sum(jnp.square(o.astype(f32)), local_heads) / D
+    return ((o * head_spread(jax.lax.rsqrt(var + cfg.norm_eps), D)
+             ).astype(o.dtype)
+            * jnp.tile(ctx.gain(lyr["o_norm"]), local_heads))
 
 
 LINEAR = AttnKind(

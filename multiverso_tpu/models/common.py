@@ -21,7 +21,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 __all__ = ["Rope", "Ctx", "AttnKind", "Draw", "unit_gain", "rms_norm",
-           "rope_freqs", "apply_rope"]
+           "head_sum", "head_spread", "rope_freqs", "apply_rope"]
 
 
 @dataclass(frozen=True)
@@ -114,8 +114,10 @@ class AttnKind(NamedTuple):
     leaves (``tp`` the axis' name or None, ``tp_size`` 1 without one),
     refusing a ``tp`` it has no layout for.  ``refuse(cfg, mesh)`` raises on a mesh it does not run over.
     ``rope(cfg)``: its recipe as given, or None.  ``heads(ctx, kind, h, lyr)
-    -> o [B, T, heads, width]`` from the normed input ``h``; the caller gates,
-    multiplies by ``wo`` and adds the residual."""
+    -> o [B, T, heads, width]`` from the normed input ``h``, or flat ``[B, T,
+    heads * width]`` from a kind that keeps that layout throughout
+    (``linear.py``); the caller gates in the shape it is given, multiplies by
+    ``wo`` and adds the residual."""
     scope: str
     saved: Tuple[str, ...]
     gate_tp: bool
@@ -163,6 +165,35 @@ def unit_gain(cfg, n: int):
 def rms_norm(x, gain, eps):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * gain
+
+
+def _head_of(heads: int, width: int, dtype):
+    """The constant 0/1 matrix ``S [heads * width, heads]``, ``S[c, h] = (c //
+    width == h)``: which head a channel of a flat ``[..., heads * width]``
+    array belongs to."""
+    shape = (heads * width, heads)
+    channel = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (channel // width
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1)).astype(dtype)
+
+
+def head_sum(x, heads: int):
+    """``x [..., heads * width]`` summed over each head's channels ->
+    ``[..., heads]`` without leaving the flat array: a product with
+    ``_head_of``'s matrix at "highest", in float32 the sum of
+    ``x.reshape(..., heads, width).sum(-1)`` in another order.  On a TPU that
+    reshape is a copy of the whole array, and its transpose another in the
+    backward (``attention/linear.py`` has why); the product is neither."""
+    S = _head_of(heads, x.shape[-1] // heads, x.dtype)
+    return jnp.dot(x, S, precision=jax.lax.Precision.HIGHEST)
+
+
+def head_spread(s, width: int):
+    """``s [..., heads]`` -> ``[..., heads * width]``, a head's number
+    repeated over its channels: ``head_sum``'s transpose, exact (every sum
+    has one term, times one)."""
+    S = _head_of(s.shape[-1], width, s.dtype)
+    return jnp.dot(s, S.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def rope_freqs(rope: Rope, half: int):

@@ -51,7 +51,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..updaters import AddOption, get_updater
 from .. import dashboard, metrics, tracing
 from .attention import KINDS
-from .common import Ctx, Draw, Rope, rms_norm, unit_gain
+from .common import Ctx, Draw, Rope, head_spread, rms_norm, unit_gain
 from .moe import (GROUPED_SAVED, moe_ffn, moe_leaves, moe_pspecs,
                   route_rungs, shared_expert)
 
@@ -878,7 +878,8 @@ def _refuse(ctx: Ctx, use_pp: bool) -> None:
 def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
     """The attention sub-layer of ``x`` [B, T, dim]: with its residual, or
     (hyper-connections) its output alone.  The kind makes the heads
-    (``AttnKind.heads``); the gate, ``wo`` and the residual are every kind's.
+    (``AttnKind.heads``: 4-D, or flat from a kind that keeps the layout
+    ``wo`` reads); the gate, ``wo`` and the residual are every kind's.
     Shapes derive from ``x`` itself — under pipeline parallelism the block
     sees microbatches, not the full batch."""
     cfg, attn = ctx.cfg, KINDS[kind.attn]
@@ -887,13 +888,16 @@ def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
                   else jax.named_scope(attn.scope))
     with jax.named_scope("attn"), kind_scope:
         h = rms_norm(ctx.read(x), ctx.gain(lyr["attn_norm"]), cfg.norm_eps)
-        o = attn.heads(ctx, kind, h, lyr)                    # [B,T,H,width]
+        # [B,T,H,width], or flat [B,T,H*width] from a kind that keeps it so
+        o = attn.heads(ctx, kind, h, lyr)
         if cfg.attn_gate:       # times sigmoid(h wg), a scalar a head
             gate = jax.nn.sigmoid(
-                (h @ ctx.wc(lyr["wg"])).astype(jnp.float32))
-            o = o * gate.astype(ctx.dt)[..., None]
-        Bb, Tb, heads, width = o.shape
-        o = o.reshape(Bb, Tb, heads * width)
+                (h @ ctx.wc(lyr["wg"])).astype(jnp.float32)).astype(ctx.dt)
+            o = o * (gate[..., None] if o.ndim == 4 else
+                     head_spread(gate, o.shape[-1] // gate.shape[-1]))
+        if o.ndim == 4:
+            Bb, Tb, heads, width = o.shape
+            o = o.reshape(Bb, Tb, heads * width)
         out = ctx.red(o @ ctx.wc(lyr["wo"]))
         return ctx.add(x, out) if residual else out
 

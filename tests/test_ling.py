@@ -5,6 +5,7 @@ router limited by groups over a share of the experts, held to the plain
 reference ``benchmarks/reference/ling_lm.py``, small, on the CPU."""
 
 import json
+import math
 import os
 import sys
 
@@ -24,7 +25,10 @@ from multiverso_tpu import metrics  # noqa: E402
 from multiverso_tpu.models import (TransformerConfig,  # noqa: E402
                                    TransformerTrainer, init_params)
 from multiverso_tpu.models import moe  # noqa: E402
+from multiverso_tpu.models.common import Ctx, Draw, rms_norm  # noqa: E402
 from multiverso_tpu.models.transformer import (STACKED_RUN,  # noqa: E402
+                                               LayerKind, _attn_sub,
+                                               _init_layer,
                                                _loss_routes_loads,
                                                expert_load, lm_loss,
                                                transformer_forward)
@@ -831,6 +835,89 @@ def test_the_step_and_the_scan_are_counted_by_hand():
     assert 420e6 < flops_ling.token_matmul_params(model) < 440e6
 
 
+# ------------------------------------------- the sub-layer, flat (ISSUE 47)
+def _plain_sub(cfg, heads, x, lyr):
+    """The linear sub-layer in its 4-D form, as the layer was written before
+    it kept the scan's layout: every ``[B, T, heads * head_dim]`` array
+    reshaped to ``[B, T, heads, head_dim]`` for a head's reduction and a
+    head's broadcast, flattened before ``wo``."""
+    B, T, _ = x.shape
+    D, taps, f32 = cfg.head_dim, cfg.linear_conv_kernel, jnp.float32
+
+    def conv(y, kernel):
+        padded = jnp.pad(y, ((0, 0), (taps - 1, 0), (0, 0)))
+        y = sum(padded[:, j:j + T] * kernel[j] for j in range(taps))
+        return jax.nn.silu(y).reshape(B, T, heads, D)
+
+    def unit(y):
+        return y * jax.lax.rsqrt(
+            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+
+    h = rms_norm(x, lyr["attn_norm"], cfg.norm_eps)
+    q = unit(conv(h @ lyr["wq"], lyr["conv_q"])) * D ** -0.5
+    k = unit(conv(h @ lyr["wk"], lyr["conv_k"]))
+    v = conv(h @ lyr["wv"], lyr["conv_v"])
+    f = (h @ lyr["wf"] + lyr["dt_bias"]).reshape(B, T, heads, D)
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(lyr["A_log"])[:, None] * f)
+    o = rms_norm(kda_ops.kda(q, k, v, g, jax.nn.sigmoid(h @ lyr["wb"])),
+                 lyr["o_norm"], cfg.norm_eps)
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid(h @ lyr["wg"])[..., None]
+    return x + o.reshape(B, T, heads * D) @ lyr["wo"]
+
+
+@pytest.mark.parametrize("gate", [True, False], ids=["gate", "no-gate"])
+@pytest.mark.parametrize("taps", [1, 4])
+@pytest.mark.parametrize("heads", [2, 3])
+def test_the_flat_sub_layer_is_the_4d_one(heads, taps, gate):
+    """``attn.linear`` kept in ``[B, T, heads * head_dim]`` from the
+    projections to ``wo`` (a head's sums and broadcasts as products with a
+    0/1 matrix) gives the 4-D form's output and the gradients of every leaf
+    and of ``x``, in float32; one trace counts once."""
+    model = _model(n_layers=1, n_heads=heads, layer_types=[LINEAR],
+                   mlp_layer_types=["dense"], layer_period=1,
+                   linear_conv_kernel=taps,
+                   attn_gate="per_head" if gate else "")
+    cfg = _cfg(model)
+    kind = LayerKind(LINEAR, heads, "dense")
+    lyr = _moved({"lyr": _init_layer(cfg, kind,
+                                     Draw(jax.random.key(heads)))})["lyr"]
+    lyr = {name: jnp.asarray(leaf) for name, leaf in lyr.items()
+           if name not in ("w1", "w2", "w3", "mlp_norm")}
+    assert ("wg" in lyr) == gate
+    B, T = 2, 96                                 # a chunk and a half
+    x = jax.random.normal(jax.random.key(7), (B, T, cfg.dim), jnp.float32)
+    ct = jax.random.normal(jax.random.key(8), (B, T, cfg.dim), jnp.float32)
+
+    def both(sub):
+        out, pull = jax.vjp(sub, x, lyr)
+        return out, pull(ct)
+
+    flat = metrics.counter("attention.linear_flat_traced",
+                           {"heads": str(heads)})
+    before = flat.value
+    ctx = Ctx(cfg, None)
+    out, (dx, dlyr) = jax.jit(lambda: both(
+        lambda x, lyr: _attn_sub(ctx, kind, x, lyr)))()
+    assert flat.value == before + 1
+    want, (want_dx, want_dlyr) = jax.jit(lambda: both(
+        lambda x, lyr: _plain_sub(cfg, heads, x, lyr)))()
+    assert flat.value == before + 1
+
+    def rel(got, ref):
+        return float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
+
+    assert rel(out, want) < 1e-6
+    assert rel(dx, want_dx) < 1e-5
+    assert set(dlyr) == set(want_dlyr) == set(lyr)
+    for name in lyr:
+        # the decay's two leaves sum terms of both signs over every token
+        # and channel, in another order: [2-1-no-gate] reads 2e-5 on A_log
+        limit = 1e-4 if name in ("A_log", "dt_bias") else 1e-5
+        assert rel(dlyr[name], want_dlyr[name]) < limit, name
+
+
 # ------------------------------------------------------ the TPU's compiler
 @pytest.fixture(scope="module")
 def one_v5e():
@@ -851,14 +938,29 @@ def one_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_for_v5e(monkeypatch, fn, *shapes):
+    """``fn`` compiled for the described chip as the program's own TPU path
+    (the kernels, not ``jax.numpy``), outside the compile cache (an entry
+    written here cannot be read back without a chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("MVTPU_NO_FLASH", raising=False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn).lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
 def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
     """Mosaic takes ``kda_fwd`` and ``kda_bwd`` at 1 x 16,384 x 32 heads of
     128 x 128 in bfloat16 (the packed solve's 16-row cuts and lane
     concatenations among them), and nothing of XLA's chunked backward is left
     beside them (no loop under the ``kda_bwd`` scope).  Nothing runs: no
     measurement."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     def shaped(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
 
@@ -866,27 +968,62 @@ def test_kda_compiles_for_v5e_at_the_cells_shape(one_v5e, monkeypatch):
         return jax.grad(lambda *a: jnp.sum(kda_ops.kda(*a).astype(
             jnp.float32)), argnums=range(5))(*a)
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.delenv("MVTPU_NO_FLASH", raising=False)
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
     B, T, H, D = 1, 16384, 32, 128
     solved = metrics.counter("attention.linear_solve_traced",
                              {"heads": str(H), "pairs": "2", "lone": "0"})
     before = solved.value
-    try:
-        text = jax.jit(grads).lower(
-            shaped(B, T, H, D), shaped(B, T, H, D), shaped(B, T, H, D),
-            shaped(B, T, H, D, dtype=jnp.float32),
-            shaped(B, T, H, dtype=jnp.float32)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
+    text = _compiled_for_v5e(
+        monkeypatch, grads,
+        shaped(B, T, H, D), shaped(B, T, H, D), shaped(B, T, H, D),
+        shaped(B, T, H, D, dtype=jnp.float32),
+        shaped(B, T, H, dtype=jnp.float32)).as_text()
     calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
     for name in ("kda_fwd", "kda_bwd"):
         assert any(f"/{name}" in line for line in calls), (name, calls)
     assert solved.value == before + 1            # the cell's: two pairs a step
     assert " while(" not in text and "dynamic-update-slice" not in text
+
+
+def test_the_linear_sub_layer_compiles_flat_for_v5e(one_v5e, monkeypatch):
+    """One ``attn.linear`` sub-layer's replay and backward (``jax.vjp``) at
+    the cell's 1 x 16,384 x 2560, compiled for v5e: no relayout copy of a
+    ``[B, T, heads * head_dim]`` array is left (none of 64 MiB or more; the
+    4-D form held six of 256 MiB and one of 128) and the compiler counts
+    under 26 GB accessed (36.5 in the 4-D form).  Nothing runs: no
+    measurement."""
+    import re
+
+    with open(CONFIG) as f:
+        cfg = TransformerConfig(**json.load(f)["model"])
+    kind = cfg.layout.kinds[0]
+    assert kind.attn == LINEAR
+    ctx = Ctx(cfg, None)
+
+    def shaped(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e)
+
+    lyr = jax.eval_shape(
+        lambda: _init_layer(cfg, kind, Draw(jax.random.key(0))))
+    lyr = {name: shaped(leaf) for name, leaf in lyr.items()
+           if name not in ("w1", "w2", "w3", "mlp_norm")}
+    x = shaped(jax.ShapeDtypeStruct((1, 16384, cfg.dim), cfg.compute_dtype))
+
+    def replay_and_backward(x, lyr, ct):
+        return jax.vjp(lambda x, lyr: _attn_sub(ctx, kind, x, lyr),
+                       x, lyr)[1](ct)
+
+    compiled = _compiled_for_v5e(monkeypatch, replay_and_backward, x, lyr, x)
+    text = compiled.as_text()
+    assert "/kda_fwd" in text and "/kda_bwd" in text
+    width = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
+             "u8": 1, "pred": 1}
+    copies = []
+    for dtype, dims in re.findall(
+            r"= (\w+)\[([\d,]*)\][^ ]* copy\(", text):
+        copies.append((width[dtype] * math.prod(
+            int(d) for d in dims.split(",") if d), f"{dtype}[{dims}]"))
+    assert copies and max(copies)[0] < 64 * 2 ** 20, sorted(copies)[-4:]
+    assert compiled.cost_analysis()["bytes accessed"] < 26e9
 
 
 # ------------------------------------------------------ the cell, rehearsed
