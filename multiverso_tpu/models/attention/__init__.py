@@ -16,7 +16,7 @@ from ..common import AttnKind
 from .eva import EVA
 from .latent import LATENT
 from .linear import LINEAR
-from .softmax import FULL, SLIDING
+from .softmax import FULL, NOPE, SLIDING
 
 __all__ = ["KINDS", "AttnKind"]
 
@@ -26,4 +26,5 @@ KINDS = {
     "latent_attention": LATENT,
     "linear_attention": LINEAR,
     "eva_attention": EVA,
+    "full_attention_nope": NOPE,
 }

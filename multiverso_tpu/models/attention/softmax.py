@@ -1,18 +1,22 @@
 """``full_attention`` and ``sliding_attention``: causal softmax attention of
 ``heads`` rotated query heads over grouped K/V heads (``n_kv_heads``), a
-sliding layer seeing the keys ``t - sliding_window < s <= t`` alone.  The
-only kinds with a layout over ``tp`` (heads shard) and, full layers, ``sp``
-(``parallel/ring_attention.py``)."""
+sliding layer seeing the keys ``t - sliding_window < s <= t`` alone; and
+``full_attention_nope``, full attention whose queries and keys carry no
+position embedding at all (the causal mask is all that orders the keys): it
+has no recipe and traces no angle, no ``cos`` and no ``sin``, counted in
+``attention.nope_traced{heads=}``.  The only kinds with a layout over ``tp``
+(heads shard) and, full layers, ``sp`` (``parallel/ring_attention.py``)."""
 
 from __future__ import annotations
 
 from jax.sharding import PartitionSpec as P
 
+from ... import metrics
 from ...parallel.ring_attention import (blockwise_attention_local,
                                         ring_attention)
 from ..common import AttnKind, apply_rope, rms_norm
 
-__all__ = ["FULL", "SLIDING"]
+__all__ = ["FULL", "SLIDING", "NOPE"]
 
 
 def _check(cfg, kind):
@@ -52,12 +56,12 @@ def _refuse_sliding(cfg, mesh):
             f"not run over an 'sp' ring (sp={mesh.shape['sp']})")
 
 
-def _heads(ctx, kind, h, lyr, window=None):
+def _heads(ctx, kind, h, lyr, window=None, rotate=True):
     cfg, wc = ctx.cfg, ctx.wc
     Bb, Tb, _ = h.shape
     local_heads = kind.heads // ctx.tp
     local_kv = (cfg.n_kv_heads or kind.heads) // ctx.tp
-    rope, scale = cfg.rope(kind.attn), cfg.head_dim ** -0.5
+    scale = cfg.head_dim ** -0.5
     q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
     if cfg.qk_norm:
         q = rms_norm(q, ctx.gain(lyr["q_norm"]), cfg.norm_eps)
@@ -65,9 +69,13 @@ def _heads(ctx, kind, h, lyr, window=None):
     q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
     k = k.reshape(Bb, Tb, local_kv, cfg.head_dim)
     v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_kv, cfg.head_dim)
-    q = apply_rope(q.transpose(0, 2, 1, 3), rope)
-    k = apply_rope(k.transpose(0, 2, 1, 3), rope)
-    v = v.transpose(0, 2, 1, 3)
+    rope = cfg.rope(kind.attn) if rotate else None
+
+    def turn(t):            # [B,H,T,D], rotated where the kind has a recipe
+        t = t.transpose(0, 2, 1, 3)
+        return t if rope is None else apply_rope(t, rope)
+
+    q, k, v = turn(q), turn(k), v.transpose(0, 2, 1, 3)
     if ctx.ring:
         o = ring_attention(q, k, v, ctx.mesh, axis_name="sp", causal=True,
                            scale=scale, window=window)
@@ -81,6 +89,11 @@ def _heads_sliding(ctx, kind, h, lyr):
     return _heads(ctx, kind, h, lyr, window=ctx.cfg.sliding_window)
 
 
+def _heads_nope(ctx, kind, h, lyr):
+    metrics.counter("attention.nope_traced", {"heads": str(kind.heads)}).inc()
+    return _heads(ctx, kind, h, lyr, rotate=False)
+
+
 FULL = AttnKind(
     scope="attn.full", saved=("flash_out", "flash_lse"), gate_tp=True,
     check=_check, init=_init, pspecs=_pspecs,
@@ -89,3 +102,5 @@ FULL = AttnKind(
 SLIDING = FULL._replace(
     scope="attn.sliding", check=_check_sliding, refuse=_refuse_sliding,
     rope=lambda cfg: cfg.rope_sliding, heads=_heads_sliding)
+NOPE = FULL._replace(scope="attn.full_nope", rope=lambda cfg: None,
+                     heads=_heads_nope)

@@ -21,7 +21,12 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 __all__ = ["Rope", "Ctx", "AttnKind", "Draw", "unit_gain", "rms_norm",
-           "head_sum", "head_spread", "rope_freqs", "apply_rope"]
+           "head_sum", "head_spread", "rope_freqs", "apply_rope", "GATE_ACTS"]
+
+# What squashes a gated FFN's ``w1`` branch, ``act(h w1) * (h w3)``: SwiGLU's
+# SiLU, or ReGLU's ReLU (``TransformerConfig.ffn_act``), dense, shared and
+# routed FFNs alike.  ReLU's derivative at 0 is ``jax.nn.relu``'s: 0.
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 @dataclass(frozen=True)

@@ -83,6 +83,16 @@ those of its ``topk_group`` best groups only (``_kept_groups``).  A share then
 sees routes only from the tokens that kept its group: ``moe_ffn`` hands that
 count back beside ``load`` (``kept``), and ``moe.traced`` carries the labels
 ``groups=,kept=``.  ``(1, 1)`` is no limit and traces what it always did.
+
+**A route made elsewhere** (``moe_ffn(route=...)``): ``moe_route`` is the
+shared router alone, under ``moe.route``, for a block whose router reads
+something other than the rows the experts multiply (the attention sub-layer's
+normed input: ``TransformerConfig.router_input``); ``moe_ffn`` then routes
+nothing itself, sorts and weighs by what it was handed, and the router's
+gradient flows to wherever the route was made.  ``moe.traced`` carries
+``router_input=`` there.  **The gate's activation** (``act``): what squashes
+the ``w1`` branch of every expert, ``common.GATE_ACTS`` (SwiGLU's ``silu``,
+ReGLU's ``relu``); ``moe.traced`` carries ``act=`` where it is not ``silu``.
 """
 
 from __future__ import annotations
@@ -97,10 +107,11 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import metrics
-from .common import Draw
+from .common import GATE_ACTS, Draw
 
 __all__ = ["GROUPED_SAVED", "init_moe_params", "moe_ffn", "moe_leaves",
-           "moe_pspecs", "moe_shardings", "route_rungs", "shared_expert"]
+           "moe_pspecs", "moe_route", "moe_shardings", "route_rungs",
+           "shared_expert"]
 
 # ``checkpoint_name``s of the three grouped-matmul outputs.  A grouped matmul
 # is not a ``dot_general``, so remat policy "dots" saves them by name
@@ -245,12 +256,24 @@ def _share(params, held):
     return first, count
 
 
+def moe_route(params: Dict[str, Any], h: jax.Array, top_k: int = 2,
+              norm_topk_prob: bool = True, routed_scale: float = 1.0,
+              scoring: str = "softmax", groups=(1, 1)):
+    """The layer's route from ``h [B, T, dim]`` alone, ``_routing``'s tuple
+    under ``moe.route``: what ``moe_ffn(route=...)`` takes where the router
+    reads other rows than the experts multiply."""
+    with jax.named_scope("moe.route"):
+        return _routing(params, h, top_k, norm_topk_prob, routed_scale,
+                        scoring, groups)
+
+
 def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
             compute_dtype=None, dispatch: str = "dense",
             norm_topk_prob: bool = True, held=None,
             routed_scale: float = 1.0, aux: bool = True,
             scoring: str = "softmax", all_load: bool = False,
-            groups=(1, 1)
+            groups=(1, 1), route=None, act: str = "silu",
+            router_input: str = ""
             ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                        Optional[jax.Array]]:
     """x [B, T, dim] → ``(out [B, T, dim], balance, z, load, kept)``: the
@@ -268,7 +291,11 @@ def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
     (``_routing``; labels ``groups=,kept=`` on the counter); ``kept`` is then
     the tokens whose kept groups include the group of the first expert held
     here (of expert 0 where all are held; an int32 scalar), which is what the
-    share's routes now hang on, and ``None`` without a limit."""
+    share's routes now hang on, and ``None`` without a limit.  ``route``: a
+    route ``moe_route`` made from other rows (``top_k`` and the rest as they
+    were given there), in place of one made here from ``x``; ``router_input``
+    names those rows on the counter.  ``act``: the experts' gate activation
+    (``common.GATE_ACTS``)."""
     schedules = {"grouped": _moe_grouped, "dense": _moe_dense}
     if dispatch not in schedules:
         raise ValueError(f"unknown moe dispatch '{dispatch}' "
@@ -282,19 +309,28 @@ def moe_ffn(params: Dict[str, Any], x: jax.Array, top_k: int = 2,
         labels["scoring"] = scoring
     if groups[0] > 1:
         labels.update(groups=str(groups[0]), kept=str(groups[1]))
+    if act not in GATE_ACTS:
+        raise ValueError(f"unknown gate activation '{act}' "
+                         f"(expected {'|'.join(GATE_ACTS)})")
+    if act != "silu":
+        labels["act"] = act
+    if route is not None:
+        labels["router_input"] = router_input or "given"
     metrics.counter("moe.traced", labels).inc()
     return schedules[dispatch](params, x, top_k, compute_dtype or x.dtype,
                                norm_topk_prob, _share(params, held),
-                               routed_scale, aux, scoring, all_load, groups)
+                               routed_scale, aux, scoring, all_load, groups,
+                               route, GATE_ACTS[act])
 
 
-def shared_expert(params: Dict[str, Any], h: jax.Array, dt) -> jax.Array:
-    """The always-on SwiGLU beside the routed experts (``shared_w1``,
-    ``shared_w3``, ``shared_w2`` of the layer), ungated."""
+def shared_expert(params: Dict[str, Any], h: jax.Array, dt,
+                  act=jax.nn.silu) -> jax.Array:
+    """The always-on gated FFN beside the routed experts (``shared_w1``,
+    ``shared_w3``, ``shared_w2`` of the layer), unweighted."""
     with jax.named_scope("moe.shared"):
         w1, w3, w2 = (checkpoint_name(params[k].astype(dt), "wcast")
                       for k in ("shared_w1", "shared_w3", "shared_w2"))
-        return (jax.nn.silu(h @ w1) * (h @ w3)) @ w2
+        return (act(h @ w1) * (h @ w3)) @ w2
 
 
 def _all_load(top_idx, E):
@@ -417,7 +453,7 @@ def route_rungs(routes: int, count: int, experts: int) -> tuple:
     return (rung, routes) if rung < routes else (routes,)
 
 
-def _experts(rows, w1, w3, w2, sizes):
+def _experts(rows, w1, w3, w2, sizes, act=jax.nn.silu):
     """The three grouped matmuls over ``rows`` in expert order, the groups
     ``sizes`` long from row 0; rows beyond the groups are left to chance."""
     gate = checkpoint_name(jax.lax.ragged_dot(rows, w1, sizes),
@@ -425,7 +461,7 @@ def _experts(rows, w1, w3, w2, sizes):
     up = checkpoint_name(jax.lax.ragged_dot(rows, w3, sizes),
                          GROUPED_SAVED[1])
     return checkpoint_name(
-        jax.lax.ragged_dot(jax.nn.silu(gate) * up, w2, sizes),
+        jax.lax.ragged_dot(act(gate) * up, w2, sizes),
         GROUPED_SAVED[2])
 
 
@@ -493,7 +529,7 @@ def _combine_first_bwd(N, dtype, res, d_out):
 _combine_first.defvjp(_combine_first_fwd, _combine_first_bwd)
 
 
-def _ffn_all(dtype, floats, ints):
+def _ffn_all(dtype, act, floats, ints):
     """A share's routed part over all ``N*k`` rows: the top rung, and the
     layer that holds every expert but for its masks.  ``floats = (x, top_p,
     w1, w3, w2)``, ``ints = (order, inv, mine, sizes)``."""
@@ -501,12 +537,12 @@ def _ffn_all(dtype, floats, ints):
     with jax.named_scope("moe.dispatch"):
         rows = _dispatch(x, order, inv, mine)
     with jax.named_scope("moe.experts"):
-        down = _experts(rows, w1, w3, w2, sizes)
+        down = _experts(rows, w1, w3, w2, sizes, act)
     with jax.named_scope("moe.combine"):
         return _combine(down, top_p, order, inv, dtype, mine)
 
 
-def _ffn_first(C: int, dtype, floats, ints):
+def _ffn_first(C: int, dtype, act, floats, ints):
     """The same over the first ``C`` rows of the expert order, which hold
     every held route when ``sum(sizes) <= C``: the gathers, the grouped
     matmuls' rows and the elementwise passes are ``C`` long, and the rows
@@ -519,37 +555,37 @@ def _ffn_first(C: int, dtype, floats, ints):
         live = jnp.arange(C) < jnp.sum(sizes)
         rows = _dispatch_first(x, tok, live)
     with jax.named_scope("moe.experts"):
-        down = _experts(rows, w1, w3, w2, sizes)
+        down = _experts(rows, w1, w3, w2, sizes, act)
     with jax.named_scope("moe.combine"):
         weight = jnp.where(live, top_p.reshape(-1)[head], 0)
         return _combine_first(down, weight, tok, live, x.shape[0], dtype)
 
 
-def _branches(rungs, dtype):
+def _branches(rungs, dtype, act):
     """One exact way to run a share's routed part a rung: the last over all
     ``N*k`` rows, the others over their first ``C``."""
-    return [functools.partial(_ffn_first, C, dtype) for C in rungs[:-1]] + [
-        functools.partial(_ffn_all, dtype)]
+    return [functools.partial(_ffn_first, C, dtype, act)
+            for C in rungs[:-1]] + [functools.partial(_ffn_all, dtype, act)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_ffn(rungs, dtype, floats, ints):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 4))
+def _held_ffn(rungs, dtype, floats, ints, act=jax.nn.silu):
     """A share's routed part, ``out [N, D]``, its row buffers as long as the
     smallest of ``rungs`` that holds this step's held routes (chosen on the
     device: ``jax.lax.switch`` over ``_branches``).  The backward keeps
     ``floats``, ``ints`` and the rung alone and builds the rows again in the
     branch of the same rung, so no branch's residuals lie beside another's."""
-    return _held_ffn_fwd(rungs, dtype, floats, ints)[0]
+    return _held_ffn_fwd(rungs, dtype, floats, ints, act)[0]
 
 
-def _held_ffn_fwd(rungs, dtype, floats, ints):
+def _held_ffn_fwd(rungs, dtype, floats, ints, act=jax.nn.silu):
     rung = jnp.sum(jnp.sum(ints[3]) > jnp.asarray(rungs[:-1], jnp.int32),
                    dtype=jnp.int32)
-    out = jax.lax.switch(rung, _branches(rungs, dtype), floats, ints)
+    out = jax.lax.switch(rung, _branches(rungs, dtype, act), floats, ints)
     return out, (floats, ints, rung)
 
 
-def _held_ffn_bwd(rungs, dtype, res, d_out):
+def _held_ffn_bwd(rungs, dtype, act, res, d_out):
     floats, ints, rung = res
 
     def transposed(run):
@@ -557,7 +593,7 @@ def _held_ffn_bwd(rungs, dtype, res, d_out):
             lambda floats: run(floats, ints), floats)[1](d_out)[0]
 
     return jax.lax.switch(rung, [transposed(run)
-                                 for run in _branches(rungs, dtype)],
+                                 for run in _branches(rungs, dtype, act)],
                           floats, ints, d_out), None
 
 
@@ -565,13 +601,15 @@ _held_ffn.defvjp(_held_ffn_fwd, _held_ffn_bwd)
 
 
 def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
-                 aux, scoring="softmax", all_load=False, groups=(1, 1)):
+                 aux, scoring="softmax", all_load=False, groups=(1, 1),
+                 route=None, act=jax.nn.silu):
     B, T, D = x.shape
     N = B * T
     E = params["router"].shape[1]
-    with jax.named_scope("moe.route"):
-        probs, logits, top_p, top_idx, kept = _routing(
-            params, x, top_k, norm_topk_prob, routed_scale, scoring, groups)
+    if route is None:
+        route = moe_route(params, x, top_k, norm_topk_prob, routed_scale,
+                          scoring, groups)
+    probs, logits, top_p, top_idx, kept = route
     with jax.named_scope("moe.dispatch"):
         # Route r = n*k + j is token n's j-th expert.  Sorted by expert, a
         # group's rows are contiguous and its size is the distance between
@@ -604,7 +642,7 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
         w1, w3, w2 = (checkpoint_name(params[k].astype(dt), "wcast")
                       for k in ("w1", "w3", "w2"))
         if share is None:
-            down = _experts(rows, w1, w3, w2, sizes)         # [N*k, D]
+            down = _experts(rows, w1, w3, w2, sizes, act)    # [N*k, D]
     top_p = top_p.reshape(N, top_k)
     if share is None:
         with jax.named_scope("moe.combine"):
@@ -616,7 +654,7 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
         metrics.counter("moe.route_rows", {"rungs": str(len(rungs)),
                                            "of": str(N * top_k)}).inc()
         out = _held_ffn(rungs, x.dtype, (x2, top_p, w1, w3, w2),
-                        (order, inv, mine, sizes))
+                        (order, inv, mine, sizes), act)
     if all_load:
         with jax.named_scope("moe.route"):
             sizes = _count_load(top_idx, E)
@@ -627,10 +665,13 @@ def _moe_grouped(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
 
 
 def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
-               aux, scoring="softmax", all_load=False, groups=(1, 1)):
+               aux, scoring="softmax", all_load=False, groups=(1, 1),
+               route=None, act=jax.nn.silu):
     E = params["router"].shape[1]
-    probs, logits, top_p, top_idx, kept = _routing(
-        params, x, top_k, norm_topk_prob, routed_scale, scoring, groups)
+    if route is None:
+        route = _routing(params, x, top_k, norm_topk_prob, routed_scale,
+                         scoring, groups)
+    probs, logits, top_p, top_idx, kept = route
     load = _all_load(top_idx, E)
     balance = z = jnp.float32(0)
     if aux:
@@ -647,8 +688,7 @@ def _moe_dense(params, x, top_k, dt, norm_topk_prob, share, routed_scale,
 
     # dense dispatch: every (held) expert sees every token, scaled post-hoc.
     xc = x.astype(dt)
-    gate = jax.nn.silu(jnp.einsum("btd,edh->beth", xc,
-                                  params["w1"].astype(dt)))
+    gate = act(jnp.einsum("btd,edh->beth", xc, params["w1"].astype(dt)))
     up = jnp.einsum("btd,edh->beth", xc, params["w3"].astype(dt))
     expert_out = jnp.einsum("beth,ehd->betd", gate * up,
                             params["w2"].astype(dt))          # [B,E,T,d]

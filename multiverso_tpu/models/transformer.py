@@ -14,16 +14,20 @@ Design (not in the reference — see models/__init__):
   repetitions and scanned (a long run of consecutive slots alike as one inner
   scan: ``Layout.runs``), a trailing part.  Every layer alike is the one-slot
   case.  **An attention kind is one module under ``attention/``**
-  (``attention.KINDS``: full and sliding softmax attention, latent, linear,
-  EVA) that owns its checks, leaves, specs, mesh refusals, scope, rotary
-  recipe and heads; this file looks a kind up there and names none.  A new
+  (``attention.KINDS``: full and sliding softmax attention, full attention
+  without a position embedding, latent, linear, EVA) that owns its checks,
+  leaves, specs, mesh refusals, scope, rotary recipe and heads; this file
+  looks a kind up there and names none.  A new
   kind is added in four steps: a file under ``attention/`` ending in its
   ``AttnKind``, a line in ``KINDS``, its sizes as fields of
   ``TransformerConfig``, its kernel under ``ops/``;
 - ``_forward`` is embed -> layers (``_run_stack``, or ``_run_pipeline`` over
   ``pp``) -> (``_mtp``) -> head over module-level parts that take a ``Ctx``
   (``common.py``: dtypes, mesh, the norm gain, the residual sum); a layer is
-  ``_make_block``'s ``block`` = ``_attn_sub`` + ``_mlp_sub``;
+  ``_make_block``'s ``block`` = ``_attn_sub`` + ``_mlp_sub``, each norming
+  its own input; where the router reads the attention's (``router_input``)
+  the block norms that once, routes from it under ``route_early`` before the
+  heads are made and hands the route on to the FFN;
 - a residual of ``hc_mult`` streams mixed by hyper-connections
   (``_hc_gates``, ``_hc_read``, ``_hc_write``), and a multi-token-prediction
   module (``mtp_layers``), both off by default; a residual stream wider in
@@ -51,8 +55,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..updaters import AddOption, get_updater
 from .. import dashboard, metrics, tracing
 from .attention import KINDS
-from .common import Ctx, Draw, Rope, head_spread, rms_norm, unit_gain
-from .moe import (GROUPED_SAVED, moe_ffn, moe_leaves, moe_pspecs,
+from .common import (GATE_ACTS, Ctx, Draw, Rope, head_spread, rms_norm,
+                     unit_gain)
+from .moe import (GROUPED_SAVED, moe_ffn, moe_leaves, moe_pspecs, moe_route,
                   route_rungs, shared_expert)
 
 __all__ = ["TransformerConfig", "Rope", "LayerKind", "Layout", "init_params",
@@ -65,9 +70,9 @@ DENSE, SPARSE = "dense", "sparse"
 class LayerKind(NamedTuple):
     """What one layer is made of: its attention (``full_attention`` |
     ``sliding_attention`` | ``latent_attention`` | ``linear_attention`` |
-    ``eva_attention``), its query heads, its FFN (``dense`` | ``sparse``).
-    Window, rotary recipe and widths follow from these and the
-    configuration."""
+    ``eva_attention`` | ``full_attention_nope``), its query heads, its FFN
+    (``dense`` | ``sparse``).  Window, rotary recipe and widths follow from
+    these and the configuration."""
     attn: str
     heads: int
     ffn: str
@@ -266,6 +271,15 @@ class TransformerConfig:
     # the group of the first expert held here (``TransformerTrainer.kept``).
     n_group: int = 1
     topk_group: int = 1
+    # Where a routed layer's router reads: "mlp" = the rows its experts
+    # multiply (the FFN sub-layer's normed input); "attn" = the attention
+    # sub-layer's normed input, so that the choice is made before attention
+    # runs: the block routes under the scope ``route_early`` and the FFN
+    # sorts and weighs by the route it is handed (models/moe.py).
+    router_input: str = "mlp"
+    # What squashes the ``w1`` branch of every gated FFN (dense, shared,
+    # routed): "silu" = SwiGLU, "relu" = ReGLU (common.GATE_ACTS).
+    ffn_act: str = "silu"
     # ---- ``latent_attention`` layers: the latents' and the heads' widths
     # (``q_lora_rank`` 0 = no query latent), YaRN's mscale, the rotated
     # parts' recipe.  ``attention/latent.py`` has the equations.
@@ -372,6 +386,15 @@ class TransformerConfig:
                 f"n_group={self.n_group}, topk_group={self.topk_group}: "
                 f"{self.num_experts} experts must lie in n_group groups of "
                 "at least two, of which 1..n_group are kept")
+        if self.router_input not in ("mlp", "attn") or (
+                self.router_input == "attn"
+                and (self.hc_mult or not self.num_experts)):
+            raise ValueError(
+                f"router_input='{self.router_input}': 'mlp' or, with routed "
+                "layers and one residual stream (no hc_mult), 'attn'")
+        if self.ffn_act not in GATE_ACTS:
+            raise ValueError(f"unknown ffn_act '{self.ffn_act}' "
+                             f"(expected {'|'.join(GATE_ACTS)})")
         if self.hc_mult < 0 or self.hc_mult == 1:
             raise ValueError(f"hc_mult={self.hc_mult}: 0 (one stream, no "
                              "hyper-connections) or at least 2 streams")
@@ -875,19 +898,34 @@ def _refuse(ctx: Ctx, use_pp: bool) -> None:
             "prediction module reads the last stage's hidden state")
 
 
-def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
+def _attn_scopes(ctx: Ctx, kind: LayerKind):
+    """``attn`` and, inside it where the layers differ, the kind's own."""
+    both = contextlib.ExitStack()
+    both.enter_context(jax.named_scope("attn"))
+    if ctx.cfg.layer_types is not None:
+        both.enter_context(jax.named_scope(KINDS[kind.attn].scope))
+    return both
+
+
+def _attn_input(ctx: Ctx, kind: LayerKind, x, lyr):
+    """What the attention sub-layer reads: ``x``'s RMS norm under its gain."""
+    with _attn_scopes(ctx, kind):
+        return rms_norm(ctx.read(x), ctx.gain(lyr["attn_norm"]),
+                        ctx.cfg.norm_eps)
+
+
+def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True, h=None):
     """The attention sub-layer of ``x`` [B, T, dim]: with its residual, or
-    (hyper-connections) its output alone.  The kind makes the heads
+    (hyper-connections) its output alone; ``h``: its normed input where the
+    block has made it already (``_attn_input``).  The kind makes the heads
     (``AttnKind.heads``: 4-D, or flat from a kind that keeps the layout
     ``wo`` reads); the gate, ``wo`` and the residual are every kind's.
     Shapes derive from ``x`` itself — under pipeline parallelism the block
     sees microbatches, not the full batch."""
     cfg, attn = ctx.cfg, KINDS[kind.attn]
-    # The kind's own scope inside ``attn`` where the layers differ.
-    kind_scope = (contextlib.nullcontext() if cfg.layer_types is None
-                  else jax.named_scope(attn.scope))
-    with jax.named_scope("attn"), kind_scope:
-        h = rms_norm(ctx.read(x), ctx.gain(lyr["attn_norm"]), cfg.norm_eps)
+    if h is None:
+        h = _attn_input(ctx, kind, x, lyr)
+    with _attn_scopes(ctx, kind):
         # [B,T,H,width], or flat [B,T,H*width] from a kind that keeps it so
         o = attn.heads(ctx, kind, h, lyr)
         if cfg.attn_gate:       # times sigmoid(h wg), a scalar a head
@@ -902,11 +940,21 @@ def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
         return ctx.add(x, out) if residual else out
 
 
-def _mlp_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
+def _route(ctx: Ctx, lyr, h):
+    """A routed layer's route from the rows ``h`` its router reads."""
+    cfg = ctx.cfg
+    return moe_route(lyr, h, cfg.top_k, cfg.norm_topk_prob, cfg.routed_scale,
+                     cfg.router_scoring, (cfg.n_group, cfg.topk_group))
+
+
+def _mlp_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True, route=None):
     """``(the FFN sub-layer of x, its weighted auxiliary loss, what it
     counted)``: a routed layer's ``(load, kept)`` (``moe_ffn``'s; ``kept`` is
-    None without a group limit), a dense one's None."""
+    None without a group limit), a dense one's None.  ``route``: a routed
+    layer's route where the block made it from other rows than these
+    (``router_input``)."""
     cfg, dt, wc = ctx.cfg, ctx.dt, ctx.wc
+    act = GATE_ACTS[cfg.ffn_act]
     with jax.named_scope("mlp"):
         h = rms_norm(ctx.read(x), ctx.gain(lyr["mlp_norm"]), cfg.norm_eps)
         if kind.ffn == SPARSE:
@@ -917,15 +965,15 @@ def _mlp_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True):
                 routed_scale=cfg.routed_scale,
                 aux=bool(cfg.aux_loss_coef or cfg.router_z_loss_coef),
                 scoring=cfg.router_scoring, all_load=cfg.rule_bias,
-                groups=(cfg.n_group, cfg.topk_group))
+                groups=(cfg.n_group, cfg.topk_group), route=route,
+                act=cfg.ffn_act, router_input=cfg.router_input)
             if cfg.shared_expert_hidden:
-                out = out + shared_expert(lyr, h, dt)
+                out = out + shared_expert(lyr, h, dt, act)
             aux = (cfg.aux_loss_coef * balance
                    + cfg.router_z_loss_coef * z)
             return ((ctx.add(x, out) if residual else out), aux,
                     (load, kept))
-        gated = (jax.nn.silu(h @ wc(lyr["w1"]))
-                 * (h @ wc(lyr["w3"])))
+        gated = act(h @ wc(lyr["w1"])) * (h @ wc(lyr["w3"]))
         out = ctx.red(gated @ wc(lyr["w2"]))
         return ((ctx.add(x, out) if residual else out),
                 jnp.float32(0), None)
@@ -952,7 +1000,15 @@ def _make_block(ctx: Ctx, kind: LayerKind, tp: int = 1, reduce=None):
         hyper-connections ``x`` is the n streams (a tuple) and
         each sub-layer reads and writes them through its own gates."""
         if not cfg.hc_mult:
-            return _mlp_sub(ctx, kind, _attn_sub(ctx, kind, x, lyr), lyr)
+            h = route = None
+            if cfg.router_input == "attn" and kind.ffn == SPARSE:
+                # the choice is made from the attention's own input, before
+                # attention runs; recomputed with the block under remat
+                h = _attn_input(ctx, kind, x, lyr)
+                with jax.named_scope("route_early"):
+                    route = _route(ctx, lyr, h)
+            return _mlp_sub(ctx, kind, _attn_sub(ctx, kind, x, lyr, h=h),
+                            lyr, route=route)
         pre, post, res = _hc_gates(x, lyr["hc_attn"], cfg)
         x = _hc_write(x, post, res, _attn_sub(ctx, kind, _hc_read(x, pre),
                                               lyr, residual=False))

@@ -26,7 +26,11 @@ from multiverso_tpu.ops.kernel_path import kernel_path
 
 MODELS = os.path.dirname(transformer.__file__)
 NAMES = ("full_attention", "sliding_attention", "latent_attention",
-         "linear_attention", "eva_attention")
+         "linear_attention", "eva_attention", "full_attention_nope")
+# the kinds' scopes: ``attn.`` + the name's first word, but for the full
+# attention that rotates nothing (PR 48)
+SCOPES = dict({n: "attn." + n.split("_")[0] for n in NAMES},
+              full_attention_nope="attn.full_nope")
 
 
 def _model(attn, **over) -> dict:
@@ -49,7 +53,7 @@ def _mesh(axes, shape):
     return Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes)
 
 
-def test_the_five_kinds_are_known_in_their_order():
+def test_the_six_kinds_are_known_in_their_order():
     assert tuple(KINDS) == NAMES
 
 
@@ -57,7 +61,8 @@ def test_the_five_kinds_are_known_in_their_order():
 def test_every_field_of_a_kind_is_set(name):
     kind = KINDS[name]
     assert isinstance(kind, AttnKind)
-    assert kind.scope == "attn." + name.split("_")[0]
+    assert kind.scope == SCOPES[name]
+    assert len(set(SCOPES.values())) == len(NAMES)
     assert kind.saved and all(isinstance(s, str) for s in kind.saved)
     assert isinstance(kind.gate_tp, bool)
     for field in ("check", "init", "pspecs", "refuse", "rope", "heads"):
@@ -243,12 +248,13 @@ def test_a_sixth_kind_is_one_entry_of_kinds(monkeypatch):
 
 
 def test_a_seeded_draw_of_every_kind_is_pinned():
-    """One layer of every kind, dense and routed FFNs, a gate, a shared
+    """One layer of the first five kinds (the sixth draws full attention's
+    leaves), dense and routed FFNs, a gate, a shared
     expert, two streams and the module: a PR that moves a draw fails here and
     not in a cell's rate.  (PR 46, which drew the weights on the device from
     a seeded key, took the digest again; before it was 1efde8ac...8097.)"""
     cfg = TransformerConfig(**_model(
-        "full_attention", n_layers=5, layer_types=list(NAMES),
+        "full_attention", n_layers=5, layer_types=list(NAMES[:5]),
         heads_per_layer=[2, 4, 2, 2, 2], n_kv_heads=0,
         mlp_layer_types=["dense", "sparse", "sparse", "dense", "sparse"],
         attn_gate="per_head", q_lora_rank=6, dense_hidden=24,

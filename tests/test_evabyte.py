@@ -885,7 +885,9 @@ def test_the_benchmark_declares_the_cell_and_its_metrics():
     assert [w["name"] for w in mine] == [CELL]
     assert bench["workloads"][8]["name"] == CELL
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    for m in bench["per_layer"][-4:]:
+    mine = [m for m in bench["per_layer"] if "eva" in m["name"]]
+    assert len(mine) == 4
+    for m in mine:
         assert m["workloads"] == [CELL] and m["moves"] == "tokens_per_chip_s"
     for w in bench["workloads"] + bench["configs"]:
         assert len(w["why"]) <= 200

@@ -17,7 +17,8 @@ of its own text without locations); the sorted multiset of the text's name
 stacks, which masking drops and the benchmark's per-layer readers parse; the
 compiled step's peak bytes.  ``--compare`` reads two directories of
 ``<mode>_<cell>.json`` and exits 1 unless every one of them is equal.  PR 43
-ran it over the eight transformer cells (CHANGES.md).
+ran it over the eight transformer cells (CHANGES.md); PR 48 added the
+ninth's toy.
 
 ``init_sha256`` follows the draw, so across PR 46 (the weights drawn on the
 device from a seeded key, where a numpy generator made them) it differs by
@@ -166,6 +167,16 @@ def toy_model(config_name: str, model: dict) -> dict:
             layer_types=[EVA] * 4, eva_window=64, eva_chunk=4, n_pred_heads=8,
             norm_unit_offset=True, residual_dtype="float32",
             logits_dtype="float32", init_std=0.05)
+    elif config_name.startswith("smallthinker"):
+        kinds = ["full_attention_nope"] + [SLIDING] * 3
+        toy = dict(
+            vocab_size=96, dim=32, n_layers=4, n_heads=14, head_dim=8,
+            n_kv_heads=2, hidden=16, max_seq=256, norm_eps=1e-6,
+            layer_types=kinds, layer_period=4, sliding_window=16,
+            rope_sliding=dict(theta=1.5e6, rotary_factor=1.0), num_experts=8,
+            top_k=3, norm_topk_prob=True, moe_dispatch="grouped",
+            aux_loss_coef=0.0, router_z_loss_coef=0.0, router_input="attn",
+            ffn_act="relu")
     else:
         raise KeyError(config_name)
     toy.update(keep)
