@@ -27,8 +27,16 @@ the window)``, the streaming softmax of ``ops/flash_attention.py`` (q
 pre-scaled, float32 scores and statistics, blocks above the diagonal skipped,
 only diagonal blocks masked).  A head's summaries are one resident block
 (``[T // chunk, D]``: 256 KiB at 16,384 positions); after the window's last k
-block the same accumulators take the ``w`` visible tiles in a loop of dynamic
-length, no mask, and the output is normalised once.
+block the same accumulators take the ``w`` visible tiles in groups of ``g``,
+as wide as an own-window tile (``_walk_group``: ``g`` tiles are 512 keys at
+EvaByte's 128 a tile; from the shapes alone, and no more than the last window
+sees): a loop of dynamic length over the ``w // g`` full groups, then the ``w
+% g`` tiles left as ONE block of exactly that many keys (a static body a
+remainder), so the streaming softmax's fixed cost is paid once for 512 keys,
+nothing is masked and no key is multiplied that the staircase hides.  The
+output is normalised once, and the row statistics leave as a row, ``[bh, 1,
+T]`` float32 (lane 0 of the scratch, transposed once a q block), as the
+backward reads them.
 
 **Backward** (``flash_eva_bwd``): one kernel, grid ``(batch x head, window, k
 block, q block)``.  With the forward's row statistics the gradient splits by
@@ -45,7 +53,9 @@ Off the TPU the same arithmetic is a dense masked softmax over ``[own window
 kernels in interpret mode under ``MVTPU_FORCE_FLASH``
 (``ops/kernel_path.py``).  A trace is counted in
 ``attention.eva_traced{window=,chunk=,path=mosaic|interpret|jnp}``, the
-backward's in ``attention.eva_bwd_traced`` under the same labels.
+backward's in ``attention.eva_bwd_traced`` under the same labels, and a trace
+through the kernels in ``attention.eva_summary_walk_traced{width=,bodies=}``:
+the keys a step of the forward's summary walk takes, and ``g``.
 """
 
 from __future__ import annotations
@@ -89,11 +99,21 @@ def summarise(k, v, phi, mu, scale: float, chunk: int):
 
 
 # ------------------------------------------------------------------ forward
+_WALK_KEYS = 512        # the summary walk's width, an own-window tile's
+
+
+def _walk_group(per_window, num_w):
+    """Staircase tiles a step of the forward's summary walk: as many as make
+    ``_WALK_KEYS`` keys, and no more than the last window sees."""
+    return max(1, min(_WALK_KEYS // per_window, num_w - 1))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, kbar_ref, vbar_ref, o_ref, lse_ref, acc,
                 m_scr, l_scr, *, block_q, block_k, num_k, q_per_window,
-                per_window):
+                per_window, group):
     # q arrives PRE-SCALED.  ``num_k`` k blocks a window, ``q_per_window`` q
-    # blocks; ``per_window`` summaries a window (one tile of the staircase).
+    # blocks; ``per_window`` summaries a window (one tile of the staircase),
+    # ``group`` tiles a step of the summary walk.
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     w = qi // q_per_window
@@ -130,19 +150,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kbar_ref, vbar_ref, o_ref, lse_ref, acc,
 
     @pl.when(ki == num_k - 1)
     def _summaries_and_finalize():
-        def tile(u, carry):
-            rows = pl.ds(pl.multiple_of(u * per_window, per_window),
-                         per_window)
+        def tiles(first, n):
+            # ``n`` (static) whole tiles from tile ``first``: one score block
+            rows = pl.ds(pl.multiple_of(first * per_window, per_window),
+                         n * per_window)
             s = jax.lax.dot_general(q_ref[0], kbar_ref[0, rows, :], _NT,
                                     preferred_element_type=jnp.float32)
             _online(s, vbar_ref[0, rows, :])
+
+        def full_group(u, carry):
+            tiles(u * group, group)
             return carry
 
-        jax.lax.fori_loop(0, w, tile, 0)
+        groups = w // group
+        jax.lax.fori_loop(0, groups, full_group, 0)
+        # the remainder exactly: no key multiplied that the staircase hides,
+        # no mask, no row past the resident block's end
+        for r in range(1, group):
+            pl.when(w % group == r)(
+                functools.partial(tiles, groups * group, r))
         l = jnp.maximum(l_scr[:, 0:1], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-        # Lane-broadcast logsumexp; only lane 0 is meaningful downstream.
-        lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+        # the statistics leave as a row, as ``_bwd_kernel`` reads them: lane 0
+        # of the scratch, transposed once a q block
+        lse = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+        lse_ref[0] = jnp.transpose(lse)[0:1, :]
 
 
 def _geometry(T, window, chunk, block_q, block_k):
@@ -182,20 +214,20 @@ def _fwd_call(q, k, v, kbar, vbar, scale, window, per_window, block_q,
         "flash_eva_fwd",
         functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
                           num_k=num_k, q_per_window=q_per_window,
-                          per_window=per_window),
+                          per_window=per_window,
+                          group=_walk_group(per_window, T // window)),
         grid=(bh, T // block_q, num_k),
         in_specs=[q_spec, k_spec, k_spec, bar_spec, bar_spec],
         out_specs=[q_spec,
-                   pl.BlockSpec((1, block_q, _LANES),
-                                lambda b, i, j: (b, i, 0))],
+                   pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))],
         out_shape=[jax.ShapeDtypeStruct((bh, T, D), q.dtype),
-                   jax.ShapeDtypeStruct((bh, T, _LANES), jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, 1, T), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=interpret,
     )(q, k, v, kbar, vbar)
-    return o, lse[:, :, 0]
+    return o, lse[:, 0, :]
 
 
 # ----------------------------------------------------------------- backward
@@ -438,6 +470,10 @@ def eva_attention(q, k, v, kbar, vbar, window: int, chunk: int,
     path = kernel_path()
     metrics.counter("attention.eva_traced",
                     _labels(window, chunk, path)).inc()
+    if path != "jnp":
+        group = _walk_group(per_window, T // window)
+        metrics.counter("attention.eva_summary_walk_traced", {
+            "width": str(group * per_window), "bodies": str(group)}).inc()
     flat = lambda x: x.reshape(B * H, x.shape[2], D)
     o = _eva(flat(q), flat(k), flat(v), flat(kbar), flat(vbar), float(scale),
              int(window), int(per_window), tuple(int(b) for b in blocks),

@@ -104,27 +104,77 @@ def _attend(path, window=32, chunk=4, **blocks):
 
 
 # ------------------------------------------- (a) the attention, both passes
-@pytest.mark.parametrize("path,blocks", [
-    ("jnp", {}),
+# The forward's summary walk (ISSUE 49) by (windows, window, chunk): 128
+# summaries a window walk four tiles a group, the cell's; 2 windows are one
+# tile alone, 5 a full group and every remainder, 6 a full group and one tile
+# more, 8 the cell's 4 + 3; 4 summaries a window are one group of 5 tiles.
+WALKS = [(2, 256, 2), (5, 256, 2), (6, 256, 2), (8, 256, 2), (6, 16, 4)]
+_WALK_BLOCKS = dict(block_q=128, block_k=128, block_q_bwd=128,
+                    block_k_bwd=128)
+
+
+@pytest.mark.parametrize("path,blocks,walk", [
+    ("jnp", {}, None),
     ("interpret", dict(block_q=16, block_k=8, block_q_bwd=8,
-                       block_k_bwd=16)),
+                       block_k_bwd=16), None),
     ("interpret", dict(block_q=32, block_k=32, block_q_bwd=32,
-                       block_k_bwd=32)),
+                       block_k_bwd=32), None),
     ("interpret", dict(block_q=8, block_k=8, block_q_bwd=16,
-                       block_k_bwd=16)),
-])
-def test_both_passes_match_the_reference_softmax(path, blocks):
+                       block_k_bwd=16), None),
+] + [(path, blocks, walk) for walk in WALKS
+     for path, blocks in (("jnp", {}), ("interpret", _WALK_BLOCKS))])
+def test_both_passes_match_the_reference_softmax(path, blocks, walk):
     """The kernels (interpreted, at several block shapes) and the ``jnp``
     path against the reference's dense masked softmax over ``[own window |
-    summaries]``: the output and the gradients of q, k, v, ``phi``, ``mu``."""
-    q, k, v, phi, mu, d_o = _qkv(0)
-    model = dict(eva_window=32, eva_chunk=4)
+    summaries]``: the output and the gradients of q, k, v, ``phi``, ``mu``;
+    at the toy's four windows of 8 summaries, and at shapes that reach every
+    branch of the forward's summary walk (``WALKS``)."""
+    windows, window, chunk = walk or (4, 32, 4)
+    q, k, v, phi, mu, d_o = (_qkv(0) if walk is None else _qkv(
+        windows, B=1, H=2, T=windows * window))
+    model = dict(eva_window=window, eva_chunk=chunk)
     want_o, want_g = evabyte_lm.attention_and_grads(q, k, v, phi, mu, d_o,
                                                     model)
-    o, pull = jax.vjp(_attend(path, **blocks), q, k, v, phi, mu)
+    o, pull = jax.vjp(_attend(path, window, chunk, **blocks), q, k, v, phi,
+                      mu)
     _close(o, want_o, "o")
     for name, got, want in zip("q k v phi mu".split(), pull(d_o), want_g):
         _close(got, want, f"d{name}")
+
+
+@pytest.mark.parametrize("windows,window,chunk", WALKS + [(1, 32, 4)])
+def test_the_forward_hands_its_statistics_back_as_a_row(windows, window,
+                                                        chunk):
+    """``_fwd_call``'s second result is ``[bh, T]`` float32 (a ``[bh, 1, T]``
+    output of the kernel: no lane-broadcast ``[bh, T, 128]``) and is the
+    logsumexp of the reference's masked scores, a window at a time."""
+    T, D = windows * window, 16
+    q, k, v, phi, mu, _ = _qkv(5, B=1, H=2, T=T, D=D)
+    scale, per = D ** -0.5, window // chunk
+    kbar, vbar = flash_eva.summarise(k, v, phi, mu, scale, chunk)
+    block = min(window, 128)
+    args = [x.reshape(2, x.shape[2], D) for x in (q, k, v, kbar, vbar)]
+    forward = lambda *a: flash_eva._fwd_call(*a, scale, window, per, block,
+                                             block, True)
+    call, = [e for e in _eqns(jax.make_jaxpr(forward)(*args).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert [x.aval.shape for x in call.outvars] == [(2, T, D), (2, 1, T)]
+    _, lse = forward(*args)
+    assert lse.shape == (2, T) and lse.dtype == jnp.float32
+    t = lambda y: np.swapaxes(np.asarray(y, np.float64), 1, 2)
+    rbar, _ = evabyte_lm._summaries(*map(jnp.asarray, map(t, (k, v))), phi,
+                                    mu, scale, chunk, jnp.float32)
+    want = np.empty((2, T))
+    for w in range(windows):
+        own = slice(window * w, window * (w + 1))
+        keys = np.concatenate([t(k)[0, own], np.asarray(rbar)[0, :per * w]])
+        s = scale * np.einsum("qhd,khd->hqk", t(q)[0, own], keys)
+        col = np.arange(keys.shape[0])[None, :]
+        seen = (col >= window) | (col <= np.arange(window)[:, None])
+        s = np.where(seen[None], s, -np.inf)
+        m = s.max(-1)
+        want[:, own] = m + np.log(np.exp(s - m[..., None]).sum(-1))
+    np.testing.assert_allclose(np.asarray(lse), want, rtol=2e-5, atol=2e-5)
 
 
 def test_a_short_sequence_is_one_window_and_wrong_shapes_are_refused():
@@ -184,6 +234,27 @@ def test_eva_counts_its_traces(path):
     q, k, v, phi, mu, d_o = _qkv(3, B=1)
     jax.vjp(_attend(path), q, k, v, phi, mu)[1](d_o)
     assert (fwd.value, bwd.value) == (before[0] + 1, before[1] + 1)
+
+
+@pytest.mark.parametrize("windows,window,chunk,width,bodies", [
+    (4, 32, 4, 24, 3), (8, 256, 2, 512, 4), (6, 16, 4, 20, 5),
+    (1, 32, 4, 8, 1)])
+def test_eva_counts_the_walk_its_shapes_give(windows, window, chunk, width,
+                                             bodies):
+    """``attention.eva_summary_walk_traced{width=,bodies=}``: once a trace
+    through the kernels, the keys a step of the forward's summary walk takes
+    (512 where the staircase's tiles and the windows allow it; a tile's
+    multiple, and no more than the last window sees) and its bodies; the
+    ``jnp`` path, which walks nothing, counts none."""
+    walk = metrics.counter("attention.eva_summary_walk_traced",
+                           {"width": str(width), "bodies": str(bodies)})
+    q, k, v, phi, mu, _ = _qkv(6, B=1, H=1, T=windows * window)
+    before = walk.value
+    jax.eval_shape(_attend("jnp", window, chunk), q, k, v, phi, mu)
+    assert walk.value == before
+    jax.eval_shape(_attend("interpret", window, chunk), q, k, v, phi, mu)
+    assert walk.value == before + 1
+    assert flash_eva._walk_group(window // chunk, windows) == bodies
 
 
 def _eqns(jaxpr):
