@@ -12,7 +12,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..common import AttnKind, apply_rope
+from ..common import AttnKind, apply_rope, elem, proj
 
 __all__ = ["EVA"]
 
@@ -76,20 +76,30 @@ def _heads(ctx, kind, h, lyr):
     def heads_of(y):
         return y.reshape(Bb, Tb, local_heads, D).transpose(0, 2, 1, 3)
 
-    q = apply_rope(heads_of(h @ wc(lyr["wq"])), rope)
-    k = apply_rope(heads_of(h @ wc(lyr["wk"])), rope)
-    v = heads_of(h @ wc(lyr["wv"]))
+    with proj():
+        q = h @ wc(lyr["wq"])
+    with elem():
+        q = apply_rope(heads_of(q), rope)
+    with proj():
+        k = h @ wc(lyr["wk"])
+    with elem():
+        k = apply_rope(heads_of(k), rope)
+    with proj():
+        v = h @ wc(lyr["wv"])
     chunk = cfg.eva_chunk
     # whole chunks, and past one window whole windows: the padding
     # lies after every query, so none sees it or its summaries
     pad = -Tb % (cfg.eva_window if Tb > cfg.eva_window else chunk)
-    if pad:
-        q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
-                   for y in (q, k, v))
+    with elem():
+        v = heads_of(v)
+        if pad:
+            q, k, v = (jnp.pad(y, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                       for y in (q, k, v))
     kbar, vbar = summarise(k, v, lyr["phi"], lyr["mu"], scale, chunk)
     o = eva_attention(q, k, v, kbar, vbar, cfg.eva_window, chunk,
-                      scale=scale)[:, :, :Tb]
-    return o.transpose(0, 2, 1, 3)                           # [B,T,H,D]
+                      scale=scale)
+    with elem():
+        return o[:, :, :Tb].transpose(0, 2, 1, 3)            # [B,T,H,D]
 
 
 EVA = AttnKind(

@@ -14,7 +14,8 @@ from __future__ import annotations
 from jax.sharding import PartitionSpec as P
 
 from ...parallel.ring_attention import blockwise_attention_local
-from ..common import AttnKind, apply_rope, rms_norm, unit_gain
+from ..common import (AttnKind, apply_rope, elem, proj, rms_norm,
+                      unit_gain)
 
 __all__ = ["LATENT"]
 
@@ -91,23 +92,34 @@ def _heads(ctx, kind, h, lyr):
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     scale = (dn + dr) ** -0.5 * cfg.attn_mscale ** 2
     if cfg.q_lora_rank:
-        c_q = rms_norm(h @ wc(lyr["wq_a"]), ctx.gain(lyr["q_a_norm"]),
-                       cfg.norm_eps)
-        q = c_q @ wc(lyr["wq_b"])
+        with proj():
+            c_q = h @ wc(lyr["wq_a"])
+        with elem():
+            c_q = rms_norm(c_q, ctx.gain(lyr["q_a_norm"]), cfg.norm_eps)
+        with proj():
+            q = c_q @ wc(lyr["wq_b"])
     else:
-        q = h @ wc(lyr["wq"])
-    q = q.reshape(Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
-    kv_a = h @ wc(lyr["wkv_a"])
-    c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank],
-                    ctx.gain(lyr["kv_a_norm"]), cfg.norm_eps)
-    kv = (c_kv @ wc(lyr["wkv_b"])).reshape(
-        Bb, Tb, local_heads, dn + dv).transpose(0, 2, 1, 3)
-    # the rotated key part is one head, whatever the query heads
-    k_r = apply_rope(kv_a[..., cfg.kv_lora_rank:][:, None], rope)
-    o = blockwise_attention_local(
-        q[..., :dn], kv[..., :dn], kv[..., dn:], scale,
-        causal=True, q_rope=apply_rope(q[..., dn:], rope), k_rope=k_r)
-    return o.transpose(0, 2, 1, 3)                           # [B,T,H,dv]
+        with proj():
+            q = h @ wc(lyr["wq"])
+    with elem():
+        q = q.reshape(Bb, Tb, local_heads, dn + dr).transpose(0, 2, 1, 3)
+    with proj():
+        kv_a = h @ wc(lyr["wkv_a"])
+    with elem():
+        c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank],
+                        ctx.gain(lyr["kv_a_norm"]), cfg.norm_eps)
+    with proj():
+        kv = c_kv @ wc(lyr["wkv_b"])
+    with elem():
+        kv = kv.reshape(Bb, Tb, local_heads, dn + dv).transpose(0, 2, 1, 3)
+        # the rotated key part is one head, whatever the query heads
+        k_r = apply_rope(kv_a[..., cfg.kv_lora_rank:][:, None], rope)
+        q_n, k_n, v = q[..., :dn], kv[..., :dn], kv[..., dn:]
+        q_r = apply_rope(q[..., dn:], rope)
+    o = blockwise_attention_local(q_n, k_n, v, scale, causal=True,
+                                  q_rope=q_r, k_rope=k_r)
+    with elem():
+        return o.transpose(0, 2, 1, 3)                       # [B,T,H,dv]
 
 
 LATENT = AttnKind(

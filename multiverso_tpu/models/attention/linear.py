@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ... import metrics
-from ..common import AttnKind, head_spread, head_sum, unit_gain
+from ..common import (AttnKind, elem, head_spread, head_sum, proj,
+                      unit_gain)
 
 __all__ = ["LINEAR"]
 
@@ -137,23 +138,37 @@ def _heads(ctx, kind, h, lyr):
     def split(x):       # a bitcast pair with kda's own flatten
         return x.reshape(Bb, Tb, local_heads, D)
 
-    q = (unit(conv(h @ wc(lyr["wq"]), lyr["conv_q"]))
-         * D ** -0.5).astype(dt)
-    k = unit(conv(h @ wc(lyr["wk"]), lyr["conv_k"])).astype(dt)
-    v = conv(h @ wc(lyr["wv"]), lyr["conv_v"])
-    f = jnp.dot(h, wc(lyr["wf"]), preferred_element_type=f32)
-    g = cfg.kda_lower_bound * jax.nn.sigmoid(
-        jnp.repeat(jnp.exp(lyr["A_log"].astype(f32)), D)
-        * (f + lyr["dt_bias"].astype(f32)))
-    beta = jax.nn.sigmoid(
-        jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32))
-    o = kda(split(q), split(k), split(v), split(g), beta)
-    o = o.reshape(Bb, Tb, local_heads * D)
-    # rms_norm a head, its mean of squares taken flat
-    var = head_sum(jnp.square(o.astype(f32)), local_heads) / D
-    return ((o * head_spread(jax.lax.rsqrt(var + cfg.norm_eps), D)
-             ).astype(o.dtype)
-            * jnp.tile(ctx.gain(lyr["o_norm"]), local_heads))
+    with proj():
+        q = h @ wc(lyr["wq"])
+    with elem():
+        q = (unit(conv(q, lyr["conv_q"])) * D ** -0.5).astype(dt)
+    with proj():
+        k = h @ wc(lyr["wk"])
+    with elem():
+        k = unit(conv(k, lyr["conv_k"])).astype(dt)
+    with proj():
+        v = h @ wc(lyr["wv"])
+    with elem():
+        v = conv(v, lyr["conv_v"])
+    with proj():
+        f = jnp.dot(h, wc(lyr["wf"]), preferred_element_type=f32)
+    with elem():
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            jnp.repeat(jnp.exp(lyr["A_log"].astype(f32)), D)
+            * (f + lyr["dt_bias"].astype(f32)))
+    with proj():
+        beta = jnp.dot(h, wc(lyr["wb"]), preferred_element_type=f32)
+    with elem():
+        beta = jax.nn.sigmoid(beta)
+        q, k, v, g = split(q), split(k), split(v), split(g)
+    o = kda(q, k, v, g, beta)
+    with elem():
+        o = o.reshape(Bb, Tb, local_heads * D)
+        # rms_norm a head, its mean of squares taken flat
+        var = head_sum(jnp.square(o.astype(f32)), local_heads) / D
+        return ((o * head_spread(jax.lax.rsqrt(var + cfg.norm_eps), D)
+                 ).astype(o.dtype)
+                * jnp.tile(ctx.gain(lyr["o_norm"]), local_heads))
 
 
 LINEAR = AttnKind(

@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 from ... import metrics
 from ...parallel.ring_attention import (blockwise_attention_local,
                                         ring_attention)
-from ..common import AttnKind, apply_rope, rms_norm
+from ..common import AttnKind, apply_rope, elem, proj, rms_norm
 
 __all__ = ["FULL", "SLIDING", "NOPE"]
 
@@ -62,27 +62,33 @@ def _heads(ctx, kind, h, lyr, window=None, rotate=True):
     local_heads = kind.heads // ctx.tp
     local_kv = (cfg.n_kv_heads or kind.heads) // ctx.tp
     scale = cfg.head_dim ** -0.5
-    q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
-    if cfg.qk_norm:
-        q = rms_norm(q, ctx.gain(lyr["q_norm"]), cfg.norm_eps)
-        k = rms_norm(k, ctx.gain(lyr["k_norm"]), cfg.norm_eps)
-    q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
-    k = k.reshape(Bb, Tb, local_kv, cfg.head_dim)
-    v = (h @ wc(lyr["wv"])).reshape(Bb, Tb, local_kv, cfg.head_dim)
     rope = cfg.rope(kind.attn) if rotate else None
 
     def turn(t):            # [B,H,T,D], rotated where the kind has a recipe
         t = t.transpose(0, 2, 1, 3)
         return t if rope is None else apply_rope(t, rope)
 
-    q, k, v = turn(q), turn(k), v.transpose(0, 2, 1, 3)
+    with proj():
+        q, k = h @ wc(lyr["wq"]), h @ wc(lyr["wk"])
+    with elem():
+        if cfg.qk_norm:
+            q = rms_norm(q, ctx.gain(lyr["q_norm"]), cfg.norm_eps)
+            k = rms_norm(k, ctx.gain(lyr["k_norm"]), cfg.norm_eps)
+        q = q.reshape(Bb, Tb, local_heads, cfg.head_dim)
+        k = k.reshape(Bb, Tb, local_kv, cfg.head_dim)
+    with proj():
+        v = h @ wc(lyr["wv"])
+    with elem():
+        v = v.reshape(Bb, Tb, local_kv, cfg.head_dim)
+        q, k, v = turn(q), turn(k), v.transpose(0, 2, 1, 3)
     if ctx.ring:
         o = ring_attention(q, k, v, ctx.mesh, axis_name="sp", causal=True,
                            scale=scale, window=window)
     else:
         o = blockwise_attention_local(q, k, v, scale, causal=True,
                                       window=window)
-    return o.transpose(0, 2, 1, 3)                           # [B,T,H,D]
+    with elem():
+        return o.transpose(0, 2, 1, 3)                       # [B,T,H,D]
 
 
 def _heads_sliding(ctx, kind, h, lyr):

@@ -20,8 +20,11 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
-__all__ = ["Rope", "Ctx", "AttnKind", "Draw", "unit_gain", "rms_norm",
-           "head_sum", "head_spread", "rope_freqs", "apply_rope", "GATE_ACTS"]
+from ..ops.kernel_path import elem
+
+__all__ = ["Rope", "Ctx", "AttnKind", "Draw", "proj", "elem", "unit_gain",
+           "rms_norm", "head_sum", "head_spread", "rope_freqs", "apply_rope",
+           "GATE_ACTS"]
 
 # What squashes a gated FFN's ``w1`` branch, ``act(h w1) * (h w3)``: SwiGLU's
 # SiLU, or ReGLU's ReLU (``TransformerConfig.ffn_act``), dense, shared and
@@ -159,6 +162,19 @@ class Draw:
     def __call__(self, name: str, *shape, scale=None):
         return self.normal(name, shape,
                            self._init_std or scale or shape[0] ** -0.5)
+
+
+def proj():
+    """The scope ``attn.proj`` (inside ``attn`` and the kind's own): every
+    product of the attention sub-layer's normed input, or of a latent's
+    normed compression, with a weight that feeds the kernel or a gate, the
+    weight's cast with it.  With ``elem`` (``ops/kernel_path.py``, which
+    the kernels' wrappers share) and ``transformer.py``'s
+    ``attn.out`` it divides what ``attn`` holds around its kernels (the
+    calls, and ``attn.eva.summarise``, stay directly under the kind's scope),
+    as ``mlp.up`` and ``mlp.down`` divide a dense ``mlp``: name-stack
+    metadata alone, read by ``benchmarks/trace/parts.py``."""
+    return jax.named_scope("attn.proj")
 
 
 def unit_gain(cfg, n: int):

@@ -55,8 +55,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..updaters import AddOption, get_updater
 from .. import dashboard, metrics, tracing
 from .attention import KINDS
-from .common import (GATE_ACTS, Ctx, Draw, Rope, head_spread, rms_norm,
-                     unit_gain)
+from .common import (GATE_ACTS, Ctx, Draw, Rope, elem, head_spread, proj,
+                     rms_norm, unit_gain)
 from .moe import (GROUPED_SAVED, moe_ffn, moe_leaves, moe_pspecs, moe_route,
                   route_rungs, shared_expert)
 
@@ -909,7 +909,7 @@ def _attn_scopes(ctx: Ctx, kind: LayerKind):
 
 def _attn_input(ctx: Ctx, kind: LayerKind, x, lyr):
     """What the attention sub-layer reads: ``x``'s RMS norm under its gain."""
-    with _attn_scopes(ctx, kind):
+    with _attn_scopes(ctx, kind), elem():
         return rms_norm(ctx.read(x), ctx.gain(lyr["attn_norm"]),
                         ctx.cfg.norm_eps)
 
@@ -929,15 +929,19 @@ def _attn_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True, h=None):
         # [B,T,H,width], or flat [B,T,H*width] from a kind that keeps it so
         o = attn.heads(ctx, kind, h, lyr)
         if cfg.attn_gate:       # times sigmoid(h wg), a scalar a head
-            gate = jax.nn.sigmoid(
-                (h @ ctx.wc(lyr["wg"])).astype(jnp.float32)).astype(ctx.dt)
-            o = o * (gate[..., None] if o.ndim == 4 else
-                     head_spread(gate, o.shape[-1] // gate.shape[-1]))
-        if o.ndim == 4:
-            Bb, Tb, heads, width = o.shape
-            o = o.reshape(Bb, Tb, heads * width)
-        out = ctx.red(o @ ctx.wc(lyr["wo"]))
-        return ctx.add(x, out) if residual else out
+            with proj():
+                gate = h @ ctx.wc(lyr["wg"])
+        with elem():
+            if cfg.attn_gate:
+                gate = jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dt)
+                o = o * (gate[..., None] if o.ndim == 4 else
+                         head_spread(gate, o.shape[-1] // gate.shape[-1]))
+            if o.ndim == 4:
+                Bb, Tb, heads, width = o.shape
+                o = o.reshape(Bb, Tb, heads * width)
+        with jax.named_scope("attn.out"):
+            out = ctx.red(o @ ctx.wc(lyr["wo"]))
+            return ctx.add(x, out) if residual else out
 
 
 def _route(ctx: Ctx, lyr, h):
@@ -955,9 +959,13 @@ def _mlp_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True, route=None):
     (``router_input``)."""
     cfg, dt, wc = ctx.cfg, ctx.dt, ctx.wc
     act = GATE_ACTS[cfg.ffn_act]
+
+    def normed():
+        return rms_norm(ctx.read(x), ctx.gain(lyr["mlp_norm"]), cfg.norm_eps)
+
     with jax.named_scope("mlp"):
-        h = rms_norm(ctx.read(x), ctx.gain(lyr["mlp_norm"]), cfg.norm_eps)
         if kind.ffn == SPARSE:
+            h = normed()
             out, balance, z, load, kept = moe_ffn(
                 lyr, h, top_k=cfg.top_k, compute_dtype=dt,
                 dispatch=cfg.moe_dispatch,
@@ -973,10 +981,14 @@ def _mlp_sub(ctx: Ctx, kind: LayerKind, x, lyr, residual=True, route=None):
                    + cfg.router_z_loss_coef * z)
             return ((ctx.add(x, out) if residual else out), aux,
                     (load, kept))
-        gated = act(h @ wc(lyr["w1"])) * (h @ wc(lyr["w3"]))
-        out = ctx.red(gated @ wc(lyr["w2"]))
-        return ((ctx.add(x, out) if residual else out),
-                jnp.float32(0), None)
+        # a dense FFN's two parts (``common.proj`` has what the names are for)
+        with jax.named_scope("mlp.up"):
+            h = normed()
+            gated = act(h @ wc(lyr["w1"])) * (h @ wc(lyr["w3"]))
+        with jax.named_scope("mlp.down"):
+            out = ctx.red(gated @ wc(lyr["w2"]))
+            return ((ctx.add(x, out) if residual else out),
+                    jnp.float32(0), None)
 
 
 def _make_block(ctx: Ctx, kind: LayerKind, tp: int = 1, reduce=None):

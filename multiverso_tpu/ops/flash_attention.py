@@ -82,6 +82,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .kernel_path import elem
+
 __all__ = ["flash_attention", "flash_attention_latent", "fit_block",
            "scale_cap_for_head_dim"]
 
@@ -486,7 +488,8 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
     if window is not None:
         num_k, _ = _band_steps(num_q, num_k, block_q, block_k, window)
     # Scale folded into q ([T, D] once), not into every [Bq, Bk] score.
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    with elem():
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     kernel = functools.partial(_fwd_kernel, causal=causal,
                                block_q=block_q, block_k=block_k,
                                num_k=num_k, window=window)
@@ -516,7 +519,8 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
     )(q, k, v)
-    return o, lse[:, :, 0]
+    with elem():
+        return o, lse[:, :, 0]
 
 
 @functools.partial(jax.custom_vjp,
@@ -538,9 +542,11 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, block_q_bwd,
     # remat tax: pre-round figure, record removed in PR 21 — a claim to
     # re-measure).  models/transformer.py's
     # "dots" policy saves both names; costs one o-sized buffer per
-    # layer (lse is ~D× smaller).
-    o = checkpoint_name(o, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    # layer (lse is ~D× smaller).  (What remat does to a saved value, its
+    # ``reduce_precision``, takes the name stack of these two.)
+    with elem():
+        o = checkpoint_name(o, "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -581,12 +587,13 @@ def _flash_bwd(scale, causal, block_q, block_k, block_q_bwd, block_k_bwd,
     do, dlse = cts
     # Δ_i = Σ_d do·o − dlse: the lse cotangent enters exactly where the
     # softmax normalizer does (∂lse/∂s_ij = p_ij), so it folds into delta.
-    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-             - dlse.astype(jnp.float32))                 # [bh, Tq]
     # Same pre-scaled-q convention as the forward (see kernel docstrings:
     # dq re-applies the factor at finalize; dk absorbs it via the q
     # operand; dv never needs it).
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    with elem():
+        delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+                 - dlse.astype(jnp.float32))             # [bh, Tq]
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     fused = _fused_fits(q.shape[1], k.shape[1], q.shape[2], group, q.dtype,
                         block_q)
     metrics.counter("attention.bwd_traced",
@@ -635,6 +642,8 @@ def _bwd_fused(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
     if group > 1:
         kv_out = pl.BlockSpec((1, Tk, D), lambda b, g, i, j: (b, 0, 0))
         kv_rows = Tk
+    with elem():
+        lse, delta = lse[:, None, :], delta[:, None, :]
     return _named_call(
         "flash_bwd" if window is None else "flash_win_bwd",
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
@@ -655,7 +664,7 @@ def _bwd_fused(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FUSED_VMEM_LIMIT),
         interpret=interpret,
-    )(q, k, v, do, lse[:, None, :], delta[:, None, :])
+    )(q, k, v, do, lse, delta)
 
 
 def _bwd_split(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
@@ -672,8 +681,9 @@ def _bwd_split(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
     if window is not None:
         k_steps, q_steps = _band_steps(num_q, num_k, block_q, block_k,
                                        window)
-    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, Tq, _LANES))
-    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, Tq, _LANES))
+    with elem():
+        lse_b = jnp.broadcast_to(lse[:, :, None], (bh, Tq, _LANES))
+        delta_b = jnp.broadcast_to(delta[:, :, None], (bh, Tq, _LANES))
 
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, D),
@@ -832,16 +842,18 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
             raise ValueError(f"no usable bwd block size (>=8) divides "
                              f"Tq={Tq}, Tk={Tk}")
         block_q_bwd, block_k_bwd = block_q, block_k
-    bh = B * H
-    o, lse = _flash(q.reshape(bh, Tq, D), k.reshape(B * KV, Tk, D),
-                    v.reshape(B * KV, Tk, D), float(scale), bool(causal),
+    with elem():
+        q = q.reshape(B * H, Tq, D)
+        k, v = k.reshape(B * KV, Tk, D), v.reshape(B * KV, Tk, D)
+    o, lse = _flash(q, k, v, float(scale), bool(causal),
                     int(block_q), int(block_k), int(block_q_bwd),
                     int(block_k_bwd), bool(interpret),
                     None if window is None else int(window), H // KV)
-    o = o.reshape(B, H, Tq, D)
-    if return_lse:
-        return o, lse.reshape(B, H, Tq)
-    return o
+    with elem():
+        o = o.reshape(B, H, Tq, D)
+        if return_lse:
+            return o, lse.reshape(B, H, Tq)
+        return o
 
 
 # ---------------------------------------------------------------- two widths
@@ -1000,8 +1012,9 @@ def _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
     bh, T, Dn = qn.shape
     Dr, Dv = qr.shape[-1], v.shape[-1]
     num_q, num_k = T // block_q, T // block_k
-    qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
-    qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
+    with elem():
+        qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
+        qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
 
     def q_spec(width):
         return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
@@ -1026,7 +1039,8 @@ def _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=interpret,
     )(qn, qr, kn, kr, v)
-    return o, lse[:, :, 0]
+    with elem():
+        return o, lse[:, :, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
@@ -1041,8 +1055,9 @@ def _flash_mla_fwd(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
     o, lse = _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
                            interpret)
     # the same remat seam as ``_flash_fwd``'s
-    o = checkpoint_name(o, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    with elem():
+        o = checkpoint_name(o, "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
     return (o, lse), (qn, qr, kn, kr, v, o, lse)
 
 
@@ -1149,10 +1164,11 @@ def _flash_mla_bwd(scale, heads, block_q, block_k, block_q_bwd, block_k_bwd,
     block_q, block_k = block_q_bwd, block_k_bwd
     qn, qr, kn, kr, v, o, lse = res
     do, dlse = cts
-    delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-             - dlse.astype(jnp.float32))
-    qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
-    qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
+    with elem():
+        delta = (jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+                 - dlse.astype(jnp.float32))
+        qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
+        qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
     fused = _mla_fused_fits(qn.shape[1], qn.shape[2], qr.shape[2], qn.dtype,
                             block_q)
     metrics.counter("attention.latent_bwd_traced",
@@ -1191,6 +1207,8 @@ def _mla_bwd_fused(qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q,
 
     row_spec = pl.BlockSpec(
         (1, 1, block_q), lambda b, h, i, j: (b * heads + h, 0, q_block(i, j)))
+    with elem():
+        lse, delta = lse[:, None, :], delta[:, None, :]
     return _named_call(
         "flash_mla_bwd",
         functools.partial(_mla_bwd_kernel, scale=scale, block_q=block_q,
@@ -1217,7 +1235,7 @@ def _mla_bwd_fused(qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FUSED_VMEM_LIMIT),
         interpret=interpret,
-    )(qn, qr, kn, kr, v, do, lse[:, None, :], delta[:, None, :])
+    )(qn, qr, kn, kr, v, do, lse, delta)
 
 
 def _mla_bwd_split(qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q,
@@ -1229,8 +1247,9 @@ def _mla_bwd_split(qn, qr, kn, kr, v, do, lse, delta, scale, heads, block_q,
     bh, T, Dn = qn.shape
     Dr, Dv = qr.shape[-1], v.shape[-1]
     num_q, num_k = T // block_q, T // block_k
-    lse_b = jnp.broadcast_to(lse[:, :, None], (bh, T, _LANES))
-    delta_b = jnp.broadcast_to(delta[:, :, None], (bh, T, _LANES))
+    with elem():
+        lse_b = jnp.broadcast_to(lse[:, :, None], (bh, T, _LANES))
+        delta_b = jnp.broadcast_to(delta[:, :, None], (bh, T, _LANES))
     operands = (qn, qr, kn, kr, v, do, lse_b, delta_b)
 
     def q_spec(width):
@@ -1321,9 +1340,11 @@ def flash_attention_latent(q_nope, q_rope, k_nope, k_rope, v,
         raise ValueError(f"no usable block size (>=8) divides T={T}")
     metrics.counter("attention.latent_traced",
                     {"qk": str(Dn + Dr), "v": str(Dv)}).inc()
-    o, _ = _flash_mla(q_nope.reshape(B * H, T, Dn),
-                      q_rope.reshape(B * H, T, Dr),
-                      k_nope.reshape(B * H, T, Dn), k_rope.reshape(B, T, Dr),
-                      v.reshape(B * H, T, Dv), float(scale), int(H),
+    with elem():
+        flat = (q_nope.reshape(B * H, T, Dn), q_rope.reshape(B * H, T, Dr),
+                k_nope.reshape(B * H, T, Dn), k_rope.reshape(B, T, Dr),
+                v.reshape(B * H, T, Dv))
+    o, _ = _flash_mla(*flat, float(scale), int(H),
                       *(int(b) for b in blocks), bool(interpret))
-    return o.reshape(B, H, T, Dv)
+    with elem():
+        return o.reshape(B, H, T, Dv)

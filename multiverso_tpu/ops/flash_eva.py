@@ -71,7 +71,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import (_FUSED_VMEM_LIMIT, _LANES, _NEG, _causal_mask,
                               _named_call, fit_block)
-from .kernel_path import kernel_path
+from .kernel_path import elem, kernel_path
 
 __all__ = ["eva_attention", "summarise"]
 
@@ -95,7 +95,8 @@ def summarise(k, v, phi, mu, scale: float, chunk: int):
         a = jax.nn.softmax(logits, axis=-1)[..., None]       # [B,H,M,c,1]
         kbar = jnp.sum(a * kc, axis=3) + mu.astype(f32)[None, :, None]
         vbar = jnp.sum(a * vc, axis=3)
-    return kbar.astype(k.dtype), vbar.astype(v.dtype)
+    with elem():
+        return kbar.astype(k.dtype), vbar.astype(v.dtype)
 
 
 # ------------------------------------------------------------------ forward
@@ -199,7 +200,8 @@ def _fwd_call(q, k, v, kbar, vbar, scale, window, per_window, block_q,
     bh, T, D = q.shape
     M = kbar.shape[1]
     q_per_window, num_k = window // block_q, window // block_k
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    with elem():
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
     def k_index(b, i, j):
         # a block above the diagonal keeps the diagonal's: nothing is fetched
@@ -227,7 +229,8 @@ def _fwd_call(q, k, v, kbar, vbar, scale, window, per_window, block_q,
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=interpret,
     )(q, k, v, kbar, vbar)
-    return o, lse[:, 0, :]
+    with elem():
+        return o, lse[:, 0, :]
 
 
 # ----------------------------------------------------------------- backward
@@ -340,6 +343,8 @@ def _bwd_call(q, k, v, kbar, vbar, do, lse, delta, scale, window, per_window,
     k_spec = pl.BlockSpec((1, block_k, D),
                           lambda b, w, i, j: (b, w * num_k + i, 0))
     bar_spec = pl.BlockSpec((1, M, D), lambda b, w, i, j: (b, 0, 0))
+    with elem():
+        lse, delta = lse[:, None, :], delta[:, None, :]
     return _named_call(
         "flash_eva_bwd",
         functools.partial(_bwd_kernel, scale=scale, block_q=block_q,
@@ -364,7 +369,7 @@ def _bwd_call(q, k, v, kbar, vbar, do, lse, delta, scale, window, per_window,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_FUSED_VMEM_LIMIT),
         interpret=interpret,
-    )(q, k, v, do, lse[:, None, :], delta[:, None, :], kbar, vbar)
+    )(q, k, v, do, lse, delta, kbar, vbar)
 
 
 # ----------------------------------------------------------- the plain form
@@ -409,8 +414,9 @@ def _eva_fwd(q, k, v, kbar, vbar, scale, window, per_window, blocks, path):
     o, lse = _forward(q, k, v, kbar, vbar, scale, window, per_window, blocks,
                       path)
     # the same remat seam as ``flash_attention._flash_fwd``'s
-    o = checkpoint_name(o, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    with elem():
+        o = checkpoint_name(o, "flash_out")
+        lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, kbar, vbar, o, lse)
 
 
@@ -426,8 +432,9 @@ def _eva_bwd(scale, window, per_window, blocks, path, res, do):
                 lambda *a: _jnp_fwd(*a, scale, window, per_window)[0],
                 q, k, v, kbar, vbar)
             return pull(do)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
-    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    with elem():
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     return _bwd_call(q, k, v, kbar, vbar, do, lse, delta, scale, window,
                      per_window, blocks[2], blocks[3], path == "interpret")
 
@@ -474,8 +481,9 @@ def eva_attention(q, k, v, kbar, vbar, window: int, chunk: int,
         group = _walk_group(per_window, T // window)
         metrics.counter("attention.eva_summary_walk_traced", {
             "width": str(group * per_window), "bodies": str(group)}).inc()
-    flat = lambda x: x.reshape(B * H, x.shape[2], D)
-    o = _eva(flat(q), flat(k), flat(v), flat(kbar), flat(vbar), float(scale),
-             int(window), int(per_window), tuple(int(b) for b in blocks),
-             path)
-    return o.reshape(B, H, T, D)
+    with elem():
+        flat = [x.reshape(B * H, x.shape[2], D) for x in (q, k, v, kbar, vbar)]
+    o = _eva(*flat, float(scale), int(window), int(per_window),
+             tuple(int(b) for b in blocks), path)
+    with elem():
+        return o.reshape(B, H, T, D)

@@ -1,4 +1,5 @@
-"""Which body a Pallas kernel of ``ops/`` is traced as."""
+"""Which body a Pallas kernel of ``ops/`` is traced as, and what its wrapper's
+own arithmetic is named."""
 
 from __future__ import annotations
 
@@ -6,7 +7,7 @@ import os
 
 import jax
 
-__all__ = ["kernel_path"]
+__all__ = ["kernel_path", "elem"]
 
 
 def kernel_path() -> str:
@@ -27,3 +28,17 @@ def kernel_path() -> str:
     if jax.default_backend() == "tpu":
         return "mosaic"
     return "interpret" if os.environ.get("MVTPU_FORCE_FLASH") else "jnp"
+
+
+def elem():
+    """The scope ``attn.elem``: what in an attention sub-layer is neither a
+    weight product nor a kernel, before and after it.  In
+    ``models/attention/`` the norms, rotary, reshapes and transposes,
+    padding, slices, convolutions, the decay and the gates' activations; in
+    the kernels' wrappers here what stands around a call (the scale folded
+    into q, a backward's ``delta``, the statistics' slices and broadcasts,
+    ``[B, H, T, D]`` to ``[B * H, T, D]`` and back), so that nothing under
+    ``attn`` is left without a part's name or a kernel's.  A call itself
+    stays outside it: its name stack is the caller's.  Name-stack metadata
+    alone (``benchmarks/trace/parts.py`` reads it)."""
+    return jax.named_scope("attn.elem")

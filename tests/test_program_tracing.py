@@ -4,8 +4,11 @@ session, the ``mv.*`` spans sit where the host work happens, and the
 compiled steps carry the scopes the benchmark's per-layer metrics read."""
 
 import contextlib
+import functools
 import glob
+import os
 import re
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -292,13 +295,33 @@ def test_flash_path_holds_kernel_name(flash_texts, path, kernel):
 LOSS_BEFORE_SCOPES = 4.708159923553467
 
 
-@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
-def test_loss_with_scopes_is_the_loss_without(monkeypatch, remat):
-    from dataclasses import replace
+def _toy(attn, ffn="dense", **over):
+    """One layer of kind ``attn`` with a dense or a routed FFN at toy widths,
+    64 tokens (the least the kernels' dispatch takes), every kind's own sizes
+    given."""
+    model = dict(
+        vocab_size=64, dim=32, n_layers=1, n_heads=2, head_dim=16, hidden=16,
+        max_seq=64, layer_types=[attn], mlp_layer_types=[ffn],
+        sliding_window=8, kv_lora_rank=12, qk_nope_dim=8, qk_rope_dim=4,
+        v_head_dim=8, eva_window=32, eva_chunk=4, scan_layers=True,
+        remat=True, remat_policy="full")
+    if ffn == "sparse":
+        model.update(num_experts=4, top_k=2, moe_dispatch="grouped")
+    model.update(over)
+    return TransformerConfig(**model)
 
-    cfg = replace(CFG, remat=remat)
+
+@pytest.mark.parametrize("config", ["dense", "linear", "latent"])
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_loss_with_scopes_is_the_loss_without(monkeypatch, remat, config):
+    # the kinds' kernels in interpret mode, so that their scopes go too
+    monkeypatch.setenv("MVTPU_FORCE_FLASH", "1")
+    base = {"dense": CFG, "linear": _toy("linear_attention"),
+            "latent": _toy("latent_attention", q_lora_rank=12)}[config]
+    cfg = replace(base, remat=remat)
     params = jax.tree_util.tree_map(jnp.asarray, init_params(cfg, seed=0))
-    toks = jnp.asarray(_tokens())
+    toks = jnp.asarray(_tokens(shape=(2, 16) if config == "dense"
+                               else (1, 64)))
     with_scopes = jax.value_and_grad(lm_loss)(params, toks, cfg)
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
@@ -307,5 +330,103 @@ def test_loss_with_scopes_is_the_loss_without(monkeypatch, remat):
     for a, b in zip(jax.tree_util.tree_leaves(with_scopes[1]),
                     jax.tree_util.tree_leaves(without[1])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_allclose(float(with_scopes[0]), LOSS_BEFORE_SCOPES,
-                               rtol=1e-6)
+    if config == "dense":
+        np.testing.assert_allclose(float(with_scopes[0]), LOSS_BEFORE_SCOPES,
+                                   rtol=1e-6)
+
+
+# ------------------------------------------- (e) a sub-layer's parts (PR 50)
+# ``attn.proj`` / ``attn.elem`` / ``attn.out`` around an attention kind's
+# kernels, ``mlp.up`` / ``mlp.down`` in a dense FFN: read on the chip by
+# ``benchmarks/trace/parts.py``, whose rule (``booked``) is the one held here.
+ATTN_PARTS = ("attn.proj", "attn.elem", "attn.out")
+MLP_PARTS = ("mlp.up", "mlp.down")
+PART_CASES = {
+    # no ``layer_types``: the parts stand directly in ``attn``
+    "untyped": lambda: replace(CFG, max_seq=64, remat_policy="full"),
+    "full": lambda: _toy("full_attention"),
+    "sliding": lambda: _toy("sliding_attention"),
+    "nope": lambda: _toy("full_attention_nope"),
+    "latent_q": lambda: _toy("latent_attention", q_lora_rank=12),
+    "latent": lambda: _toy("latent_attention"),
+    "linear": lambda: _toy("linear_attention"),
+    "eva": lambda: _toy("eva_attention"),
+    "full_gated": lambda: _toy("full_attention", attn_gate="per_head"),
+    "linear_gated": lambda: _toy("linear_attention", attn_gate="per_head"),
+    "qk_norm": lambda: _toy("full_attention", qk_norm=True),
+    "hc": lambda: _toy("latent_attention", hc_mult=2, hc_sinkhorn_iters=2),
+    "routed": lambda: _toy("full_attention", "sparse"),
+}
+@functools.lru_cache(maxsize=None)
+def _part_text(case):
+    """The ``op_name``s of the case's compiled gradient, kernels in interpret
+    mode (a kind's jnp path has no kernel's name to stand under); compiled
+    once a process."""
+    cfg = PART_CASES[case]()
+    before = os.environ.get("MVTPU_FORCE_FLASH")
+    os.environ["MVTPU_FORCE_FLASH"] = "1"
+    try:
+        return _op_names(
+            jax.jit(jax.grad(lambda p, t: lm_loss(p, t, cfg))).lower(
+                init_params(cfg, seed=0),
+                jnp.asarray(_tokens(shape=(1, 64)))).compile().as_text())
+    finally:
+        if before is None:
+            del os.environ["MVTPU_FORCE_FLASH"]
+        else:
+            os.environ["MVTPU_FORCE_FLASH"] = before
+
+
+@pytest.mark.parametrize("part", ATTN_PARTS + MLP_PARTS)
+@pytest.mark.parametrize("case", list(PART_CASES))
+def test_compiled_step_holds_each_part_that_applies(case, part):
+    names = _part_text(case)
+    if case == "routed" and part in MLP_PARTS:
+        # a routed layer's ``mlp`` stays whole: ``model.moe_*`` read it
+        assert not _holds(names, part) and _holds(names, "moe.route")
+        return
+    assert _holds(names, part)
+    inside = "mlp" if part in MLP_PARTS else "attn"
+    if case != "untyped" and part in ATTN_PARTS:
+        from multiverso_tpu.models.attention import KINDS
+
+        inside += "/" + KINDS[PART_CASES[case]().layout.kinds[0].attn].scope
+    held = [n for n in names if f"/{inside}/{part}/" in n]
+    assert held, f"not directly inside {inside}"
+    assert any("transpose(" in n for n in held), "no backward under it"
+
+
+@pytest.mark.parametrize("case", list(PART_CASES))
+def test_the_parts_close_their_scope(case):
+    """No instruction has ``attn``, a kind's scope or a dense layer's ``mlp``
+    as the innermost name it knows: it is under a part, a kernel's name or
+    ``attn.eva.summarise``."""
+    from benchmarks.trace import parts
+
+    open_attn, open_mlp = [], []
+    for n in _part_text(case):
+        known, under, in_attn, in_mlp = parts.booked(n)
+        if in_attn and known is None:
+            open_attn.append(n)
+        if in_mlp and under != "mlp":
+            open_mlp.append(n)
+    assert not open_attn
+    if case == "routed":
+        assert open_mlp
+    else:
+        assert not open_mlp
+
+
+@pytest.mark.parametrize("case", list(PART_CASES))
+def test_a_kernel_sits_under_no_part(case):
+    """A kernel's call keeps the name stack it had: the kind's scope, then the
+    kernel's own name, and no part between or inside."""
+    from benchmarks.trace import parts, program
+
+    under_kernel = [n for n in _part_text(case)
+                    if any(name.startswith(k) for k in parts.KERNELS
+                           for name, _ in program.components(n))]
+    assert under_kernel, "the kernels' jnp path was traced"
+    for n in under_kernel:
+        assert not any(name in parts.PARTS
+                       for name, _ in program.components(n)), n
