@@ -28,8 +28,21 @@ keeps the output accumulator and the streaming-softmax statistics (m, l)
 in VMEM scratch across k-steps, writing the normalized output and the
 row logsumexp once on the last step.  Score/accumulator math is float32
 regardless of input dtype; the matmuls run on the MXU in the input dtype.
-Fully-masked causal blocks are skipped with ``pl.when`` — the causal
-schedule does half the FLOPs, which the XLA dense path cannot do.
+The causal schedule does half the FLOPs, which the XLA dense path cannot
+do, and **a causal forward's grid holds a step only where there is a tile**
+(PR 51; ``flash_fwd`` and ``flash_mla_fwd``): q block ``i`` and q block
+``num_q - 1 - i`` compute ``num_k + 1`` k blocks between them, so the grid
+is ``(batch·head, num_q / 2, num_k + 1)`` and row ``p`` walks q block
+``p``'s k blocks and then q block ``num_q - 1 - p``'s (``_fold_step``:
+arithmetic on the program ids in the index maps, no operand), 72 steps a
+head for 128 at 8,192 tokens and 512 x 1024 blocks.  The shapes decide
+(``_fwd_grid``; counter ``attention.fwd_traced{grid=folded|clamped|full|
+band}``): a causal call the fold does not cover (``num_q`` odd or 1,
+``block_q > block_k``) and the split backward's ``flash_bwd_dq`` keep a
+grid over every k block, skip the blocks above the diagonal with
+``pl.when`` and **clamp their K/V index to the row's last computed block**
+(``_kv_index``), as the band's grids and the fused backwards do, so that no
+block is fetched for a step that computes nothing.
 
 Differentiation is a ``jax.custom_vjp``: the forward saves (q, k, v, o,
 lse) and the backward rebuilds the probability blocks from lse instead of
@@ -178,18 +191,88 @@ def _in_band(computed, full, qi, ki, block_q, block_k, window):
     return computed, full
 
 
+# ---- the causal forward's grid.  Without a window q block ``i`` computes the
+# k blocks ``0 .. _last_k(i)``, a staircase: a grid over every k block would
+# spend 44% of its steps at 8,192 tokens (512 x 1024 blocks) on blocks above
+# the diagonal.  With ``r = block_k // block_q`` q block ``p`` computes ``p //
+# r + 1`` k blocks and q block ``num_q - 1 - p`` computes ``num_k - p // r``:
+# together ``num_k + 1``, whatever ``p``.  So the grid is FOLDED: ``(head,
+# num_q / 2, num_k + 1)``, row ``p`` walking q block ``p``'s k blocks and
+# then q block ``num_q - 1 - p``'s, each in ascending order, and every step
+# has a tile.  The schedule is arithmetic on the program ids inside the index
+# maps and the kernel (no prefetched table, no operand).
+def _fwd_grid(causal, window, num_q, block_q, block_k):
+    """Which grid a forward call runs, from its shapes alone: ``band`` (a
+    window: the band's steps, clamped), ``folded`` (causal, ``num_q`` even,
+    ``block_k`` a multiple of ``block_q``: a step only where there is a
+    tile), ``clamped`` (any other causal call: every k block a q block, the
+    steps past the diagonal skipped with their K/V index clamped to the
+    row's last computed block, so that nothing is fetched for them) or
+    ``full`` (not causal: every block is computed)."""
+    if window is not None:
+        return "band"
+    if not causal:
+        return "full"
+    if num_q % 2 == 0 and block_k % block_q == 0:
+        return "folded"
+    return "clamped"
+
+
+def _fold_step(p, j, num_q, r):
+    """Step ``j`` of the folded grid's row ``p``: ``(q block, k block, first,
+    last)``, ``first`` / ``last`` on the q block's first and last k block.
+    Plain arithmetic, so that program ids and numpy arrays both pass."""
+    short = p // r + 1                   # k blocks of q block p
+    second = (j >= short) * 1            # 0 or 1: in the long half
+    qi = p + second * (num_q - 1 - 2 * p)
+    ki = j - second * short
+    first = (j == 0) | (j == short)
+    last = (j == short - 1) | (j == num_q // r)
+    return qi, ki, first, last
+
+
+def _fwd_step(fold, num_k):
+    """``(q block, k step, first, last)`` of this grid step of a forward
+    kernel: the folded schedule (``fold = (num_q, r)``), or ``(program_id(1),
+    program_id(2))`` of a grid ``(head, q block, k step)``."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    if fold is not None:
+        return _fold_step(i, j, *fold)
+    return i, j, j == 0, j == num_k - 1
+
+
+def _causal_tile(compute, qi, ki, block_q, block_k, window=None,
+                 every_step_computed=False):
+    """Run ``compute(masked)`` for tile (qi, ki) of a causal forward.  Three
+    block classes: strictly-above-diagonal blocks contribute nothing (skip:
+    half the FLOPs); blocks fully below the diagonal need no mask (skip the
+    iota/compare/select VPU passes); only diagonal-straddling blocks pay for
+    masking.  The folded grid holds no block of the first class
+    (``every_step_computed``), so two remain."""
+    full = qi * block_q >= ki * block_k + block_k - 1
+    if every_step_computed:
+        pl.when(full)(lambda: compute(False))
+        pl.when(jnp.logical_not(full))(lambda: compute(True))
+        return
+    computed = ki * block_k <= qi * block_q + block_q - 1
+    if window is not None:
+        computed, full = _in_band(computed, full, qi, ki, block_q, block_k,
+                                  window)
+    pl.when(computed & full)(lambda: compute(False))
+    pl.when(computed & jnp.logical_not(full))(lambda: compute(True))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
-                causal, block_q, block_k, num_k, window=None):
+                causal, block_q, block_k, num_k, window=None, fold=None):
     # q arrives PRE-SCALED (softmax scale folded into the [T, D] input —
     # one multiply per q element instead of one per [Bq, Bk] score).
-    # ``num_k`` is the grid's extent: every k block, or the band's steps.
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    ki = step
+    # ``num_k`` is the grid's extent: every k block, or the band's steps;
+    # ``fold = (num_q, r)`` where the grid is the folded one (``_fold_step``).
+    qi, ki, first, last = _fwd_step(fold, num_k)
     if window is not None:
-        ki = _first_k(qi, block_q, block_k, window) + step
+        ki = _first_k(qi, block_q, block_k, window) + ki
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _NEG)
@@ -217,21 +300,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         l_scr[:, 0:1] = l_new
 
     if causal:
-        # Three block classes: strictly-above-diagonal blocks contribute
-        # nothing (skip: half the FLOPs); blocks fully below the diagonal
-        # need no mask (skip the iota/compare/select VPU passes);
-        # only diagonal-straddling blocks pay for masking.
-        computed = ki * block_k <= qi * block_q + block_q - 1
-        full = qi * block_q >= ki * block_k + block_k - 1
-        if window is not None:
-            computed, full = _in_band(computed, full, qi, ki, block_q,
-                                      block_k, window)
-        pl.when(computed & full)(lambda: _compute(False))
-        pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+        _causal_tile(_compute, qi, ki, block_q, block_k, window,
+                     every_step_computed=fold is not None)
     else:
         _compute(False)
 
-    @pl.when(step == num_k - 1)
+    @pl.when(last)
     def _finalize():
         l = jnp.maximum(l_scr[:, 0:1], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
@@ -464,49 +538,81 @@ def _named_call(name, kernel, **kwargs):
     return scoped
 
 
-def _kv_index(block_q, block_k, window, group):
+def _kv_index(block_q, block_k, window, group, causal, fold=None):
     """Index map of a K/V block for the grids ``(head, q block, k step)``:
     query head ``b`` reads K/V head ``b // group`` (nothing is repeated in
-    HBM); with a window, step ``j`` is the band's j-th block, clamped."""
+    HBM); with a window, step ``j`` is the band's j-th block.  A causal
+    row's steps past its last computed block keep that block's index: they
+    are skipped, and nothing is fetched for them.  On the folded grid
+    (``fold``) every step is a tile, ``_fold_step``'s."""
     def index(b, i, j):
-        if window is not None:
-            j = jnp.minimum(_first_k(i, block_q, block_k, window) + j,
-                            _last_k(i, block_q, block_k))
+        if fold is not None:
+            j = _fold_step(i, j, *fold)[1]
+        else:
+            if window is not None:
+                j = _first_k(i, block_q, block_k, window) + j
+            if causal:
+                j = jnp.minimum(j, _last_k(i, block_q, block_k))
         return (b if group == 1 else b // group, j, 0)
 
     return index
+
+
+def _fwd_plan(causal, window, num_q, num_k, block_q, block_k):
+    """A forward call's grid, from its shapes (``_fwd_grid``): ``(kind, fold,
+    (rows, steps), q_index, kv_index)``.  ``fold`` is the kernel's (``(num_q,
+    r)``, or ``None`` for a grid ``(head, q block, k step)``), ``q_index`` the
+    index map of the q, o and lse blocks and ``kv_index(group)`` that of a
+    K/V block read by ``group`` query heads."""
+    kind = _fwd_grid(causal, window, num_q, block_q, block_k)
+    fold = None
+    if kind == "folded":
+        fold = (num_q, block_k // block_q)
+        num_q, num_k = num_q // 2, num_k + 1
+    elif kind == "band":
+        num_k, _ = _band_steps(num_q, num_k, block_q, block_k, window)
+
+    def q_index(b, i, j):
+        return (b, i if fold is None else _fold_step(i, j, *fold)[0], 0)
+
+    def kv_index(group):
+        return _kv_index(block_q, block_k, window, group, causal, fold)
+
+    return kind, fold, (num_q, num_k), q_index, kv_index
 
 
 def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret,
               window=None, group=1):
     """q [bh, Tq, D], k/v [bh / group, Tk, D] → (o [bh, Tq, D], lse [bh, Tq]
     f32)."""
+    from .. import metrics
+
     bh, Tq, D = q.shape
     Tk = k.shape[1]
     num_q = Tq // block_q
     num_k = Tk // block_k
-    if window is not None:
-        num_k, _ = _band_steps(num_q, num_k, block_q, block_k, window)
+    kind, fold, grid, q_index, kv_index = _fwd_plan(
+        causal, window, num_q, num_k, block_q, block_k)
+    metrics.counter("attention.fwd_traced", {"grid": kind}).inc()
     # Scale folded into q ([T, D] once), not into every [Bq, Bk] score.
     with elem():
         q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     kernel = functools.partial(_fwd_kernel, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               num_k=num_k, window=window)
-    kv_spec = pl.BlockSpec((1, block_k, D),
-                           _kv_index(block_q, block_k, window, group))
+                               num_k=grid[1], window=window, fold=fold)
+    kv_spec = pl.BlockSpec((1, block_k, D), kv_index(group))
     o, lse = _named_call(
         "flash_fwd" if window is None else "flash_win_fwd",
         kernel,
-        grid=(bh, num_q, num_k),
+        grid=(bh, *grid),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, D), q_index),
             kv_spec,
             kv_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, D), q_index),
+            pl.BlockSpec((1, block_q, _LANES), q_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, Tq, D), q.dtype),
@@ -687,7 +793,7 @@ def _bwd_split(q, k, v, do, lse, delta, scale, causal, block_q, block_k,
 
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, D),
-                           _kv_index(block_q, block_k, window, group))
+                           _kv_index(block_q, block_k, window, group, causal))
     dq = _named_call(
         "flash_bwd_dq" if window is None else "flash_win_bwd_dq",
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -781,6 +887,14 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
     kernels otherwise; the shapes decide (``_fused_fits``), no argument
     does.
 
+    The forward's grid follows the shapes as well (``_fwd_grid``): causal
+    without a window it is folded, one row of ``num_k + 1`` steps for q
+    blocks ``p`` and ``num_q - 1 - p`` and a tile on every step, where
+    ``num_q`` is even and ``block_k`` a multiple of ``block_q`` (every
+    power-of-two length from two q blocks up); any other causal call walks
+    every k block and fetches nothing for the steps it skips; a call that is
+    not causal computes every block.
+
     ``causal=True`` requires Tq == Tk (the standard aligned causal mask);
     cross-length blocks (ring attention's low/high steps) use
     ``causal=False``.  Fully differentiable via ``jax.custom_vjp`` —
@@ -860,13 +974,14 @@ def flash_attention(q, k, v, scale: Optional[float] = None,
 # Latent attention's decompressed form: scores from an unrotated part a head
 # and a rotated part whose key is one head shared by every query head;
 # values of their own width.  Causal, no window.  Same streaming softmax,
-# same pre-scaled q (both parts), same three block classes as above.
+# same pre-scaled q (both parts), same grid and block classes as above.
 def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
-                    acc, m_scr, l_scr, *, block_q, block_k, num_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+                    acc, m_scr, l_scr, *, block_q, block_k, num_k,
+                    fold=None):
+    # ``_fwd_kernel``'s grid: folded (``fold``), or every k block, clamped
+    qi, ki, first, last = _fwd_step(fold, num_k)
 
-    @pl.when(ki == 0)
+    @pl.when(first)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, _NEG)
@@ -889,12 +1004,10 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
         m_scr[:, 0:1] = m_new
         l_scr[:, 0:1] = l_new
 
-    computed = ki * block_k <= qi * block_q + block_q - 1
-    full = qi * block_q >= ki * block_k + block_k - 1
-    pl.when(computed & full)(lambda: _compute(False))
-    pl.when(computed & jnp.logical_not(full))(lambda: _compute(True))
+    _causal_tile(_compute, qi, ki, block_q, block_k,
+                 every_step_computed=fold is not None)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(last)
     def _finalize():
         l = jnp.maximum(l_scr[:, 0:1], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
@@ -1009,27 +1122,33 @@ def _mla_fwd_impl(qn, qr, kn, kr, v, scale, heads, block_q, block_k,
                   interpret):
     """qn/kn [bh, T, Dn], qr [bh, T, Dr], kr [b, T, Dr], v [bh, T, Dv] →
     (o [bh, T, Dv], lse [bh, T] f32)."""
+    from .. import metrics
+
     bh, T, Dn = qn.shape
     Dr, Dv = qr.shape[-1], v.shape[-1]
     num_q, num_k = T // block_q, T // block_k
+    # ``_fwd_impl``'s grid; the rotated key is one head that all ``heads``
+    # read
+    kind, fold, grid, q_index, kv_index = _fwd_plan(
+        True, None, num_q, num_k, block_q, block_k)
+    metrics.counter("attention.fwd_traced", {"grid": kind}).inc()
     with elem():
         qn = (qn.astype(jnp.float32) * scale).astype(qn.dtype)
         qr = (qr.astype(jnp.float32) * scale).astype(qr.dtype)
 
     def q_spec(width):
-        return pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0))
+        return pl.BlockSpec((1, block_q, width), q_index)
 
     def k_spec(width):
-        return pl.BlockSpec((1, block_k, width), lambda b, i, j: (b, j, 0))
+        return pl.BlockSpec((1, block_k, width), kv_index(1))
 
     o, lse = _named_call(
         "flash_mla_fwd",
         functools.partial(_mla_fwd_kernel, block_q=block_q, block_k=block_k,
-                          num_k=num_k),
-        grid=(bh, num_q, num_k),
+                          num_k=grid[1], fold=fold),
+        grid=(bh, *grid),
         in_specs=[q_spec(Dn), q_spec(Dr), k_spec(Dn),
-                  pl.BlockSpec((1, block_k, Dr),
-                               lambda b, i, j: (b // heads, j, 0)),
+                  pl.BlockSpec((1, block_k, Dr), kv_index(heads)),
                   k_spec(Dv)],
         out_specs=[q_spec(Dv), q_spec(_LANES)],
         out_shape=[jax.ShapeDtypeStruct((bh, T, Dv), v.dtype),

@@ -33,11 +33,22 @@ def bwd_path(request, monkeypatch):
     assert _bwd_traced(request.param) > before
 
 
+def _fwd_traced(grid):
+    return metrics.counter("attention.fwd_traced", {"grid": grid}).value
+
+
+# The causal forward's grid by shape (``fa._fwd_grid``): folded where num_q
+# is even and block_k a multiple of block_q, else every k block with the K/V
+# index clamped; not causal, every block.
 @pytest.mark.parametrize("B,H,T,D,bq,bk", [
-    (2, 2, 256, 64, 128, 128),
-    (1, 4, 128, 32, 64, 32),
-    (2, 1, 64, 64, 64, 64),
+    (2, 2, 256, 64, 128, 128),          # folded, num_q = 2
+    (1, 4, 128, 32, 64, 32),            # clamped: block_q > block_k
+    (2, 1, 64, 64, 64, 64),             # clamped: num_q = 1
     (1, 2, 256, 128, 256, 64),
+    (1, 2, 512, 32, 64, 128),           # folded, r = 2, num_q = 8
+    (1, 2, 256, 32, 64, 64),            # folded, r = 1
+    (1, 1, 256, 32, 32, 128),           # folded, r = 4
+    (1, 1, 1536, 32, 512, 1024),        # clamped: blocks 512 x 512, num_q = 3
 ])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_matches_dense(B, H, T, D, bq, bk, causal):
@@ -94,7 +105,11 @@ def _dense_loss(q, k, v, causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("T,bq,bk", [(128, 64, 64), (256, 128, 128)])
+@pytest.mark.parametrize("T,bq,bk", [
+    (128, 64, 64), (256, 128, 128),
+    (512, 64, 128),                     # folded, r = 2
+    (384, 128, 128),                    # clamped, num_q = 3
+])
 def test_flash_grad_matches_dense(causal, T, bq, bk, bwd_path):
     rng = np.random.RandomState(2)
     B, H, D = 1, 2, 32
@@ -375,3 +390,178 @@ def test_backward_over_the_budget_takes_the_split_kernels():
     want = jax.grad(_dense_loss, (0, 1, 2))(q, k, v, False)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The causal forward's grid (PR 51): a step only where there is a tile.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("num_q,r", [
+    (num_q, r) for r in (1, 2, 4) for num_q in range(2, 33, 2)
+    if num_q % r == 0])
+def test_folded_schedule_visits_each_tile_once(num_q, r):
+    """``_fold_step`` on the host: row p's ``num_k + 1`` steps are q block
+    p's k blocks and then q block ``num_q - 1 - p``'s.  Every computed (q
+    block, k block) exactly once, k ascending within a q block, a q block's
+    steps consecutive with ``first`` / ``last`` on their ends, and no step
+    without a tile."""
+    num_k = num_q // r
+    p, j = np.meshgrid(np.arange(num_q // 2), np.arange(num_k + 1),
+                       indexing="ij")
+    qi, ki, first, last = (np.asarray(x).ravel()      # in the grid's order
+                           for x in fa._fold_step(p, j, num_q, r))
+    assert np.all((0 <= ki) & (ki <= qi // r))        # a tile on every step
+    want = {(a, b) for a in range(num_q) for b in range(a // r + 1)}
+    assert len(qi) == len(want)
+    assert set(zip(qi.tolist(), ki.tolist())) == want
+    # q blocks in runs of consecutive steps, each run k = 0, 1, .. in order
+    starts = np.flatnonzero(np.r_[True, qi[1:] != qi[:-1]])
+    assert len(starts) == num_q == len(set(qi[starts].tolist()))
+    for lo, hi in zip(starts, np.r_[starts[1:], len(qi)]):
+        assert ki[lo:hi].tolist() == list(range(hi - lo))
+    assert np.flatnonzero(first).tolist() == starts.tolist()
+    assert np.flatnonzero(last).tolist() == (
+        np.r_[starts[1:], len(qi)] - 1).tolist()
+    # the call's grid and index maps are that schedule's
+    kind, fold, grid, q_index, kv_index = fa._fwd_plan(
+        True, None, num_q, num_k, 64, 64 * r)
+    assert (kind, fold, grid) == ("folded", (num_q, r),
+                                  (num_q // 2, num_k + 1))
+    assert np.asarray(q_index(5, p, j)[1]).ravel().tolist() == qi.tolist()
+    assert np.asarray(kv_index(2)(5, p, j)[1]).ravel().tolist() == ki.tolist()
+    assert (q_index(5, 0, 0)[0], kv_index(2)(5, 0, 0)[0]) == (5, 2)
+
+
+@pytest.mark.parametrize("num_q,block_q,block_k", [
+    (3, 512, 512), (1, 64, 64), (5, 16, 16), (4, 64, 32), (8, 256, 64),
+    (7, 32, 64)])
+def test_clamped_grid_fetches_no_block_it_skips(num_q, block_q, block_k):
+    """A causal call the fold does not take keeps the grid ``(q block, every
+    k block)``; the K/V index of a step past the diagonal is the row's last
+    computed block's, so the pipeline fetches nothing for it."""
+    T = num_q * block_q
+    num_k = -(-T // block_k)
+    assert fa._fwd_grid(True, None, num_q, block_q, block_k) == "clamped"
+    index = fa._kv_index(block_q, block_k, None, 1, causal=True)
+    for i in range(num_q):
+        last = (i * block_q + block_q - 1) // block_k
+        got = [int(index(0, i, j)[1]) for j in range(num_k)]
+        assert got == [min(j, last) for j in range(num_k)]
+    # not causal: every block is computed, and fetched
+    index = fa._kv_index(block_q, block_k, None, 1, causal=False)
+    assert [int(index(0, 0, j)[1]) for j in range(num_k)] == list(range(num_k))
+
+
+def test_the_grid_is_decided_by_the_shapes():
+    # every cell's causal forward (2,048 to 16,384 tokens at the dispatch's
+    # 512 x 1024 blocks) is folded
+    for T in (2048, 4096, 8192, 16384):
+        bq, bk = fa.fit_block(512, T), fa.fit_block(1024, T)
+        assert fa._fwd_grid(True, None, T // bq, bq, bk) == "folded"
+    assert fa._fwd_grid(True, None, 2, 128, 128) == "folded"
+    assert fa._fwd_grid(True, None, 3, 512, 512) == "clamped"    # T = 1,536
+    assert fa._fwd_grid(True, None, 1, 64, 64) == "clamped"
+    assert fa._fwd_grid(True, None, 4, 64, 32) == "clamped"
+    assert fa._fwd_grid(False, None, 16, 512, 1024) == "full"
+    assert fa._fwd_grid(True, 512, 16, 512, 512) == "band"
+
+
+# id: (grid, H, KV, T, block_q, block_k, causal, window, dtype)
+GRID_CASES = {
+    "folded-r2": ("folded", 2, 2, 512, 64, 128, True, None, jnp.float32),
+    "folded-r1": ("folded", 2, 2, 256, 64, 64, True, None, jnp.float32),
+    "folded-r4": ("folded", 1, 1, 256, 32, 128, True, None, jnp.float32),
+    "folded-num_q-2": ("folded", 2, 2, 128, 64, 128, True, None,
+                       jnp.float32),
+    "folded-group-2": ("folded", 4, 2, 512, 64, 128, True, None,
+                       jnp.float32),
+    "folded-group-7": ("folded", 7, 1, 256, 32, 64, True, None, jnp.float32),
+    "folded-bf16": ("folded", 4, 2, 512, 64, 128, True, None, jnp.bfloat16),
+    "clamped-odd": ("clamped", 2, 2, 1536, 512, 1024, True, None,
+                    jnp.float32),
+    "clamped-odd-group": ("clamped", 4, 2, 384, 128, 128, True, None,
+                          jnp.float32),
+    "clamped-num_q-1": ("clamped", 2, 2, 64, 64, 64, True, None,
+                        jnp.float32),
+    "clamped-wide-q": ("clamped", 2, 1, 256, 128, 32, True, None,
+                       jnp.float32),
+    "full": ("full", 2, 2, 256, 64, 128, False, None, jnp.float32),
+    "band": ("band", 4, 2, 256, 64, 64, True, 100, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", list(GRID_CASES))
+def test_each_forward_grid_matches_dense_and_is_counted(name):
+    """``o`` and ``lse`` (``return_lse``) of every grid the forward can take
+    against dense float32 attention; one trace counts its grid once in
+    ``attention.fwd_traced{grid=}`` and no other."""
+    grid, H, KV, T, bq, bk, causal, window, dtype = GRID_CASES[name]
+    rng = np.random.RandomState(len(name))
+    q = jnp.asarray(rng.randn(1, H, T, 32), dtype)
+    k = jnp.asarray(rng.randn(1, KV, T, 32), dtype)
+    v = jnp.asarray(rng.randn(1, KV, T, 32), dtype)
+    kinds = ("folded", "clamped", "full", "band")
+    before = [_fwd_traced(g) for g in kinds]
+    o, lse = flash_attention(q, k, v, causal=causal, window=window,
+                             block_q=bq, block_k=bk, interpret=True,
+                             return_lse=True)
+    assert [_fwd_traced(g) - b for g, b in zip(kinds, before)] == [
+        float(g == grid) for g in kinds]
+    want_o, want_lse = _dense_out_lse(q, k, v, causal, window)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(want_o), atol=tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=tol)
+
+
+def _dense_latent(qn, qr, kn, kr, v, scale):
+    T = qn.shape[2]
+    s = (jnp.einsum("bhtd,bhsd->bhts", qn, kn)
+         + jnp.einsum("bhtd,bsd->bhts", qr, kr[:, 0])) * scale
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+    return (jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(s, axis=-1), v),
+            jax.scipy.special.logsumexp(s, axis=-1))
+
+
+@pytest.mark.parametrize("grid,T,bq,bk", [
+    ("folded", 512, 64, 128), ("folded", 256, 64, 64),
+    ("clamped", 384, 128, 128), ("clamped", 64, 64, 64)],
+    ids=["folded-r2", "folded-r1", "clamped-odd", "clamped-num_q-1"])
+def test_latent_forward_grids_match_dense_and_are_counted(grid, T, bq, bk):
+    """The two-width forward takes the same grids by the same rule: o and
+    lse below the wrapper (which drops lse), and the five gradients through
+    it, against dense attention (tests/test_xing.py's tolerance)."""
+    from multiverso_tpu.ops.flash_attention import flash_attention_latent
+
+    rng = np.random.RandomState(T)
+    B, H, dn, dr, dv = 1, 3, 16, 8, 24
+    scale = 0.3
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32))
+
+    args = (draw(B, H, T, dn), draw(B, H, T, dr), draw(B, H, T, dn),
+            draw(B, 1, T, dr), draw(B, H, T, dv))
+    weight = draw(B, H, T, dv)
+
+    def rel(a, b):
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    with jax.default_matmul_precision("highest"):
+        before = _fwd_traced("folded"), _fwd_traced("clamped")
+        flat = [a.reshape(-1, T, a.shape[-1]) for a in args]
+        o, lse = fa._mla_fwd_impl(*flat, scale, H, bq, bk, True)
+        assert (_fwd_traced("folded") - before[0],
+                _fwd_traced("clamped") - before[1]) == (
+                    float(grid == "folded"), float(grid == "clamped"))
+        want_o, want_lse = _dense_latent(*args, scale)
+        assert rel(o.reshape(want_o.shape), want_o) < 1e-5
+        assert rel(lse.reshape(want_lse.shape), want_lse) < 1e-5
+        got = jax.grad(lambda *a: jnp.sum(flash_attention_latent(
+            *a, scale=scale, block_q=bq, block_k=bk, block_q_bwd=bq,
+            block_k_bwd=bk, interpret=True) * weight), argnums=range(5))(*args)
+        want = jax.grad(lambda *a: jnp.sum(_dense_latent(*a, scale)[0]
+                                           * weight), argnums=range(5))(*args)
+    for g, w, a in zip(got, want, args):
+        assert g.shape == a.shape and rel(g, w) < 1e-5
